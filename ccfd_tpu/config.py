@@ -424,9 +424,9 @@ class Config:
     batch_workers: int = 4  # overlapped dispatches (device-RTT pipelining)
     dynamic_batching: bool = True  # serving-side request coalescing
     native_front: bool = True  # C++ HTTP front when the toolchain allows
-    host_tier_rows: int = -1  # -1 = auto: measured at scorer warmup (host
-    # forward rate vs device dispatch RTT, crossover at RTT/2, <=8192;
-    # 256 provisionally until warmup runs); 0 = off; >0 = fixed threshold
+    host_tier_rows: int = -1  # request batches of at most this many rows
+    # score on the host in numpy instead of the device; -1 = auto, which
+    # is off (0) on every backend; >0 = explicit threshold
     dispatch_deadline_ms: float = -1.0  # server-side device-dispatch bound
     # (the reference's SELDON_TIMEOUT applied inside the server): -1 = auto
     # (accelerator backends: seldon_timeout_ms; cpu/mesh: off), 0 = off,

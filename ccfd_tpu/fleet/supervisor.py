@@ -19,6 +19,14 @@ The supervisor is the drill/ops actor around them:
 Nothing here runs inside a member: the supervisor is bus-client + process
 babysitter only, so killing IT loses no fleet state (membership is
 gossip, ownership is the bus's consumer group).
+
+The supervisor never initialises JAX, and must not: a chip belongs to one
+process at a time, and every member is a JAX command under the one backend
+rule (utils/backend.py). On a one-chip host only ONE member can hold the
+chip; members beyond the first run with ``JAX_PLATFORMS=cpu`` (pass it in
+``env``) or on a chip of their own (one member per host, or a per-member
+device-visibility setting in ``env``). The drills (tools/fleet_drill.py,
+tools/fleet_smoke.py) run every member on the CPU.
 """
 
 from __future__ import annotations

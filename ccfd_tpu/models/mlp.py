@@ -91,11 +91,11 @@ def apply(params: Params, x: jax.Array, compute_dtype=jnp.bfloat16) -> jax.Array
 def apply_numpy(params: Params, x: np.ndarray) -> np.ndarray:
     """Pure-numpy forward (f32), semantically `apply` without a device.
 
-    The serving host tier uses this for small request batches when the
-    accelerator sits behind a high-RTT attachment: a 3-layer MLP at
-    16-256 rows is tens of microseconds on the host, versus a full device
-    round trip. Tolerance vs the bf16 device path is ~1e-2 in probability
-    (asserted by tests); params must be host numpy arrays.
+    The float32 reference the device paths are compared against (the
+    chip smoke, the parity tests), and what the wedge fallback, the
+    challenger slot and an explicit host tier score with. Tolerance vs the
+    bf16 device path is ~1e-2 in probability (asserted by tests); params
+    must be host numpy arrays.
     """
     from ccfd_tpu.utils.metrics_math import stable_sigmoid
 

@@ -21,8 +21,8 @@ class ModelSpec:
     apply: Callable[..., jax.Array]  # (params, x) -> proba_1 (B,)
     logits: Callable[..., jax.Array]
     trainable: bool
-    # optional pure-numpy forward: enables the serving host latency tier
-    # (small batches skip the device round trip on high-RTT attachments)
+    # optional pure-numpy forward: the host reference, the wedge fallback,
+    # the challenger slot and an explicit host tier score with it
     apply_numpy: Callable[..., Any] | None = None
 
 

@@ -90,14 +90,14 @@ def logits_mxu(params: Params, x: jax.Array) -> jax.Array:
     FLOP cost grows (every node evaluates, not just the D on the path),
     but the work is MXU-shaped and gather-free — the same trade the
     dense tree embedding itself makes. Exact same semantics as
-    :func:`logits` (parity-tested); choose per backend via the
-    ``gbt_mxu`` registry entry.
+    :func:`logits` (parity-tested on the CPU and, by chip_smoke.py, on the
+    chip); choose per backend via the ``gbt_mxu`` registry entry.
 
     Measured regimes (BASELINE.md "Model variants"): on CPU the gather
     path wins decisively (221k vs 79k tx/s, BENCH_r02 zoo) — extra FLOPs
     with no systolic array to feed them to. The MXU inversion is the
     HYPOTHESIS this variant exists to test; treat ``gbt_mxu`` as
-    experimental until an on-TPU zoo capture records it winning.
+    experimental until an on-chip A/B records it winning.
     """
     feat, thr, leaf = params["feature"], params["threshold"], params["leaf"]
     n_trees = leaf.shape[0]
@@ -116,7 +116,13 @@ def logits_mxu(params: Params, x: jax.Array) -> jax.Array:
     onehot = jax.nn.one_hot(
         feat.reshape(-1), x.shape[1], dtype=x.dtype
     ).T  # (F, T*nI)
-    xv = (x_safe @ onehot).reshape(x.shape[0], n_trees, n_int)
+    # HIGHEST: the MXU's default f32 matmul rounds its operands to bf16,
+    # and a feature value rounded before it meets its threshold takes the
+    # wrong branch (6e-2 in probability against the gather path at 16,384
+    # rows on a v5e). The multi-pass form reproduces every selected f32
+    # value exactly — the one-hot columns are 0/1.
+    xv = jnp.matmul(x_safe, onehot, precision=jax.lax.Precision.HIGHEST)
+    xv = xv.reshape(x.shape[0], n_trees, n_int)
     dec = (xv > thr[None]).astype(jnp.int32)  # (B, T, nI)
     idx = jnp.zeros((x.shape[0], n_trees), jnp.int32)
     for _ in range(depth):

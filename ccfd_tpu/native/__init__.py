@@ -1,7 +1,8 @@
 """Native (C++) hot-path bindings with a transparent numpy fallback.
 
-Builds ``decode.cpp`` with g++ on first import (cached next to the source),
-loads it via ctypes, and exposes:
+Builds ``decode.cpp`` / ``log.cpp`` / ``httpfront.cpp`` with g++ on first
+import (cached next to the sources), loads the result via ctypes, and
+exposes:
 
 - ``decode_csv(data: bytes, n_features) -> (np.ndarray (B, F) f32, bad_rows)``
 - ``pad_batch(x, bucket_rows) -> np.ndarray (bucket, F) f32``
@@ -9,12 +10,21 @@ loads it via ctypes, and exposes:
 If no toolchain is available the numpy implementations (identical
 semantics, asserted by tests/test_native.py) are used — the framework never
 hard-requires a compiler at runtime.
+
+The library is always the one built from the sources on disk for the
+machine it runs on: its file name carries a digest of the three sources,
+the compiler flags and — because the default ``-march=native`` means "this
+CPU" — the host CPU's identity. A ``.so`` copied in from another machine,
+or left over from before a source edit, has another name and is never
+loaded while the sources are present.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -26,6 +36,7 @@ _SRCS = [
     os.path.join(_HERE, "log.cpp"),
     os.path.join(_HERE, "httpfront.cpp"),
 ]
+# the un-digested name: only a package shipped WITHOUT its sources loads it
 _SO = os.path.join(_HERE, "_ccfd_native.so")
 
 _lib = None
@@ -33,30 +44,66 @@ _lib_lock = threading.Lock()
 _build_failed = False
 
 
-def _build() -> str | None:
-    present = [s for s in _SRCS if os.path.exists(s)]
-    if os.path.exists(_SO) and (
-        len(present) < len(_SRCS)  # sources (partially) stripped: a
-        # rebuild is impossible, so trust the shipped .so
-        or os.path.getmtime(_SO) >= max(os.path.getmtime(s) for s in present)
-    ):
-        return _SO
-    if len(present) < len(_SRCS):
-        return None  # no .so and no complete sources: numpy fallback
+def _host_fingerprint() -> str:
+    """Short stable id for this host's CPU: ``-march=native`` machine code
+    built on one CPU can SIGILL on another, so the id is part of the
+    library's name whenever the flags say ``native``."""
+    material = platform.machine()
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    material += line
+                    break
+    except OSError:
+        material += platform.processor()
+    return hashlib.sha256(material.encode()).hexdigest()[:12]
+
+
+def _flags() -> list[str]:
     # CCFD_NATIVE_MARCH overrides the target microarchitecture: container
     # images built on one CPU and deployed to another must NOT bake the
     # builder's -march=native (a zmm-tuned .so can SIGILL on the deploy
     # node) — e.g. x86-64-v3 is the portable-with-AVX2 choice
     march = os.environ.get("CCFD_NATIVE_MARCH", "native")
+    return ["-O3", f"-march={march}", "-shared", "-fPIC", "-pthread"]
+
+
+def _so_path(flags: list[str]) -> str:
+    """Where the library built from the present sources with ``flags`` on
+    this host lives: next to ``_SO``, named by the digest of everything the
+    machine code depends on."""
+    h = hashlib.sha256()
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    h.update(" ".join(flags).encode())
+    if "-march=native" in flags:
+        h.update(_host_fingerprint().encode())
+    return os.path.join(os.path.dirname(_SO),
+                        f"_ccfd_native.{h.hexdigest()[:16]}.so")
+
+
+def _build() -> str | None:
+    if not all(os.path.exists(s) for s in _SRCS):
+        # sources (partially) stripped: a rebuild is impossible, so trust
+        # the shipped .so; with neither, the numpy fallback
+        return _SO if os.path.exists(_SO) else None
+    flags = _flags()
+    so = _so_path(flags)
+    if os.path.exists(so):
+        return so
+    tmp = f"{so}.{os.getpid()}.tmp"  # concurrent importers race to publish
     try:
         subprocess.run(
-            ["g++", "-O3", f"-march={march}", "-shared", "-fPIC", "-pthread",
-             *_SRCS, "-o", _SO],
+            ["g++", *flags, *_SRCS, "-o", tmp],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        return _SO
+        # ccfd-lint: disable=durability-seam -- a build output, rebuilt from source whenever absent; the rename only keeps a concurrent importer from loading a half-written file
+        os.replace(tmp, so)
+        return so
     except (OSError, subprocess.SubprocessError):
         return None
 
@@ -73,9 +120,10 @@ def _load():
         try:
             lib = ctypes.CDLL(path)
         except OSError:
-            # a shipped .so that won't load here (glibc/arch mismatch on a
-            # different deploy node): rebuild from sources when possible,
-            # else degrade to the numpy paths — never hard-fail the caller
+            # a .so that won't load here (a shipped one on a mismatched
+            # deploy node, a torn file): rebuild from sources when
+            # possible, else degrade to the numpy paths — never hard-fail
+            # the caller
             try:
                 os.remove(path)
             except OSError:

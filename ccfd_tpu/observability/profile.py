@@ -361,21 +361,13 @@ class StageProfiler:
         armed profiler through a weakref (a torn-down platform's profiler
         is collectable and stops receiving events — newest wins, exactly
         like supervisor respawns elsewhere)."""
-        global _COMPILE_TARGET
+        global _COMPILE_TARGET, _COMPILE_HOOK_REGISTERED
         if not self._compile_armed:
-            try:
-                import jax.monitoring as monitoring
-            # ccfd-lint: disable=counted-drops -- capability probe: no jax.monitoring means compile attribution is off, reported via the False return
-            except Exception:  # noqa: BLE001 - profile without jax works
-                return False
-            global _COMPILE_HOOK_REGISTERED
+            import jax.monitoring as monitoring
+
             if not _COMPILE_HOOK_REGISTERED:
-                try:
-                    monitoring.register_event_duration_secs_listener(
-                        _on_compile_event)
-                # ccfd-lint: disable=counted-drops -- capability probe: older jax without the hook, reported via the False return
-                except Exception:  # noqa: BLE001 - older jax, no hook
-                    return False
+                monitoring.register_event_duration_secs_listener(
+                    _on_compile_event)
                 _COMPILE_HOOK_REGISTERED = True
             self._compile_armed = True
         _COMPILE_TARGET = weakref.ref(self)

@@ -112,9 +112,8 @@ def fused_mlp_score(
 ) -> jax.Array:
     """(B, F<=128) float or bfloat16 -> (B,) float32 proba. B must be a tile
     multiple. bfloat16 input is the fast path: the kernel computes in bf16
-    regardless, and bf16 rows halve the host->HBM transfer — on serving
-    setups where the wire dominates (tunneled chips, DCN-remote hosts) that
-    is ~2x end-to-end throughput for identical numerics."""
+    regardless, and bf16 rows halve the host->HBM transfer for identical
+    numerics."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

@@ -241,11 +241,15 @@ fused_score = fused_mlp_q8_score
 # H2D bytes), and the kernel starts straight at the first MXU matmul.
 # Bit-identical to the full kernel / XLA graph: the host performs the
 # model's OWN first requantization, just on the other side of the wire.
-# On a tunneled attachment where H2D dominates the serving hop (the
-# reason the bf16 kernel ships bf16 rows), this is the q8 path's wire
-# lever; the numpy quantize cost rides the host, so the tradeoff is
-# attachment-specific and recorded by the bench quant section, not
-# assumed.
+# Where H2D dominates the serving hop (the reason the bf16 kernel ships
+# bf16 rows), this is the q8 path's wire lever; the numpy quantize cost
+# rides the host, so the tradeoff is recorded by the bench quant section,
+# not assumed. The two sides of the wire divide in different hardware:
+# on the chip the host's (x - mu) / sigma and the device's can differ in
+# the last ulp, which moves a quantisation step for an occasional row
+# (max 1.8e-3 in probability against the device's XLA graph in one probe
+# of 16,384 rows on a v5e, PERF.md Findings PR 21); in interpret mode on
+# the CPU both sides are the same arithmetic and parity is bit-exact.
 # ---------------------------------------------------------------------------
 
 

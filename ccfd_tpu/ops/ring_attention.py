@@ -22,9 +22,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ccfd_tpu.ops.shard_compat import pcast_varying, shard_map
 
 
 def _online_block(q, k_blk, v_blk, m, l, o):
@@ -64,9 +63,9 @@ def _ring_body(q, k, v, axis_name: str):
     l0 = jnp.zeros((batch, heads, lq), jnp.float32)
     o0 = jnp.zeros((batch, heads, lq, d), jnp.float32)
     # the accumulators become device-varying after one step; mark the scan
-    # carry as varying over the ring axis up front (shard_map scan-vma rule;
-    # identity on pre-vma jax, ops/shard_compat.py)
-    m0, l0, o0 = (pcast_varying(t, axis_name) for t in (m0, l0, o0))
+    # carry as varying over the ring axis up front (shard_map scan-vma rule)
+    m0, l0, o0 = (jax.lax.pcast(t, (axis_name,), to="varying")
+                  for t in (m0, l0, o0))
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def step(carry, _):

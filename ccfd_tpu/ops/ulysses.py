@@ -29,10 +29,10 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ccfd_tpu.ops.ring_attention import reference_attention
-from ccfd_tpu.ops.shard_compat import shard_map
 
 
 def _ulysses_body(q, k, v, axis_name: str):

@@ -191,8 +191,8 @@ class OnlineUserTaskModel:
     def _warmup_cancel(self) -> None:
         self._warmup_stop.set()
         if self._warmup_thread is not None:
-            # bounded join: if a compile wedged (e.g. a hung device tunnel)
-            # the thread never sees the stop event — cap the wait so
+            # bounded join: if a compile wedged (a device call that never
+            # returns) the thread never sees the stop event — cap the wait so
             # interpreter exit is never blocked forever
             self._warmup_thread.join(timeout=10.0)
 

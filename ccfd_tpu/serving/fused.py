@@ -21,12 +21,13 @@ Contracts kept truthful:
   custom ``when_fn`` fails :func:`~ccfd_tpu.ops.fused_decision.compile_rules`
   at construction: ONE warning, ``enabled`` False, the whole set serves
   staged. Never a silent per-row fallback.
-- **The ladder still rules.** An unhealthy fused executable (dispatch
-  failure, lowering error) disables the plane — latched for
-  lowering-class failures, until the next successful swap precompile
-  otherwise — and the call falls back to the STAGED path
-  (``Scorer.score`` + host rules); if the device itself is sick that
-  raises through to the router's host and rules tiers unchanged.
+- **The ladder still rules.** A fused executable that fails while
+  serving (a dispatch, or a swap precompile) disables the plane until
+  the next successful swap precompile, counted in ``staged_fallbacks``,
+  and the call falls back to the STAGED path (``Scorer.score`` + host
+  rules); if the device itself is sick that raises through to the
+  router's host and rules tiers unchanged. ``warmup()`` is start-up, not
+  serving: a grid that cannot compile there raises.
 - **Swaps precompile before publishing.** The plane registers a
   prepublish hook on the base scorer: ``swap_params`` runs every bucket
   of the fused grid against the staged artifacts (under the
@@ -84,7 +85,6 @@ class FusedDecisionScorer:
         self._lock = threading.Lock()
         self._dispatch_counts: dict[int, int] = {}
         self._disabled = False
-        self._latched = False
         self.enabled = False
         self.host_syncs = 0  # device->host materializations (the transfer)
         self.staged_fallbacks = 0
@@ -242,11 +242,11 @@ class FusedDecisionScorer:
                 # carries score + threshold verdict + fired rule together
                 chunks.append(np.asarray(done)[:took])
                 self.host_syncs += 1
-        # ccfd-lint: disable=counted-drops -- _disable logs the failure with its latch decision and _staged counts it in fused_decision_fallbacks_total
+        # ccfd-lint: disable=counted-drops -- _disable logs the failure and _staged counts it in fused_decision_fallbacks_total
         except Exception as e:  # noqa: BLE001 - unhealthy executable:
-            # disable the plane (latched for lowering-class failures) and
-            # serve THIS call staged; a sick device raises out of the
-            # staged path into the router's host/rules tiers
+            # disable the plane and serve THIS call staged; a sick device
+            # raises out of the staged path into the router's host/rules
+            # tiers
             self._disable(e)
             return self._staged(x)
         if self._c_decide:
@@ -296,20 +296,18 @@ class FusedDecisionScorer:
         return np.asarray(self._base.score(x), np.float32), None
 
     def _disable(self, e: Exception) -> None:
-        latch = self._base._is_lowering_error(e)
         log.warning(
             "fused decision executable failed (%r); serving the staged "
-            "path %s", e,
-            "permanently" if latch else "until the next swap precompile")
+            "path until the next swap precompile", e)
         self._disabled = True
-        self._latched = self._latched or latch
 
     # -- warmup / swap precompile -------------------------------------------
 
     def warmup(self) -> None:
         """Precompile the whole fused decision grid (every batch bucket)
         under the ``fused.warm`` compile stage — serving dispatches then
-        run with zero serving-stage compiles."""
+        run with zero serving-stage compiles. A grid that cannot compile
+        raises, like ``Scorer.warmup``."""
         if not self.enabled:
             return
         self._precompile(*self._snapshot())
@@ -319,11 +317,16 @@ class FusedDecisionScorer:
         """Scorer prepublish hook: run the staged artifacts through every
         bucket of the decision grid BEFORE ``swap_params`` flips the
         serving reference — the seq variant swap's discipline applied to
-        the fused grid. A healthy precompile re-arms a transiently
-        disabled plane; a latched (lowering) disable stays latched."""
+        the fused grid. A healthy precompile re-arms a disabled plane; a
+        failing one disables it — serving is live here, so the publish
+        goes ahead and verdicts come from the staged path."""
         if not self.enabled:
             return
-        self._precompile(staged, staged_fused, staged_preq_norm)
+        try:
+            self._precompile(staged, staged_fused, staged_preq_norm)
+        # ccfd-lint: disable=counted-drops -- _disable logs the failure; later decide() calls count staged service in fused_decision_fallbacks_total
+        except Exception as e:  # noqa: BLE001 - must not block a publish
+            self._disable(e)
 
     def _precompile(self, params: Any, fused_params: Any,
                     preq_norm: Any) -> None:
@@ -333,21 +336,12 @@ class FusedDecisionScorer:
         fn = self._fn_preq() if preq else self._fn_for(fused_params)
         which = params if fused_params is None else fused_params
         base = self._base
-        try:
-            with compile_stage("fused.warm"):
-                for b in base.batch_sizes:
-                    zeros = np.zeros((b, base.num_features), np.float32)
-                    jax.block_until_ready(
-                        self._dispatch_one(fn, which, zeros, preq,
-                                           preq_norm))
-        # ccfd-lint: disable=counted-drops -- _disable logs the failure with its latch decision; later decide() calls count staged service in fused_decision_fallbacks_total
-        except Exception as e:  # noqa: BLE001 - a grid that cannot compile
-            # must not brick warmup or a swap publish: the plane disables
-            # and serving continues staged
-            self._disable(e)
-            return
-        if not self._latched:
-            self._disabled = False
+        with compile_stage("fused.warm"):
+            for b in base.batch_sizes:
+                zeros = np.zeros((b, base.num_features), np.float32)
+                jax.block_until_ready(
+                    self._dispatch_one(fn, which, zeros, preq, preq_norm))
+        self._disabled = False  # the whole grid compiled and ran: (re-)armed
 
     # -- observability -------------------------------------------------------
 

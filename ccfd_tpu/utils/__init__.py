@@ -1,5 +1,1 @@
-"""Shared utilities. Tracer/trace_span re-export from their new home
-(observability/trace.py) for back-compat — importing the old
-``ccfd_tpu.utils.tracing`` module directly warns DeprecationWarning."""
-
-from ccfd_tpu.observability.trace import Tracer, trace_span  # noqa: F401
+"""Shared utilities."""

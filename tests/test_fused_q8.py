@@ -183,9 +183,11 @@ def test_mesh_sharded_fused_q8_matches_xla():
     np.testing.assert_allclose(fused.score(x), plain.score(x), atol=1e-5)
 
 
-def test_warmup_kernel_failure_falls_back_to_xla(monkeypatch):
-    """A Mosaic lowering error at first call (only reproducible on real
-    TPU) must degrade warmup to the XLA graph, not kill serving."""
+def test_warmup_kernel_failure_raises(monkeypatch):
+    """A kernel the compiler refuses at first call must fail warmup — on
+    BOTH device entry points (the q8 scorer serves through the int8-wire
+    path by default) — and leave the scorer on the fused path: the XLA
+    graph is never selected because an exception was raised."""
     qp, ds = _quantized_params(seed=3)
     scorer = Scorer(model_name="mlp_q8", params=qp, batch_sizes=(64, 128),
                     use_fused=True)
@@ -194,51 +196,18 @@ def test_warmup_kernel_failure_falls_back_to_xla(monkeypatch):
     def boom(*a, **k):
         raise RuntimeError("Mosaic lowering failed (simulated)")
 
-    # patch BOTH device entry points: the q8 scorer serves through the
-    # int8-wire path (fused_mlp_q8_score_preq) by default
     monkeypatch.setattr(scorer._fused_mod, "fused_score", boom)
     monkeypatch.setattr(scorer._fused_mod, "fused_mlp_q8_score_preq", boom)
-    scorer.warmup()  # must not raise
-    assert not scorer.fused
-    ref = Scorer(model_name="mlp_q8", params=qp, batch_sizes=(64, 128),
-                 use_fused=False).score(ds.X[:64])
-    np.testing.assert_allclose(scorer.score(ds.X[:64]), ref, atol=1e-6)
-    # the fallback LATCHES: a retrain publish re-folds successfully (fold
-    # is pure layout) but must not resurrect the kernel that cannot lower
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        scorer.warmup()
+    assert scorer.fused
+    # a retrain publish re-folds (pure layout) and stays fused; the
+    # failure keeps surfacing at dispatch instead of being papered over
     qp2, _ = _quantized_params(seed=4)
     scorer.swap_params(qp2)
-    assert not scorer.fused
-    ref2 = Scorer(model_name="mlp_q8", params=qp2, batch_sizes=(64, 128),
-                  use_fused=False).score(ds.X[:64])
-    np.testing.assert_allclose(scorer.score(ds.X[:64]), ref2, atol=1e-6)
-
-
-def test_transient_warmup_failure_does_not_latch(monkeypatch):
-    """A non-lowering (attachment-hiccup-shaped) warmup error falls back
-    for availability but must NOT latch: the next retrain swap re-enables
-    the kernel."""
-    qp, ds = _quantized_params(seed=6)
-    scorer = Scorer(model_name="mlp_q8", params=qp, batch_sizes=(64,),
-                    use_fused=True)
-    real = scorer._fused_mod.fused_score
-    real_preq = scorer._fused_mod.fused_mlp_q8_score_preq
-
-    def flaky(*a, **k):
-        raise RuntimeError("socket closed mid-transfer (simulated)")
-
-    monkeypatch.setattr(scorer._fused_mod, "fused_score", flaky)
-    monkeypatch.setattr(scorer._fused_mod, "fused_mlp_q8_score_preq", flaky)
-    scorer.warmup()
-    assert not scorer.fused
-    monkeypatch.setattr(scorer._fused_mod, "fused_score", real)
-    monkeypatch.setattr(scorer._fused_mod, "fused_mlp_q8_score_preq",
-                        real_preq)
-    qp2, _ = _quantized_params(seed=7)
-    scorer.swap_params(qp2)
-    assert scorer.fused  # transient failure: swap re-enables the kernel
-    ref = Scorer(model_name="mlp_q8", params=qp2, batch_sizes=(64,),
-                 use_fused=False).score(ds.X[:64])
-    np.testing.assert_allclose(scorer.score(ds.X[:64]), ref, atol=1e-5)
+    assert scorer.fused
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        scorer.score(ds.X[:64])
 
 
 def test_fold_rejects_wide_last_layer_beyond_f32_exact_bound():

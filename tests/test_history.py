@@ -520,7 +520,10 @@ def test_quantized_swap_rebinds_the_serving_graph():
     before = s.score(x, ids=list(range(6)))
     s.swap_params(quantize_seq(params))
     assert is_quantized(s.params)
-    after = s.score(x, ids=list(range(6)))
+    # fresh customer ids: re-scoring ids 0..5 would append x to histories
+    # that already hold it, and the band would then compare two different
+    # inputs ([x] vs [x, x]) as well as two precisions
+    after = s.score(x, ids=list(range(6, 12)))
     assert after.shape == (6,)
     np.testing.assert_allclose(after, before, atol=0.06)
 

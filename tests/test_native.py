@@ -325,10 +325,9 @@ def test_decode_csv_fuzz_never_crashes():
         assert x.shape[1] == 30 and bad >= 0
 
 
-def test_native_degrades_never_hard_fails(tmp_path, monkeypatch):
-    """The fallback contract across broken-artifact states: a corrupt
-    shipped .so rebuilds from sources; stripped sources trust the .so;
-    nothing usable degrades to None (numpy paths) — no state raises."""
+def _private_native_dir(tmp_path, monkeypatch):
+    """Point the native module at a private copy of its sources so build
+    tests never touch the package directory or the loaded library."""
     import shutil
 
     import ccfd_tpu.native as n
@@ -338,7 +337,6 @@ def test_native_degrades_never_hard_fails(tmp_path, monkeypatch):
     for s in n._SRCS:
         shutil.copy(s, pkg / os.path.basename(s))
     srcs = [str(pkg / os.path.basename(s)) for s in n._SRCS]
-    so = str(pkg / "_ccfd_native.so")
 
     def fresh(srcs_override, so_path):
         monkeypatch.setattr(n, "_SRCS", srcs_override)
@@ -346,17 +344,26 @@ def test_native_degrades_never_hard_fails(tmp_path, monkeypatch):
         monkeypatch.setattr(n, "_lib", None)
         monkeypatch.setattr(n, "_build_failed", False)
 
-    # NOTE: each scenario uses its own .so path, and corrupt content goes
-    # into fresh files — overwriting a path a previous CDLL still has
-    # mmap'd would corrupt the live mapping (SIGBUS), which is a test
-    # artifact, not the contract under test.
+    return n, pkg, srcs, fresh
 
-    # corrupt .so + sources present: rebuilt, loads
-    so1 = str(pkg / "one_ccfd_native.so")
-    with open(so1, "wb") as f:
+
+def test_native_degrades_never_hard_fails(tmp_path, monkeypatch):
+    """The fallback contract across broken-artifact states: a corrupt
+    .so under the expected name rebuilds from sources; stripped sources
+    trust the shipped .so; nothing usable degrades to None (numpy paths)
+    — no state raises."""
+    import shutil
+
+    n, pkg, srcs, fresh = _private_native_dir(tmp_path, monkeypatch)
+
+    # NOTE: corrupt content goes into fresh files — overwriting a path a
+    # previous CDLL still has mmap'd would corrupt the live mapping
+    # (SIGBUS), which is a test artifact, not the contract under test.
+
+    # corrupt .so at the digest name + sources present: rebuilt, loads
+    fresh(srcs, str(pkg / "_ccfd_native.so"))
+    with open(n._so_path(n._flags()), "wb") as f:
         f.write(b"not an elf")
-    os.utime(so1, (2**31 - 1, 2**31 - 1))  # newer than sources: trusted path
-    fresh(srcs, so1)
     assert n._load() is not None
 
     # corrupt .so + sources stripped: degrade to None, not an exception
@@ -366,10 +373,44 @@ def test_native_degrades_never_hard_fails(tmp_path, monkeypatch):
     fresh([str(pkg / "missing.cpp")], so2)
     assert n._load() is None
 
-    # partial sources + valid-mtime .so: trusted (no FileNotFoundError)
+    # partial sources + a shipped .so: trusted (no FileNotFoundError)
     so3 = str(pkg / "three_ccfd_native.so")
     fresh(srcs, so3)
-    n._build_failed = False
-    assert n._build() is not None  # build a real .so at so3 first
+    shutil.copy(n._build(), so3)
     fresh([srcs[0], str(pkg / "missing.cpp")], so3)
+    assert n._build() == so3
     assert n._load() is not None
+
+
+def test_so_name_digest_decides_what_loads(tmp_path, monkeypatch):
+    """While the sources are present the library is the one built from
+    THEM, here: a .so left under the un-digested name (copied in from
+    another machine, newer mtime and all) is not loaded, and editing a
+    source or a flag changes the name, so the stale build is rebuilt, not
+    reused."""
+    n, pkg, srcs, fresh = _private_native_dir(tmp_path, monkeypatch)
+    foreign = str(pkg / "_ccfd_native.so")
+    with open(foreign, "wb") as f:
+        f.write(b"machine code from somewhere else")
+    os.utime(foreign, (2**31 - 1, 2**31 - 1))  # newer than every source
+    fresh(srcs, foreign)
+    built = n._build()
+    assert built is not None and built != foreign
+    assert built == n._so_path(n._flags()) and os.path.dirname(built) == str(pkg)
+    assert n._load() is not None
+
+    # an edited source: new digest, new build; the old file is not it
+    os.utime(built, (2**31 - 1, 2**31 - 1))  # mtime must not matter
+    with open(srcs[0], "a") as f:
+        f.write("\n// edited\n")
+    rebuilt = n._build()
+    assert rebuilt not in (None, built, foreign) and os.path.exists(rebuilt)
+
+    # the flags and (under -march=native) the host CPU are in the name too
+    monkeypatch.setenv("CCFD_NATIVE_MARCH", "x86-64")
+    portable = n._so_path(n._flags())
+    assert portable != rebuilt
+    monkeypatch.setattr(n, "_host_fingerprint", lambda: "another-cpu")
+    assert n._so_path(n._flags()) == portable  # portable march: any host
+    monkeypatch.delenv("CCFD_NATIVE_MARCH")
+    assert n._so_path(n._flags()) != rebuilt   # native: this host only

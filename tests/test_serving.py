@@ -287,30 +287,48 @@ def test_host_tier_parity_and_routing():
     assert (shifted > host).all()  # +9 logit bias must show through the tier
 
 
-def test_host_tier_auto_off_on_cpu_backend():
+def test_host_tier_auto_is_zero(monkeypatch):
+    """Auto resolves to 0 whatever the backend says it is — the default
+    serving path always reaches the device — and warmup never moves it;
+    an explicit positive value still works and survives warmup."""
+    import jax as _jax
+
     from ccfd_tpu.serving.scorer import Scorer
 
-    s = Scorer(model_name="mlp", batch_sizes=(16,))
-    assert s.host_tier_rows == 0  # default backend here is cpu
-
-
-def test_host_tier_autotune_measures_crossover():
-    """The auto threshold is a measured property of the attachment: rows
-    where host forward cost reaches half the device dispatch RTT. An
-    explicit host_tier_rows must never be adapted away."""
-    from ccfd_tpu.serving.scorer import Scorer
-
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(_jax, "default_backend", lambda b=backend: b)
+        s = Scorer(model_name="mlp", batch_sizes=(16,), use_fused=False,
+                   dispatch_deadline_ms=0)
+        assert s.host_tier_rows == 0, backend
+        s.warmup()
+        assert s.host_tier_rows == 0, backend
+    monkeypatch.undo()
     s = Scorer(model_name="mlp", batch_sizes=(16,), host_tier_rows=256)
     s.warmup()
-    assert not s._host_tier_auto
-    assert s.host_tier_rows == 256  # explicit value survives warmup
+    assert s.host_tier_rows == 256
 
-    thr = s._autotune_host_tier()
-    assert 0 <= thr <= 8192
-    # on this CPU backend the "device" and host run the same silicon, so
-    # the crossover must be modest (RTT/2 of a 16-row dispatch cannot
-    # justify thousands of host rows)
-    assert thr < 8192
+
+def test_fused_kernel_exception_in_warmup_propagates(monkeypatch):
+    """A fused kernel that fails at warm-up is a bug to fix in the kernel:
+    warmup raises and the scorer stays on the fused path — it never
+    drops to the XLA graph because an exception was raised."""
+    import pytest
+
+    from ccfd_tpu.serving.scorer import Scorer
+
+    s = Scorer(model_name="mlp", batch_sizes=(16, 128), use_fused=True)
+    assert s.fused
+
+    def boom(*a, **k):
+        raise RuntimeError("Mosaic lowering failed (simulated)")
+
+    monkeypatch.setattr(s._fused_mod, "fused_score", boom)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        s.warmup()
+    assert s.fused
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        s.score(np.zeros((16, 30), np.float32))
+    assert s.fused
 
 
 def test_host_tier_gbt_small_batch_scores():

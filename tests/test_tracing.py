@@ -510,20 +510,6 @@ def test_slog_stamps_trace_ids_and_extras():
     assert len(log.handlers) == 1
 
 
-# -- deprecation shim --------------------------------------------------------
-def test_old_tracing_import_path_warns_and_works():
-    import importlib
-    import sys
-
-    sys.modules.pop("ccfd_tpu.utils.tracing", None)
-    with pytest.warns(DeprecationWarning):
-        mod = importlib.import_module("ccfd_tpu.utils.tracing")
-    reg = Registry()
-    with mod.Tracer(reg).span("old"):
-        pass
-    assert reg.histogram("trace_span_seconds").count({"span": "old"}) == 1
-
-
 # -- exporter contract -------------------------------------------------------
 @pytest.fixture()
 def exporter_with_sink():
