@@ -27,6 +27,13 @@ SLOs need per-stage critical-path visibility, not endpoint histograms):
   (metrics/prom.py exemplar support), so a Grafana heat-map cell links to
   the exact retained trace via the exporter's ``/traces/<id>`` endpoint.
 
+- **Phases on the profiler's timeline** — :class:`phase` marks one stretch
+  of the served path (router loop, score worker, scorer, store, device
+  wait). It always opens a ``jax.profiler.TraceAnnotation`` of the same
+  name, so the phase is an event on the host plane of ANY device capture,
+  beside ``XLA Ops`` on one timeline, whoever started the capture; under an
+  active span it is also a child :class:`Span` in the same sink.
+
 Span context is tracked per-thread via ``contextvars``; pipelined code that
 hops threads (the router's score worker) passes ``parent=`` explicitly.
 """
@@ -54,14 +61,16 @@ class SpanContext(NamedTuple):
     sampled: bool = True
 
 
-_current: contextvars.ContextVar[SpanContext | None] = contextvars.ContextVar(
-    "ccfd_trace_ctx", default=None
-)
+# the active span's context on this thread and the tracer that activated
+# it: a phase opened under it records its child span through that tracer
+_current: "contextvars.ContextVar[tuple[SpanContext, Tracer] | None]" = (
+    contextvars.ContextVar("ccfd_trace_ctx", default=None))
 
 
 def current_context() -> SpanContext | None:
     """The active span's context on THIS thread (None outside any span)."""
-    return _current.get()
+    cur = _current.get()
+    return cur[0] if cur is not None else None
 
 
 def new_trace_id() -> str:
@@ -370,20 +379,17 @@ class Tracer:
     component's own series — the fix for the old global tracer whose
     private registry the exporter never served). ``sink`` is the shared
     :class:`SpanSink`; a tracer without one still times spans into the
-    histogram and the debug ring, it just feeds no retained traces.
+    histogram, it just feeds no retained traces.
     """
 
     def __init__(self, registry: Registry | None = None,
-                 component: str = "ccfd", sink: SpanSink | None = None,
-                 ring_size: int = 1024):
+                 component: str = "ccfd", sink: SpanSink | None = None):
         self.registry = registry or Registry()
         self.component = component
         self.sink = sink
         self._hist = self.registry.histogram(
             "trace_span_seconds", "span durations by name"
         )
-        self._ring: collections.deque = collections.deque(maxlen=ring_size)
-        self._lock = threading.Lock()
 
     # -- explicit begin/finish (thread-hopping pipelines) ------------------
     def start(self, name: str, parent: SpanContext | None = None,
@@ -398,14 +404,17 @@ class Tracer:
         return Span(trace_id, new_span_id(), parent_id, name,
                     self.component, time.time(), attrs)
 
-    def finish(self, span: Span, status: str | None = None) -> None:
-        span.duration_s = max(0.0, time.perf_counter() - span._t0)
+    def finish(self, span: Span, status: str | None = None,
+               duration_s: float | None = None) -> None:
+        """``duration_s``: the caller already timed the span (a
+        :class:`phase` reads the clock once for the span, the annotation's
+        stats and the histogram its call site feeds)."""
+        span.duration_s = (duration_s if duration_s is not None
+                           else max(0.0, time.perf_counter() - span._t0))
         if status is not None:
             span.status = status
         self._hist.observe(span.duration_s, labels={"span": span.name},
                            exemplar={"trace_id": span.trace_id})
-        with self._lock:
-            self._ring.append((span.start, span.name, span.duration_s))
         if self.sink is not None:
             self.sink.add(span)
 
@@ -414,7 +423,7 @@ class Tracer:
     def span(self, name: str, parent: SpanContext | None = None,
              attrs: dict | None = None) -> Iterator[Span]:
         sp = self.start(name, parent=parent, attrs=attrs)
-        token = _current.set(sp.context)
+        token = _current.set((sp.context, self))
         try:
             yield sp
         except BaseException:
@@ -429,32 +438,89 @@ class Tracer:
         """Make ``ctx`` the current context on this thread without opening
         a span (consumers resuming a bus-carried context around work whose
         spans are created piecemeal)."""
-        token = _current.set(ctx)
+        token = _current.set((ctx, self) if ctx is not None else None)
         try:
             yield
         finally:
             _current.reset(token)
 
-    def recent(self, n: int = 50) -> list[tuple[float, str, float]]:
-        with self._lock:
-            return list(self._ring)[-n:]
 
-    @contextlib.contextmanager
-    def profile(self, logdir: str) -> Iterator[None]:
-        """Device-level XLA trace (TensorBoard format) around a block."""
-        import jax
-
-        with jax.profiler.trace(logdir):
-            yield
+_annotation: Any = None  # jax.profiler.TraceAnnotation, bound at first use:
+# HTTP clients and load generators import this module and must stay JAX-free
 
 
-_GLOBAL = Tracer()
+class phase:  # noqa: N801 - reads as a statement: ``with phase("seq.pad"):``
+    """One phase of the served path, per router batch or per dispatch
+    (never per record): the ONE way the path marks where its time goes.
 
+    Always a ``jax.profiler.TraceAnnotation`` named ``name``: with a device
+    capture running (the benchmark's traced run, the exporter's
+    ``profile_device`` endpoint) the phase is an event on the capture's host
+    plane, on the timeline of ``XLA Ops``, carrying ``stats`` and the
+    thread's CPU time inside it (``cpu_ns``; wall minus CPU is time spent
+    off the CPU — on this path, waiting for the interpreter lock or the
+    device). With no capture running the annotation costs under a
+    microsecond and the CPU clock is not read.
 
-@contextlib.contextmanager
-def trace_span(name: str) -> Iterator[None]:
-    """Module-level convenience span on the default (ad-hoc, UNSCRAPED)
-    tracer — debug use only; wired components get a registry-injected
-    tracer from the operator."""
-    with _GLOBAL.span(name):
-        yield
+    Under an active span — ``parent`` given with its ``tracer`` (the
+    router's stages, parented on the in-flight batch span that hops
+    threads), or a span activated on this thread (everything the scorer
+    and the store open inside ``router.score``) — it is also a child
+    :class:`Span` of it: same trace id, same sink, ``stats`` as attrs,
+    activated for whatever opens below. With neither it is the annotation
+    alone.
+
+    ``seconds`` holds the phase's duration after exit: call sites that keep
+    a histogram of the interval feed it from this, so the interval is
+    timed once.
+    """
+
+    __slots__ = ("name", "stats", "span", "seconds", "_tracer", "_parent",
+                 "_ann", "_token", "_t0", "_cpu0")
+
+    def __init__(self, name: str, tracer: "Tracer | None" = None,
+                 parent: SpanContext | None = None, **stats: Any):
+        self.name = name
+        self.stats = stats
+        self.span: Span | None = None
+        self.seconds = 0.0
+        self._tracer = tracer
+        self._parent = parent
+
+    def set(self, **stats: Any) -> None:
+        """Stats known only once the work is done (rows polled, bucket
+        chosen); ``stats`` doubles as the span's attrs."""
+        self.stats.update(stats)
+
+    def __enter__(self) -> "phase":
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        cur = _current.get() or (None, None)
+        parent = self._parent or cur[0]
+        tracer = self._tracer = self._tracer or cur[1]
+        self._token = None
+        if tracer is not None and parent is not None:
+            self.span = tracer.start(self.name, parent=parent,
+                                     attrs=self.stats)
+            self._token = _current.set((self.span.context, tracer))
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        self._cpu0 = (time.thread_time_ns() if _annotation.is_enabled()
+                      else None)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        if self._cpu0 is not None:
+            self._ann.set_metadata(
+                cpu_ns=time.thread_time_ns() - self._cpu0, **self.stats)
+        self._ann.__exit__(exc_type, exc, tb)
+        if self.span is not None:
+            _current.reset(self._token)
+            self._tracer.finish(
+                self.span, "error" if exc_type is not None else None,
+                duration_s=self.seconds)

@@ -36,7 +36,6 @@ semantics hold: a scorer failure drops that batch, counted.
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import operator
 import threading
@@ -50,6 +49,7 @@ from ccfd_tpu.config import Config
 from ccfd_tpu.data.ccfd import FEATURE_NAMES
 from ccfd_tpu.metrics.prom import Registry
 from ccfd_tpu.native import decode_csv as native_decode_csv
+from ccfd_tpu.observability.trace import extract_context, phase
 from ccfd_tpu.process.fraud import CUSTOMER_RESPONSE_SIGNAL
 from ccfd_tpu.router.rules import RuleSet, default_rules
 
@@ -64,7 +64,6 @@ class EngineClient(Protocol):
 
 _SCHEMA_GETTER = operator.itemgetter(*FEATURE_NAMES)
 _ZERO_ROW = (0.0,) * len(FEATURE_NAMES)
-_NULL_CM = contextlib.nullcontext()  # reusable: enter/exit hold no state
 
 
 def default_scorer_breaker(registry):
@@ -332,7 +331,8 @@ class Router:
         # decode/score/route — the per-stage latency attribution the
         # Tracing board and tools/trace_report.py decompose. Fraud-routed
         # and degraded-tier batches flag their spans, which the tail
-        # sampler always keeps.
+        # sampler always keeps. Each stage is a trace.phase: with no
+        # tracer it still shows in a device capture, by the same name.
         self.tracer = tracer
         # history-aware scorers (serving/history.py SeqScorer) score each
         # transaction against the customer's history: they expose
@@ -615,12 +615,13 @@ class Router:
         errors likewise leave the batch uncommitted (it redelivers)."""
         if not self._commit_after_route or offs is None:
             return
-        try:
-            self._tx_consumer.commit(offs)
-        except StaleEpochError:
-            self._c_fenced.inc()
-        except Exception:  # noqa: BLE001 - bus edge down; batch redelivers
-            self._c_commit_err.inc()
+        with phase("router.commit", partitions=len(offs)):
+            try:
+                self._tx_consumer.commit(offs)
+            except StaleEpochError:
+                self._c_fenced.inc()
+            except Exception:  # noqa: BLE001 - bus edge down; batch redelivers
+                self._c_commit_err.inc()
 
     # -- loop stages (composed by step() and the pipelined run loop) -------
     def _drain_signals(self) -> None:
@@ -660,6 +661,12 @@ class Router:
         board) observe it as lag (``bus_topic_backlog``) instead of an
         unbounded consumed-then-shed churn. Polling resumes as routed
         batches release rows."""
+        with phase("router.poll") as ph:
+            records = self._poll_records(poll_timeout_s)
+            ph.set(rows=len(records))
+        return records
+
+    def _poll_records(self, poll_timeout_s: float) -> list:
         cap = self.max_batch
         granted = -1
         if self._overload is not None:
@@ -697,8 +704,6 @@ class Router:
         when tracing is off."""
         if self.tracer is None:
             return None
-        from ccfd_tpu.observability.trace import extract_context
-
         parent = None
         for rec in records[:16]:  # stamped records carry it up front
             h = getattr(rec, "headers", None)
@@ -711,6 +716,13 @@ class Router:
             attrs["worker"] = self.worker_id
         return self.tracer.start("router.batch", parent=parent, attrs=attrs)
 
+    def _stage(self, name: str, batch_span, rows: int) -> phase:
+        """One stage of a micro-batch: always an event in a device
+        capture, and under a tracer a child span of the batch's."""
+        return phase(name, self.tracer,
+                     batch_span.context if batch_span is not None else None,
+                     rows=rows)
+
     def _decode_batch(
         self, records: list, batch_span=None
     ) -> tuple[np.ndarray, list, np.ndarray]:
@@ -718,11 +730,8 @@ class Router:
         self._c_in.inc(n)
         self._h_batch.observe(n)
         self._c_worker_batch.inc(labels=self._worker_labels)
-        span_cm = (self.tracer.span("router.decode",
-                                    parent=batch_span.context)
-                   if batch_span is not None else None)
         t0 = time.perf_counter()
-        with (span_cm if span_cm is not None else _NULL_CM):
+        with self._stage("router.decode", batch_span, n):
             x, txs, bad = decode_records(records)
         if bad:
             self._c_decode_err.inc(bad)
@@ -937,15 +946,10 @@ class Router:
 
     def _score_batch(self, x: np.ndarray, txs: list,
                      batch_span=None, meta=None) -> tuple:
-        if self.tracer is not None and batch_span is not None:
-            with self.tracer.span("router.score",
-                                  parent=batch_span.context) as sp:
-                if self._degrade:
-                    return self._score_tiered(x, txs, span=sp, meta=meta)
-                return self._score_direct(x, txs, span=sp, meta=meta)
-        if self._degrade:
-            return self._score_tiered(x, txs, meta=meta)
-        return self._score_direct(x, txs, meta=meta)
+        with self._stage("router.score", batch_span, len(txs)) as ph:
+            if self._degrade:
+                return self._score_tiered(x, txs, span=ph.span, meta=meta)
+            return self._score_direct(x, txs, span=ph.span, meta=meta)
 
     # -- one synchronous cycle (used by tests and the run loop) ------------
     def step(self, poll_timeout_s: float = 0.0) -> int:
@@ -1001,29 +1005,21 @@ class Router:
     def _route(self, x: np.ndarray, txs: list, proba: np.ndarray,
                ts: np.ndarray | None = None, batch_span=None,
                meta=None, fired: np.ndarray | None = None) -> int:
-        route_sp = None
-        if self.tracer is not None and batch_span is not None:
-            route_sp = self.tracer.start("router.route",
-                                         parent=batch_span.context)
         t0 = time.perf_counter() if self._profiler is not None else 0.0
         try:
-            if route_sp is None:
+            # under a tracer the phase's span is ACTIVE on this thread:
+            # the engine calls below (and the notification records the
+            # engine produces inside them, process/fraud.py notify) read
+            # current_context() to join the trace — an unactivated span
+            # would orphan the engine/notify leg
+            with self._stage("router.route", batch_span, len(txs)) as ph:
                 return self._route_inner(x, txs, proba, ts, batch_span,
-                                         route_sp, meta, fired)
-            # activate on THIS thread: the engine calls below (and the
-            # notification records the engine produces inside them,
-            # process/fraud.py notify) read current_context() to join the
-            # trace — an unactivated span would orphan the engine/notify leg
-            with self.tracer.activate(route_sp.context):
-                return self._route_inner(x, txs, proba, ts, batch_span,
-                                         route_sp, meta, fired)
+                                         ph.span, meta, fired)
         finally:
             if self._profiler is not None:
                 self._profiler.observe(
                     "router.route", service_s=time.perf_counter() - t0,
                     batch=len(txs), rows=len(txs))
-            if route_sp is not None:
-                self.tracer.finish(route_sp)
 
     def _route_inner(self, x: np.ndarray, txs: list, proba: np.ndarray,
                      ts: np.ndarray | None, batch_span, route_sp,

@@ -59,13 +59,13 @@ from __future__ import annotations
 
 import itertools
 import threading
-import time
 from collections import OrderedDict, deque
 from typing import Any
 
 import numpy as np
 
 from ccfd_tpu.data.ccfd import NUM_FEATURES
+from ccfd_tpu.observability.trace import phase
 from ccfd_tpu.runtime.faults import device_seam
 
 DEFAULT_STRIPES = 8
@@ -866,10 +866,22 @@ class SeqScorer:
         history would diverge from the routed stream. The overlay keeps
         same-customer visibility across chunks; the generation token
         makes a commit that raced a crash restore a no-op (the rewind
-        re-drives those records)."""
+        re-drives those records).
+
+        Every stretch of the call is a :class:`phase` (``seq.gather``,
+        ``seq.pad``, ``seq.enqueue``, ``seq.wait``, ``seq.commit`` inside
+        ``seq.score``), so a device capture shows what the host did
+        beside what the device did; ``seq_assembly_seconds`` is the
+        batch's gather + pad, ``seq_dispatch_seconds`` its enqueue + wait,
+        from the same clock reads."""
         n = len(x)
         if n == 0:
             return np.zeros((0,), np.float32)
+        with phase("seq.score", rows=n):
+            return self._score(x, ids)
+
+    def _score(self, x: np.ndarray, ids: list | None) -> np.ndarray:
+        n = len(x)
         if ids is None:
             ids = [None] * n
         out = np.empty((n,), np.float32)
@@ -897,33 +909,40 @@ class SeqScorer:
         start = 0
         while start < n:
             stop = min(start + largest, n)
-            t0 = time.perf_counter()
-            chunk_ids = ids[start:stop]
-            hist, (chunk_gen, staged, filled) = self.store.prepare(
-                chunk_ids, x[start:stop], overlay=merged
-            )
-            # the FIRST chunk's generation stamps the whole batch: a
-            # restore landing between chunk prepares bumps the store's
-            # generation, and committing with a later chunk's (fresh) gen
-            # would publish the earlier chunks' pre-restore staging onto
-            # the restored state — the first gen is stale then, so the
-            # commit is the no-op replay correctness requires
-            if gen is None:
-                gen = chunk_gen
-            # recency = LAST occurrence: a key re-staged by a later chunk
-            # moves to the end of merged, so commit stamps (and therefore
-            # LRU eviction under a binding cap) follow stream order, not
-            # first-touch order — replay with different batch boundaries
-            # must rebuild the same survivor set
-            for k in staged:
-                if k in merged:
-                    del merged[k]
-            merged.update(staged)
-            n_anon += chunk_ids.count(None)
-            li = self._len_bucket_index(filled)
-            if keep_hist:
-                tap_chunks.append((hist, start, stop))
-            t_asm += time.perf_counter() - t0
+            with phase("seq.gather", rows=stop - start) as ph:
+                chunk_ids = ids[start:stop]
+                hist, (chunk_gen, staged, filled) = self.store.prepare(
+                    chunk_ids, x[start:stop], overlay=merged
+                )
+                # the FIRST chunk's generation stamps the whole batch: a
+                # restore landing between chunk prepares bumps the store's
+                # generation, and committing with a later chunk's (fresh)
+                # gen would publish the earlier chunks' pre-restore staging
+                # onto the restored state — the first gen is stale then, so
+                # the commit is the no-op replay correctness requires
+                if gen is None:
+                    gen = chunk_gen
+                # recency = LAST occurrence: a key re-staged by a later
+                # chunk moves to the end of merged, so commit stamps (and
+                # therefore LRU eviction under a binding cap) follow stream
+                # order, not first-touch order — replay with different
+                # batch boundaries must rebuild the same survivor set
+                for k in staged:
+                    if k in merged:
+                        del merged[k]
+                merged.update(staged)
+                anon = chunk_ids.count(None)
+                n_anon += anon
+                li = self._len_bucket_index(filled)
+                if keep_hist:
+                    tap_chunks.append((hist, start, stop))
+                # a row at depth 1 is anonymous or its customer's first;
+                # rows beyond one per staged key repeat a key of the chunk
+                # (> 0: the store took its per-row path, not the batched)
+                ph.set(new_customers=int(np.count_nonzero(filled == 1))
+                       - anon,
+                       repeated_keys=stop - start - anon - len(staged))
+            t_asm += ph.seconds
             for bi in np.unique(li):
                 lb = ladder[bi]
                 idx = np.nonzero(li == bi)[0]
@@ -935,39 +954,43 @@ class SeqScorer:
                 pos = 0
                 m_total = len(idx)
                 while pos < m_total:
-                    t0 = time.perf_counter()
-                    rem = m_total - pos
-                    bucket = None
-                    for b in reversed(self.batch_sizes):
-                        if b <= rem:
-                            bucket = b
-                            break
-                    if bucket is None:
-                        bucket = self.batch_sizes[0]
-                    m = min(rem, bucket)
-                    sub_idx = idx[pos:pos + m]
-                    pos += m
-                    if lb == L and m == len(hist):
-                        sub = hist
-                    else:  # right-aligned window
-                        sub = hist[sub_idx, L - lb:, :]
-                    if m < bucket:
-                        sub = np.concatenate(
-                            [sub, np.zeros((bucket - m, *sub.shape[1:]),
-                                           np.float32)]
-                        )
-                    with self._params_lock:
-                        params, apply_fn = self.params, self._apply
-                    t_asm += time.perf_counter() - t0
-                    t0 = time.perf_counter()
-                    # device-fault dispatch seam (runtime/faults.py):
-                    # device_hang / compile_stall drill the heal ladder
-                    # through the seq path's own dispatch loop
-                    device_seam("dispatch")
-                    # JAX async dispatch: the call ENQUEUES the executable
-                    # and returns; the next group assembles while it runs.
-                    dev = apply_fn(params, self._put_hist(sub))
-                    t_disp += time.perf_counter() - t0
+                    with phase("seq.pad", l_bucket=lb) as ph:
+                        rem = m_total - pos
+                        bucket = None
+                        for b in reversed(self.batch_sizes):
+                            if b <= rem:
+                                bucket = b
+                                break
+                        if bucket is None:
+                            bucket = self.batch_sizes[0]
+                        m = min(rem, bucket)
+                        sub_idx = idx[pos:pos + m]
+                        pos += m
+                        if lb == L and m == len(hist):
+                            sub = hist
+                        else:  # right-aligned window
+                            sub = hist[sub_idx, L - lb:, :]
+                        if m < bucket:
+                            sub = np.concatenate(
+                                [sub, np.zeros((bucket - m, *sub.shape[1:]),
+                                               np.float32)]
+                            )
+                        with self._params_lock:
+                            params, apply_fn = self.params, self._apply
+                        ph.set(rows=m, b_bucket=bucket,
+                               padded_rows=bucket - m)
+                    t_asm += ph.seconds
+                    with phase("seq.enqueue", bytes=sub.nbytes,
+                               b_bucket=bucket, l_bucket=lb) as ph:
+                        # device-fault dispatch seam (runtime/faults.py):
+                        # device_hang / compile_stall drill the heal ladder
+                        # through the seq path's own dispatch loop
+                        device_seam("dispatch")
+                        # JAX async dispatch: the call ENQUEUES the
+                        # executable and returns; the next group assembles
+                        # while it runs.
+                        dev = apply_fn(params, self._put_hist(sub))
+                    t_disp += ph.seconds
                     if self.telemetry is not None:
                         self.telemetry.record_h2d(sub.nbytes)
                     pending.append((dev, sub_idx + start, m))
@@ -984,9 +1007,11 @@ class SeqScorer:
         while pending:
             t_disp += self._resolve(pending, out)
         if gen is not None:
-            if not self.store.commit((gen, merged)):
-                if self._c_stale is not None:
-                    self._c_stale.inc()
+            with phase("seq.commit", customers=len(merged)) as ph:
+                committed = self.store.commit((gen, merged))
+                ph.set(stale=int(not committed))
+            if not committed and self._c_stale is not None:
+                self._c_stale.inc()
         if tap is not None:
             # the tap pairs PURE champion scores (offered before any
             # canary override, like the row lane's tap-inside/gate-outside
@@ -1018,13 +1043,12 @@ class SeqScorer:
         returns the blocking wait (the dispatch time overlap failed to
         hide)."""
         dev, idx, m = pending.popleft()
-        t0 = time.perf_counter()
-        proba = np.asarray(dev)
-        dt = time.perf_counter() - t0
+        with phase("seq.wait", rows=m) as ph:
+            proba = np.asarray(dev)
         out[idx] = proba[:m]
         if self._g_inflight is not None:
             self._g_inflight.set(float(len(pending)))
-        return dt
+        return ph.seconds
 
     # Router contract: passing the SeqScorer OBJECT as the router's
     # score_fn makes it callable for the plain (x,) path, and the router

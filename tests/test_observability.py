@@ -5,7 +5,7 @@ import re
 
 from ccfd_tpu.metrics.prom import Registry
 from ccfd_tpu.observability.dashboards import build_all_dashboards, write_dashboards
-from ccfd_tpu.observability.trace import Tracer
+from ccfd_tpu.observability.trace import SpanSink, Tracer
 
 
 # The reference's full metrics contract (SURVEY.md §5): router business
@@ -298,13 +298,14 @@ def test_docs_state_generated_board_count_once():
 
 def test_tracer_spans_land_in_histogram():
     reg = Registry()
-    tr = Tracer(reg)
-    with tr.span("score"):
+    sink = SpanSink(sample=1.0)
+    tr = Tracer(reg, sink=sink)
+    with tr.span("score") as first:
         pass
-    with tr.span("score"):
+    with tr.span("score") as second:
         pass
     assert reg.histogram("trace_span_seconds").count({"span": "score"}) == 2
-    assert len(tr.recent()) == 2
+    assert [len(sink.trace(sp.trace_id)) for sp in (first, second)] == [1, 1]
 
 
 # -- dashboard ↔ exported-metric contract (round 7 CI guard) -----------------

@@ -1,0 +1,228 @@
+"""``trace.phase``: the served path's phases are events of any profiler
+capture (router loop, score worker, scorer, store, device wait), child
+spans under an active span, and the one clock read behind the scorer's two
+histograms."""
+
+import glob
+import os
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from ccfd_tpu.bus.broker import Broker
+from ccfd_tpu.config import Config
+from ccfd_tpu.data.ccfd import FEATURE_NAMES
+from ccfd_tpu.metrics.prom import Registry
+from ccfd_tpu.models import seq as seq_mod
+from ccfd_tpu.observability.trace import SpanSink, Tracer, phase
+from ccfd_tpu.process.fraud import build_engine
+from ccfd_tpu.router.router import Router
+from ccfd_tpu.serving.history import SeqScorer
+
+SEQ_PHASES = ("seq.gather", "seq.pad", "seq.enqueue", "seq.wait",
+              "seq.commit")
+
+
+class Capture:
+    """A profiler capture around a block; ``lines`` afterwards holds the
+    host plane's lines that carry a phase, each a list of
+    ``(name, start_ns, end_ns, stats)``."""
+
+    def __init__(self, logdir):
+        self.logdir = str(logdir)
+        self.lines: list[list[tuple]] = []
+
+    def __enter__(self):
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.logdir, profiler_options=options)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        (path,) = glob.glob(os.path.join(
+            self.logdir, "plugins", "profile", "*", "*.xplane.pb"))
+        profile = jax.profiler.ProfileData.from_file(path)
+        for plane in profile.planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                events = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                           dict(e.stats)) for e in line.events
+                          if e.name.startswith(("seq.", "router."))]
+                if events:
+                    self.lines.append(events)
+
+    def named(self, name):
+        return [e for line in self.lines for e in line if e[0] == name]
+
+    def line_of(self, name):
+        found = [line for line in self.lines
+                 if any(e[0] == name for e in line)]
+        assert len(found) == 1, f"{name} is on {len(found)} lines"
+        return found[0]
+
+
+def tiny_scorer(registry=None, batch_sizes=(16,)):
+    return SeqScorer(seq_mod.init(jax.random.PRNGKey(0)), length=8,
+                     batch_sizes=batch_sizes, compute_dtype="float32",
+                     registry=registry)
+
+
+def rows(n, seed=0):
+    return np.random.default_rng(seed).normal(size=(n, 30)).astype(np.float32)
+
+
+@pytest.mark.parametrize("explicit", [False, True])
+def test_a_phase_under_an_active_span_is_its_child(explicit):
+    sink = SpanSink(sample=1.0)
+    tr = Tracer(Registry(), component="router", sink=sink)
+    batch = tr.start("router.batch")
+    # the router's stages are parented on the batch span, which hops
+    # threads; the scorer's on whatever span is active on the thread
+    outer_cm = (phase("router.score", tr, batch.context, rows=3) if explicit
+                else tr.span("router.score", parent=batch.context))
+    with outer_cm as outer:
+        with phase("seq.gather", rows=3) as inner:
+            inner.set(new_customers=2)
+    outer = outer.span if explicit else outer
+    tr.finish(batch)
+    assert inner.span.parent_id == outer.span_id
+    assert outer.parent_id == batch.span_id
+    assert inner.span.trace_id == outer.trace_id == batch.trace_id
+    assert inner.span.attrs == {"rows": 3, "new_customers": 2}
+    assert inner.span.duration_s == inner.seconds > 0.0
+    got = {d["name"] for d in sink.trace(batch.trace_id)}
+    assert got == {"router.batch", "router.score", "seq.gather"}
+    assert tr.registry.histogram("trace_span_seconds").count(
+        {"span": "seq.gather"}) == 1
+
+
+def test_a_phase_with_no_tracer_is_the_annotation_alone():
+    sink = SpanSink(sample=1.0)
+    Tracer(Registry(), sink=sink)  # wired elsewhere, not active here
+    with phase("seq.gather", rows=3) as ph:
+        ph.set(new_customers=1)
+    assert ph.span is None and ph.seconds > 0.0
+    tiny_scorer().score(rows(5), ids=list("abcde"))
+    assert sink.traces() == [] and sink.registry.counter(
+        "ccfd_trace_spans_total").total() == 0
+
+
+def test_a_failing_phase_marks_its_span_and_restores_the_context():
+    from ccfd_tpu.observability.trace import current_context
+
+    sink = SpanSink(sample=0.0)  # an errored trace is kept whatever the rate
+    tr = Tracer(Registry(), sink=sink)
+    with tr.span("router.score") as sp:
+        with pytest.raises(RuntimeError):
+            with phase("seq.enqueue", bytes=8):
+                raise RuntimeError("staging failed")
+        assert current_context() == sp.context
+    spans = {d["name"]: d for d in sink.trace(sp.trace_id)}
+    assert spans["seq.enqueue"]["status"] == "error"
+
+
+def test_a_capture_around_the_scorer_holds_its_phases(tmp_path):
+    reg = Registry()
+    scorer = tiny_scorer(reg)
+    scorer.warmup()
+    x = rows(40)
+    ids = [f"c{i % 10}" for i in range(40)]  # chunks of 16, 16, 8 rows
+    with Capture(tmp_path) as cap:
+        scorer.score(x, ids=ids)
+    dispatches = sum(g["dispatches"]
+                     for g in scorer.executable_grid()["grid"])
+    assert dispatches == 3
+    (score,) = cap.named("seq.score")
+    assert score[3]["rows"] == 40
+    line = cap.line_of("seq.score")
+    for name in SEQ_PHASES:
+        events = cap.named(name)
+        assert events, name
+        for e in events:
+            assert e in line
+            assert score[1] <= e[1] and e[2] <= score[2]
+            assert e[3]["cpu_ns"] >= 0
+    assert len(cap.named("seq.enqueue")) == dispatches
+    assert len(cap.named("seq.wait")) == dispatches
+    assert len(cap.named("seq.commit")) == 1
+    gathers = cap.named("seq.gather")
+    assert [e[3]["rows"] for e in gathers] == [16, 16, 8]
+    # customers c0..c9: the first chunk meets all ten and six of them twice
+    assert [e[3]["new_customers"] for e in gathers] == [10, 0, 0]
+    assert [e[3]["repeated_keys"] for e in gathers] == [6, 6, 0]
+    for e in cap.named("seq.enqueue"):
+        assert e[3]["bytes"] == 16 * 8 * 30 * 4
+        assert (e[3]["b_bucket"], e[3]["l_bucket"]) == (16, 8)
+    assert [e[3]["padded_rows"] for e in cap.named("seq.pad")] == [0, 0, 8]
+    assert sum(e[3]["rows"] for e in cap.named("seq.wait")) == 40
+    (commit,) = cap.named("seq.commit")
+    assert (commit[3]["customers"], commit[3]["stale"]) == (10, 0)
+
+
+def test_a_pipelined_router_with_no_tracer_shows_in_a_capture(tmp_path):
+    cfg = Config(fraud_threshold=0.99)
+    broker = Broker()
+    engine = build_engine(cfg, broker, Registry())
+    scorer = tiny_scorer(Registry(), batch_sizes=(16, 128))
+    scorer.warmup()
+    router = Router(cfg, broker, scorer, engine, Registry(), max_batch=64)
+    assert router.tracer is None
+    records = [{FEATURE_NAMES[j]: float(j % 5) for j in range(30)}
+               | {"id": i % 7, "customer_id": i % 7} for i in range(256)]
+    with Capture(tmp_path) as cap:
+        thread = router.start(poll_timeout_s=0.01, pipeline=True)
+        try:
+            broker.produce_batch(cfg.kafka_topic, records)
+            deadline = time.monotonic() + 60.0
+            consumed = router.registry.counter("transaction_incoming_total")
+            routed = router.registry.counter("transaction_outgoing_total")
+            while (routed.total() < len(records)
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+            assert consumed.value() == routed.total() == len(records)
+        finally:
+            router.stop()
+            thread.join(timeout=30)
+            assert not thread.is_alive()
+    worker = cap.line_of("router.score")
+    loop = cap.line_of("router.decode")
+    assert loop is cap.line_of("router.route") is cap.line_of("router.poll")
+    assert worker is not loop
+    assert worker is cap.line_of("seq.score")
+    for name in ("router.score", "router.decode", "router.route"):
+        assert sum(e[3]["rows"] for e in cap.named(name)) == len(records)
+    assert sum(e[3]["rows"] for e in cap.named("router.poll")) == len(records)
+    for e in cap.named("seq.score"):  # the scorer's call, inside the stage
+        assert any(s[1] <= e[1] and e[2] <= s[2]
+                   for s in cap.named("router.score"))
+
+
+def test_the_scorers_histograms_are_fed_from_the_phases_clock_reads():
+    reg = Registry()
+    scorer = tiny_scorer(reg)
+    sink = SpanSink(sample=1.0)
+    tr = Tracer(Registry(), sink=sink)
+    batches = [rows(40, seed=1), rows(7, seed=2), rows(16, seed=3)]
+    with tr.span("router.score") as sp:
+        for i, x in enumerate(batches):
+            scorer.score(x, ids=[f"k{i}-{j % 9}" for j in range(len(x))])
+    spans = sink.trace(sp.trace_id)
+
+    def total(*names):
+        return sum(d["duration_s"] for d in spans if d["name"] in names)
+
+    assembly = reg.get("seq_assembly_seconds")
+    dispatch = reg.get("seq_dispatch_seconds")
+    assert assembly.count() == dispatch.count() == len(batches)
+    assert assembly.sum() == pytest.approx(
+        total("seq.gather", "seq.pad"), abs=1e-9)
+    assert dispatch.sum() == pytest.approx(
+        total("seq.enqueue", "seq.wait"), abs=1e-9)
+    assert sum(d["name"] == "seq.score" for d in spans) == len(batches)
+    assert total("seq.score") >= (assembly.sum() + dispatch.sum()
+                                  + total("seq.commit"))

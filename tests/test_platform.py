@@ -160,12 +160,18 @@ class TestBringUp:
             e2e = [t for t in traces
                    if {"producer", "router"} <= set(t["components"])]
             assert e2e, traces[:3]
-            with urllib.request.urlopen(
-                metrics + f"/traces/{e2e[0]['trace_id']}"
-            ) as r:
-                spans = json.loads(r.read())["spans"]
-            assert {"producer.batch", "router.batch"} <= {
-                s["name"] for s in spans}
+            # the newest trace may be a batch still in flight: its
+            # router.batch span closes last, after the route
+            deadline = time.monotonic() + 10.0
+            while True:
+                with urllib.request.urlopen(
+                    metrics + f"/traces/{e2e[0]['trace_id']}"
+                ) as r:
+                    names = {s["name"] for s in json.loads(r.read())["spans"]}
+                if "router.batch" in names or time.monotonic() > deadline:
+                    break
+                time.sleep(0.05)
+            assert {"producer.batch", "router.batch"} <= names
         finally:
             platform.down()
 
