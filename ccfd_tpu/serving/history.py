@@ -9,20 +9,25 @@ chunk loop into an overlapped serving dataflow — BENCH_r05 measured the
 old path at 1412 ms device dispatch vs 13 ms assembly per bucket
 (assembly_fraction 0.009): entirely dispatch-bound, serialized anyway.
 
-- ``HistoryStore`` — fixed-depth ring buffer per customer, bounded total
-  customers (LRU eviction at the cap), INTERNALLY STRIPED by key hash:
-  N stripes with per-stripe locks so ParallelRouter workers stop
-  convoying on one global lock, a global monotonic touch-stamp keeping
-  LRU eviction exact across stripes, an all-anonymous fast path that
-  takes no lock at all (cold REST scoring), and a vectorized ``prepare``
-  for the common no-duplicate-key chunk. Mutation is two-phase:
-  ``prepare()`` stages copies, ``commit()`` publishes them — a failed
-  scorer dispatch must not leave transactions in history that were never
-  routed. The store is CHECKPOINTABLE (snapshot/restore); ``snapshot``
-  is stripe-incremental (clean stripes reuse the previous snapshot's
-  entry list — no 150 MB memcpy under the checkpoint barrier; buffers
-  are immutable-by-convention, so entries are shared, never copied), and
-  the recovery coordinator treats the store as pipeline state: after a
+- ``HistoryStore`` — a fixed-depth ring per customer, all rings in one
+  slab that grows in blocks, bounded total customers (LRU eviction at the
+  cap; a freed slot is reused), INTERNALLY STRIPED by key hash: N stripes
+  with per-stripe locks so ParallelRouter workers stop convoying on one
+  global lock, a global monotonic touch-stamp keeping LRU eviction exact
+  across stripes, and an all-anonymous path that takes no lock at all
+  (cold REST scoring). The assembly touches each byte once: an append
+  writes the one new row at the ring's cursor, and ``prepare`` copies a
+  customer's ``filled`` rows — at most two contiguous slices — straight
+  into the (B, L, F) batch, which the caller may hand in already mapped
+  (``out=``, a ``StagingBatch`` that is used again). One path serves a
+  chunk with and without repeated keys. Mutation is two-phase:
+  ``prepare()`` stages views of the batch's rows, ``commit()`` writes
+  the new rows — a failed scorer dispatch must not leave transactions
+  in history that were never routed. The store is CHECKPOINTABLE
+  (snapshot/restore, format version 1): rings are mutable, so
+  ``snapshot`` copies each history out linearised and the barrier pays
+  for the live history bytes (154 MB at the default store), and the
+  recovery coordinator treats the store as pipeline state: after a
   crash rewind, replayed records re-build exactly the histories the cut
   had — without this, at-least-once redelivery would append every
   replayed transaction a second time and silently corrupt every active
@@ -52,8 +57,12 @@ old path at 1412 ms device dispatch vs 13 ms assembly per bucket
 
 TPU-first notes: histories assemble host-side into one contiguous array
 per micro-batch (one transfer, one dispatch — never per-customer gathers
-on device); every L bucket is static so XLA sees fixed (bucket, L, F)
-shapes; the model runs bf16 with f32 accumulation.
+on device), in a staging batch the scorer recycles once the batch's
+dispatches resolved and its commit landed (the runtime reads the host
+buffer on its own thread after the call returned; a batch the shadow tap
+or the canary gate keeps is its own and never recycled); every L bucket
+is static so XLA sees fixed (bucket, L, F) shapes; the model runs bf16
+with f32 accumulation.
 """
 from __future__ import annotations
 
@@ -78,33 +87,79 @@ DEFAULT_LEN_BUCKETS: tuple = ()
 DEFAULT_INFLIGHT = 2
 
 
+# bytes of slab mapped at a time: a block is one lazily zeroed mapping
+# (np.zeros of this size is calloc's fresh mmap), so memory becomes resident
+# in the kernel's fault granule as rows are written and not before: 4 kB
+# pages on Linux, where a ring two rows deep holds a fifteenth of its slot;
+# the whole slot under gVisor (the chip tool's sandbox) or where transparent
+# huge pages are always on, as the 61 kB buffer per customer before it did
+_BLOCK_BYTES = 32 << 20
+
+
 class _Stripe:
-    __slots__ = ("lock", "h", "dirty", "cache")
+    __slots__ = ("lock", "h")
 
     def __init__(self) -> None:
         self.lock = threading.Lock()
-        # key -> (buffer (L, F) f32, filled count, touch stamp)
-        self.h: OrderedDict[Any, tuple[np.ndarray, int, int]] = OrderedDict()
-        self.dirty = True
-        self.cache: list[tuple[int, Any, np.ndarray, int]] = []
+        # key -> (slot, filled count, touch stamp, write cursor)
+        self.h: OrderedDict[Any, tuple[int, int, int, int]] = OrderedDict()
+
+
+class StagingBatch:
+    """A reusable (rows, L, F) batch for ``HistoryStore.prepare(out=)``.
+
+    ``depth[i]`` is how many rows of ``hist[i]``, counted from the newest,
+    may be nonzero: everything left of them is zero. ``prepare`` keeps it
+    true, so a batch that is used again is cleared only where its last
+    use reached deeper than this one does."""
+
+    __slots__ = ("hist", "depth")
+
+    def __init__(self, rows: int, length: int, num_features: int):
+        self.hist = np.zeros((rows, length, num_features), np.float32)
+        self.depth = np.zeros((rows,), np.int32)
+
+    def settle(self, n: int, filled: np.ndarray) -> None:
+        """The first ``n`` rows were just filled ``filled`` deep: zero what
+        the last use left deeper than that, and whatever it left in the
+        rows past ``n``."""
+        L = self.hist.shape[1]
+        depth = self.depth
+        for i in np.nonzero(depth[:n] > filled)[0]:
+            self.hist[i, L - depth[i]:L - filled[i]] = 0.0
+        depth[:n] = filled
+        for i in np.nonzero(depth[n:])[0] + n:
+            self.hist[i, L - depth[i]:] = 0.0
+        depth[n:] = 0
 
 
 class HistoryStore:
-    """Fixed-depth per-customer ring buffers with bounded total keys.
+    """Fixed-depth per-customer rings in one slab, bounded total keys.
 
-    Memory bound: ``max_customers * length * num_features * 4`` bytes —
-    the default (20k x 64 x 30 x f32) admits ~150 MB resident on the
-    serving host; size the cap to the deployment's live-customer working
-    set, not its total cardinality (LRU keeps the hot set).
+    A customer is a slot of the (slots, L, F) float32 slab with a write
+    cursor and a ``filled`` count; an append writes the one new row at the
+    cursor. The linearised, newest-last (L, F) view of a history exists
+    only where something asks for it: in the batch ``prepare`` fills, in
+    a snapshot. The slab grows in blocks of ``_BLOCK_BYTES`` as slots are
+    first used, and nothing of it is touched at construction.
+
+    Memory bound: ``max_customers * length * num_features * 4`` bytes (a
+    commit may stand one batch's new keys over the cap until its eviction
+    pass) — the default (20k x 64 x 30 x f32) admits ~150 MB resident on
+    the serving host; size the cap to the deployment's live-customer
+    working set, not its total cardinality (LRU keeps the hot set). A
+    ring holds resident the pages its rows are on; a slot that eviction
+    frees is reused before the slab grows.
 
     Concurrency: reads/stages take only the key's stripe lock (and the
     all-anonymous path none); ``commit``/``restore``/``snapshot``
     serialize on one commit lock (commits are per router batch — rare
     next to prepares — and a restore interleaving a half-published
-    commit would corrupt the cut). Stored buffers are IMMUTABLE by
-    convention: prepare copies before mutating and commit replaces
-    entries, which is what lets lookups hand out references under the
-    stripe lock and snapshots share entries across generations."""
+    commit would corrupt the cut). Rows in the slab are MUTABLE: a slot
+    is reachable only through its key's entry, is written under that
+    key's stripe lock, and is copied out under it — ``prepare`` never
+    hands out a reference into the slab. The slot allocator is touched
+    only under the commit lock."""
 
     def __init__(self, length: int = 64, num_features: int = NUM_FEATURES,
                  max_customers: int = 20_000, stripes: int = DEFAULT_STRIPES):
@@ -118,6 +173,12 @@ class HistoryStore:
         self._commit_lock = threading.Lock()
         self._count_lock = threading.Lock()
         self._total = 0
+        slot_bytes = self.length * self.num_features * 4
+        self._block_slots = max(1, min(self.max_customers,
+                                       _BLOCK_BYTES // slot_bytes))
+        self._blocks: list[np.ndarray] = []  # each (block_slots, L, F)
+        self._free_slots: list[int] = []     # freed by eviction
+        self._next_slot = 0
         # global touch stamp: commit order defines recency ACROSS stripes,
         # so LRU eviction at the cap stays exact despite per-stripe LRU
         # order (itertools.count().__next__ is GIL-atomic)
@@ -140,10 +201,52 @@ class HistoryStore:
         with self._count_lock:
             return self._total
 
+    # -- the slab (allocator under the commit lock, rows under the key's
+    # stripe lock) ----------------------------------------------------------
+    def _ring(self, slot: int) -> np.ndarray:
+        return self._blocks[slot // self._block_slots][
+            slot % self._block_slots]
+
+    def _new_slot(self) -> int:
+        if self._free_slots:
+            return self._free_slots.pop()
+        slot = self._next_slot
+        self._next_slot += 1
+        if slot >= len(self._blocks) * self._block_slots:
+            self._blocks.append(np.zeros(
+                (self._block_slots, self.length, self.num_features),
+                np.float32))
+        return slot
+
+    def _append(self, slot: int, cursor: int, view: np.ndarray,
+                m: int) -> int:
+        """Write the ``m`` newest rows of a linearised ``view`` into the
+        ring at ``cursor`` (at most two slices); returns the new cursor."""
+        L = self.length
+        ring = self._ring(slot)
+        first = min(m, L - cursor)
+        ring[cursor:cursor + first] = view[L - m:L - m + first]
+        if m > first:
+            ring[:m - first] = view[L - m + first:]
+        return (cursor + m) % L
+
+    def _linear(self, dst: np.ndarray, slot: int, k: int,
+                cursor: int) -> None:
+        """Copy the ``k`` newest rows of a ring, oldest first, into
+        ``dst`` (k, F): at most two contiguous slices."""
+        ring = self._ring(slot)
+        lo = cursor - k
+        if lo >= 0:
+            dst[:] = ring[lo:cursor]
+        else:
+            dst[:-lo] = ring[lo:]
+            dst[-lo:] = ring[:cursor]
+
     # -- staging ------------------------------------------------------------
     # ccfd-lint: hot-path
     def prepare(
-        self, ids: list, rows: np.ndarray, overlay: dict | None = None
+        self, ids: list, rows: np.ndarray, overlay: dict | None = None,
+        out: StagingBatch | None = None,
     ) -> tuple[np.ndarray, tuple[int, dict, np.ndarray]]:
         """Stage this chunk: return the (B, L, F) batch of post-append
         histories (newest last) plus a token ``(gen, staged, filled)``,
@@ -160,119 +263,88 @@ class HistoryStore:
         anonymous: scored against an empty history and NEVER stored — a
         bounded store must not spend its cap (and evict real customers)
         on keys no future record can match. An ALL-anonymous chunk takes
-        no lock and stages nothing (the cold-REST fast path)."""
+        no lock and stages nothing (the cold-REST fast path).
+
+        ``out``: the batch to fill, in memory that is already mapped. The
+        returned batch is then ``out.hist[:B]``, and the rows of ``out``
+        past B are zero: padding to a larger bucket is already there.
+        Without it a fresh zeroed batch is allocated. Either way each
+        staged entry is ``(view, filled, base, new)``: a VIEW of the
+        batch's row of the key's last occurrence, the stamp of the store
+        entry it derives from (None for a fresh key) and how many of its
+        rows are new since — so the batch must stay as it is until the
+        token is committed or dropped."""
         rows = np.ascontiguousarray(rows, np.float32)
         n = len(rows)
         L = self.length
-        out = np.zeros((n, L, self.num_features), np.float32)
-        filled_out = np.ones((n,), np.int32)
+        if out is None:
+            hist = np.zeros((n, L, self.num_features), np.float32)
+        else:
+            hist = out.hist[:n]
         gen = self._gen
         if n:
-            out[:, -1] = rows
-        keyed = [(i, ids[i]) for i in range(n) if ids[i] is not None]
-        if not keyed:
-            return out, (gen, {}, filled_out)
-        keys = [k for _, k in keyed]
-        if len(set(keys)) == len(keys):
-            staged = self._prepare_unique(keyed, rows, out, filled_out,
-                                          overlay)
-        else:
-            staged = self._prepare_general(ids, rows, out, filled_out,
-                                           overlay)
-        return out, (gen, staged, filled_out)
-
-    def _lookup_refs(self, pairs: list[tuple[int, Any]]) -> dict:
-        """(row, key) pairs -> {row: (buf_ref, filled)} for keys live in
-        the store; one pass per touched stripe, references only under the
-        lock (buffers are immutable, see class docstring)."""
+            hist[:, -1] = rows
+        # key -> (row of its last occurrence, filled there, base, new);
+        # None until the key's first occurrence is assembled
+        state: dict[Any, tuple | None] = {}
         by_stripe: dict[int, list[tuple[int, Any]]] = {}
-        for i, key in pairs:
-            by_stripe.setdefault(hash(key) % self.stripes, []).append((i, key))
-        hits: dict[int, tuple[np.ndarray, int, int]] = {}
+        later: list[tuple[int, Any]] = []  # overlay hits and repeats
+        repeats = False
+        for i, key in enumerate(ids):
+            if key is None:
+                continue  # cold context + this row, already assembled
+            if key in state:
+                repeats = True
+                later.append((i, key))
+                continue
+            state[key] = None
+            if overlay and key in overlay:
+                later.append((i, key))
+            else:
+                by_stripe.setdefault(hash(key) % self.stripes, []).append(
+                    (i, key))
+        filled_out = [1] * n
+        # first occurrences: one pass per touched stripe, the copy out of
+        # the slab taken under the lock (rings are mutable)
         for si, group in by_stripe.items():
             st = self._stripes[si]
             with st.lock:
                 h = st.h
                 for i, key in group:
                     ent = h.get(key)
-                    if ent is not None:
-                        hits[i] = ent  # (buf, filled, stamp) — immutable
-        return hits
-
-    def _prepare_unique(self, keyed, rows, out, filled_out, overlay) -> dict:
-        """No key repeats in the chunk: assembly vectorizes — one stripe
-        pass collects buffer references, one batched shifted-gather fills
-        ``out``, one contiguous copy per row stages."""
-        L = self.length
-        hits: dict[int, tuple[np.ndarray, int]] = {}
-        if overlay:
-            missing = []
-            for i, key in keyed:
-                ent = overlay.get(key)
-                if ent is not None:
-                    hits[i] = ent
-                else:
-                    missing.append((i, key))
-        else:
-            missing = keyed
-        if missing:
-            hits.update(self._lookup_refs(missing))
-        if hits:
-            # shift-left ring, batched: rows 1..L-1 of each prior buffer
-            # land at 0..L-2; the newest transaction is already at L-1
-            hi = np.fromiter(hits.keys(), np.intp, len(hits))
-            out[hi, : L - 1] = np.stack([hits[i][0] for i in hi])[:, 1:]
-        staged: dict[Any, tuple[np.ndarray, int, int | None]] = {}
-        for i, key in keyed:
-            ent = hits.get(i)
-            filled = min((ent[1] if ent is not None else 0) + 1, L)
-            # base = the stamp of the store entry this staging derives
-            # from (None for a fresh key): commit's optimistic check
-            staged[key] = (out[i].copy(), filled,
-                           ent[2] if ent is not None else None)
-            filled_out[i] = filled
-        return staged
-
-    def _prepare_general(self, ids, rows, out, filled_out, overlay) -> dict:
-        """Duplicate keys in the chunk: the per-row loop (earlier
-        same-chunk rows must be visible to later assemblies), with store
-        lookups still batched per stripe up front."""
-        L = self.length
-        seen: dict[Any, int] = {}
-        firsts = []
-        for i, key in enumerate(ids):
-            if key is not None and key not in seen:
-                seen[key] = i
-                firsts.append((i, key))
-        refs_by_row = self._lookup_refs(firsts)
-        refs = {ids[i]: ent for i, ent in refs_by_row.items()}
-        staged: dict[Any, tuple[np.ndarray, int, int | None]] = {}
-        for i, key in enumerate(ids):
-            if key is None:
-                continue  # cold context + this row, already assembled
-            ent = staged.get(key)
-            if ent is None and overlay is not None:
-                o = overlay.get(key)
-                if o is not None:  # earlier chunk's staged copy keeps its
-                    ent = (o[0].copy(), o[1], o[2])  # original base stamp
-            if ent is None:
-                r = refs.get(key)
-                if r is None:
-                    buf = np.zeros((L, self.num_features), np.float32)
-                    filled, base = 0, None
-                else:  # copy-on-write: the live buffer stays untouched
-                    buf, filled, base = r[0].copy(), r[1], r[2]
+                    if ent is None:
+                        state[key] = (i, 1, None, 1)
+                        continue
+                    slot, filled, stamp, cursor = ent
+                    k = min(filled, L - 1)
+                    if k:
+                        self._linear(hist[i, L - 1 - k:L - 1], slot, k,
+                                     cursor)
+                    filled_out[i] = k + 1
+                    state[key] = (i, k + 1, stamp, 1)
+        # then, in arrival order, the rows whose context is an earlier
+        # chunk's staged view or an earlier row of this batch
+        for i, key in later:
+            s = state[key]
+            if s is None:  # an earlier chunk's staging keeps its base
+                src, filled, base, new = overlay[key]
             else:
-                buf, filled, base = ent
-            buf[:-1] = buf[1:]
-            buf[-1] = rows[i]
-            filled = min(filled + 1, L)
-            if key in staged:  # recency = LAST occurrence (see score())
-                del staged[key]
-            staged[key] = (buf, filled, base)
-            out[i] = buf
-            filled_out[i] = filled
-        return staged
+                src, (_, filled, base, new) = hist[s[0]], s
+            k = min(filled, L - 1)
+            if k:
+                hist[i, L - 1 - k:L - 1] = src[L - k:]
+            filled_out[i] = k + 1
+            state[key] = (i, k + 1, base, new + 1)
+        filled_arr = np.array(filled_out, np.int32)
+        if out is not None:
+            out.settle(n, filled_arr)
+        # recency = LAST occurrence (see score()): commit stamps in the
+        # order of ``staged``
+        items = (sorted(state.items(), key=lambda kv: kv[1][0])
+                 if repeats else state.items())
+        staged = {key: (hist[i], filled, base, new)
+                  for key, (i, filled, base, new) in items}
+        return hist, (gen, staged, filled_arr)
 
     # -- publication --------------------------------------------------------
     # ccfd-lint: hot-path
@@ -290,15 +362,21 @@ class HistoryStore:
         next batch on the same partition keys) is SKIPPED rather than
         clobbering the newer state, counted in ``contended_skips``. The
         skipped batch's appends are recovered by the next crash-restore
-        replay (the records are in the routed stream)."""
+        replay (the records are in the routed stream).
+
+        Where the live entry still stands on the base stamp, only the
+        staged entry's new rows are appended to its ring; a key with no
+        live entry (fresh, or evicted since the prepare) takes a slot and
+        the whole staged history."""
         gen, staged = token[0], token[1]
         if not staged:
             return True
+        L = self.length
         with self._commit_lock:
             if gen != self._gen:
                 return False
             # stamps follow the batch's ARRIVAL order (staged dicts
-            # preserve first-occurrence order), assigned BEFORE the
+            # preserve last-occurrence order), assigned BEFORE the
             # per-stripe insertion pass: stamping inside that pass would
             # make whole stripe-groups "newest" within a batch, and under
             # a binding cap eviction would systematically keep one hash
@@ -313,20 +391,21 @@ class HistoryStore:
                 st = self._stripes[si]
                 with st.lock:
                     h = st.h
-                    for key, (buf, filled, base), stamp in items:
+                    for key, (view, filled, base, new), stamp in items:
                         cur = h.get(key)
-                        if cur is not None and (base is None
-                                                or cur[2] != base):
+                        if cur is None:
+                            slot, cursor, m = self._new_slot(), 0, filled
+                            added += 1
+                        elif base is None or cur[2] != base:
                             # live entry moved since this prepare: a
                             # concurrent batch owns the newer state
                             self._contended += 1
                             continue
-                        if cur is not None:
-                            h.move_to_end(key)
                         else:
-                            added += 1
-                        h[key] = (buf, filled, stamp)
-                    st.dirty = True
+                            slot, cursor, m = cur[0], cur[3], min(new, L)
+                            h.move_to_end(key)
+                        h[key] = (slot, filled, stamp,
+                                  self._append(slot, cursor, view, m))
             if added:
                 with self._count_lock:
                     self._total += added
@@ -337,7 +416,9 @@ class HistoryStore:
         """Pop the globally-oldest entry until under the cap. Runs under
         the commit lock (single evictor); takes one stripe lock at a time
         — the scan reads each stripe's LRU head stamp, the pop re-checks
-        under the chosen stripe's lock."""
+        under the chosen stripe's lock. The freed slot keeps its rows:
+        nothing reads a ring past its ``filled``, and the next key to
+        take the slot starts at cursor 0."""
         while True:
             with self._count_lock:
                 if self._total <= self.max_customers:
@@ -354,34 +435,33 @@ class HistoryStore:
             st = self._stripes[best_i]
             with st.lock:
                 if st.h:
-                    st.h.popitem(last=False)
-                    st.dirty = True
+                    _, ent = st.h.popitem(last=False)
+                    self._free_slots.append(ent[0])
                     with self._count_lock:
                         self._total -= 1
 
     # -- checkpoint surface (pipeline state, like the engine) ---------------
     def snapshot(self) -> dict:
         """State for the recovery coordinator's cut: runs under the
-        checkpoint barrier. Stripe-incremental and ZERO-copy: a stripe
-        untouched since the last snapshot reuses its cached entry list,
-        and entries share the live buffers (immutable by convention — the
-        store replaces, never mutates them), so the barrier cost is
-        proportional to churn, not store size. The coordinator
-        JSON-normalizes outside the barrier (recovery.py _np_jsonable);
-        ``restore`` accepts either form. Entries are ordered coldest
-        first (global touch stamps), so a restore rebuilds the same
-        eviction order."""
+        checkpoint barrier. Format version 1: ``[key, (L, F) buffer
+        newest last, filled]`` per customer, each buffer a COPY linearised
+        out of its ring under the stripe lock (rings are mutable, so
+        nothing can be shared with the live store). The barrier's cost is
+        therefore proportional to the live history bytes, not to churn:
+        a full default store copies 20,000 x 64 x 30 x 4 = 154 MB. The
+        coordinator JSON-normalizes outside the barrier (recovery.py
+        _np_jsonable); ``restore`` accepts either form. Entries are
+        ordered coldest first (global touch stamps), so a restore
+        rebuilds the same eviction order."""
+        L = self.length
         with self._commit_lock:
             entries: list[tuple[int, Any, np.ndarray, int]] = []
             for st in self._stripes:
                 with st.lock:
-                    if st.dirty:
-                        st.cache = [
-                            (stamp, key, buf, filled)
-                            for key, (buf, filled, stamp) in st.h.items()
-                        ]
-                        st.dirty = False
-                    entries.extend(st.cache)
+                    for key, (slot, filled, stamp, cursor) in st.h.items():
+                        buf = np.zeros((L, self.num_features), np.float32)
+                        self._linear(buf[L - filled:], slot, filled, cursor)
+                        entries.append((stamp, key, buf, filled))
             entries.sort(key=lambda e: e[0])
             return {
                 "version": 1,
@@ -399,12 +479,16 @@ class HistoryStore:
         generation bumps LAST, so a prepare racing this call either sees
         the old generation (its commit is dropped) or the fully-restored
         state."""
+        L = self.length
         with self._commit_lock:
             for st in self._stripes:
                 with st.lock:
                     st.h.clear()
-                    st.dirty = True
-                    st.cache = []
+            # every stripe is empty, so no slot is reachable: the slab
+            # starts over
+            self._blocks = []
+            self._free_slots = []
+            self._next_slot = 0
             total = 0
             if snap is not None:
                 if snap.get("version") != 1:
@@ -414,14 +498,14 @@ class HistoryStore:
                         or int(snap["num_features"]) != self.num_features):
                     raise ValueError("history snapshot shape mismatch")
                 for key, buf, filled in snap["customers"]:
+                    buf = np.asarray(buf, np.float32).reshape(
+                        L, self.num_features)
+                    filled = int(filled)
                     st = self._stripe_of(key)
                     with st.lock:
-                        st.h[key] = (
-                            np.asarray(buf, np.float32).reshape(
-                                self.length, self.num_features),
-                            int(filled),
-                            self._stamp(),
-                        )
+                        slot = self._new_slot()
+                        st.h[key] = (slot, filled, self._stamp(),
+                                     self._append(slot, 0, buf, filled))
                     total += 1
             with self._count_lock:
                 self._total = total
@@ -489,6 +573,12 @@ class SeqScorer:
 
         self.store = HistoryStore(length=length, max_customers=max_customers,
                                   stripes=stripes)
+        # recycled (largest bucket, L, F) staging batches: a call takes at
+        # most inflight + 1 and puts them back once its batch is
+        # committed, so the list is bounded by what is in flight
+        # (deque.pop / extend are GIL-atomic: safe under the
+        # ParallelRouter's workers)
+        self._staging: deque = deque()
         # device telemetry plane (observability/device.py): the seq
         # dispatch ships (B, L, F) history batches whose transfer happens
         # INSIDE the jitted call, so only the bytes are separately
@@ -853,6 +943,14 @@ class SeqScorer:
         return np.searchsorted(np.asarray(self.len_buckets), filled,
                                side="left")
 
+    def _take_staging(self) -> tuple[StagingBatch, int]:
+        """A staging batch and whether it came from the free list."""
+        try:
+            return self._staging.pop(), 1
+        except IndexError:
+            return StagingBatch(self.batch_sizes[-1], self.store.length,
+                                self.store.num_features), 0
+
     # -- the overlapped scoring loop ---------------------------------------
     def score(self, x: np.ndarray, ids: list | None = None) -> np.ndarray:
         """Router-compatible scorer: (B, F) rows -> (B,) probabilities,
@@ -903,6 +1001,7 @@ class SeqScorer:
             gate = None
         tap_chunks: list[tuple[np.ndarray, int, int]] = []
         keep_hist = tap is not None or gate is not None
+        taken: list[StagingBatch] = []
         t_asm = 0.0
         t_disp = 0.0
         n_anon = 0
@@ -911,8 +1010,15 @@ class SeqScorer:
             stop = min(start + largest, n)
             with phase("seq.gather", rows=stop - start) as ph:
                 chunk_ids = ids[start:stop]
+                # a batch the tap or the gate keeps past this call is the
+                # chunk's own (prepare allocates it); so is one past what
+                # can be in flight
+                stage, recycled = None, 0
+                if not keep_hist and len(taken) <= self.inflight:
+                    stage, recycled = self._take_staging()
+                    taken.append(stage)
                 hist, (chunk_gen, staged, filled) = self.store.prepare(
-                    chunk_ids, x[start:stop], overlay=merged
+                    chunk_ids, x[start:stop], overlay=merged, out=stage
                 )
                 # the FIRST chunk's generation stamps the whole batch: a
                 # restore landing between chunk prepares bumps the store's
@@ -937,11 +1043,14 @@ class SeqScorer:
                 if keep_hist:
                     tap_chunks.append((hist, start, stop))
                 # a row at depth 1 is anonymous or its customer's first;
-                # rows beyond one per staged key repeat a key of the chunk
-                # (> 0: the store took its per-row path, not the batched)
+                # rows beyond one per staged key repeat a key of the chunk;
+                # every row's context is its depth less the row itself
                 ph.set(new_customers=int(np.count_nonzero(filled == 1))
                        - anon,
-                       repeated_keys=stop - start - anon - len(staged))
+                       repeated_keys=stop - start - anon - len(staged),
+                       gathered_bytes=(int(filled.sum()) - (stop - start))
+                       * hist.shape[2] * hist.itemsize,
+                       recycled=recycled)
             t_asm += ph.seconds
             for bi in np.unique(li):
                 lb = ladder[bi]
@@ -953,6 +1062,10 @@ class SeqScorer:
                 # dispatch the extra launches pipeline instead of queuing
                 pos = 0
                 m_total = len(idx)
+                # every row of the chunk at full length (always, with the
+                # ladder off): the group is the chunk in order, and its
+                # sub-batches are views of it, not copies
+                whole = lb == L and m_total == len(hist)
                 while pos < m_total:
                     with phase("seq.pad", l_bucket=lb) as ph:
                         rem = m_total - pos
@@ -965,15 +1078,19 @@ class SeqScorer:
                             bucket = self.batch_sizes[0]
                         m = min(rem, bucket)
                         sub_idx = idx[pos:pos + m]
-                        pos += m
-                        if lb == L and m == len(hist):
-                            sub = hist
-                        else:  # right-aligned window
+                        if not whole:  # right-aligned window
                             sub = hist[sub_idx, L - lb:, :]
-                        if m < bucket:
+                        elif stage is None:
+                            sub = hist[pos:pos + m]
+                        else:
+                            # a staging batch is zero past the chunk's
+                            # rows: its padding is already there
+                            sub = stage.hist[pos:pos + bucket]
+                        pos += m
+                        if len(sub) < bucket:
                             sub = np.concatenate(
-                                [sub, np.zeros((bucket - m, *sub.shape[1:]),
-                                               np.float32)]
+                                [sub, np.zeros((bucket - len(sub),
+                                                *sub.shape[1:]), np.float32)]
                             )
                         with self._params_lock:
                             params, apply_fn = self.params, self._apply
@@ -1012,6 +1129,11 @@ class SeqScorer:
                 ph.set(stale=int(not committed))
             if not committed and self._c_stale is not None:
                 self._c_stale.inc()
+        # every dispatch resolved and the staged views are spent: only now
+        # may another call fill these batches (the runtime reads a host
+        # buffer on its own thread after apply_fn returned; a call that
+        # raised keeps its batches out of the list for that reason)
+        self._staging.extend(taken)
         if tap is not None:
             # the tap pairs PURE champion scores (offered before any
             # canary override, like the row lane's tap-inside/gate-outside

@@ -485,25 +485,27 @@ def test_async_overlapped_scores_match_synchronous():
         np.testing.assert_array_equal(a[k], b[k])
 
 
-def test_snapshot_is_stripe_incremental_and_zero_copy():
-    """Clean stripes reuse the cached entry list and entries share the
-    live buffers (immutable by convention): back-to-back snapshots hand
-    out the SAME arrays, and a commit touching one key only refreshes
-    that stripe's entries."""
+def test_snapshot_copies_out_of_the_mutable_rings():
+    """Rings in the slab are appended to in place, so a snapshot shares
+    nothing with the live store (PR 25; the immutable buffers it used to
+    share are gone): each call hands out its own linearised copies, and a
+    later commit moves neither an older snapshot nor the untouched key."""
     st = HistoryStore(length=2, num_features=2, max_customers=8, stripes=4)
     st.commit(st.prepare(["a", "b"], np.ones((2, 2), np.float32))[1])
     s1 = st.snapshot()
     s2 = st.snapshot()
     bufs1 = {c[0]: c[1] for c in s1["customers"]}
     bufs2 = {c[0]: c[1] for c in s2["customers"]}
-    assert all(bufs1[k] is bufs2[k] for k in bufs1)  # no re-copy
+    assert all(bufs1[k] is not bufs2[k] for k in bufs1)  # copies
+    assert all(np.array_equal(bufs1[k], bufs2[k]) for k in bufs1)
     st.commit(st.prepare(["a"], np.full((1, 2), 2.0, np.float32))[1])
     s3 = st.snapshot()
     bufs3 = {c[0]: c[1] for c in s3["customers"]}
-    assert bufs3["a"] is not bufs1["a"]  # touched: fresh entry
-    assert bufs3["b"] is bufs1["b"]      # untouched stripe: shared
+    assert np.all(np.asarray(bufs3["a"])[-1] == 2.0)  # touched: appended
+    assert np.array_equal(bufs3["b"], bufs1["b"])     # untouched: equal
     # and the older snapshots were not corrupted by the later commit
     assert np.all(np.asarray(bufs1["a"])[-1] == 1.0)
+    assert np.all(np.asarray(bufs1["a"])[0] == 0.0)
 
 
 def test_quantized_swap_rebinds_the_serving_graph():
