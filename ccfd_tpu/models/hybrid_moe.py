@@ -1,0 +1,668 @@
+"""Hybrid linear-attention / latent-attention / sparse-expert backbone over
+a customer's tokenised transaction window: the third history family.
+
+A decoder-only language model of three layer kinds in one stack, driven by
+the published ``config.json`` keys of the model it serves (nothing here is
+one model's numbers): **KDA** (gated delta-rule linear attention with a
+short causal convolution, served as a chunked scan), **MLA** (latent keys
+and values with a decoupled rotary part) and a **sparse expert layer**
+(sigmoid scores, expert bias, group-limited top-k, one shared expert).
+Causal throughout. The window (B, L, F) of the ``HistoryStore`` is
+tokenised on the device (TabFormer-style: column j of a record is token
+j * bins + its quantile bin), so a verdict is one L * F token pass read
+out at the newest record's last token.
+
+**The share.** The expert layer is told which experts it holds
+(``HybridConfig.held_first`` / ``held_count``), routes over all of the
+published experts and computes its own experts' part of the result: what
+expert parallelism asks of the program. A token's pairs with absent
+experts are left out and the partial sum goes on; on one chip the layer
+runs without its exchange and nothing stands in for the absent chips.
+**Dropless**: the (token, held expert) pairs are sorted by expert and each
+expert's group is cut into tiles of ``MOE_TILE`` rows; a loop whose trip
+count is the number of tiles the batch really has multiplies each tile with
+its expert's matrices. No capacity factor, no pair dropped, work in
+proportion to the pairs served.
+
+**Padding.** ``filled`` (B,) is each row's count of real records
+(right-aligned, as ``StagingBatch`` stages them). Positions count from a
+row's first real token; padding keys are masked in MLA; a padding token
+has beta = 0, alpha = 1 and sends zeros into the convolution, so the KDA
+state passes it unchanged; it routes to no expert. A row's verdict is
+therefore the same at every window length that holds its history.
+
+The equations, with the key each symbol is read from, are in the plain
+reference ``benchmark/reference/hybrid_moe_f32.py`` (which imports nothing
+from here); the parameter tree is the one its ``make_params`` draws.
+
+Precision: matrices bfloat16, products accumulated in float32, the
+residual stream, norms, gates, softmax and the router in float32 (the
+router at ``highest``: a token near a tie must choose as the model does),
+the KDA state and everything inside a chunk in float32.
+
+Device scopes (``jax.named_scope``, so a capture's operations carry them):
+``lm.embed``, ``kda``, ``mla``, ``dense_ffn``, ``moe.route``,
+``moe.experts``, ``moe.shared``, ``lm.head``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from functools import partial
+from typing import Any, Mapping
+
+import jax
+import jax.numpy as jnp
+
+Params = Mapping[str, Any]
+
+F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
+# inside a KDA chunk: what touches the carried state multiplies float32
+# operands as three bfloat16 passes; what stays inside the chunk (the
+# pairwise matrices and their products with u) as one; the triangular
+# inverse at ``highest`` (few operations, and the solve's error is every
+# later token's)
+KDA_PRECISION = jax.lax.Precision.HIGH
+KDA_INSIDE = jax.lax.Precision.DEFAULT
+L2_EPS = 1e-6
+MASKED = -1e30
+MOE_TILE = 256  # rows of one expert's group multiplied at a time
+KDA_SUB = 16  # kda_lower_bound * KDA_SUB must stay inside float32's exponent
+MLA_QUERY_BLOCKS = 4
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    """The model's settings, hashable so that a jit takes them as static."""
+
+    heads: int
+    head_dim: int
+    nope: int
+    rope: int
+    v_dim: int
+    kv_rank: int
+    kda_lower_bound: float
+    kda_chunk: int
+    rope_theta: float
+    eps: float
+    layers: tuple[tuple[str, str], ...]  # (mixer, feed-forward) per layer
+    routed: int  # experts the router scores: the published count
+    held_first: int
+    held_count: int
+    per_token: int
+    groups: int
+    groups_kept: int
+    routed_scale: float
+    bins: int
+    fraud_id: int
+    legit_id: int
+    shift: float
+
+    @classmethod
+    def from_dict(cls, m: Mapping[str, Any]) -> "HybridConfig":
+        """From a configuration under the published key names (plus the
+        cut: ``layers_kept``, ``experts_held``, ``num_experts_routed_over``,
+        and the deployment's ``bins``, ``kda_chunk`` and ``readout``)."""
+        period, dense = int(m["layer_group_size"]), int(
+            m["first_k_dense_replace"])
+        layers = tuple(("mla" if (i + 1) % period == 0 else "kda",
+                        "dense" if i < dense else "moe")
+                       for i in m["layers_kept"])
+        held = m["experts_held"]
+        if int(held["count"]) != int(m["num_experts"]):
+            raise ValueError("experts_held.count is not num_experts")
+        if int(m["v_head_dim"]) != int(m["head_dim"]):
+            raise ValueError("KDA heads are head_dim wide in keys and values")
+        chunk = int(m.get("kda_chunk", 64))
+        if chunk % KDA_SUB or abs(float(m["kda_lower_bound"])) * KDA_SUB > 85:
+            raise ValueError("kda_chunk / kda_lower_bound outside what the "
+                             "chunked scan holds in float32")
+        return cls(
+            heads=int(m["num_attention_heads"]),
+            head_dim=int(m["head_dim"]), nope=int(m["qk_nope_head_dim"]),
+            rope=int(m["qk_rope_head_dim"]), v_dim=int(m["v_head_dim"]),
+            kv_rank=int(m["kv_lora_rank"]),
+            kda_lower_bound=float(m["kda_lower_bound"]), kda_chunk=chunk,
+            rope_theta=float(m["rope_theta"]), eps=float(m["rms_norm_eps"]),
+            layers=layers, routed=int(m["num_experts_routed_over"]),
+            held_first=int(held["first"]), held_count=int(held["count"]),
+            per_token=int(m["num_experts_per_tok"]), groups=int(m["n_group"]),
+            groups_kept=int(m["topk_group"]),
+            routed_scale=float(m["routed_scaling_factor"]),
+            bins=int(m["bins"]),
+            fraud_id=int(m["readout"]["fraud_id"]),
+            legit_id=int(m["readout"]["legit_id"]),
+            shift=float(m["readout"]["shift"]))
+
+    @property
+    def moe_layers(self) -> int:
+        return sum(1 for _, ffn in self.layers if ffn == "moe")
+
+
+def owns(params: Any) -> bool:
+    """Whether a parameter tree is this family's (the scorer asks once,
+    where a tree arrives without a name)."""
+    return isinstance(params, Mapping) and "edges" in params \
+        and "layers" in params
+
+
+# -- small pieces ---------------------------------------------------------------
+
+def _rms(x, weight, eps):
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * weight
+
+
+def _mm(x, w, dtype):
+    """x @ w in ``dtype`` with float32 accumulation."""
+    return jnp.einsum("...i,io->...o", x.astype(dtype), w.astype(dtype),
+                      preferred_element_type=F32)
+
+
+def _swiglu(p, x, dtype):
+    h = jax.nn.silu(_mm(x, p["gate"], dtype)) * _mm(x, p["up"], dtype)
+    return _mm(h, p["down"], dtype)
+
+
+def tokenise(edges, hist, bins: int):
+    """(B, L, F) records -> (B, L * F) token ids: a value's bin is the
+    number of its column's edges below it."""
+    b, length, cols = hist.shape
+    bin_ = jnp.sum(hist[..., None] > edges, axis=-1, dtype=jnp.int32)
+    return (bin_ + jnp.arange(cols, dtype=jnp.int32) * bins).reshape(
+        b, length * cols)
+
+
+# -- KDA ------------------------------------------------------------------------
+
+def _short_conv(u, taps):
+    """Causal depthwise convolution along axis 1 of ``u`` (B, T, H, d);
+    ``taps`` (K, H, d), the last on the current token. T is a major axis
+    of this layout, so a shift by a token moves whole tiles."""
+    k, length = taps.shape[0], u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0), (0, 0)))
+    out = padded[:, :length] * taps[0]
+    for j in range(1, k):
+        out = out + padded[:, j:j + length] * taps[j]
+    return out
+
+
+def _unit_lower_inverse(a, base: int = 8):
+    """(I + a)^-1 for strictly lower-triangular ``a`` (..., C, C), block
+    by block. The ``base`` x ``base`` diagonal blocks are inverted by the
+    nilpotent series (with n = -a, (I + a)^-1 = (I + n)(I + n^2)(I + n^4):
+    at 8 rows no power grows past a few tens even where every entry of a is
+    near 1, as it is between identical tokens under a slow gate); then
+    neighbouring blocks are joined, size by size: the inverse of [[A, 0],
+    [C, B]] is [[A^-1, 0], [-B^-1 C A^-1, B^-1]]. Squaring the whole
+    matrix instead meets powers of 1e5 and more at C = 64 and cancels them
+    in float32: wrong by far."""
+    c = a.shape[-1]
+    lead = a.shape[:-2]
+
+    def diagonal(x, size, offset=0):  # (..., c // step, size, size)
+        step = size * (2 if offset else 1)
+        return jnp.stack([
+            x[..., lo + offset:lo + offset + size, lo:lo + size]
+            for lo in range(0, c, step)], axis=-3)
+
+    n = -diagonal(a, base)
+    eye = jnp.eye(base, dtype=a.dtype)
+    inv = eye + n
+    power = 1
+    while power * 2 < base:
+        n = jnp.matmul(n, n, precision=HIGHEST)
+        inv = jnp.matmul(inv, eye + n, precision=HIGHEST)
+        power *= 2
+    size = base
+    while size < c:
+        top, bottom = inv[..., 0::2, :, :], inv[..., 1::2, :, :]
+        below = diagonal(a, size, offset=size)  # C of each pair of blocks
+        corner = -jnp.matmul(jnp.matmul(bottom, below, precision=HIGHEST),
+                             top, precision=HIGHEST)
+        zeros = jnp.zeros_like(top)
+        inv = jnp.concatenate([jnp.concatenate([top, zeros], -1),
+                               jnp.concatenate([corner, bottom], -1)], -2)
+        size *= 2
+    return inv.reshape(*lead, c, c)
+
+
+def _kda_chunk(state, chunk, sub: int):
+    """One chunk of the gated delta rule for every (row, head) at once.
+
+    ``state`` (B, H, dk, dv) is S at the chunk's start; ``chunk`` holds q,
+    k (B, C, H, dk), v (B, C, H, dv), the log-decays g (B, C, H, dk) <= 0
+    and beta (B, C, H), laid out as the projections leave them. With G_t
+    the running sum of g inside the chunk, u_t = beta_t (v_t - S_(t-1)^T
+    Diag(alpha_t) k_t) solves (I + A) U = beta (V - (K e^G) S), A_ti =
+    beta_t sum_c k_tc k_ic e^(G_tc - G_ic) for i < t, and o_t = (q_t
+    e^G_t)^T S + sum_(i<=t) P_ti u_i with P like A on q. e^(G_t - G_i) is
+    formed per ``sub`` rows against the running sum at their first row, so
+    that neither factor leaves float32 (|g| <= 5 a token: at most e^80
+    inside 16 rows). Returns the new state and o (B, C, H, dv)."""
+    q, k, v, g, beta = chunk
+    q, k, v = q.astype(F32), k.astype(F32), v.astype(F32)
+    c = q.shape[1]
+    run = jnp.cumsum(g, axis=1)  # G_t, inclusive
+    a_rows, p_rows = [], []
+    for lo in range(0, c, sub):
+        hi = lo + sub
+        ref = run[:, lo - 1:lo] if lo else jnp.zeros_like(run[:, :1])
+        rows = jnp.exp(run[:, lo:hi] - ref)
+        cols = k[:, :hi] * jnp.exp(ref - run[:, :hi])
+        pad = ((0, 0), (0, 0), (0, 0), (0, c - hi))
+        a_rows.append(jnp.pad(jnp.einsum(
+            "bthc,bihc->bhti", k[:, lo:hi] * rows, cols,
+            precision=KDA_INSIDE), pad))
+        p_rows.append(jnp.pad(jnp.einsum(
+            "bthc,bihc->bhti", q[:, lo:hi] * rows, cols,
+            precision=KDA_INSIDE), pad))
+    at = jnp.arange(c)
+    a = jnp.where(at[:, None] > at[None, :], jnp.concatenate(a_rows, 2), 0.0)
+    p = jnp.where(at[:, None] >= at[None, :], jnp.concatenate(p_rows, 2), 0.0)
+    beta_h = jnp.swapaxes(beta, 1, 2)  # (B, H, C)
+    solve = _unit_lower_inverse(a * beta_h[..., None]) * beta_h[..., None, :]
+    decay = jnp.exp(run)
+    seen = jnp.einsum("bthc,bhcv->bthv", k * decay, state,  # (e^G_t k_t)^T S
+                      precision=KDA_PRECISION)
+    u = jnp.einsum("bhti,bihv->bthv", solve, v - seen,
+                   precision=KDA_INSIDE)
+    out = (jnp.einsum("bthc,bhcv->bthv", q * decay, state,
+                      precision=KDA_PRECISION)
+           + jnp.einsum("bhti,bihv->bthv", p, u, precision=KDA_INSIDE))
+    last = run[:, -1:]
+    k_out = k * jnp.exp(last - run)  # e^(G_C - G_i) k_i
+    state = (state * jnp.exp(last)[:, 0, :, :, None]
+             + jnp.einsum("bihc,bihv->bhcv", k_out, u,
+                          precision=KDA_PRECISION))
+    return state, out
+
+
+def kda(p, z, real, cfg: HybridConfig, dtype):
+    """(B, T, hidden) normed input -> (B, T, hidden) mixer output. The
+    projections leave their result as (B, T, H, d) and everything up to
+    the output projection stays in that layout: a chunk is then a slice
+    of a major axis, and so is the convolution's shift."""
+    b, t, _ = z.shape
+    h, dk = cfg.heads, cfg.head_dim
+    keep = real[:, :, None, None].astype(F32)
+    zc = z.astype(dtype)
+
+    def heads(w, out):  # (hidden, H * d) -> (B, T, H, d), f32 accumulation
+        return jnp.einsum("bti,ihd->bthd", zc,
+                          w.astype(dtype).reshape(-1, h, dk),
+                          preferred_element_type=out)
+
+    def branch(w, taps):  # the product leaves the matmul in ``dtype``
+        return jax.nn.silu(_short_conv(
+            heads(w, dtype).astype(F32) * keep, taps.reshape(-1, h, dk)))
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
+
+    q = (unit(branch(p["wq"], p["conv_q"])) * dk ** -0.5).astype(dtype)
+    k = unit(branch(p["wk"], p["conv_k"])).astype(dtype)
+    v = branch(p["wv"], p["conv_v"]).astype(dtype)
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(p["a_log"])[:, None]
+        * (heads(p["wg"], F32) + p["dt_bias"].reshape(h, dk))) * keep
+    beta = jax.nn.sigmoid(_mm(z, p["wb"], dtype)) * keep[..., 0]
+
+    c = cfg.kda_chunk
+    lead = -t % c  # padding tokens on the left pass the state unchanged
+    n = (t + lead) // c
+
+    def chunks(x):  # (B, T, ...) -> (B, N, C, ...): no data moves
+        x = jnp.pad(x, ((0, 0), (lead, 0)) + ((0, 0),) * (x.ndim - 2))
+        return x.reshape(b, n, c, *x.shape[2:])
+
+    xs = tuple(chunks(x) for x in (q, k, v, g, beta))
+
+    def one_chunk(i, carry):
+        state, out = carry
+        state, o = _kda_chunk(state, tuple(
+            jax.lax.dynamic_index_in_dim(x, i, 1, keepdims=False)
+            for x in xs), KDA_SUB)
+        return state, jax.lax.dynamic_update_index_in_dim(
+            out, o.astype(out.dtype), i, 1)
+
+    _, o = jax.lax.fori_loop(0, n, one_chunk, (
+        jnp.zeros((b, h, dk, dk), F32), jnp.zeros((b, n, c, h, dk), F32)))
+    o = o.reshape(b, t + lead, h, dk)[:, lead:]
+    o = _rms(o, p["o_norm"], cfg.eps)
+    o = o * jax.nn.sigmoid(_mm(z, p["wog"], dtype))[..., None]
+    return jnp.einsum("bthd,hdo->bto", o.astype(dtype),
+                      p["wo"].astype(dtype).reshape(h, dk, -1),
+                      preferred_element_type=F32)
+
+
+# -- MLA ------------------------------------------------------------------------
+
+def _rotary(x, position, theta: float):
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=F32) / half)
+    angle = position.astype(F32)[..., None] * freq
+    if x.ndim == 4:
+        angle = angle[:, :, None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
+
+
+def _attendable(real_keys, lo: int, hi: int):
+    """(hi - lo, hi) bool: query lo + i may read key j: a real token at or
+    before it."""
+    return real_keys[None, :] & (jnp.arange(hi)[None, :]
+                                 <= jnp.arange(lo, hi)[:, None])
+
+
+def mla(p, z, real, position, cfg: HybridConfig, dtype):
+    b, t, _ = z.shape
+    h, nope, rope, vd = cfg.heads, cfg.nope, cfg.rope, cfg.v_dim
+    q = _mm(z, p["wq"], dtype).reshape(b, t, h, nope + rope)
+    down = _mm(z, p["wdkv"], dtype)
+    c = _rms(down[..., :cfg.kv_rank], p["c_norm"], cfg.eps)
+    up = _mm(c, p["wukv"], dtype).reshape(b, t, h, nope + vd)
+    # the rotary part rides beside the rest as extra width of q and k:
+    # one product gives q_n k_n^T + q_r k_r^T
+    q = jnp.concatenate([
+        _rms(q[..., :nope], p["qn_norm"], cfg.eps),
+        _rotary(_rms(q[..., nope:], p["qr_norm"], cfg.eps), position,
+                cfg.rope_theta)], -1).astype(dtype)
+    k_r = _rotary(_rms(down[..., cfg.kv_rank:], p["kr_norm"], cfg.eps),
+                  position, cfg.rope_theta)
+    k = jnp.concatenate([
+        _rms(up[..., :nope], p["kn_norm"], cfg.eps),
+        jnp.broadcast_to(k_r[:, :, None, :], (b, t, h, rope))],
+        -1).astype(dtype)
+    v = up[..., nope:].astype(dtype)
+    scale = 1.0 / math.sqrt(nope + rope)
+    blocks = MLA_QUERY_BLOCKS if t % MLA_QUERY_BLOCKS == 0 and t >= 512 else 1
+    step = t // blocks
+
+    def one_row(row):
+        q1, k1, v1, real1 = row  # (T, H, d), (T,)
+        outs = []
+        for lo in range(0, t, step):  # a block's keys end where it ends
+            hi = lo + step
+            s = jnp.einsum("qhd,khd->hqk", q1[lo:hi], k1[:hi],
+                           preferred_element_type=F32) * scale
+            w = jax.nn.softmax(jnp.where(
+                _attendable(real1[:hi], lo, hi)[None], s, MASKED), axis=-1)
+            outs.append(jnp.einsum("hqk,khd->qhd", w.astype(dtype), v1[:hi],
+                                   preferred_element_type=F32))
+        return jnp.concatenate(outs, 0).astype(dtype)
+
+    o = jax.lax.map(one_row, (q, k, v, real))
+    return _mm(o.reshape(b, t, h * vd), p["wo"], dtype)
+
+
+# -- the expert layer -------------------------------------------------------------
+
+def route(p, z, real, cfg: HybridConfig):
+    """``(experts (N, k) int32, weights (N, k) float32)`` over all routed
+    experts for tokens ``z`` (N, hidden); a padding token gets expert -1
+    and weight 0."""
+    per = cfg.routed // cfg.groups
+    s = jax.nn.sigmoid(jnp.matmul(z.astype(F32), p["router"].astype(F32),
+                                  precision=HIGHEST))
+    choice = s + p["bias"]
+    by_group = choice.reshape(-1, cfg.groups, per)
+    best = by_group.max(-1, keepdims=True)
+    at_best = jnp.argmax(by_group, -1)[..., None] == jnp.arange(per)
+    score = best[..., 0] + jnp.where(at_best, -jnp.inf, by_group).max(-1)
+    # a group is kept if fewer than ``groups_kept`` groups beat it (an
+    # equal score beats it from a lower index, as top_k orders ties)
+    ahead = (score[:, None, :] > score[:, :, None]) | (
+        (score[:, None, :] == score[:, :, None])
+        & (jnp.arange(cfg.groups)[None, :] < jnp.arange(cfg.groups)[:, None]))
+    open_ = ahead.sum(-1) < cfg.groups_kept
+    masked = jnp.where(jnp.repeat(open_, per, axis=1), choice, -jnp.inf)
+    _, chosen = jax.lax.top_k(masked, cfg.per_token)
+    # s at the chosen, as a masked sum: a gather of (N, k) from (N, routed)
+    # costs more on this device than the compare over routed
+    w = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(cfg.routed),
+                          s[:, None, :], 0.0), axis=-1)
+    w = w / w.sum(-1, keepdims=True) * cfg.routed_scale
+    return (jnp.where(real[:, None], chosen, -1).astype(jnp.int32),
+            jnp.where(real[:, None], w, 0.0))
+
+
+def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
+                 tile: int = MOE_TILE):
+    """The held experts' part of the layer for tokens ``z`` (N, hidden):
+    ``(y (N, hidden) float32, pairs (held,) int32, served int32)``.
+
+    Every (token, slot) whose expert is held is a pair; pairs are sorted
+    by expert (stable, so a group keeps token order) and each expert's
+    group is cut into tiles of ``tile`` rows, the last one part empty. The
+    loop runs once per tile that exists: gather the tile's tokens, the
+    expert's SwiGLU, and write the rows where the tile stands in a buffer
+    laid out tile after tile. After the loop every token adds up its own
+    slots' rows, scaled by the routing weights (a gather: on this device a
+    row-wise scatter-add into the result costs several times as much).
+    ``served`` counts the pairs the loop multiplied; it equals
+    ``pairs.sum()`` because no pair is dropped."""
+    n, k = chosen.shape
+    held = cfg.held_count
+    local = chosen - cfg.held_first
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held).reshape(-1)  # ``held``: not here
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    pairs = jnp.sum(group[:, None] == jnp.arange(held, dtype=group.dtype),
+                    axis=0, dtype=jnp.int32)
+    tiles = (pairs + tile - 1) // tile
+    tile_end = jnp.cumsum(tiles)
+    first_tile = tile_end - tiles
+    first_pair = jnp.cumsum(pairs) - pairs
+    # by tile: its expert, and where its pairs start in ``order``
+    most = (n * k + tile - 1) // tile + held
+    expert_of = jnp.minimum(jnp.searchsorted(
+        tile_end, jnp.arange(most), side="right"), held - 1).astype(jnp.int32)
+    inside = (jnp.arange(most, dtype=jnp.int32) - first_tile[expert_of]) * tile
+    start_of = first_pair[expert_of] + inside
+    live_of = jnp.clip(pairs[expert_of] - inside, 0, tile)
+    order_padded = jnp.pad(order, (0, tile))
+    zc = z.astype(dtype)
+
+    def one_tile(j, carry):
+        rows, served = carry
+        e = expert_of[j]
+        slot = jax.lax.dynamic_slice(order_padded, (start_of[j],), (tile,))
+        part = _swiglu({name: jax.lax.dynamic_index_in_dim(
+            ex[name], e, 0, keepdims=False) for name in ("gate", "up", "down")},
+            zc[slot // k], dtype)
+        rows = jax.lax.dynamic_update_slice(rows, part.astype(dtype),
+                                            (j * tile, 0))
+        return rows, served + live_of[j]
+
+    rows, served = jax.lax.fori_loop(
+        0, tile_end[-1], one_tile,
+        (jnp.zeros((most * tile, z.shape[-1]), dtype),
+         jnp.zeros((), jnp.int32)))
+    # where each (token, slot) stands in ``rows``: its rank among the
+    # sorted pairs, moved by the empty ends of the groups before it
+    rank = jnp.argsort(order).astype(jnp.int32)  # the inverse permutation
+    own = group % held
+    at = jnp.where(mine.reshape(-1), rank - first_pair[own]
+                   + first_tile[own] * tile, 0).reshape(n, k)
+    scale = jnp.where(mine, w, 0.0)
+    y = jnp.zeros((n, z.shape[-1]), F32)
+    for slot in range(k):  # one slot at a time: a gather of N rows each
+        y = y + jnp.where(mine[:, slot:slot + 1],
+                          rows[at[:, slot]].astype(F32), 0.0) \
+            * scale[:, slot:slot + 1]
+    return y, pairs, served
+
+
+def moe(p, z, real, cfg: HybridConfig, dtype):
+    """``(y, pairs (held,), served, row_pairs (B,))`` of one expert layer."""
+    b, t, d = z.shape
+    flat = z.reshape(b * t, d)
+    with jax.named_scope("moe.route"):
+        chosen, w = route(p, flat, real.reshape(-1), cfg)
+    with jax.named_scope("moe.experts"):
+        y, pairs, served = held_experts(p["experts"], flat, chosen, w, cfg,
+                                        dtype)
+    with jax.named_scope("moe.shared"):
+        y = y + _swiglu(p["shared"], flat, dtype)
+    local = chosen - cfg.held_first
+    row_pairs = jnp.sum(((local >= 0) & (local < cfg.held_count)).reshape(
+        b, -1), axis=1, dtype=jnp.int32)
+    return y.reshape(b, t, d), pairs, served, row_pairs
+
+
+# -- the model ----------------------------------------------------------------------
+
+def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
+                  dtype=jnp.bfloat16):
+    """``(x (B, T, hidden) float32 before the final norm, aux)``."""
+    b, length, cols = hist.shape
+    t = length * cols
+    filled = filled.astype(jnp.int32)
+    at = jnp.arange(t, dtype=jnp.int32)
+    real = (at // cols)[None, :] >= (length - filled)[:, None]
+    position = jnp.maximum(at[None, :] - ((length - filled) * cols)[:, None],
+                           0)
+    with jax.named_scope("lm.embed"):
+        ids = tokenise(params["edges"], hist.astype(F32), cfg.bins)
+        x = params["embed"][ids].astype(F32)
+    pairs, served, row_pairs = [], jnp.zeros((), jnp.int32), jnp.zeros(
+        (b,), jnp.int32)
+    for (mixer, ffn), p in zip(cfg.layers, params["layers"]):
+        z = _rms(x, p["norm1"], cfg.eps)
+        if mixer == "kda":
+            with jax.named_scope("kda"):
+                x = x + kda(p["mixer"], z, real, cfg, dtype)
+        else:
+            with jax.named_scope("mla"):
+                x = x + mla(p["mixer"], z, real, position, cfg, dtype)
+        z = _rms(x, p["norm2"], cfg.eps)
+        if ffn == "dense":
+            with jax.named_scope("dense_ffn"):
+                x = x + _swiglu(p["ffn"], z, dtype)
+        else:
+            y, layer_pairs, layer_served, layer_rows = moe(
+                p["ffn"], z, real, cfg, dtype)
+            x = x + y
+            pairs.append(layer_pairs)
+            served = served + layer_served
+            row_pairs = row_pairs + layer_rows
+    aux = {"pairs": (jnp.stack(pairs) if pairs else jnp.zeros(
+               (0, cfg.held_count), jnp.int32)),
+           "pairs_served": served,
+           "routed_tokens": jnp.sum(real, dtype=jnp.int32),
+           "row_pairs": row_pairs}
+    return x, aux
+
+
+def slice_logits(params: Params, x, cfg: HybridConfig, dtype=jnp.bfloat16):
+    """Final norm and the untied head over the vocabulary slice."""
+    with jax.named_scope("lm.head"):
+        return _mm(_rms(x, params["final_norm"], cfg.eps), params["head"],
+                   dtype)
+
+
+@partial(jax.jit, static_argnames=("cfg", "compute_dtype"))
+def logits_everywhere(params: Params, hist, filled, cfg: HybridConfig,
+                      compute_dtype=jnp.bfloat16):
+    """Slice logits at every position (B, T, vocab): tests and offline
+    use; the served path reads one position."""
+    x, aux = hidden_states(params, hist, filled, cfg, compute_dtype)
+    return slice_logits(params, x, cfg, compute_dtype), aux
+
+
+@partial(jax.jit, static_argnames=("cfg", "compute_dtype"))
+def apply_serving(params: Params, hist, filled, cfg: HybridConfig,
+                  compute_dtype=jnp.bfloat16):
+    """What :class:`~ccfd_tpu.serving.history.SeqScorer` dispatches:
+    ``(proba (B,), aux)``. ``proba`` = sigmoid(z_fraud - z_legit + shift)
+    at the newest record's last token. ``aux``: ``logits`` (B, vocab) at
+    that token, ``pairs`` (expert layers, held) pairs served per held
+    expert, ``pairs_served`` (what the tile loop multiplied),
+    ``routed_tokens`` (the batch's real tokens) and ``row_pairs`` (B,)."""
+    x, aux = hidden_states(params, hist, filled, cfg, compute_dtype)
+    z = slice_logits(params, x[:, -1], cfg, compute_dtype)
+    aux["logits"] = z
+    verdict = z[:, cfg.fraud_id] - z[:, cfg.legit_id] + cfg.shift
+    return jax.nn.sigmoid(verdict), aux
+
+
+def make_observer(registry: Any):
+    """``observe(aux) -> stats`` for one resolved dispatch: the family's
+    counters (``moe_pairs_served_total``, ``moe_pairs_routed_total``,
+    ``moe_routed_tokens_total``, ``lm_tokens_total``, the gauge
+    ``moe_expert_pairs_max`` per expert layer, and the sum and count of the
+    busiest-over-mean expert load per dispatch and layer), and what
+    ``seq.wait``
+    carries: ``pairs_served``, ``routed_tokens``, ``max_expert_pairs``."""
+    served = registry.counter(
+        "moe_pairs_served_total",
+        "(token, held expert) pairs the expert layers multiplied")
+    routed_pairs = registry.counter(
+        "moe_pairs_routed_total",
+        "(token, held expert) pairs the routing chose; equals the served "
+        "count because no pair is dropped")
+    routed = registry.counter(
+        "moe_routed_tokens_total", "real tokens routed (per dispatch, not "
+        "per expert layer)")
+    tokens = registry.counter(
+        "lm_tokens_total", "real tokens through the backbone")
+    busiest = registry.gauge(
+        "moe_expert_pairs_max",
+        "pairs of the busiest held expert in the last dispatch, by layer")
+    skew = registry.counter(
+        "moe_expert_load_ratio_total",
+        "busiest held expert's pairs over the held experts' mean, summed "
+        "over dispatches and expert layers")
+    layer_dispatches = registry.counter(
+        "moe_layer_dispatches_total",
+        "expert layers run, over all dispatches (those that served a pair)")
+
+    def observe(aux: dict) -> dict:
+        pairs = aux["pairs"]  # (expert layers, held)
+        n_tokens = int(aux["routed_tokens"])
+        total = int(pairs.sum())
+        served.inc(int(aux["pairs_served"]))
+        routed_pairs.inc(total)
+        routed.inc(n_tokens)
+        tokens.inc(n_tokens)
+        top = pairs.max(axis=1) if pairs.size else pairs.sum(axis=1)
+        per_layer = pairs.sum(axis=1)
+        for layer, most in enumerate(top):
+            busiest.set(float(most), labels={"layer": str(layer)})
+        live = per_layer > 0
+        if live.any():
+            skew.inc(float((top[live] * pairs.shape[1]
+                            / per_layer[live]).sum()))
+            layer_dispatches.inc(int(live.sum()))
+        return {"pairs_served": int(aux["pairs_served"]),
+                "routed_tokens": n_tokens,
+                "max_expert_pairs": int(top.max()) if pairs.size else 0}
+
+    return observe
+
+
+def register() -> None:
+    """``hybrid_moe`` in the zoo's history families. Not swappable: a
+    second tree of the served size does not fit beside the first, so
+    ``swap_params`` refuses it by name (counted) instead of running the
+    device out of memory."""
+    from ccfd_tpu.models.registry import HistorySpec, register_history
+
+    def make_apply(dtype, _pos_length, cfg: HybridConfig):
+        if cfg is None:
+            raise ValueError("hybrid_moe needs its configuration "
+                             "(SeqScorer(family_config=...))")
+        return lambda p, xs, filled: apply_serving(p, xs, filled, cfg, dtype)
+
+    register_history(HistorySpec(
+        "hybrid_moe", owns=owns, make_apply=make_apply, reads_filled=True,
+        make_observer=make_observer,
+        describe=lambda cfg: {
+            "experts_held": [cfg.held_first, cfg.held_first + cfg.held_count],
+            "experts_routed_over": cfg.routed,
+            "layers": [list(kind) for kind in cfg.layers]},
+        swappable=False))

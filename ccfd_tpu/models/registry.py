@@ -26,7 +26,61 @@ class ModelSpec:
     apply_numpy: Callable[..., Any] | None = None
 
 
+@dataclass(frozen=True)
+class HistorySpec:
+    """A history family as :class:`~ccfd_tpu.serving.history.SeqScorer`
+    serves it: the scorer finds its device program here, by name, once.
+
+    ``make_apply(dtype, pos_length, config)`` returns the program
+    ``fn(params, hist)``, or ``fn(params, hist, filled)`` where
+    ``reads_filled``: ``hist`` (B, L, F) float32 windows, ``filled`` (B,)
+    int32 real records per row (right-aligned; a family without a padding
+    mask never sees it). It returns ``proba`` (B,), or ``(proba, aux)``
+    with ``aux`` a dict of small device arrays. ``make_observer(registry)``
+    returns ``observe(aux) -> stats``: it feeds the family's counters from
+    a resolved dispatch's ``aux`` (numpy by then) and returns what the
+    ``seq.wait`` phase should carry. ``owns(params)`` says whether a
+    parameter tree that arrives without a name is the family's.
+    ``mesh_logits`` is the logits function the mesh / sequence-parallel
+    path jits (None: the family is not served over a mesh).
+    ``describe(config)`` adds to ``executable_grid()``. ``swappable``:
+    whether ``swap_params`` may stage a second tree beside the served
+    one."""
+
+    name: str
+    owns: Callable[[Any], bool]
+    make_apply: Callable[[Any, int, Any], Callable[..., Any]]
+    reads_filled: bool = False
+    make_observer: Callable[[Any], Callable[[dict], dict]] | None = None
+    mesh_logits: Callable[..., jax.Array] | None = None
+    describe: Callable[[Any], dict] | None = None
+    swappable: bool = True
+
+
 _REGISTRY: dict[str, ModelSpec] = {}
+_HISTORY: dict[str, HistorySpec] = {}
+
+
+def register_history(spec: HistorySpec) -> None:
+    _HISTORY[spec.name] = spec
+
+
+def get_history(name: str) -> HistorySpec:
+    try:
+        return _HISTORY[name]
+    except KeyError:
+        raise KeyError(f"unknown history family {name!r}; known: "
+                       f"{sorted(_HISTORY)}") from None
+
+
+def history_family_of(params: Any) -> HistorySpec:
+    """The family a nameless parameter tree belongs to (a lifecycle
+    promotion hands ``swap_params`` a tree, not a name)."""
+    for spec in _HISTORY.values():
+        if spec.owns(params):
+            return spec
+    raise ValueError("no registered history family owns this parameter "
+                     f"tree; known: {sorted(_HISTORY)}")
 
 
 def register_model(spec: ModelSpec) -> None:
@@ -85,3 +139,9 @@ _quant.register()
 from ccfd_tpu.ops import seq_quant as _seq_quant  # noqa: E402
 
 _seq_quant.register()
+
+# hybrid_moe: the KDA + MLA + sparse-expert backbone over a tokenised
+# window (models/hybrid_moe.py), a third history family behind SeqScorer
+from ccfd_tpu.models import hybrid_moe as _hybrid_moe  # noqa: E402
+
+_hybrid_moe.register()

@@ -213,12 +213,26 @@ def register() -> None:
     SeqScorer` is their serving layer (the operator special-cases
     ``model: seq``/``seq_q8`` accordingly)."""
     from ccfd_tpu.models import seq as seq_mod
-    from ccfd_tpu.models.registry import ModelSpec, register_model
+    from ccfd_tpu.models.registry import (HistorySpec, ModelSpec,
+                                          register_history, register_model)
 
     register_model(
         ModelSpec("seq", seq_mod.init, seq_mod.apply, seq_mod.logits,
                   trainable=False)
     )
+    # the served programs: neither reads ``filled`` (no padding mask,
+    # ROADMAP B0), so the seam does not hand it to them
+    register_history(HistorySpec(
+        "seq_q8", owns=is_quantized,
+        make_apply=lambda dtype, plen, _cfg: (
+            lambda p, xs: apply_serving(p, xs, dtype, pos_length=plen)),
+        mesh_logits=logits))
+    register_history(HistorySpec(
+        "seq", owns=lambda p: "blocks" in p and not is_quantized(p),
+        make_apply=lambda dtype, plen, _cfg: (
+            lambda p, xs: seq_mod.apply_serving(p, xs, dtype,
+                                                pos_length=plen)),
+        mesh_logits=seq_mod.logits_readout))
 
     def init_q8(key=None, **kw):
         return quantize_seq(

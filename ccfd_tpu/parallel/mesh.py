@@ -31,6 +31,12 @@ MODEL_AXIS = "model"
 FSDP_AXIS = "fsdp"
 TP_AXIS = "tp"
 NAMED_AXES = (DATA_AXIS, FSDP_AXIS, TP_AXIS)
+# the axis the chips that share each layer of an expert model lie on:
+# experts and the vocabulary divide over it, attention replicates
+# (partition.hybrid_moe_rules / expert_share). One chip of such a group
+# serves its share without a mesh; the exchange across the axis is not in
+# the program yet (ROADMAP B2).
+EXPERT_AXIS = "ep"
 
 
 def make_mesh(

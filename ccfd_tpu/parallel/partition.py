@@ -185,6 +185,36 @@ def seq_rules(layout: SpecLayout | None = None) -> list[tuple[str, P]]:
     ]
 
 
+def expert_share(num_experts: int, shares: int, index: int) -> dict:
+    """The experts chip ``index`` of ``shares`` chips holds when they divide
+    ``num_experts`` among them in order: ``{"first", "count"}``, what an
+    expert layer is told (``models/hybrid_moe.py`` ``experts_held``). The
+    router keeps its full width on every chip; a chip computes its own
+    experts' part and leaves the rest to the others."""
+    if num_experts % shares or not 0 <= index < shares:
+        raise ValueError(
+            f"{num_experts} experts over {shares} chips, chip {index}")
+    count = num_experts // shares
+    return {"first": index * count, "count": count}
+
+
+def hybrid_moe_rules(expert_axis: str = "ep") -> list[tuple[str, P]]:
+    """Layout of the ``hybrid_moe`` tree (models/hybrid_moe.py) over the
+    chips that share each layer: the experts' matrices and the vocabulary
+    (embedding rows, head columns) divide over ``expert_axis``; mixers,
+    router, shared expert, norms and the tokeniser's edges replicate (each
+    chip runs attention on its own micro-batches). What one chip of the
+    group holds is one shard of this layout; serving over the whole mesh
+    also needs the exchange of routed tokens, which the program does not
+    have yet."""
+    return [
+        (r"ffn/experts/(gate|up|down)", P(expert_axis, None, None)),
+        (r"^embed$", P(expert_axis, None)),
+        (r"^head$", P(None, expert_axis)),
+        (r".*", P()),
+    ]
+
+
 # -- shard / gather ----------------------------------------------------------
 
 def make_shard_and_gather_fns(

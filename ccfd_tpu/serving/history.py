@@ -49,11 +49,15 @@ old path at 1412 ms device dispatch vs 13 ms assembly per bucket
   dispatches through a short-sequence executable (the ``len_buckets``
   ladder) instead of padding to full L, with per-(L, B)-bucket hit
   counters; shapes stay static per (L, B) pair so XLA never re-traces.
-  The device graph is ``seq.apply_serving`` (exact last-block readout
-  optimization) or ``ops/seq_quant.apply`` when the installed params are
-  the int8 variant — ``swap_params`` re-binds the jit by sniffing the
-  param tree, which is how a lifecycle-promoted ``seq_q8`` candidate
-  takes over serving.
+  The device graph is the history family's, found by name in
+  ``models/registry.py`` (``HistorySpec``: ``seq``'s exact last-block
+  readout, ``seq_q8``'s int8 variant, ``hybrid_moe``'s tokenised
+  language-model backbone); a tree that arrives without a name
+  (``swap_params`` on a lifecycle promotion) is asked of the registry once,
+  which is how a promoted ``seq_q8`` candidate takes over serving. A family
+  with a padding mask (``reads_filled``) gets each row's ``filled`` depth
+  with the batch; a family that fills the device is not swappable and
+  ``swap_params`` refuses it by name (``seq_swap_refused_total``).
 
 TPU-first notes: histories assemble host-side into one contiguous array
 per micro-batch (one transfer, one dispatch — never per-customer gathers
@@ -542,8 +546,16 @@ class SeqScorer:
         telemetry: Any = None,
         partitioner: Any = None,
         seq_parallel: str = "none",
+        family: str | None = None,
+        family_config: Any = None,
     ):
-        """``mesh``: serve the seq dispatch over a device mesh — history
+        """``family``: the history family's name in ``models/registry``
+        (``seq``, ``seq_q8``, ``hybrid_moe``); left out, the registered
+        family that owns ``params``. ``family_config``: what the family's
+        program is built from where its tree does not say it (the
+        ``hybrid_moe`` settings).
+
+        ``mesh``: serve the seq dispatch over a device mesh — history
         batches split over the partitioned axes, params replicated (the
         same SPMD layout the row Scorer's data-axis path uses; history
         ASSEMBLY stays host-side either way). Bucket sizes round up to
@@ -655,8 +667,21 @@ class SeqScorer:
         self.params = params
         self.batch_sizes = tuple(sorted(set(batch_sizes)))
         self._jax = jax
-        self._quantized = self._is_quantized(params)
-        self._apply = self._make_apply(self._quantized)
+        from ccfd_tpu.models import registry as model_registry
+
+        self._family_config = family_config
+        self._family = (model_registry.get_history(family) if family
+                        else model_registry.history_family_of(params))
+        self._apply = self._make_apply(self._family)
+        # per resolved dispatch: the family's counters from the program's
+        # ``aux``, and the stats ``seq.wait`` carries
+        self._observe = None
+        if registry is not None and self._family.make_observer is not None:
+            self._observe = self._family.make_observer(registry)
+        # the deployment's tap on what the program returns beside the
+        # probabilities: ``aux_tap(rows, m, aux)`` with ``rows`` the
+        # dispatch's row numbers inside the router batch, per dispatch
+        self.aux_tap: Any = None
         self._params_lock = threading.Lock()
         # challenger slot (lifecycle/): a second params tree + jit scored
         # off the hot path by the shadow tap's worker — how the seq_q8
@@ -675,7 +700,13 @@ class SeqScorer:
         self._h_assembly = self._h_dispatch = None
         self._c_bucket = self._c_bucket_rows = None
         self._g_inflight = self._c_anon = self._c_stale = None
+        self._c_swap_refused = None
         if registry is not None:
+            self._c_swap_refused = registry.counter(
+                "seq_swap_refused_total",
+                "swap_params calls refused: the served family holds one "
+                "tree at a time, or the tree is another family's program",
+            )
             self._g_customers = registry.gauge(
                 "seq_history_customers", "customers with live history"
             )
@@ -715,12 +746,6 @@ class SeqScorer:
             )
 
     # -- variant dispatch ---------------------------------------------------
-    @staticmethod
-    def _is_quantized(params: Any) -> bool:
-        from ccfd_tpu.ops import seq_quant
-
-        return seq_quant.is_quantized(params)
-
     def _sp_attention(self):
         """The operator-selected sequence-parallel attention (ring /
         ulysses over the sp axis), or None. Static-shape gated: the
@@ -772,11 +797,11 @@ class SeqScorer:
 
         return attn
 
-    def _make_apply(self, quantized: bool):
+    def _make_apply(self, family: Any):
+        """The family's device program, from its registered spec:
+        ``fn(params, hist)`` or, where the family reads the padding,
+        ``fn(params, hist, filled)``."""
         import jax
-
-        from ccfd_tpu.models import seq as seq_mod
-        from ccfd_tpu.ops import seq_quant
 
         dtype = self._dtype
         # positional encodings anchor at the store's FULL length: a short
@@ -785,14 +810,13 @@ class SeqScorer:
         # crossovers (models/seq.py logits_readout pos_length)
         plen = self.store.length
         if self.mesh is None:
-            if quantized:
-                return lambda p, xs: seq_quant.apply_serving(
-                    p, xs, dtype, pos_length=plen)
-            return lambda p, xs: seq_mod.apply_serving(
-                p, xs, dtype, pos_length=plen)
+            return family.make_apply(dtype, plen, self._family_config)
+        if family.mesh_logits is None:
+            raise ValueError(
+                f"history family {family.name!r} is not served over a mesh")
         from jax.sharding import NamedSharding, PartitionSpec
 
-        fn = seq_quant.logits if quantized else seq_mod.logits_readout
+        fn = family.mesh_logits
         attn = self._sp_attention()
         return jax.jit(
             lambda p, xs: jax.nn.sigmoid(
@@ -852,21 +876,32 @@ class SeqScorer:
         treedef, same executable). All staging (mesh re-layout, variant
         grid precompile) happens BEFORE the publish gate: with a gate
         armed the router pool quiesces only for the reference flip."""
-        staged, quantized, new_apply = self._stage_swap(params)
+        staged, family, new_apply = self._stage_swap(params)
         gate = getattr(self, "_swap_gate", None)
         if gate is None:
-            self._commit_swap(staged, quantized, new_apply)
+            self._commit_swap(staged, family, new_apply)
             return
         with gate:
-            self._commit_swap(staged, quantized, new_apply)
+            self._commit_swap(staged, family, new_apply)
 
     def _stage_swap(self, params: Any) -> tuple:
+        from ccfd_tpu.models.registry import history_family_of
+
+        family = history_family_of(params)
+        if not (self._family.swappable and family.swappable):
+            # a family that fills the device holds one tree: staging a
+            # second beside it would run the device out of memory
+            if self._c_swap_refused is not None:
+                self._c_swap_refused.inc()
+            held = family if self._family.swappable else self._family
+            raise ValueError(
+                f"swap_params refused: history family {held.name!r} holds "
+                "one parameter tree at a time (restart to change it)")
         if self.mesh is not None:
             params = self._jax.device_put(params,
                                           self._param_layout(params))
-        quantized = self._is_quantized(params)
         new_apply = None
-        if quantized != self._quantized:
+        if family is not self._family:
             # variant change (e.g. a promoted seq_q8): compile the whole
             # (B, L) executable grid BEFORE publishing — scoring keeps the
             # old graph meanwhile, so the hot path never pays an XLA
@@ -874,23 +909,27 @@ class SeqScorer:
             # and roll back the candidate that was just promoted)
             from ccfd_tpu.observability.profile import compile_stage
 
-            new_apply = self._make_apply(quantized)
+            new_apply = self._make_apply(family)
             with compile_stage("seq.swap"):
-                for b in self.batch_sizes:
-                    for lb in self.len_buckets:
-                        xs = np.zeros((b, lb, self.store.num_features),
-                                      np.float32)
-                        self._jax.block_until_ready(
-                            new_apply(params, self._put_hist(xs)))
-        return params, quantized, new_apply
+                self._run_grid(new_apply, params, family)
+        return params, family, new_apply
 
-    def _commit_swap(self, params: Any, quantized: bool,
-                     new_apply: Any) -> None:
+    def _commit_swap(self, params: Any, family: Any, new_apply: Any) -> None:
         with self._params_lock:
             self.params = params
             if new_apply is not None:
-                self._quantized = quantized
+                self._family = family
                 self._apply = new_apply
+
+    def _run_grid(self, apply_fn: Any, params: Any, family: Any) -> None:
+        """Every (B bucket, L bucket) executable once, on zeros."""
+        for b in self.batch_sizes:
+            for lb in self.len_buckets:
+                xs = np.zeros((b, lb, self.store.num_features), np.float32)
+                extra = ((np.zeros((b,), np.int32),)
+                         if family.reads_filled else ())
+                self._jax.block_until_ready(
+                    apply_fn(params, self._put_hist(xs), *extra))
 
     def warmup(self) -> None:
         """Compile every (B bucket, L bucket) executable the ladder can
@@ -898,12 +937,7 @@ class SeqScorer:
         from ccfd_tpu.observability.profile import compile_stage
 
         with compile_stage("seq.warmup"):
-            for b in self.batch_sizes:
-                for lb in self.len_buckets:
-                    xs = np.zeros((b, lb, self.store.num_features),
-                                  np.float32)
-                    self._jax.block_until_ready(
-                        self._apply(self.params, self._put_hist(xs)))
+            self._run_grid(self._apply, self.params, self._family)
 
     def executable_grid(self) -> dict:
         """The (L, B) executable grid with per-executable dispatch counts
@@ -917,10 +951,12 @@ class SeqScorer:
                         {"l_bucket": str(lb), "b_bucket": str(b)}))
                 grid.append(entry)
         out = {
-            "model": "seq_q8" if self._quantized else "seq",
+            "model": self._family.name,
             "length": int(self.store.length),
             "grid": grid,
         }
+        if self._family.describe is not None:
+            out.update(self._family.describe(self._family_config))
         if self.mesh is not None:
             out["mesh_devices"] = int(self.mesh.size)
             out["seq_parallel"] = self.seq_parallel
@@ -988,7 +1024,7 @@ class SeqScorer:
         ladder = self.len_buckets
         merged: dict = {}
         gen = None
-        pending: deque = deque()  # (device array, global row idx, m)
+        pending: deque = deque()  # (device result, global row idx, m, tokens)
         # shadow/canary lane: when a challenger is armed (tap) or a
         # canary slice is live (gate), keep each chunk's assembled
         # (full-L) history batch so the challenger scores the SAME
@@ -1092,13 +1128,23 @@ class SeqScorer:
                                 [sub, np.zeros((bucket - len(sub),
                                                 *sub.shape[1:]), np.float32)]
                             )
+                        # real records per row of the dispatch (a window
+                        # shorter than L holds at most its own length; a
+                        # padding row holds none): goes to the device with
+                        # the batch where the family masks its padding
+                        sub_filled = np.zeros((bucket,), np.int32)
+                        np.minimum(filled[sub_idx], lb, out=sub_filled[:m])
+                        tokens = int(sub_filled.sum()) * sub.shape[2]
                         with self._params_lock:
                             params, apply_fn = self.params, self._apply
+                            extra = ((sub_filled,)
+                                     if self._family.reads_filled else ())
                         ph.set(rows=m, b_bucket=bucket,
                                padded_rows=bucket - m)
                     t_asm += ph.seconds
                     with phase("seq.enqueue", bytes=sub.nbytes,
-                               b_bucket=bucket, l_bucket=lb) as ph:
+                               b_bucket=bucket, l_bucket=lb,
+                               tokens=tokens) as ph:
                         # device-fault dispatch seam (runtime/faults.py):
                         # device_hang / compile_stall drill the heal ladder
                         # through the seq path's own dispatch loop
@@ -1106,11 +1152,11 @@ class SeqScorer:
                         # JAX async dispatch: the call ENQUEUES the
                         # executable and returns; the next group assembles
                         # while it runs.
-                        dev = apply_fn(params, self._put_hist(sub))
+                        dev = apply_fn(params, self._put_hist(sub), *extra)
                     t_disp += ph.seconds
                     if self.telemetry is not None:
                         self.telemetry.record_h2d(sub.nbytes)
-                    pending.append((dev, sub_idx + start, m))
+                    pending.append((dev, sub_idx + start, m, tokens))
                     if self._c_bucket is not None:
                         self._c_bucket.inc(labels={
                             "l_bucket": str(lb), "b_bucket": str(bucket)})
@@ -1164,9 +1210,17 @@ class SeqScorer:
         """Block on the oldest in-flight dispatch and scatter its rows;
         returns the blocking wait (the dispatch time overlap failed to
         hide)."""
-        dev, idx, m = pending.popleft()
-        with phase("seq.wait", rows=m) as ph:
-            proba = np.asarray(dev)
+        dev, idx, m, tokens = pending.popleft()
+        with phase("seq.wait", rows=m, tokens=tokens) as ph:
+            if isinstance(dev, tuple):  # (proba, aux): the family's counts
+                proba = np.asarray(dev[0])
+                aux = {k: np.asarray(v) for k, v in dev[1].items()}
+                if self._observe is not None:
+                    ph.set(**self._observe(aux))
+                if self.aux_tap is not None:
+                    self.aux_tap(idx, m, aux)
+            else:
+                proba = np.asarray(dev)
         out[idx] = proba[:m]
         if self._g_inflight is not None:
             self._g_inflight.set(float(len(pending)))
@@ -1206,16 +1260,16 @@ class SeqScorer:
             self._challenger = (int(version), params, fn)
 
     def _make_challenger_apply(self, params: Any):
-        from ccfd_tpu.models import seq as seq_mod
-        from ccfd_tpu.ops import seq_quant
+        from ccfd_tpu.models.registry import history_family_of
 
-        dtype = self._dtype
-        plen = self.store.length
-        if self._is_quantized(params):
-            return lambda p, xs: seq_quant.apply_serving(
-                p, xs, dtype, pos_length=plen)
-        return lambda p, xs: seq_mod.apply_serving(
-            p, xs, dtype, pos_length=plen)
+        family = history_family_of(params)
+        if family.reads_filled != self._family.reads_filled:
+            raise ValueError(
+                f"challenger of family {family.name!r} beside a "
+                f"{self._family.name!r} champion: the two read different "
+                "batches")
+        return family.make_apply(self._dtype, self.store.length,
+                                 self._family_config)
 
     def clear_challenger(self, version: int | None = None) -> None:
         with self._params_lock:
@@ -1268,7 +1322,15 @@ class SeqScorer:
                 sub = np.concatenate(
                     [sub, np.zeros((bucket - m, *sub.shape[1:]), np.float32)]
                 )
-            proba = np.asarray(fn(params, put(np.ascontiguousarray(sub))))
+            extra = ()
+            if self._family.reads_filled:
+                # a right-aligned window's depth: from its first record
+                # that is not all zeros
+                live = np.abs(sub).sum(-1) > 0
+                extra = (np.where(live.any(1), sub.shape[1] - live.argmax(1),
+                                  0).astype(np.int32),)
+            res = fn(params, put(np.ascontiguousarray(sub)), *extra)
+            proba = np.asarray(res[0] if isinstance(res, tuple) else res)
             out[start:stop] = proba[:m]
             start = stop
         return out

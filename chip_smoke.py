@@ -343,6 +343,34 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
         zoo[label] = {"max_abs_diff": max(diffs),
                       "grid": s.executable_grid()["grid"]}
 
+    # hybrid_moe (KDA + MLA + sparse experts over a tokenised window): the
+    # tests' small preset and seeded weights, found by name through the
+    # registry. What is held here is the seam (ids, windows, ``filled``,
+    # buckets): the served scores against the family's own program called
+    # directly on the same windows. How close bfloat16 serving comes to the
+    # plain float32 reference is the benchmark's to say, at real widths (at
+    # width 64 a token near a routing tie moves a score by 0.03)
+    from benchmark.reference import hybrid_moe_f32
+    from ccfd_tpu.models import hybrid_moe
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "ling3_small_config.json")) as f:
+        small = json.load(f)
+    h_cfg = hybrid_moe.HybridConfig.from_dict(small)
+    hp = hybrid_moe_f32.make_params(small)
+    s = SeqScorer(hp, length=8, batch_sizes=(16,), family="hybrid_moe",
+                  family_config=h_cfg, max_customers=64)
+    s.warmup()
+    rows = x[:16]
+    hist = np.zeros((16, 8, rows.shape[1]), np.float32)
+    hist[:, -1] = rows
+    direct = np.asarray(hybrid_moe.apply_serving(
+        hp, hist, np.ones(16, np.int32), h_cfg, jnp.bfloat16)[0])
+    zoo["hybrid_moe"] = {
+        "max_abs_diff": check.close("hybrid_moe B=16 L=8", s.score(
+            rows, list(range(10_000, 10_016))), direct, 1e-6),
+        "grid": s.executable_grid()}
+
     # the fused-decision grid over the flagship: score + threshold + rules
     # in one executable per bucket, against the staged seam
     thr = Config().fraud_threshold
