@@ -298,6 +298,28 @@ def decode_records(records) -> tuple[np.ndarray, list[Mapping[str, Any]], int]:
     return x, txs, bad
 
 
+class DeferrableRecords(list):
+    """A micro-batch's decoded records as the pipelined loop hands them to
+    a ``score_with_ids`` scorer: the mark says that this caller takes the
+    scores before they are ready (anything whose ``np.asarray`` blocks
+    until they are, with ``deferred`` true and, once ready, ``ready_at``:
+    serving/history.py DeferredScores) and forces them itself, on its
+    loop thread, before it routes the batch. A scorer that ignores the
+    mark returns host memory as ever."""
+
+    __slots__ = ()
+    takes_deferred = True
+
+
+def _deferred(scores: Any) -> bool:
+    return getattr(scores, "deferred", False)
+
+
+def _host_scores(scores: Any) -> Any:
+    """Host memory, forced here, unless the scorer deferred the result."""
+    return scores if _deferred(scores) else np.asarray(scores)
+
+
 class Router:
     def __init__(
         self,
@@ -341,7 +363,7 @@ class Router:
         score_with_ids = getattr(score_fn, "score_with_ids", None)
         if callable(score_with_ids):
             self._score2 = lambda x, txs: (
-                np.asarray(score_with_ids(txs, x)), None)
+                _host_scores(score_with_ids(txs, x)), None)
         else:
             self._score2 = lambda x, txs: (np.asarray(self.score(x)), None)
         self.engine = engine
@@ -1269,20 +1291,25 @@ class Router:
         the device and the Python/engine work pipeline instead of taking
         turns. One stage in flight is enough — depth beyond 1 only adds
         queueing latency because the loop itself is busy between waits.
-        """
-        from concurrent.futures import ThreadPoolExecutor
 
-        def timed_score(x: np.ndarray, txs: list, batch_sp,
-                        meta) -> tuple:
-            # time INSIDE the worker so the histogram records the scorer
-            # round trip, not dispatch + however long the loop polled.
-            # batch_sp (and the audit meta) ride along explicitly — the
-            # worker thread has no ambient trace context (contextvars are
-            # per-thread), and batch-scoped audit state must never live
-            # on self while two batches are in flight
-            t0 = time.perf_counter()
-            proba, fired = self._score_batch(x, txs, batch_sp, meta)
-            score_s = time.perf_counter() - t0
+        A ``score_with_ids`` scorer may return batch k's scores before
+        they are ready (the records it gets are marked: it keeps k open
+        and resolves it inside its call for k+1, whose gather and
+        transfer so run beside k's device time). The worker then forces
+        nothing; ``finish`` does, on this thread, before it routes. Two
+        batches consumed and unrouted is still all there is: k+1 is
+        submitted before k is finished, as it always was, and routing,
+        offset commits and budget release stay in batch order. Not for a
+        pool's worker (its scorer is shared, and the workers' calls
+        already run beside one another's device time), nor under the
+        ladder, which has to see the scores to judge them.
+        """
+        from concurrent.futures import ThreadPoolExecutor, wait
+
+        defer = (self.worker_id is None and not self._degrade
+                 and self._decision_fn is None)
+
+        def observe_score(score_s: float, batch_sp, rows: int) -> None:
             self._h_score_s.observe(
                 score_s,
                 exemplar=({"trace_id": batch_sp.trace_id}
@@ -1291,14 +1318,39 @@ class Router:
                 self._overload.observe_stage(score_s)
             if self._profiler is not None:
                 self._profiler.observe("router.score", dispatch_s=score_s,
-                                       batch=len(txs), rows=len(txs))
-            return proba, fired
+                                       batch=rows, rows=rows)
 
-        def finish(pending: tuple) -> None:
+        def timed_score(x: np.ndarray, txs: list, batch_sp,
+                        meta) -> tuple:
+            # time INSIDE the worker so the histogram records the scorer
+            # round trip, not dispatch + however long the loop polled
+            # (a deferred result's round trip ends when it is ready:
+            # finish observes it). batch_sp (and the audit meta) ride
+            # along explicitly — the worker thread has no ambient trace
+            # context (contextvars are per-thread), and batch-scoped audit
+            # state must never live on self while two batches are in flight
+            t0 = time.perf_counter()
+            proba, fired = self._score_batch(x, txs, batch_sp, meta)
+            if not _deferred(proba):
+                observe_score(time.perf_counter() - t0, batch_sp, len(txs))
+            return proba, fired, t0
+
+        def finish(pending: tuple, newer: tuple | None = None) -> None:
             pfut, px, ptxs, pts, psp, pmeta, poffs = pending
             try:
                 try:
-                    proba, fired = pfut.result()
+                    proba, fired, t0 = pfut.result()
+                    if _deferred(proba):
+                        if newer is not None:
+                            # the worker readies these scores inside its
+                            # call for the newer batch, which is already
+                            # submitted: forcing them before it got there
+                            # would take the scorer from it and put the
+                            # two batches back in series
+                            wait((newer[0],))
+                        deferred, proba = proba, np.asarray(proba)
+                        observe_score(deferred.ready_at - t0, psp,
+                                      len(ptxs))
                 except Exception:
                     # a transient scorer failure (e.g. remote model timeout)
                     # drops this batch, not the routing loop. The drop IS
@@ -1366,6 +1418,8 @@ class Router:
                     try:
                         batch_sp = self._begin_batch_span(records)
                         x, txs, ts = self._decode_batch(records, batch_sp)
+                        if defer:
+                            txs = DeferrableRecords(txs)
                         fut = ex.submit(timed_score, x, txs, batch_sp, meta)
                     except BaseException:
                         # reserved rows must not leak out of a crashed
@@ -1383,7 +1437,7 @@ class Router:
                     if fut is not None else None)
                 if done is not None:
                     try:
-                        finish(done)
+                        finish(done, pending)
                     except BaseException:
                         # the loop is going down and the batch just
                         # submitted can never be routed: release its rows

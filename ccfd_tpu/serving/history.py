@@ -44,7 +44,19 @@ old path at 1412 ms device dispatch vs 13 ms assembly per bucket
   worker's next batch, the store's per-key optimistic check skips the
   contended keys instead of clobbering newer state
   (``HistoryStore.contended_skips``; the skipped appends are in the
-  routed stream, so the next crash-restore replay recovers them). Rows bucket by
+  routed stream, so the next crash-restore replay recovers them). For a
+  caller that takes its result before it is ready (the pipelined
+  router: its records are marked, ``score_with_ids`` answers with
+  ``DeferredScores``) the window spans consecutive calls: batch k stays
+  open when its call returns, and the call for k+1 gathers, pads and
+  enqueues k+1 on k's staged rows BEFORE it blocks on k, commits k and
+  marks k ready, so the host's assembly and the transfer in run beside
+  the device's work on the batch before. Whoever forces an open batch
+  resolves every older one first, under the scorer's lock; a batch that
+  fails to resolve is dropped alone, and the open batches staged on its
+  rows are staged and dispatched again without them. Every other caller
+  (``score``, REST, tools, an ``aux_tap``, the shadow tap, the canary
+  gate) has its batch resolved inside the call. Rows bucket by
   HISTORY LENGTH as well as batch size: a mostly-cold row (filled << L)
   dispatches through a short-sequence executable (the ``len_buckets``
   ladder) instead of padding to full L, with per-(L, B)-bucket hit
@@ -72,6 +84,7 @@ from __future__ import annotations
 
 import itertools
 import threading
+import time
 from collections import OrderedDict, deque
 from typing import Any
 
@@ -263,7 +276,9 @@ class HistoryStore:
         A customer appearing twice in one chunk sees its earlier
         same-chunk rows in the later assembly; ``overlay`` extends that
         visibility across the chunks of ONE router batch (the caller
-        accumulates staged dicts and commits once). ``None`` ids are
+        accumulates staged dicts and commits once) and across the
+        scorer's open batches (staged, not committed yet; each commits in
+        its turn). ``None`` ids are
         anonymous: scored against an empty history and NEVER stored — a
         bounded store must not spend its cap (and evict real customers)
         on keys no future record can match. An ALL-anonymous chunk takes
@@ -274,10 +289,13 @@ class HistoryStore:
         past B are zero: padding to a larger bucket is already there.
         Without it a fresh zeroed batch is allocated. Either way each
         staged entry is ``(view, filled, base, new)``: a VIEW of the
-        batch's row of the key's last occurrence, the stamp of the store
-        entry it derives from (None for a fresh key) and how many of its
-        rows are new since — so the batch must stay as it is until the
-        token is committed or dropped."""
+        batch's row of the key's last occurrence; ``base``, the store
+        entry it derives from, as ``[stamp, rows committed]`` (stamp None
+        for a fresh key; one list per lineage: an entry staged on an
+        ``overlay`` entry shares its list, and ``commit`` moves it on);
+        and how many of its rows are new since the list was made — so
+        the batch must stay as it is until the token is committed or
+        dropped."""
         rows = np.ascontiguousarray(rows, np.float32)
         n = len(rows)
         L = self.length
@@ -317,7 +335,7 @@ class HistoryStore:
                 for i, key in group:
                     ent = h.get(key)
                     if ent is None:
-                        state[key] = (i, 1, None, 1)
+                        state[key] = (i, 1, [None, 0], 1)
                         continue
                     slot, filled, stamp, cursor = ent
                     k = min(filled, L - 1)
@@ -325,7 +343,7 @@ class HistoryStore:
                         self._linear(hist[i, L - 1 - k:L - 1], slot, k,
                                      cursor)
                     filled_out[i] = k + 1
-                    state[key] = (i, k + 1, stamp, 1)
+                    state[key] = (i, k + 1, [stamp, 0], 1)
         # then, in arrival order, the rows whose context is an earlier
         # chunk's staged view or an earlier row of this batch
         for i, key in later:
@@ -369,9 +387,17 @@ class HistoryStore:
         replay (the records are in the routed stream).
 
         Where the live entry still stands on the base stamp, only the
-        staged entry's new rows are appended to its ring; a key with no
-        live entry (fresh, or evicted since the prepare) takes a slot and
-        the whole staged history."""
+        staged entry's rows not committed yet are appended to its ring;
+        a key with no live entry (fresh, or evicted since the prepare)
+        takes a slot and the whole staged history.
+
+        A batch prepared LATER with this one as its ``overlay`` and not
+        committed yet (the scorer's open batches) shares the ``base`` of
+        every entry it derives from one of this batch's. This commit
+        moves that ``base`` on to the stamp it gave the key and the rows
+        it has appended, which rebases the later entry: its own commit
+        finds the live entry on its base, appends exactly its own rows,
+        and is not taken for a contended one."""
         gen, staged = token[0], token[1]
         if not staged:
             return True
@@ -400,16 +426,18 @@ class HistoryStore:
                         if cur is None:
                             slot, cursor, m = self._new_slot(), 0, filled
                             added += 1
-                        elif base is None or cur[2] != base:
+                        elif cur[2] != base[0]:
                             # live entry moved since this prepare: a
                             # concurrent batch owns the newer state
                             self._contended += 1
                             continue
                         else:
-                            slot, cursor, m = cur[0], cur[3], min(new, L)
+                            slot, cursor = cur[0], cur[3]
+                            m = min(new - base[1], L)
                             h.move_to_end(key)
                         h[key] = (slot, filled, stamp,
                                   self._append(slot, cursor, view, m))
+                        base[0], base[1] = stamp, new
             if added:
                 with self._count_lock:
                     self._total += added
@@ -519,9 +547,79 @@ class HistoryStore:
     def contended_skips(self) -> int:
         return self._contended
 
+    @property
+    def generation(self) -> int:
+        """Bumped by every ``restore``; what a prepare's token carries."""
+        return self._gen
+
     def snapshot_counts(self) -> dict:
         return {"customers": len(self), "length": self.length,
                 "stripes": self.stripes}
+
+
+class _Batch:
+    """One router batch from its staging to its commit: what an open
+    batch keeps so that a later call, or whoever forces its scores, can
+    resolve it, commit it, or stage it again."""
+
+    __slots__ = ("x", "ids", "out", "scores", "error", "t_asm", "t_disp",
+                 "gen", "merged", "pending", "taken", "kept", "n_anon")
+
+    def __init__(self, x: np.ndarray, ids: list):
+        self.x = x
+        self.ids = ids
+        self.out = np.empty((len(x),), np.float32)
+        self.scores: DeferredScores | None = None  # of a deferred batch
+        # what an older batch's dispatch raised while a later call waited
+        # on it: raised again where the batch's turn to settle comes
+        self.error: Exception | None = None
+        self.t_asm = self.t_disp = 0.0
+        self.reset()
+
+    def reset(self) -> None:
+        """Nothing staged (yet, or any more). What the last staging left
+        is dropped, not used again: the runtime may still read a staging
+        batch whose dispatch was never resolved."""
+        self.gen: int | None = None
+        self.merged: dict = {}
+        # (device result, rows inside the batch, real rows, tokens)
+        self.pending: deque = deque()
+        self.taken: list[StagingBatch] = []
+        self.kept: list[tuple[np.ndarray, int, int]] = []  # tap / gate
+        self.n_anon = 0
+
+
+class DeferredScores:
+    """A router batch's probabilities, handed out while its dispatches may
+    still be in flight (``SeqScorer.score_with_ids`` for a caller that
+    takes them so). ``np.asarray`` of it is the (B,) float32 array: ready
+    once the scorer's next call has resolved and committed the batch, and
+    forced before that by whoever asks (every older open batch first, in
+    order, on the asking thread), or the exception the batch was dropped
+    for. ``ready_at`` is ``time.perf_counter()`` at that instant."""
+
+    __slots__ = ("_scorer", "_batch", "_value", "ready_at")
+    deferred = True
+
+    def __init__(self, scorer: "SeqScorer", batch: _Batch):
+        self._scorer = scorer
+        self._batch: _Batch | None = batch
+        self._value: np.ndarray | Exception | None = None
+        self.ready_at: float | None = None
+
+    def ready(self, value: "np.ndarray | Exception") -> None:
+        self._value = value
+        self._batch = None  # with its records, staging and device results
+        self.ready_at = time.perf_counter()
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        batch = self._batch
+        if batch is not None:
+            self._scorer._force(batch)
+        if isinstance(self._value, Exception):
+            raise self._value
+        return (self._value if dtype is None
+                else self._value.astype(dtype, copy=False))
 
 
 class SeqScorer:
@@ -529,7 +627,10 @@ class SeqScorer:
     bucketed static shapes — run as an overlapped dataflow: per-(L, B)
     bucket dispatches enqueue asynchronously while the next group
     assembles, bounded by ``inflight``; ONE commit per router batch after
-    every dispatch resolved (see module docstring)."""
+    every dispatch resolved (see module docstring). For a caller that
+    takes a deferred result the window of ``inflight`` open dispatches
+    spans consecutive calls: batch k+1 is gathered, enqueued and on its
+    way to the device while the device computes batch k."""
 
     def __init__(
         self,
@@ -576,7 +677,9 @@ class SeqScorer:
         static at trace time, so the choice costs nothing at runtime.
 
         ``inflight``: async dispatches in flight before the loop blocks
-        on the oldest (0 = resolve immediately, the synchronous path).
+        on the oldest (0 = resolve immediately, the synchronous path),
+        counted over every open batch: for a caller that takes a deferred
+        result the window spans consecutive calls.
         ``len_buckets``: the short-sequence ladder; the full ``length``
         is always appended. A row dispatches at the smallest bucket
         covering its post-append history depth."""
@@ -585,12 +688,16 @@ class SeqScorer:
 
         self.store = HistoryStore(length=length, max_customers=max_customers,
                                   stripes=stripes)
-        # recycled (largest bucket, L, F) staging batches: a call takes at
-        # most inflight + 1 and puts them back once its batch is
-        # committed, so the list is bounded by what is in flight
-        # (deque.pop / extend are GIL-atomic: safe under the
-        # ParallelRouter's workers)
+        # recycled (largest bucket, L, F) staging batches: a batch takes
+        # at most inflight + 1 and puts them back once it is committed, so
+        # the list is bounded by what is in flight (deque.pop / extend are
+        # GIL-atomic: safe under the ParallelRouter's workers)
         self._staging: deque = deque()
+        # batches a deferring caller left open, oldest first: staged and
+        # enqueued, not yet resolved or committed. The lock is held by
+        # whoever stages, settles or forces one, for as long as it does
+        self._open: deque = deque()
+        self._lock = threading.Lock()
         # device telemetry plane (observability/device.py): the seq
         # dispatch ships (B, L, F) history batches whose transfer happens
         # INSIDE the jitted call, so only the bytes are separately
@@ -700,6 +807,7 @@ class SeqScorer:
         self._h_assembly = self._h_dispatch = None
         self._c_bucket = self._c_bucket_rows = None
         self._g_inflight = self._c_anon = self._c_stale = None
+        self._c_overlapped = None
         self._c_swap_refused = None
         if registry is not None:
             self._c_swap_refused = registry.counter(
@@ -729,9 +837,16 @@ class SeqScorer:
                 "rows scored per L bucket (short buckets = the cold-row "
                 "fast lane actually firing)",
             )
+            self._c_overlapped = registry.counter(
+                "seq_overlapped_batches_total",
+                "router batches staged and enqueued while an earlier "
+                "batch's dispatch was unresolved (the in-flight window "
+                "spanning two score calls, actually engaged)",
+            )
             self._g_inflight = registry.gauge(
                 "seq_inflight_dispatches",
-                "async seq dispatches currently in flight",
+                "async seq dispatches currently in flight, over every "
+                "open batch",
             )
             self._c_anon = registry.counter(
                 "seq_anonymous_rows_total",
@@ -1002,6 +1117,10 @@ class SeqScorer:
         makes a commit that raced a crash restore a no-op (the rewind
         re-drives those records).
 
+        Resolved inside the call: whatever ``score_with_ids`` left open
+        for a deferring caller is resolved and committed first, in order,
+        so this batch reads their rows from the store.
+
         Every stretch of the call is a :class:`phase` (``seq.gather``,
         ``seq.pad``, ``seq.enqueue``, ``seq.wait``, ``seq.commit`` inside
         ``seq.score``), so a device capture shows what the host did
@@ -1011,36 +1130,141 @@ class SeqScorer:
         n = len(x)
         if n == 0:
             return np.zeros((0,), np.float32)
-        with phase("seq.score", rows=n):
+        with phase("seq.score", rows=n, open_batches=0, overlapped=0):
             return self._score(x, ids)
 
     def _score(self, x: np.ndarray, ids: list | None) -> np.ndarray:
-        n = len(x)
-        if ids is None:
-            ids = [None] * n
-        out = np.empty((n,), np.float32)
-        largest = self.batch_sizes[-1]
-        L = self.store.length
-        ladder = self.len_buckets
-        merged: dict = {}
-        gen = None
-        pending: deque = deque()  # (device result, global row idx, m, tokens)
+        if self._open:
+            with self._lock:
+                while self._open:
+                    self._settle_oldest()
         # shadow/canary lane: when a challenger is armed (tap) or a
         # canary slice is live (gate), keep each chunk's assembled
         # (full-L) history batch so the challenger scores the SAME
         # contexts the champion just did (one flag read when idle)
+        tap, gate = self._armed()
+        batch = _Batch(x, [None] * len(x) if ids is None else ids)
+        self._stage(batch, (), keep_hist=tap is not None or gate is not None)
+        self._settle(batch)
+        out = batch.out
+        if tap is not None:
+            # the tap pairs PURE champion scores (offered before any
+            # canary override, like the row lane's tap-inside/gate-outside
+            # composition)
+            for hist, s0, s1 in batch.kept:
+                tap.offer(hist, out[s0:s1])
+        if gate is not None and batch.kept:
+            # canary slice: the challenger arm re-scores against the SAME
+            # assembled contexts (bounded by the gate's weight; a
+            # challenger failure keeps champion scores and counts)
+            def rescore(mask: np.ndarray) -> np.ndarray:
+                parts = [h[mask[s0:s1]] for h, s0, s1 in batch.kept]
+                sel = parts[0] if len(parts) == 1 else np.concatenate(parts)
+                return self.challenger_score(sel)
+
+            out = gate.apply(np.ascontiguousarray(x, np.float32), out,
+                             rescore=rescore)
+        return out
+
+    def _armed(self) -> tuple:
+        """The shadow tap and the canary gate, each None unless live."""
         tap = self.shadow_tap
         if tap is not None and tap.armed_version is None:
             tap = None
         gate = self.canary_gate
         if gate is not None and not gate.active:
             gate = None
-        tap_chunks: list[tuple[np.ndarray, int, int]] = []
-        keep_hist = tap is not None or gate is not None
-        taken: list[StagingBatch] = []
-        t_asm = 0.0
-        t_disp = 0.0
-        n_anon = 0
+        return tap, gate
+
+    def _score_deferred(self, x: np.ndarray, ids: list) -> "DeferredScores":
+        """``score`` for a caller that takes the result before it is
+        ready: this batch is gathered, padded and enqueued FIRST, beside
+        whatever the device still computes of the batch before it; then
+        that batch is resolved and committed and its scores are marked
+        ready; this one stays open, for the next call or for whoever
+        forces its scores (``_force``). One caller at a time: the lock is
+        held for the whole call."""
+        with phase("seq.score", rows=len(x)) as ph, self._lock:
+            older = tuple(self._open)
+            overlapped = any(b.pending for b in older)
+            ph.set(open_batches=len(older), overlapped=int(overlapped))
+            batch = _Batch(x, ids)
+            batch.scores = DeferredScores(self, batch)
+            self._open.append(batch)
+            try:
+                self._stage(batch, older)
+            except BaseException:
+                self._open.remove(batch)
+                raise
+            if overlapped and self._c_overlapped is not None:
+                self._c_overlapped.inc()
+            while self._open and self._open[0] is not batch:
+                self._settle_oldest()
+            return batch.scores
+
+    def _force(self, batch: "_Batch") -> None:
+        """Make an open batch's scores ready now, on the calling thread:
+        every older open batch first, in order."""
+        with self._lock:
+            while batch.scores.ready_at is None:
+                self._settle_oldest()
+
+    def _settle_oldest(self) -> None:
+        """Resolve and commit the oldest open batch and mark its scores
+        ready (under the lock). If it cannot be resolved it is dropped,
+        as a failed call's batch always was, and every later open batch,
+        staged on rows that will now never be committed, is staged and
+        dispatched again from the store and the open batches that are
+        left: its verdicts are what they would have been had the dropped
+        batch never arrived."""
+        batch = self._open[0]
+        try:
+            if batch.error is not None:
+                raise batch.error
+            self._settle(batch)
+        # ccfd-lint: disable=counted-drops -- not swallowed: raised to whoever forces the batch's scores (the router counts it in router_score_errors_total)
+        except Exception as e:  # noqa: BLE001
+            self._open.popleft()
+            batch.scores.ready(e)
+            later = tuple(self._open)
+            self._open.clear()
+            for b in later:
+                b.reset()
+                older = tuple(self._open)
+                self._open.append(b)
+                try:
+                    self._stage(b, older)
+                # ccfd-lint: disable=counted-drops -- as above
+                except Exception as e2:  # noqa: BLE001
+                    self._open.pop()
+                    b.scores.ready(e2)
+        else:
+            self._open.popleft()
+            batch.scores.ready(batch.out)
+
+    def _stage(self, batch: "_Batch", older: tuple,
+               keep_hist: bool = False) -> None:
+        """Gather, pad and enqueue every dispatch of ``batch``. ``older``:
+        the open batches before it, oldest first; their staged rows are
+        this batch's context beside the store's, and their dispatches
+        count toward the in-flight window."""
+        x, ids = batch.x, batch.ids
+        n = len(x)
+        largest = self.batch_sizes[-1]
+        L = self.store.length
+        ladder = self.len_buckets
+        merged = batch.merged
+        taken = batch.taken
+        pending = batch.pending
+        context: dict = {}  # the older open batches' staged entries
+        if older:
+            # a batch staged before a restore is doomed (its commit will
+            # be the stale no-op): its rows are no context for this one,
+            # and this one's commit stands on the generation read here
+            batch.gen = self.store.generation
+            for b in older:
+                if b.gen == batch.gen and b.error is None:
+                    context.update(b.merged)
         start = 0
         while start < n:
             stop = min(start + largest, n)
@@ -1054,7 +1278,9 @@ class SeqScorer:
                     stage, recycled = self._take_staging()
                     taken.append(stage)
                 hist, (chunk_gen, staged, filled) = self.store.prepare(
-                    chunk_ids, x[start:stop], overlay=merged, out=stage
+                    chunk_ids, x[start:stop], out=stage,
+                    overlay=({**context, **merged} if context and merged
+                             else context or merged)
                 )
                 # the FIRST chunk's generation stamps the whole batch: a
                 # restore landing between chunk prepares bumps the store's
@@ -1062,8 +1288,8 @@ class SeqScorer:
                 # gen would publish the earlier chunks' pre-restore staging
                 # onto the restored state — the first gen is stale then, so
                 # the commit is the no-op replay correctness requires
-                if gen is None:
-                    gen = chunk_gen
+                if batch.gen is None:
+                    batch.gen = chunk_gen
                 # recency = LAST occurrence: a key re-staged by a later
                 # chunk moves to the end of merged, so commit stamps (and
                 # therefore LRU eviction under a binding cap) follow stream
@@ -1074,10 +1300,10 @@ class SeqScorer:
                         del merged[k]
                 merged.update(staged)
                 anon = chunk_ids.count(None)
-                n_anon += anon
+                batch.n_anon += anon
                 li = self._len_bucket_index(filled)
                 if keep_hist:
-                    tap_chunks.append((hist, start, stop))
+                    batch.kept.append((hist, start, stop))
                 # a row at depth 1 is anonymous or its customer's first;
                 # rows beyond one per staged key repeat a key of the chunk;
                 # every row's context is its depth less the row itself
@@ -1087,7 +1313,7 @@ class SeqScorer:
                        gathered_bytes=(int(filled.sum()) - (stop - start))
                        * hist.shape[2] * hist.itemsize,
                        recycled=recycled)
-            t_asm += ph.seconds
+            batch.t_asm += ph.seconds
             for bi in np.unique(li):
                 lb = ladder[bi]
                 idx = np.nonzero(li == bi)[0]
@@ -1141,7 +1367,7 @@ class SeqScorer:
                                      if self._family.reads_filled else ())
                         ph.set(rows=m, b_bucket=bucket,
                                padded_rows=bucket - m)
-                    t_asm += ph.seconds
+                    batch.t_asm += ph.seconds
                     with phase("seq.enqueue", bytes=sub.nbytes,
                                b_bucket=bucket, l_bucket=lb,
                                tokens=tokens) as ph:
@@ -1153,7 +1379,7 @@ class SeqScorer:
                         # executable and returns; the next group assembles
                         # while it runs.
                         dev = apply_fn(params, self._put_hist(sub), *extra)
-                    t_disp += ph.seconds
+                    batch.t_disp += ph.seconds
                     if self.telemetry is not None:
                         self.telemetry.record_h2d(sub.nbytes)
                     pending.append((dev, sub_idx + start, m, tokens))
@@ -1162,55 +1388,65 @@ class SeqScorer:
                             "l_bucket": str(lb), "b_bucket": str(bucket)})
                         self._c_bucket_rows.inc(
                             m, labels={"l_bucket": str(lb)})
-                    if self._g_inflight is not None:
-                        self._g_inflight.set(float(len(pending)))
-                    while len(pending) > self.inflight:
-                        t_disp += self._resolve(pending, out)
+                    self._bound_window(batch, older)
             start = stop
-        while pending:
-            t_disp += self._resolve(pending, out)
-        if gen is not None:
-            with phase("seq.commit", customers=len(merged)) as ph:
-                committed = self.store.commit((gen, merged))
+
+    def _bound_window(self, batch: "_Batch", older: tuple) -> None:
+        """Block on the oldest dispatches until at most ``inflight`` are
+        unresolved: the older open batches' first, then this batch's own.
+        An older batch that fails here is settled, and dropped, where its
+        turn comes (``_settle_oldest``); this batch's own failure is the
+        call's."""
+        queues = [b for b in (*older, batch) if b.pending]
+        over = sum(len(b.pending) for b in queues) - self.inflight
+        for b in queues:
+            while over > 0 and b.pending:
+                over -= 1
+                try:
+                    self._resolve(b)
+                except Exception as e:  # noqa: BLE001 - see above
+                    if b is batch:
+                        raise
+                    over -= len(b.pending)
+                    b.pending.clear()
+                    b.error = e
+        if self._g_inflight is not None:
+            self._g_inflight.set(float(
+                sum(len(b.pending) for b in queues)))
+
+    def _settle(self, batch: "_Batch") -> None:
+        """Every dispatch of ``batch`` resolved, then its one commit
+        (which moves the open batches staged on its rows on to what it
+        wrote), then its staging batches back on the free list."""
+        while batch.pending:
+            self._resolve(batch)
+        if self._g_inflight is not None:
+            self._g_inflight.set(float(
+                sum(len(b.pending) for b in self._open)))
+        if batch.gen is not None:
+            with phase("seq.commit", customers=len(batch.merged)) as ph:
+                committed = self.store.commit((batch.gen, batch.merged))
                 ph.set(stale=int(not committed))
             if not committed and self._c_stale is not None:
                 self._c_stale.inc()
         # every dispatch resolved and the staged views are spent: only now
         # may another call fill these batches (the runtime reads a host
-        # buffer on its own thread after apply_fn returned; a call that
-        # raised keeps its batches out of the list for that reason)
-        self._staging.extend(taken)
-        if tap is not None:
-            # the tap pairs PURE champion scores (offered before any
-            # canary override, like the row lane's tap-inside/gate-outside
-            # composition)
-            for hist, s0, s1 in tap_chunks:
-                tap.offer(hist, out[s0:s1])
-        if gate is not None and tap_chunks:
-            # canary slice: the challenger arm re-scores against the SAME
-            # assembled contexts (bounded by the gate's weight; a
-            # challenger failure keeps champion scores and counts)
-            def rescore(mask: np.ndarray) -> np.ndarray:
-                parts = [h[mask[s0:s1]] for h, s0, s1 in tap_chunks]
-                sel = parts[0] if len(parts) == 1 else np.concatenate(parts)
-                return self.challenger_score(sel)
-
-            out = gate.apply(np.ascontiguousarray(x, np.float32), out,
-                             rescore=rescore)
+        # buffer on its own thread after apply_fn returned; a batch that
+        # failed keeps its own out of the list for that reason)
+        self._staging.extend(batch.taken)
         if self._g_customers is not None:
             self._g_customers.set(float(len(self.store)))
         if self._h_assembly is not None:
-            self._h_assembly.observe(t_asm)
-            self._h_dispatch.observe(t_disp)
-        if n_anon and self._c_anon is not None:
-            self._c_anon.inc(n_anon)
-        return out
+            self._h_assembly.observe(batch.t_asm)
+            self._h_dispatch.observe(batch.t_disp)
+        if batch.n_anon and self._c_anon is not None:
+            self._c_anon.inc(batch.n_anon)
 
-    def _resolve(self, pending: deque, out: np.ndarray) -> float:
-        """Block on the oldest in-flight dispatch and scatter its rows;
-        returns the blocking wait (the dispatch time overlap failed to
-        hide)."""
-        dev, idx, m, tokens = pending.popleft()
+    def _resolve(self, batch: "_Batch") -> None:
+        """Block on the batch's oldest in-flight dispatch and scatter its
+        rows; the blocking wait (the dispatch time overlap failed to
+        hide) goes to the batch's dispatch time."""
+        dev, idx, m, tokens = batch.pending.popleft()
         with phase("seq.wait", rows=m, tokens=tokens) as ph:
             if isinstance(dev, tuple):  # (proba, aux): the family's counts
                 proba = np.asarray(dev[0])
@@ -1221,10 +1457,8 @@ class SeqScorer:
                     self.aux_tap(idx, m, aux)
             else:
                 proba = np.asarray(dev)
-        out[idx] = proba[:m]
-        if self._g_inflight is not None:
-            self._g_inflight.set(float(len(pending)))
-        return ph.seconds
+        batch.out[idx] = proba[:m]
+        batch.t_disp += ph.seconds
 
     # Router contract: passing the SeqScorer OBJECT as the router's
     # score_fn makes it callable for the plain (x,) path, and the router
@@ -1232,13 +1466,21 @@ class SeqScorer:
     def __call__(self, x: np.ndarray) -> np.ndarray:
         return self.score(x)
 
-    def score_with_ids(self, txs: list, x: np.ndarray) -> np.ndarray:
+    def score_with_ids(self, txs: list, x: np.ndarray):
         """Batch entry for the router: ids come from each record's
         ``customer_id``/``id`` field; records with neither are anonymous
         (scored cold, not tracked). When the shadow tap is armed,
         ``score`` offers each chunk's assembled history batch alongside
         the champion's probabilities — the challenger shadow-scores the
-        SAME contexts."""
+        SAME contexts.
+
+        Records that say their caller takes a result before it is ready
+        (``takes_deferred``, the pipelined router's mark on the list it
+        hands over) get :class:`DeferredScores` and leave the batch open
+        across the call, unless something has to see the batch resolved
+        inside it: an ``aux_tap``, an armed shadow tap, a live canary
+        gate, or a window of no open dispatch (``inflight`` 0). Everyone
+        else gets host memory."""
         ids: list = []
         for t in txs:
             key = None
@@ -1247,6 +1489,10 @@ class SeqScorer:
                 if key is None:
                     key = t.get("id")
             ids.append(key)
+        if (ids and getattr(txs, "takes_deferred", False)
+                and self.inflight > 0 and self.aux_tap is None
+                and self._armed() == (None, None)):
+            return self._score_deferred(x, ids)
         return self.score(x, ids)
 
     # -- challenger slot (model lifecycle: shadow scoring of seq_q8) --------

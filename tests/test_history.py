@@ -12,6 +12,7 @@ import time
 
 import jax
 import numpy as np
+import pytest
 
 from ccfd_tpu.bus.broker import Broker
 from ccfd_tpu.config import Config
@@ -630,3 +631,315 @@ def test_late_commit_from_abandoned_batch_cannot_clobber_newer_state():
     (key, buf, filled), = st.snapshot()["customers"]
     assert key == "c" and filled == 2
     assert np.asarray(buf)[-1, 0] == 3.0  # B2's append survived
+
+
+# -- the in-flight window across calls (PR 27) ------------------------------
+
+def _records(ids):
+    """What the pipelined router hands ``score_with_ids``: the decoded
+    records, marked as a caller's that takes the scores deferred."""
+    from ccfd_tpu.router.router import DeferrableRecords
+
+    return DeferrableRecords(
+        [{} if i is None else {"id": i, "customer_id": i} for i in ids])
+
+
+def _stream(case: str, batches: int = 6):
+    """(x, ids) per router batch; ring length 4 wraps under all of them."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    out = []
+    for k in range(batches):
+        if case == "repeated_inside_a_batch":
+            ids = [f"c{k}", "hot", f"c{k}", "hot", "hot", f"d{k}", "hot"]
+        elif case == "across_two_batches":
+            ids = [f"p{k // 2}", f"q{k}", f"p{k // 2}"]
+        elif case == "across_three_batches":
+            ids = ["every", f"t{k // 3}", "every", f"u{k}"]
+        elif case == "fresh_customers":
+            ids = [f"k{k}_{j}" for j in range(9)]
+        elif case == "anonymous_rows":
+            ids = [None, "a", None, f"n{k}", "a", None]
+        elif case == "multichunk":  # 2-3 dispatches a batch, keys shared
+            ids = [f"m{j % 7}" for j in range(20 + 7 * (k % 2))]
+        else:  # mixed: random keys over a small set, a few anonymous
+            n = int(rng.integers(3, 16))
+            ids = [None if j == 9 else f"r{j}"
+                   for j in rng.integers(0, 10, n)]
+        out.append((rng.normal(size=(len(ids), 30)).astype(np.float32), ids))
+    return out
+
+
+OVERLAP_CASES = ["repeated_inside_a_batch", "across_two_batches",
+                 "across_three_batches", "fresh_customers", "anonymous_rows",
+                 "multichunk", "mixed"]
+
+
+def _same_snapshot(a, b):
+    sa, sb = a.snapshot(), b.snapshot()
+    assert [c[0] for c in sa["customers"]] == [c[0] for c in sb["customers"]]
+    for (_, ba, fa), (_, bb, fb) in zip(sa["customers"], sb["customers"]):
+        assert fa == fb
+        np.testing.assert_array_equal(ba, bb)
+
+
+@pytest.mark.parametrize("force", ["by_the_next_call", "before_the_next_call"])
+@pytest.mark.parametrize("case", OVERLAP_CASES)
+def test_overlapped_entry_matches_batch_by_batch(case, force):
+    """The same stream through ``score`` batch by batch and through the
+    deferring entry (batch k left open, resolved and committed inside the
+    call for k+1, which is staged on k's uncommitted rows): bit-identical
+    probabilities, an identical store, nothing contended, nothing stale.
+    ``before_the_next_call`` is light load: each result is forced before
+    the next batch arrives, so nothing overlaps and the path is the same."""
+    params = seq_mod.init(jax.random.PRNGKey(11))
+    reg = Registry()
+    kw = dict(length=4, batch_sizes=(4, 16), compute_dtype="float32")
+    plain = SeqScorer(params, **kw)
+    over = SeqScorer(params, registry=reg, **kw)
+    stream = _stream(case)
+    want = [plain.score(x, ids) for x, ids in stream]
+    got = []
+    for x, ids in stream:
+        res = over.score_with_ids(_records(ids), x)
+        assert res.deferred and res.ready_at is None
+        if got and force == "by_the_next_call":
+            assert got[-1].ready_at is not None  # this call readied it
+        if force == "before_the_next_call":
+            np.asarray(res)
+            assert not over._open
+        got.append(res)
+    assert len(over._open) == (force == "by_the_next_call")
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(g), w)
+    assert not over._open
+    _same_snapshot(plain.store, over.store)
+    assert over.store.contended_skips == 0
+    assert reg.counter("seq_stale_commits_total").total() == 0
+    overlapped = reg.counter("seq_overlapped_batches_total").total()
+    assert overlapped == (len(stream) - 1 if force == "by_the_next_call"
+                          else 0)
+    assert reg.get("seq_dispatch_seconds").count() == len(stream)
+    assert reg.get("seq_assembly_seconds").count() == len(stream)
+    assert reg.gauge("seq_inflight_dispatches").value() == 0
+    # every staging batch came back, and no more than two open batches
+    # of at most inflight + 1 each were ever out
+    assert 1 <= len(over._staging) <= 2 * (over.inflight + 1)
+
+
+@pytest.mark.parametrize("blocker", ["aux_tap", "shadow_tap", "canary_gate",
+                                     "inflight_0", "unmarked_records"])
+def test_callers_that_must_see_the_batch_resolved_get_host_memory(blocker):
+    """The deferring entry is taken only where the call's own arguments
+    say the caller forces the result and nothing armed on the scorer has
+    to see the batch resolved inside the call."""
+    params = seq_mod.init(jax.random.PRNGKey(12))
+    s = SeqScorer(params, length=4, batch_sizes=(4,),
+                  compute_dtype="float32",
+                  inflight=0 if blocker == "inflight_0" else 2)
+
+    class Armed:
+        armed_version = 1
+        active = True
+
+        def offer(self, hist, proba):
+            pass
+
+        def apply(self, x, out, rescore):
+            return out
+
+    if blocker == "aux_tap":
+        s.aux_tap = lambda rows, m, aux: None
+    elif blocker == "shadow_tap":
+        s.shadow_tap = Armed()
+    elif blocker == "canary_gate":
+        s.canary_gate = Armed()
+    x = np.ones((3, 30), np.float32)
+    ids = ["a", "b", "a"]
+    records = (_records(ids) if blocker != "unmarked_records"
+               else [{"id": i} for i in ids])
+    out = s.score_with_ids(records, x)
+    assert isinstance(out, np.ndarray) and out.shape == (3,)
+    assert not s._open and len(s.store) == 2
+
+
+def test_a_resolving_call_settles_what_a_deferring_caller_left_open():
+    """``score`` after the deferring entry (the REST path, a tool, the
+    router's ``step``) reads the open batch's rows from the store: it
+    resolves and commits every open batch first, in order."""
+    params = seq_mod.init(jax.random.PRNGKey(13))
+    kw = dict(length=4, batch_sizes=(4,), compute_dtype="float32")
+    plain, over = SeqScorer(params, **kw), SeqScorer(params, **kw)
+    (x0, i0), (x1, i1) = _stream("across_two_batches", 2)
+    want = [plain.score(x0, i0), plain.score(x1, i1)]
+    res = over.score_with_ids(_records(i0), x0)
+    got1 = over.score(x1, i1)
+    assert res.ready_at is not None and not over._open
+    np.testing.assert_array_equal(np.asarray(res), want[0])
+    np.testing.assert_array_equal(got1, want[1])
+    _same_snapshot(plain.store, over.store)
+
+
+class _LostResult:
+    """A device result that the runtime fails to deliver."""
+
+    def __array__(self, dtype=None, copy=None):
+        raise RuntimeError("device result lost")
+
+
+def _recording_apply(scorer, lose: set):
+    """Wrap the scorer's program: keep every input it was handed (the
+    memory the runtime reads), and lose the results of the dispatches
+    numbered in ``lose``."""
+    real, seen = scorer._apply, []
+
+    def apply(p, xs):
+        seen.append(xs)
+        return _LostResult() if len(seen) - 1 in lose else real(p, xs)
+
+    scorer._apply = apply
+    return seen
+
+
+def test_a_batch_that_fails_with_the_next_one_open_is_dropped_alone():
+    """Batch k's dispatch fails to resolve while k+1 is open, staged on
+    k's rows: k is dropped (its error raised where it is forced), k+1 is
+    staged and dispatched again from the store without k's rows, so its
+    verdicts and the store are those of a stream in which k never
+    arrived; and no input the runtime may still read is filled again."""
+    params = seq_mod.init(jax.random.PRNGKey(14))
+    reg = Registry()
+    kw = dict(length=4, batch_sizes=(4,), compute_dtype="float32")
+    plain = SeqScorer(params, **kw)
+    over = SeqScorer(params, registry=reg, **kw)
+    stream = _stream("across_three_batches", 4)
+    seen = _recording_apply(over, lose={1})  # batch 1's one dispatch
+    got = [over.score_with_ids(_records(ids), x) for x, ids in stream]
+    with pytest.raises(RuntimeError, match="device result lost"):
+        np.asarray(got[1])
+    with pytest.raises(RuntimeError, match="device result lost"):
+        np.asarray(got[1])  # every time it is read
+    want = [plain.score(x, ids) for k, (x, ids) in enumerate(stream)
+            if k != 1]
+    for w, g in zip(want, [got[0], got[2], got[3]]):
+        np.testing.assert_array_equal(np.asarray(g), w)
+    _same_snapshot(plain.store, over.store)
+    assert over.store.contended_skips == 0
+    assert reg.counter("seq_stale_commits_total").total() == 0
+    # dispatches: 0, 1 (lost), 2 (staged on 1's rows, dropped), 2 again, 3
+    assert len(seen) == 5
+    for dropped in (seen[1], seen[2]):
+        for later in seen[3:]:
+            assert not np.shares_memory(dropped, later)
+        for free in over._staging:
+            assert not np.shares_memory(dropped, free.hist)
+    assert not np.array_equal(seen[2], seen[3])  # without batch 1's rows
+
+
+def test_a_failed_enqueue_leaves_the_open_batch_to_be_forced():
+    """The dispatch seam of ``runtime/faults.py`` (``put_fail`` at the
+    staging put): batch k+1 fails while it is enqueued, with k open. The
+    call raises and k+1 is dropped as a failed call's batch always was; k
+    stays open, is forced later, and the store holds k's rows alone."""
+    from ccfd_tpu.runtime import faults
+
+    params = seq_mod.init(jax.random.PRNGKey(15))
+    kw = dict(length=4, batch_sizes=(4,), compute_dtype="float32")
+    plain, over = SeqScorer(params, **kw), SeqScorer(params, **kw)
+    (x0, i0), (x1, i1), (x2, i2) = _stream("across_three_batches", 3)
+    res0 = over.score_with_ids(_records(i0), x0)
+    faults.install_device_faults(faults.DeviceFaultPlan(
+        {"put_fail": faults.DeviceFaultSpec(rate=1.0)}))
+    try:
+        with pytest.raises(faults.InjectedFault):
+            over.score_with_ids(_records(i1), x1)
+    finally:
+        faults.install_device_faults(None)
+    assert len(over._open) == 1 and res0.ready_at is None
+    res2 = over.score_with_ids(_records(i2), x2)
+    np.testing.assert_array_equal(np.asarray(res0), plain.score(x0, i0))
+    np.testing.assert_array_equal(np.asarray(res2), plain.score(x2, i2))
+    _same_snapshot(plain.store, over.store)
+
+
+def test_restore_with_batches_open_makes_their_commits_counted_no_ops():
+    """A crash restore lands while batch k is open and k+1 is being
+    enqueued on k's rows: both commits are stale no-ops, counted, and the
+    store is exactly the cut (the rewound bus re-drives both). A batch
+    staged after the restore with the doomed k+1 still open reads nothing
+    of it and commits onto the restored state."""
+    params = seq_mod.init(jax.random.PRNGKey(16))
+    reg = Registry()
+    kw = dict(length=4, batch_sizes=(4,), compute_dtype="float32")
+    s = SeqScorer(params, registry=reg, **kw)
+    stream = _stream("across_three_batches", 4)
+    s.score(*stream[0])
+    snap = s.store.snapshot()
+    real, calls = s._apply, []
+
+    def apply(p, xs):
+        calls.append(len(s._open))
+        if len(calls) == 2:  # k+1's enqueue: k and k+1 are open
+            s.store.restore(snap)
+        return real(p, xs)
+
+    s._apply = apply
+    res1 = s.score_with_ids(_records(stream[1][1]), stream[1][0])
+    res2 = s.score_with_ids(_records(stream[2][1]), stream[2][0])
+    assert calls == [1, 2]
+    assert res1.ready_at is not None and res2.ready_at is None
+    assert reg.counter("seq_stale_commits_total").total() == 1
+    res3 = s.score_with_ids(_records(stream[3][1]), stream[3][0])
+    assert np.asarray(res2).shape == (len(stream[2][0]),)
+    assert reg.counter("seq_stale_commits_total").total() == 2
+    fresh = SeqScorer(params, **kw)
+    fresh.store.restore(snap)
+    np.testing.assert_array_equal(np.asarray(res3), fresh.score(*stream[3]))
+    _same_snapshot(fresh.store, s.store)
+    assert s.store.contended_skips == 0
+
+
+def test_forcing_threads_race_the_staging_thread_and_nothing_is_lost():
+    """One thread stages batch after batch through the deferring entry
+    while others force whatever results exist, in any order, under a
+    shortened switch interval: every batch is resolved once, in order,
+    and probabilities and store are those of the batch-by-batch run."""
+    import sys
+    import threading
+
+    params = seq_mod.init(jax.random.PRNGKey(17))
+    kw = dict(length=4, batch_sizes=(4, 16), compute_dtype="float32")
+    plain, over = SeqScorer(params, **kw), SeqScorer(params, **kw)
+    stream = _stream("mixed", 40)
+    want = [plain.score(x, ids) for x, ids in stream]
+    results: list = []
+    stop = threading.Event()
+    errors: list = []
+
+    def force(seed):
+        rng = np.random.default_rng(seed)
+        while not stop.is_set():
+            if results:
+                try:
+                    np.asarray(results[int(rng.integers(len(results)))])
+                except Exception as e:  # noqa: BLE001 - fails the test
+                    errors.append(e)
+                    return
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    forcers = [threading.Thread(target=force, args=(s,)) for s in range(6)]
+    try:
+        for t in forcers:
+            t.start()
+        for x, ids in stream:
+            results.append(over.score_with_ids(_records(ids), x))
+    finally:
+        stop.set()
+        for t in forcers:
+            t.join(timeout=30)
+        sys.setswitchinterval(old)
+    assert not errors and not any(t.is_alive() for t in forcers)
+    for w, g in zip(want, results):
+        np.testing.assert_array_equal(np.asarray(g), w)
+    assert not over._open and over.store.contended_skips == 0
+    _same_snapshot(plain.store, over.store)

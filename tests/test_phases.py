@@ -164,6 +164,45 @@ def test_a_capture_around_the_scorer_holds_its_phases(tmp_path):
     assert (commit[3]["customers"], commit[3]["stale"]) == (10, 0)
 
 
+def test_a_deferred_batch_waits_and_commits_inside_the_next_call(tmp_path):
+    """The deferring entry: ``seq.wait`` and ``seq.commit`` of batch k
+    open inside ``seq.score`` of k+1 (after k+1's gather, pad and
+    enqueue); a batch that is forced waits and commits outside every
+    ``seq.score``; the stats say how many batches were open and whether
+    this one was staged beside an unresolved one."""
+    from ccfd_tpu.router.router import DeferrableRecords
+
+    scorer = tiny_scorer(Registry())
+    scorer.warmup()
+    sizes = (5, 9, 3)
+    with Capture(tmp_path) as cap:
+        results = [
+            scorer.score_with_ids(DeferrableRecords(
+                [{"id": f"b{k}-{j}"} for j in range(n)]), rows(n, seed=k))
+            for k, n in enumerate(sizes)]
+        np.asarray(results[-1])
+    scores = cap.named("seq.score")
+    assert [e[3]["rows"] for e in scores] == list(sizes)
+    assert [e[3]["open_batches"] for e in scores] == [0, 1, 1]
+    assert [e[3]["overlapped"] for e in scores] == [0, 1, 1]
+
+    def inside(name, span):
+        return [e for e in cap.named(name)
+                if span[1] <= e[1] and e[2] <= span[2]]
+
+    assert not inside("seq.wait", scores[0])
+    assert not inside("seq.commit", scores[0])
+    for k in (1, 2):
+        (wait,) = inside("seq.wait", scores[k])
+        (commit,) = inside("seq.commit", scores[k])
+        (enqueue,) = inside("seq.enqueue", scores[k])
+        assert wait[3]["rows"] == commit[3]["customers"] == sizes[k - 1]
+        assert enqueue[2] <= wait[1] <= wait[2] <= commit[1]
+    forced = [e for e in cap.named("seq.wait")
+              if not any(s[1] <= e[1] and e[2] <= s[2] for s in scores)]
+    assert [e[3]["rows"] for e in forced] == [sizes[-1]]
+
+
 def test_a_pipelined_router_with_no_tracer_shows_in_a_capture(tmp_path):
     cfg = Config(fraud_threshold=0.99)
     broker = Broker()
