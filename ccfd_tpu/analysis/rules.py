@@ -251,7 +251,7 @@ REFERENCE_BOARD_NAMES = frozenset((
     "proba_1", "Amount", "V17", "V10",  # ModelPrediction.json:96-322
 ))
 # Kind-keyed exemptions: a (kind, name) pair predating the rule whose
-# rename would break checked-in dashboards and recorded bench history.
+# rename would break checked-in dashboards and recorded readings.
 # Keyed by kind so the exemption cannot silently re-admit a FUTURE
 # metric registered under the same name as a different kind.
 GRANDFATHERED_NAMES = frozenset((

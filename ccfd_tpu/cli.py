@@ -8,7 +8,6 @@ in-process:
                              # process -> notify -> retrain, prints metrics
   python -m ccfd_tpu serve   # REST scorer (Seldon contract) on a port
   python -m ccfd_tpu train   # offline-train the flagship MLP + checkpoint
-  python -m ccfd_tpu bench   # the benchmark JSON line (same as bench.py)
 """
 
 from __future__ import annotations
@@ -906,19 +905,6 @@ def cmd_analyze(args: argparse.Namespace) -> int:
     return 0
 
 
-def cmd_bench(args: argparse.Namespace) -> int:
-    # bench.py lives at the repo root (next to the package), not inside it
-    import importlib.util
-    import os
-
-    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py")
-    spec = importlib.util.spec_from_file_location("bench", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    mod.main()
-    return 0
-
-
 def cmd_store(args: argparse.Namespace) -> int:
     """Object-store ops: the reference run-book's Ceph/S3 steps
     (README.md:136-343 — serve the store, upload the CSV, `aws s3 ls`)."""
@@ -1500,10 +1486,9 @@ def cmd_tasks(args: argparse.Namespace) -> int:
 
 
 def cmd_loadgen(args: argparse.Namespace) -> int:
-    """Benchmark a RUNNING scorer endpoint (local or remote) with the same
-    lean client the in-tree bench uses, so operator numbers compare
-    directly against BASELINE.md's rest section. Exits non-zero when any
-    request errored — usable as a smoke gate in deploy pipelines."""
+    """Drive a RUNNING scorer endpoint (local or remote) with the lean
+    client of ``utils/loadgen.py`` and print its report. Exits non-zero
+    when any request errored — usable as a smoke gate in deploy pipelines."""
     from ccfd_tpu.utils.loadgen import run_loadgen
 
     cfg = Config.from_env()
@@ -1523,7 +1508,7 @@ def cmd_doctor(args: argparse.Namespace) -> int:
     section runs in a child process with a timeout, which is what makes
     that child legitimate (a chip belongs to one process at a time, and
     the parent never holds it). For the same reason the probe FAILS while
-    a server, bench or smoke on this host holds the chip — run it before
+    a server, benchmark or smoke on this host holds the chip — run it before
     starting one, or read the server's own ``/debug/device``.
 
     Sections: accelerator (platform, device count, measured dispatch RTT),
@@ -1703,8 +1688,8 @@ def _tune_gc() -> None:
     """Service processes amortize gc over large gen-0 batches: jax's gc
     callback runs XLA garbage collection on EVERY Python collection, and
     the hot loops' record churn fires gen-0 hundreds of times per second
-    at the default threshold — measured +51% pipeline throughput on the
-    1-core host (utils/gctune.py; CCFD_GC_THRESHOLD=0 opts out)."""
+    at the default threshold (utils/gctune.py; CCFD_GC_THRESHOLD=0 opts
+    out)."""
     from ccfd_tpu.utils.gctune import tune_for_service
 
     tune_for_service()
@@ -1712,8 +1697,8 @@ def _tune_gc() -> None:
 
 # commands whose code path imports jax; the others (bus, notify, producer,
 # store, engine) stay jax-free and must not pay the import at startup
-_JAX_CMDS = {"demo", "serve", "train", "analyze", "bench", "router", "up",
-             "score", "quantize"}
+_JAX_CMDS = {"demo", "serve", "train", "analyze", "router", "up", "score",
+             "quantize"}
 
 
 def _is_jax_command(argv: list[str]) -> bool:
@@ -1915,9 +1900,6 @@ def main(argv: list[str] | None = None) -> int:
     an.add_argument("--drift-split", action="store_true",
                     help="also run a first-half vs second-half drift self-check")
     an.set_defaults(fn=cmd_analyze)
-
-    b = sub.add_parser("bench", help="print the benchmark JSON line")
-    b.set_defaults(fn=cmd_bench)
 
     st = sub.add_parser("store", help="S3-shaped object store (serve/put/ls)")
     st.add_argument("action", choices=("serve", "put", "ls"))

@@ -188,10 +188,10 @@ class Config:
     # the slow budget window at burn 1.0 (CCFD_SLO_WINDOWS)
     slo_windows: str = "300,3600,21600"
     slo_fast_burn: float = 14.4            # CCFD_SLO_FAST_BURN
-    # REST transport floor for the budget ledger: the r04
-    # rest_latency_floor measurement (NativeFront 1x1-row RTT p99,
-    # REST_SWEEP/BENCH_r04) — re-measure with tools/rest_sweep.py when
-    # the front or host changes (CCFD_SLO_TRANSPORT_FLOOR_MS)
+    # REST transport floor for the budget ledger: a NativeFront 1x1-row
+    # RTT p99 taken on a CPU host before the chip. No cell of the
+    # benchmark measures the REST path yet (ROADMAP A7): re-measure on
+    # the deployment's own host and set CCFD_SLO_TRANSPORT_FLOOR_MS
     slo_transport_floor_ms: float = 0.072
 
     # --- device telemetry (observability/device.py; CR block `device:`) ---

@@ -34,7 +34,7 @@ What is matched (against the public Kaggle dataset card / EDA consensus):
 
 It is labeled a surrogate everywhere it surfaces; the moment a real
 ``creditcard.csv`` is available, ``CCFD_CSV=/path`` switches every consumer
-(train/serve/producer/bench) to it with no code change
+(train/serve/producer) to it with no code change
 (``data/ccfd.load_dataset``), and tests/test_real_csv.py runs the real-data
 lifecycle when that env var is set.
 """

@@ -18,8 +18,7 @@ alert-rate deltas. Shadow evaluation is a SAMPLE by design, bounded two
 ways so the live pipeline never pays for it: a token-bucket row budget
 (``max_rows_per_s``; on a saturated host the worker thread's numpy
 forwards and pair production would otherwise steal cores from the routing
-loop — bench.py's ``pipeline.shadow`` row is the acceptance number) and a
-bounded queue (challenger slower than the admitted stream). Batches past
+loop) and a bounded queue (challenger slower than the admitted stream). Batches past
 either bound drop OLDEST-first, counted in
 ``ccfd_lifecycle_shadow_dropped_total`` — the evaluator's verdict just
 accumulates over a slightly longer window.

@@ -80,7 +80,7 @@ def fit_numpy(
 ) -> Params:
     """Self-contained IRLS trainer (no sklearn): standardizes then folds back.
 
-    Used by tests and the bench baseline when scikit-learn is unavailable.
+    Used by tests when scikit-learn is unavailable.
     """
     mean = X.mean(axis=0)
     scale = X.std(axis=0)

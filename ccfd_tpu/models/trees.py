@@ -93,11 +93,11 @@ def logits_mxu(params: Params, x: jax.Array) -> jax.Array:
     :func:`logits` (parity-tested on the CPU and, by chip_smoke.py, on the
     chip); choose per backend via the ``gbt_mxu`` registry entry.
 
-    Measured regimes (BASELINE.md "Model variants"): on CPU the gather
-    path wins decisively (221k vs 79k tx/s, BENCH_r02 zoo) — extra FLOPs
-    with no systolic array to feed them to. The MXU inversion is the
-    HYPOTHESIS this variant exists to test; treat ``gbt_mxu`` as
-    experimental until an on-chip A/B records it winning.
+    Regimes (BASELINE.md "Model variants"): on a CPU the gather path
+    wins — extra FLOPs with no systolic array to feed them to. The MXU
+    inversion is the HYPOTHESIS this variant exists to test; treat
+    ``gbt_mxu`` as experimental until an on-chip A/B records it winning
+    (no cell of the benchmark serves it yet: ROADMAP C4).
     """
     feat, thr, leaf = params["feature"], params["threshold"], params["leaf"]
     n_trees = leaf.shape[0]

@@ -300,7 +300,7 @@ class AuditLog:
         the threshold in force, the lineage sample, the open incident —
         is resolved ONCE here and shared across the batch (the
         per-batch-not-per-row contract that keeps the armed plane
-        inside bench noise). The route seam owns ``threshold``: it is a
+        cheap). The route seam owns ``threshold``: it is a
         property of the decision, not of this log."""
         if not rows:
             return

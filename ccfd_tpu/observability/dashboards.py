@@ -493,9 +493,8 @@ def seq_serving_dashboard() -> dict:
     """Sequence Serving board (round 11; serving/history.py).
 
     The overlapped seq dataflow's surface: host assembly vs device
-    dispatch per router batch (the BENCH_r05 1412-vs-13 ms split, now
-    live numbers — dispatch here counts only the blocking waits the
-    overlap failed to hide), the (L, B)-bucket executable mix (short L
+    dispatch per router batch (live numbers — dispatch here counts only
+    the blocking waits the overlap failed to hide), the (L, B)-bucket executable mix (short L
     buckets firing = the cold-row fast lane actually serving), async
     in-flight depth, the anonymous lock-free fast path, live-history
     customers against the LRU cap, and the stale-generation commit

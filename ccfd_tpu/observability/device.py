@@ -26,7 +26,7 @@ identical plumbing the TPU run reports from:
   (:func:`~ccfd_tpu.observability.profile.compile_stage`).
 
 One instance per platform (operator ``device:`` block, ``CCFD_DEVICE=0``
-kill switch). ``set_default``/``get_default`` exist for harnesses (bench)
+kill switch). ``set_default``/``get_default`` exist for harnesses
 that build scorers deep inside helpers; the operator always passes the
 instance explicitly.
 """
@@ -46,7 +46,7 @@ _DEFAULT: "DeviceTelemetry | None" = None
 
 
 def set_default(telemetry: "DeviceTelemetry | None") -> None:
-    """Install a process-default telemetry plane (bench harness hook;
+    """Install a process-default telemetry plane (harness hook;
     scorers built with ``telemetry=None`` pick it up). Pass None to
     clear."""
     global _DEFAULT
@@ -205,7 +205,7 @@ class DeviceTelemetry:
 
     def peak_memory_bytes(self) -> int | None:
         """Max peak_bytes_in_use across devices; None when no backend
-        reports allocator stats (CPU) — bench rows record null then."""
+        reports allocator stats (CPU)."""
         peaks = [e["peak_bytes_in_use"]
                  for e in self.device_memory().values()
                  if "peak_bytes_in_use" in e]

@@ -1,6 +1,6 @@
 """Stage profiles: a machine-readable queueing/service/dispatch decomposition.
 
-PR 2's spans, PR 6's overload gauges and the bench rows are human-readable
+PR 2's spans and PR 6's overload gauges are human-readable
 evidence; ROADMAP item 3's InferLine-style provisioning planner
 (arXiv:1812.01776) needs a machine-readable PROFILE of each pipeline stage
 — per stage, how much of a transaction's latency was queueing (waiting for

@@ -26,9 +26,9 @@ machinery, sized for this pipeline:
 
 - :class:`BudgetLedger` — the per-layer latency budget for the NativeFront
   REST path ROADMAP item 1 needs before the ≥50k tx/s on-device target
-  can be decomposed: the r04 ``rest_latency_floor`` transport floor
-  (0.072 ms p99, REST_SWEEP; ``CCFD_SLO_TRANSPORT_FLOOR_MS``) as a static
-  layer, measured batcher wait and device dispatch from the
+  can be decomposed: the REST transport floor
+  (``Config.slo_transport_floor_ms``; ``CCFD_SLO_TRANSPORT_FLOOR_MS``) as
+  a static layer, measured batcher wait and device dispatch from the
   :class:`~ccfd_tpu.observability.profile.StageProfiler`, and an H2D
   layer that reads the MEASURED transfer digest from the device
   telemetry plane (observability/device.py) when it is armed — the

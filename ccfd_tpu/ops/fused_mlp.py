@@ -37,8 +37,8 @@ INPUT_DTYPE = "bfloat16"  # wire format for rows: half the H2D bytes
 
 def fit_tile(rows: int) -> int:
     """Largest power-of-two-ish tile <= DEFAULT_TILE dividing ``rows`` —
-    the ONE tiling policy every caller (both kernels' dispatch paths and
-    the bench) shares."""
+    the ONE tiling policy every caller (both kernels' dispatch paths)
+    shares."""
     tile = min(rows, DEFAULT_TILE)
     while rows % tile:
         tile //= 2

@@ -243,8 +243,8 @@ fused_score = fused_mlp_q8_score
 # model's OWN first requantization, just on the other side of the wire.
 # Where H2D dominates the serving hop (the reason the bf16 kernel ships
 # bf16 rows), this is the q8 path's wire lever; the numpy quantize cost
-# rides the host, so the tradeoff is recorded by the bench quant section,
-# not assumed. The two sides of the wire divide in different hardware:
+# rides the host, so the tradeoff has to be measured, not assumed (no
+# cell of the benchmark serves ``mlp_q8`` yet: ROADMAP C4). The two sides of the wire divide in different hardware:
 # on the chip the host's (x - mu) / sigma and the device's can differ in
 # the last ulp, which moves a quantisation step for an occasional row
 # (max 1.8e-3 in probability against the device's XLA graph in one probe

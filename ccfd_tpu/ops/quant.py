@@ -4,9 +4,9 @@ Architectural rationale: TPU MXUs execute int8 x int8 -> int32 matmuls at
 up to twice the bf16 rate, and int8 weights/activations halve the HBM and
 H2D bytes again over bf16 — on a wire-bound attachment that is the larger
 win. NOTE these are the hardware's numbers, not this model's: ``mlp_q8``
-has no recorded on-TPU throughput yet (the bench's ``quant_int8`` section
-is TPU-gated; accuracy IS measured — see below and BASELINE.md "Model
-variants"). Until a capture lands, the claim this module makes is accuracy
+has no recorded on-TPU throughput yet (no cell of the benchmark serves
+it: ROADMAP C4; accuracy IS measured — see below and BASELINE.md "Model
+variants"). Until a cell lands, the claim this module makes is accuracy
 preservation, not speed. This module quantizes the flagship MLP
 (models/mlp.py) for inference:
 

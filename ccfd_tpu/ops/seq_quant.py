@@ -17,9 +17,9 @@ members share one accuracy story:
 
 On a TPU the MXU runs int8 x int8 -> int32 at up to twice the bf16 rate
 and the weights ship/reside at a quarter of f32 — the same hardware
-argument as ``mlp_q8``, here applied to the dispatch-bound seq path
-(BENCH_r05: 1412 ms dispatch vs 13 ms assembly). As with ``mlp_q8`` the
-claim made on CPU captures is accuracy preservation, not speed.
+argument as ``mlp_q8``, here applied to the seq path, whose pace the
+device program sets (PERF.md section 5). As with ``mlp_q8`` the claim
+made on CPU captures is accuracy preservation, not speed.
 
 Registered in the model zoo as ``seq_q8``; it reaches serving ONLY through
 the lifecycle shadow lane (AUC/PSI guardrails against the bf16 champion —

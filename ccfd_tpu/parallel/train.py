@@ -145,7 +145,7 @@ def _opt_spec_like(opt_state: Any, params: Any, pspec: Any, mesh: Mesh) -> Any:
 
 
 # ---------------------------------------------------------------------------
-# Convenience offline trainer (model prep for serving/bench)
+# Convenience offline trainer (model prep for serving)
 
 
 def fit_mlp(

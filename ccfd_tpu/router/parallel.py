@@ -2,8 +2,8 @@
 
 One :class:`~ccfd_tpu.router.router.Router` thread consumes every bus
 partition and serializes decode + engine hand-off even in the pipelined
-loop — ``bench.py``'s ``pipeline`` section sustains a fraction of what the
-same scorer does alone. The reference scales this exact hop by Kafka
+loop, so a row model's pipeline sustains a fraction of what the same
+scorer does alone. The reference scales this exact hop by Kafka
 partitions × router replicas (reference deploy/frauddetection_cr.yaml
 partitions, router.yaml replicas); the TPU-native analog is many consumer
 workers feeding ONE accelerator through a coalescing batcher — the
@@ -53,7 +53,7 @@ the fan-out.
 The facade mirrors the Router surface the rest of the runtime touches
 (pause/resume/recycle_consumers/swap_engine/engine/run/start/stop/close/
 step and the ``_stop`` liveness flag), so the CheckpointCoordinator, the
-Supervisor, the ChaosMonkey and the soak/bench tools drive it unchanged.
+Supervisor, the ChaosMonkey and the soak tools drive it unchanged.
 """
 
 from __future__ import annotations

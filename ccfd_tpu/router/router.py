@@ -880,10 +880,10 @@ class Router:
             try:
                 ov = self._overload
                 if ov is not None and ov.dispatch_deadline_s > 0:
-                    # dispatch watchdog: a hung/slow device dispatch (the
-                    # seq path measured 1412 ms, BENCH_r05) is killed at
-                    # the deadline and lands in this except — one breaker
-                    # failure and a ladder fall, not a stalled worker
+                    # dispatch watchdog: a hung/slow device dispatch is
+                    # killed at the deadline and lands in this except —
+                    # one breaker failure and a ladder fall, not a
+                    # stalled worker
                     proba, fired = ov.bounded_dispatch(
                         lambda: self._score2(x, txs))
                 else:

@@ -541,7 +541,7 @@ def read_json_artifact(path: str, artifact: str = "artifact",
 def write_json_interchange(path: str, doc: Any, artifact: str = "interchange",
                            best_effort: bool = True, **dump_kw: Any) -> bool:
     """Crash-safe write for documents external readers ``json.load``
-    directly (incident bundles, profile artifacts, bench JSON): the body
+    directly (incident bundles, profile artifacts): the body
     stays plain JSON; integrity rides a ``<path>.sha256`` sidecar written
     AFTER the body, so every crash window leaves either the old pair or
     a new body whose missing/stale sidecar reads as unverified — never a

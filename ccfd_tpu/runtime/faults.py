@@ -655,7 +655,7 @@ def device_oom_overlay() -> float | None:
     if s is None:
         return None
     # one injection per activation window, not per read: device_memory()
-    # runs on every scrape / bench meter / heal tick, and a read-rate
+    # runs on every scrape / heal tick, and a read-rate
     # artifact would make injected[] counts incomparable across kinds
     if plan._oom_counted_epoch != plan.activations:
         plan._oom_counted_epoch = plan.activations

@@ -5,9 +5,7 @@ that either works or is someone else's problem: PR 1 hardened the RPC
 edges around it, PR 6 bounded individual dispatches into it, PR 10
 measured it. Nothing OWNS the device as a fallible component — detects
 that it wedged / OOM'd / fell into a compile storm, takes it out of
-rotation, heals it, and returns traffic safely. That gap is ROADMAP
-item 1's operational blocker (every bench capture since 2026-07-30 runs
-``cpu (fallback: accelerator probe failed)``), and it is the layer the
+rotation, heals it, and returns traffic safely. It is the layer the
 serving literature presupposes: InferLine's planner retunes over hardware
 it assumes stays healthy, and the "300M predictions/sec" utilization
 story needs chips that stay IN rotation.

@@ -4,10 +4,10 @@ The seq model (models/seq.py) scores the NEWEST transaction given the
 customer's recent history (B, L, F). Single-row REST scoring is stateless
 by design (the Seldon contract); history lives where the stream lives —
 in the routing tier, which already sees every transaction in arrival
-order. This module is that state, reworked (round 11) from a synchronous
-chunk loop into an overlapped serving dataflow — BENCH_r05 measured the
-old path at 1412 ms device dispatch vs 13 ms assembly per bucket
-(assembly_fraction 0.009): entirely dispatch-bound, serialized anyway.
+order. This module is that state, as an overlapped serving dataflow: a
+synchronous chunk loop leaves the device idle while the host assembles
+and the host idle while the device computes (PERF.md section 5 has what
+a batch's time is made of today).
 
 - ``HistoryStore`` — a fixed-depth ring per customer, all rings in one
   slab that grows in blocks, bounded total customers (LRU eviction at the

@@ -95,7 +95,7 @@ class Scorer:
         # every staging put on the dispatch path is timed + byte-counted
         # (ccfd_h2d_bytes_total / ccfd_h2d_seconds — the measured numbers
         # the BudgetLedger's h2d layer reads). None resolves through the
-        # module default so harnesses (bench) arm scorers built deep
+        # module default so harnesses arm scorers built deep
         # inside helpers; the operator passes its instance explicitly.
         if telemetry is None:
             from ccfd_tpu.observability import device as _device
@@ -216,8 +216,8 @@ class Scorer:
         # free (staging side); a failing hook never blocks the publish.
         self._prepublish_hooks: list[Any] = []
         # host materializations per score_pipelined call site: the staged
-        # path pays one np.asarray(done) sync per chunk; the fused decision
-        # bench reads this to report host_syncs_per_batch for BOTH paths.
+        # path pays one np.asarray(done) sync per chunk; the fused path
+        # counts the same under the same name (serving/fused.py).
         self.host_syncs = 0
         # challenger slot (lifecycle/shadow.py): a second, double-buffered
         # (version, host_params) pair living NEXT TO the champion — shadow
@@ -784,7 +784,7 @@ class Scorer:
 
     def _device_score_deadline(self, x: np.ndarray) -> np.ndarray:
         """Device path with a bounded round trip (serving latency path only;
-        ``score_pipelined`` called directly — bulk/bench — is unbounded by
+        ``score_pipelined`` called directly — bulk scoring — is unbounded by
         design). Timeout => host fallback at ANY batch size, or
         :class:`~ccfd_tpu.serving.dispatch.ScorerTimeout` for the fronts to
         map to 503 when the model has no host forward."""

@@ -4,7 +4,7 @@ The system is written for a TPU and its speed is only ever claimed there;
 the CPU backend exists for tier-1 and the ``tools/*_smoke.py`` drills. So a
 JAX command either was told to use the CPU, by ``JAX_PLATFORMS=cpu`` in the
 environment, or it runs on a TPU — it never finds no chip and quietly
-serves from the CPU. ``cli.main`` (for the JAX commands), ``bench.py``,
+serves from the CPU. ``cli.main`` (for the JAX commands),
 ``chip_smoke.py`` and ``__graft_entry__`` all start with
 :func:`require_backend`; nothing else chooses a platform, and JAX reads
 ``JAX_PLATFORMS`` itself.

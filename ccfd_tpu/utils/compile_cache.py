@@ -21,8 +21,8 @@ Where the cache lives is decided outside the program when it can be:
 - ``CCFD_COMPILE_CACHE=0`` (or ``off``): disabled wherever it would have
   lived. tier-1 sets this (tests/conftest.py) for the same reload hazard.
 
-``enable()`` is called by the CLI for jax-using commands, by bench.py and
-by chip_smoke.py.
+``enable()`` is called by the CLI for jax-using commands and by
+chip_smoke.py.
 """
 
 from __future__ import annotations

@@ -5,9 +5,9 @@ Python gc pass (jax/_src/lib/__init__.py, jax issue #14882). The router
 decodes tens of thousands of records per second into short-lived Python
 objects, so the default gen-0 threshold (700 allocations) fires collections
 hundreds of times per second — and each one pays the XLA callback plus a
-scan of every tracked object. Profiled on the 1-core bench host this was
-one of the largest single consumers in the pipeline loop (~2,200
-collections in a 6 s window).
+scan of every tracked object. Profiled on a 1-core CPU host this was
+one of the largest single consumers in the pipeline loop (a count:
+~2,200 collections in a 6 s window).
 
 ``tune_for_service()`` raises the gen-0 threshold so collections amortize
 over far more allocations (the hot loops' churn is flat per batch — no

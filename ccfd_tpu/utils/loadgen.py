@@ -8,11 +8,10 @@ scheduling), each client is a raw socket + pre-serialized request bytes
 (an http.client loop burns hundreds of µs/request on header objects),
 and latency is measured send-to-full-response per request.
 
-``_CLIENT`` is the single copy of that client — bench.py's ``rest``
-section runs the same script, so ``ccfd_tpu loadgen`` numbers compare
-directly against BASELINE.md. It imports NO jax, and must stay so: the
-bench starts it from a process that holds the chip, and a child that
-touched JAX there would fail or hang (one process per chip). It handles real-deployment HTTP, not just
+``_CLIENT`` is the single copy of that client. It imports NO jax, and
+must stay so: a harness may start it from a process that holds the
+chip, and a child that touched JAX there would fail or hang (one
+process per chip). It handles real-deployment HTTP, not just
 the in-tree server: Content-Length and chunked responses, servers or
 proxies that close the connection per response (reconnect + retry), and
 non-200s counted as errors rather than dying.

@@ -2,7 +2,7 @@
 
 Mirrors the CI strategy in SURVEY.md §4: multi-chip sharding logic is
 exercised on `--xla_force_host_platform_device_count=8` CPU devices; real-TPU
-runs happen in bench.py / the driver's dryrun, not in unit tests.
+runs happen through benchmark/run.py and chip_smoke.py, not in unit tests.
 """
 
 import os

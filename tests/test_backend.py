@@ -43,7 +43,7 @@ def test_tpu_backend_is_accepted(monkeypatch):
 def test_cli_applies_the_rule_to_jax_commands_only():
     from ccfd_tpu.cli import _is_jax_command
 
-    for argv in (["serve"], ["demo"], ["bench"], ["fleet", "member", "--spec",
+    for argv in (["serve"], ["demo"], ["train"], ["fleet", "member", "--spec",
                  "x"], ["replay", "--live"]):
         assert _is_jax_command(argv), argv
     # the fleet supervisor spawns the members that need the chip; it and

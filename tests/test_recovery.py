@@ -177,12 +177,28 @@ def _pipeline(tmp_path=None):
     return broker, router, coord
 
 
-def _drain(router, n, timeout_s=20.0):  # generous: the 1-core CI host
+def _reaches(read, n, timeout_s=20.0):  # generous: the 1-core CI host
     # runs the whole suite concurrently with background watchers
+    """``read()`` once it has reached ``n``, or as the deadline left it."""
     deadline = time.time() + timeout_s
-    while router._c_in.value() < n and time.time() < deadline:
+    while read() < n and time.time() < deadline:
         time.sleep(0.01)
-    assert router._c_in.value() >= n
+    return read()
+
+
+def _drain(router, n):
+    assert _reaches(router._c_in.value, n) >= n
+
+
+def _wait_started(registry, n):
+    """The engine's STARTED counter for the standard process, once it has
+    reached ``n`` (or the deadline passed). ``_drain`` returns when the
+    decode-side counter does: the pipelined loop counts incoming at decode
+    time, so the batch may still be in flight and a route-side counter
+    read right after it reads short (flaky under load)."""
+    started_c = registry.counter("process_instances_started_total")
+    return _reaches(
+        lambda: started_c.value(labels={"process": "standard"}), n)
 
 
 def test_checkpoint_restore_replays_post_cut_records():
@@ -195,29 +211,20 @@ def test_checkpoint_restore_replays_post_cut_records():
         cut = coord.checkpoint()
         assert cut is not None and coord.checkpoints == 1
         # post-cut work: the doomed engine processes 10 more. Wait on the
-        # engine's STARTED counter, not _c_in: the pipelined loop counts
-        # incoming at decode time, so _c_in can hit 30 with the batch
-        # still in flight — started_before would read short and restore's
-        # barrier-drained batch would inflate the delta (flaky under load)
+        # engine's STARTED counter, not _c_in: started_before would read
+        # short and restore's barrier-drained batch would inflate the delta
         broker.produce_batch(CFG.kafka_topic,
                              [tx(i, 10.0) for i in range(20, 30)])
         _drain(router, 30)
-        started_c = router.engine.registry.counter(
-            "process_instances_started_total")
-        deadline = time.time() + 20.0
-        while (started_c.value(labels={"process": "standard"}) < 30
-               and time.time() < deadline):
-            time.sleep(0.01)
-        started_before = started_c.value(labels={"process": "standard"})
+        started_before = _wait_started(router.engine.registry, 30)
         assert started_before == 30
         # crash + restore: the 10 post-cut records must re-deliver into the
         # restored engine (at-least-once), through the SAME live router
         new_engine = coord.restore(reason="test")
         assert router.engine is new_engine
         _drain(router, 40)  # 30 + 10 replayed
-        started_after = new_engine.registry.counter(
-            "process_instances_started_total"
-        ).value(labels={"process": "standard"})
+        started_after = _wait_started(new_engine.registry,
+                                      started_before + 10)
         assert started_after - started_before == 10
     finally:
         router.stop()
@@ -348,10 +355,7 @@ def test_restore_without_checkpoint_is_genesis_replay():
         _drain(router, 6)
         engine = coord.restore(reason="no-checkpoint")
         _drain(router, 12)  # full replay from offset 0
-        started = engine.registry.counter(
-            "process_instances_started_total"
-        ).value(labels={"process": "standard"})
-        assert started >= 6
+        assert _wait_started(engine.registry, 6) >= 6
     finally:
         router.stop()
         t.join(timeout=5)
@@ -397,10 +401,7 @@ def test_full_process_crash_recovery_from_disk(tmp_path):
     t2 = r2.start(poll_timeout_s=0.01)
     try:
         _drain(r2, 10)  # exactly the post-cut gap re-drives
-        started = reg2.counter("process_instances_started_total").value(
-            labels={"process": "standard"}
-        )
-        assert started == 10
+        assert _wait_started(reg2, 10) == 10
     finally:
         r2.stop()
         t2.join(timeout=5)

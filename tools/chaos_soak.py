@@ -38,9 +38,10 @@ never progress or correctness.
     JAX_PLATFORMS=cpu python tools/chaos_soak.py --seconds 240
     JAX_PLATFORMS=cpu python tools/chaos_soak.py --seconds 240 --net-faults
 
-Prints one JSON line; record it in BASELINE.md.  Exit 0 only when the
-pipeline drained, the device path recovered, engine kills happened and
-every accounting check passed.
+Prints one JSON line (counts and verdicts; its rates are a CPU host's and
+are no record of speed).  Exit 0 only when the pipeline drained, the
+device path recovered, engine kills happened and every accounting check
+passed.
 """
 from __future__ import annotations
 
@@ -401,11 +402,11 @@ def main() -> int:
     ds = synthetic_dataset(n=4096, fraud_rate=0.002, seed=0)
     params = mlp.init(jax.random.PRNGKey(0))
     params = mlp.set_normalizer(params, ds.X.mean(0), ds.X.std(0))
-    # push probabilities to a trained-model-like range (bench.py does the
-    # same): an untrained MLP fires ~half of all traffic into the fraud
-    # process, which floods the engine with open investigations at a rate
-    # no investigator pool could match and turns the soak into a
-    # snapshot-size stress test instead of a failure drill
+    # push probabilities to a trained-model-like range: an untrained MLP
+    # fires ~half of all traffic into the fraud process, which floods the
+    # engine with open investigations at a rate no investigator pool could
+    # match and turns the soak into a snapshot-size stress test instead of
+    # a failure drill
     import jax.numpy as jnp
 
     params = dict(params)
@@ -738,7 +739,7 @@ def main() -> int:
     # feeder: keep the topic loaded without unbounded backlog; the gate
     # lets the bus drill quiesce production without killing the thread.
     # CSV byte rows with the customer id as the record KEY — the produce
-    # wire the reference producer uses (and bench.py's pipeline section):
+    # wire the reference producer uses:
     # ~6x smaller retained records than feature dicts, GC-untracked
     # (bus/broker.py Record note), and crash_restart replays them without
     # a JSON decode per record — the soak's flat-RSS claim is about the

@@ -255,9 +255,9 @@ def run_drill(
         out["kill_bundles"] = bundles
         checks["exactly_one_kill_bundle"] = len(bundles) == 1
 
-        # fleet-scaling bench row (tools/multichip_scaling.py analog for
-        # the HOST dimension): routed throughput across the whole drill
-        # window, kill and rebalance included — the survivable number
+        # fleet-scaling row (the HOST dimension): routed throughput across
+        # the whole drill window, kill and rebalance included — the
+        # survivable number
         bench = {
             "mode": "fleet_scaling",
             "members": members,

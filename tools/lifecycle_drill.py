@@ -20,10 +20,10 @@ The governed-rollout acceptance run (lifecycle/):
 Every transition is checked against the persisted audit trail, and the
 ``ccfd_lifecycle_stage`` / ``ccfd_lifecycle_promotions_total`` /
 ``ccfd_lifecycle_rollbacks_total`` series are asserted observable through
-a live MetricsExporter scrape. Writes LIFECYCLE_DRILL.json (lineage +
-audit + metrics) and exits 0 on success.
+a live MetricsExporter scrape. Writes its artifact (lineage + audit +
+metrics) under the system's temporary directory and exits 0 on success.
 
-Usage:  python tools/lifecycle_drill.py [--out LIFECYCLE_DRILL.json]
+Usage:  python tools/lifecycle_drill.py [--out PATH]
 """
 
 from __future__ import annotations
@@ -43,7 +43,8 @@ import numpy as np  # noqa: E402
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--out", default="LIFECYCLE_DRILL.json")
+    ap.add_argument("--out", default=os.path.join(
+        tempfile.gettempdir(), "ccfd_lifecycle_drill.json"))
     ap.add_argument("--state-dir", default="",
                     help="lifecycle state dir (default: a temp dir)")
     args = ap.parse_args()

@@ -26,7 +26,8 @@ Checks that make it a proof rather than a smoke:
     across a real process boundary (tensor-parallel stays in-process by
     design, asserted)
 
-Artifact: MULTIHOST_r04.json.  Run:  python tools/multihost_drill.py
+Artifact: ``--out`` (default under the system's temporary directory).
+Run:  python tools/multihost_drill.py
 
 Reference contrast: the reference scales out with k8s replicas over
 Kafka + REST (SURVEY.md §2 'distributed communication backend'); this is
@@ -40,6 +41,7 @@ import os
 import socket
 import subprocess
 import sys
+import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -248,7 +250,8 @@ def main() -> int:
                     help="comma-separated PROCxDEV pairs; every topology "
                     "keeps 8 global devices so the same program shapes run")
     ap.add_argument("--timeout", type=float, default=300.0)
-    ap.add_argument("--out", default=os.path.join(REPO, "MULTIHOST_r04.json"))
+    ap.add_argument("--out", default=os.path.join(
+        tempfile.gettempdir(), "ccfd_multihost_drill.json"))
     args = ap.parse_args()
 
     # parse and validate EVERY topology before running any: a malformed

@@ -210,16 +210,6 @@
 #                                          and zero serving-stage
 #                                          compiles after warmup:
 #                                          FUSEDSMOKE verdict=PASS|FAIL
-#   tools/verify_tier1.sh --bench-compare  normalize BENCH_r*.json
-#                                          captures into the append-only
-#                                          BENCH_HISTORY.jsonl ledger
-#                                          (tools/bench_compare.py) and
-#                                          gate on the per-row verdict
-#                                          vs the last SAME-PLATFORM
-#                                          capture: exit 1 iff a newly
-#                                          appended row regressed
-#                                          (throughput < 0.7x or p99 >
-#                                          1.3x its prior)
 set -u
 
 REPO_DIR="$(cd "$(dirname "$0")/.." && pwd)"
@@ -408,22 +398,6 @@ if [ "${1:-}" = "--fused-smoke" ]; then
     if JAX_PLATFORMS=cpu python tools/fused_smoke.py; then
         exit 0
     fi
-    exit 1
-fi
-
-if [ "${1:-}" = "--bench-compare" ]; then
-    # bench trajectory gate: fold fresh BENCH_r*.json captures into the
-    # append-only, platform-labeled BENCH_HISTORY.jsonl ledger and fail
-    # iff a newly appended row regressed against the last same-platform
-    # capture (see tools/bench_compare.py)
-    cd "$REPO_DIR" || exit 2
-    python tools/bench_compare.py
-    rc=$?
-    if [ "$rc" -eq 0 ]; then
-        echo "BENCHCOMPARE verdict=PASS"
-        exit 0
-    fi
-    echo "BENCHCOMPARE verdict=FAIL rc=${rc}"
     exit 1
 fi
 
