@@ -24,6 +24,7 @@ import numpy as np
 
 from ccfd_tpu.data.ccfd import NUM_FEATURES
 from ccfd_tpu.ops.ring_attention import reference_attention
+from ccfd_tpu.ops.seq_attention import attention as serving_attention
 
 Params = Mapping[str, Any]
 
@@ -189,8 +190,14 @@ def logits_readout(
     must give them the SAME encodings — without this, a customer's
     tokens would shift position at every ladder crossover. ``None``
     (default) anchors at ``x``'s own length — identical to ``logits``.
+
+    Left to itself it attends with ``ops/seq_attention.py::attention``:
+    the full blocks through the kernel that keeps the scores on the chip
+    where their shape admits it, ``reference_attention`` elsewhere (the
+    readout block's single query always). ``logits`` keeps
+    ``reference_attention``: it is what training differentiates.
     """
-    attn = attention_fn or reference_attention
+    attn = attention_fn or serving_attention
     mu = jax.lax.stop_gradient(params["norm"]["mu"])
     sigma = jax.lax.stop_gradient(params["norm"]["sigma"])
     h = ((x - mu) / sigma).astype(compute_dtype)

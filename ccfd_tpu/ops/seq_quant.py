@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ccfd_tpu.models.seq import N_HEADS, _layer_norm, _positions
-from ccfd_tpu.ops.ring_attention import reference_attention
+from ccfd_tpu.ops.seq_attention import attention as serving_attention
 
 Params = Mapping[str, Any]
 
@@ -132,8 +132,9 @@ def logits(
     like :func:`ccfd_tpu.models.seq.logits_readout` (the serving shape —
     this variant exists for the serving path), and ``pos_length``
     right-anchors positional encodings the same way (short L-bucket
-    windows keep the full-L path's token positions)."""
-    attn = attention_fn or reference_attention
+    windows keep the full-L path's token positions), and it attends as
+    ``logits_readout`` does (``ops/seq_attention.py::attention``)."""
+    attn = attention_fn or serving_attention
     mu = jax.lax.stop_gradient(params["norm"]["mu"])
     sigma = jax.lax.stop_gradient(params["norm"]["sigma"])
     h = ((x.astype(jnp.float32) - mu) / sigma)

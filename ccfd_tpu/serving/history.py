@@ -86,6 +86,7 @@ import itertools
 import threading
 import time
 from collections import OrderedDict, deque
+from functools import partial
 from typing import Any
 
 import numpy as np
@@ -622,6 +623,48 @@ class DeferredScores:
                 else self._value.astype(dtype, copy=False))
 
 
+class _Program:
+    """A family's device program as ``SeqScorer._make_apply`` built it,
+    and what its own trace says of each (L bucket, B bucket) executable:
+    whether the full-attention block holds the kernel that keeps the
+    scores on the chip (``ops/seq_attention.py``). Read the first time it
+    is asked (a look-up in the jit's trace cache once the executable has
+    run), from the memo afterwards; a swap to another variant builds
+    another program, so the memo never outlives what it describes."""
+
+    __slots__ = ("fn", "reads_filled", "num_features", "_held")
+
+    def __init__(self, fn: Any, reads_filled: bool, num_features: int):
+        self.fn = fn
+        self.reads_filled = reads_filled
+        self.num_features = num_features
+        self._held: dict = {}
+
+    def __call__(self, params: Any, hist: Any, *extra: Any):
+        return self.fn(params, hist, *extra)
+
+    def holds_attn_kernel(self, params: Any, lb: int, b: int) -> bool:
+        got = self._held.get((lb, b))
+        if got is None:
+            import jax
+
+            from ccfd_tpu.ops.seq_attention import held_by
+
+            shape = jax.ShapeDtypeStruct
+            extra = (shape((b,), np.int32),) if self.reads_filled else ()
+            got = self._held[(lb, b)] = held_by(
+                self.fn, params,
+                shape((b, lb, self.num_features), np.float32), *extra)
+        return got
+
+
+def _holds_attn_kernel(apply_fn: Any, params: Any, lb: int, b: int) -> bool:
+    """``_Program.holds_attn_kernel``; a stand-in for the program (a test's
+    or a drill's gate around it) has no trace to read and holds none."""
+    holds = getattr(apply_fn, "holds_attn_kernel", None)
+    return holds is not None and holds(params, lb, b)
+
+
 class SeqScorer:
     """History-aware scorer with the row scorer's serving discipline —
     bucketed static shapes — run as an overlapped dataflow: per-(L, B)
@@ -673,8 +716,9 @@ class SeqScorer:
         dormant flag, now operator-selectable (CR ``mesh.seq_parallel``).
         Blocks whose static shapes can't shard (the readout block's
         single-query attention; an L bucket not divisible by the axis)
-        fall back to reference attention per-executable — shapes are
-        static at trace time, so the choice costs nothing at runtime.
+        fall back per-executable to what one chip's program attends with
+        (ops/seq_attention.py) — shapes are static at trace time, so the
+        choice costs nothing at runtime.
 
         ``inflight``: async dispatches in flight before the loop blocks
         on the oldest (0 = resolve immediately, the synchronous path),
@@ -727,7 +771,7 @@ class SeqScorer:
         self._batch_sharding = None
         self._part_axes = None
         self._sp_axis = None
-        # trace-time seq-parallel engagement tally (_sp_attention): did
+        # trace-time seq-parallel engagement tally (_mesh_attention): did
         # the configured mode ever actually shard an attention block?
         self._sp_engaged = 0
         self._sp_fallback = 0
@@ -805,7 +849,7 @@ class SeqScorer:
         self._swap_gate: Any = None  # partitioner publish gate (set_swap_gate)
         self._g_customers = None
         self._h_assembly = self._h_dispatch = None
-        self._c_bucket = self._c_bucket_rows = None
+        self._c_bucket = self._c_bucket_rows = self._c_attn_kernel = None
         self._g_inflight = self._c_anon = self._c_stale = None
         self._c_overlapped = None
         self._c_swap_refused = None
@@ -831,6 +875,12 @@ class SeqScorer:
             self._c_bucket = registry.counter(
                 "seq_bucket_dispatch_total",
                 "seq dispatches by (L bucket, B bucket) executable",
+            )
+            self._c_attn_kernel = registry.counter(
+                "seq_attention_kernel_dispatch_total",
+                "seq dispatches of executables whose full-attention block "
+                "holds the kernel that keeps the scores on the chip (beside "
+                "seq_bucket_dispatch_total: the rest attended through XLA)",
             )
             self._c_bucket_rows = registry.counter(
                 "seq_bucket_rows_total",
@@ -861,20 +911,35 @@ class SeqScorer:
             )
 
     # -- variant dispatch ---------------------------------------------------
-    def _sp_attention(self):
-        """The operator-selected sequence-parallel attention (ring /
-        ulysses over the sp axis), or None. Static-shape gated: the
-        readout block's single-query attention and any L bucket the axis
-        doesn't divide (ulysses additionally: a head count it doesn't
-        divide) take reference attention for that executable — decided at
-        trace time, free at runtime. Engagement is TRACKED at trace time
-        (``_sp_engaged``/``_sp_fallback``) so the executable inventory
-        reports whether the configured mode ever actually sharded an
-        attention block, and an all-fallback config warns loudly instead
-        of silently serving unsharded under a ``seq_parallel`` label."""
-        if self._sp_axis is None:
-            return None
+    def _mesh_attention(self):
+        """What a mesh executable attends with. By default one chip's
+        program (``ops/seq_attention.py::attention``) on each device's
+        rows: under ``shard_map`` over the batch axes, because a kernel is
+        not partitioned for us, so each device decides from the shapes it
+        holds. Where the operator selected a sequence-parallel attention
+        (ring / ulysses over the sp axis) that wins wherever the static
+        shapes shard: the readout block's single-query attention and any
+        L bucket the axis doesn't divide (ulysses additionally: a head
+        count it doesn't divide) take the default for that executable —
+        decided at trace time, free at runtime. Engagement is TRACKED at
+        trace time (``_sp_engaged``/``_sp_fallback``) so the executable
+        inventory reports whether the configured mode ever actually
+        sharded an attention block, and an all-fallback config warns
+        loudly instead of silently serving unsharded under a
+        ``seq_parallel`` label."""
+        from jax import shard_map
+        from jax.sharding import PartitionSpec
+
+        from ccfd_tpu.ops.seq_attention import attention
+
         mesh, axis = self.mesh, self._sp_axis
+        rows = PartitionSpec(self._part_axes, None, None, None)
+        # unchecked: rows are independent and nothing inside communicates;
+        # the kernel's interpreter (off the TPU) does not pass the check
+        local = shard_map(attention, mesh=mesh, in_specs=(rows, rows, rows),
+                          out_specs=rows, check_vma=False)
+        if axis is None:
+            return local
         n = int(mesh.shape[axis])
         if self.seq_parallel == "ring":
             from ccfd_tpu.ops.ring_attention import ring_attention as sp_fn
@@ -901,12 +966,10 @@ class SeqScorer:
                     logging.getLogger(__name__).warning(
                         "seq_parallel=%s cannot shard a (heads=%d, L=%d)"
                         " attention over the %d-way %r axis; that "
-                        "executable serves reference attention",
+                        "executable serves unsharded attention",
                         self.seq_parallel, q.shape[1], q.shape[2], n,
                         axis)
-                from ccfd_tpu.ops.ring_attention import reference_attention
-
-                return reference_attention(q, k, v)
+                return local(q, k, v)
             self._sp_engaged += 1
             return sp_fn(q, k, v, mesh, axis)
 
@@ -924,21 +987,24 @@ class SeqScorer:
         # gives them, so a customer's score doesn't jump at ladder
         # crossovers (models/seq.py logits_readout pos_length)
         plen = self.store.length
+        program = partial(_Program, reads_filled=family.reads_filled,
+                          num_features=self.store.num_features)
         if self.mesh is None:
-            return family.make_apply(dtype, plen, self._family_config)
+            return program(family.make_apply(dtype, plen,
+                                             self._family_config))
         if family.mesh_logits is None:
             raise ValueError(
                 f"history family {family.name!r} is not served over a mesh")
         from jax.sharding import NamedSharding, PartitionSpec
 
         fn = family.mesh_logits
-        attn = self._sp_attention()
-        return jax.jit(
+        attn = self._mesh_attention()
+        return program(jax.jit(
             lambda p, xs: jax.nn.sigmoid(
                 fn(p, xs, dtype, attention_fn=attn, pos_length=plen)),
             out_shardings=NamedSharding(self.mesh,
                                         PartitionSpec(self._part_axes)),
-        )
+        ))
 
     def _put_hist(self, hist: np.ndarray):
         """H2D with placement: on a mesh each device gets its row shard.
@@ -1056,11 +1122,17 @@ class SeqScorer:
 
     def executable_grid(self) -> dict:
         """The (L, B) executable grid with per-executable dispatch counts
-        — the seq family's entry in the device telemetry inventory."""
+        and whether the executable's full attention is the kernel — the
+        seq family's entry in the device telemetry inventory."""
+        with self._params_lock:
+            params, apply_fn = self.params, self._apply
         grid = []
         for lb in self.len_buckets:
             for b in self.batch_sizes:
-                entry: dict = {"l_bucket": int(lb), "b_bucket": int(b)}
+                entry: dict = {
+                    "l_bucket": int(lb), "b_bucket": int(b),
+                    "attn_kernel": _holds_attn_kernel(
+                        apply_fn, params, lb, b)}
                 if self._c_bucket is not None:
                     entry["dispatches"] = int(self._c_bucket.value(
                         {"l_bucket": str(lb), "b_bucket": str(b)}))
@@ -1368,9 +1440,12 @@ class SeqScorer:
                         ph.set(rows=m, b_bucket=bucket,
                                padded_rows=bucket - m)
                     batch.t_asm += ph.seconds
+                    attn_kernel = _holds_attn_kernel(
+                        apply_fn, params, lb, bucket)
                     with phase("seq.enqueue", bytes=sub.nbytes,
                                b_bucket=bucket, l_bucket=lb,
-                               tokens=tokens) as ph:
+                               tokens=tokens,
+                               attn_kernel=int(attn_kernel)) as ph:
                         # device-fault dispatch seam (runtime/faults.py):
                         # device_hang / compile_stall drill the heal ladder
                         # through the seq path's own dispatch loop
@@ -1386,6 +1461,8 @@ class SeqScorer:
                     if self._c_bucket is not None:
                         self._c_bucket.inc(labels={
                             "l_bucket": str(lb), "b_bucket": str(bucket)})
+                        if attn_kernel:
+                            self._c_attn_kernel.inc()
                         self._c_bucket_rows.inc(
                             m, labels={"l_bucket": str(lb)})
                     self._bound_window(batch, older)

@@ -320,8 +320,11 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
 
     # seq / seq_q8: no host forward exists; the reference is the model's
     # own full XLA graph at float32 over the SAME assembled histories
+    # 128 records: the shortest window whose full-attention block is the
+    # Pallas kernel (ops/seq_attention.py), so Mosaic compiles it and the
+    # device serves it here at both ends of the B ladder
     sp = seq_mod.init(jax.random.PRNGKey(21))
-    length = 64
+    length = 128
     for label, p, full in (
         ("seq", sp, seq_mod.apply),
         ("seq_q8", seq_quant.quantize_seq(sp), seq_quant.apply),
@@ -340,8 +343,10 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             ref = np.asarray(full(p, jnp.asarray(hist), jnp.float32))
             diffs.append(check.close(f"{label} B={b} L={length}",
                                      s.score(rows, ids), ref, 0.03))
-        zoo[label] = {"max_abs_diff": max(diffs),
-                      "grid": s.executable_grid()["grid"]}
+        grid = s.executable_grid()["grid"]
+        check(f"{label} executables hold the attention kernel",
+              all(g["attn_kernel"] for g in grid))
+        zoo[label] = {"max_abs_diff": max(diffs), "grid": grid}
 
     # hybrid_moe (KDA + MLA + sparse experts over a tokenised window): the
     # tests' small preset and seeded weights, found by name through the

@@ -158,6 +158,7 @@ def test_a_capture_around_the_scorer_holds_its_phases(tmp_path):
     for e in cap.named("seq.enqueue"):
         assert e[3]["bytes"] == 16 * 8 * 30 * 4
         assert (e[3]["b_bucket"], e[3]["l_bucket"]) == (16, 8)
+        assert e[3]["attn_kernel"] == 0  # 8 records: no length it tiles
     assert [e[3]["padded_rows"] for e in cap.named("seq.pad")] == [0, 0, 8]
     assert sum(e[3]["rows"] for e in cap.named("seq.wait")) == 40
     (commit,) = cap.named("seq.commit")
