@@ -1,13 +1,24 @@
 """Hybrid linear-attention / latent-attention / sparse-expert backbone over
 a customer's tokenised transaction window: the third history family.
 
-A decoder-only language model of three layer kinds in one stack, driven by
-the published ``config.json`` keys of the model it serves (nothing here is
-one model's numbers): **KDA** (gated delta-rule linear attention with a
+A decoder-only language model of several layer kinds in one stack, driven
+by the published ``config.json`` keys of the model it serves (nothing here
+is one model's numbers; ``model_type`` picks the mixers, the router and the
+residual rule). Mixers: **KDA** (gated delta-rule linear attention with a
 short causal convolution, served as a chunked scan), **MLA** (latent keys
-and values with a decoupled rotary part) and a **sparse expert layer**
-(sigmoid scores, expert bias, group-limited top-k, one shared expert).
-Causal throughout. The window (B, L, F) of the ``HistoryStore`` is
+and values with a decoupled rotary part) and **CCA** (grouped-query softmax
+attention inside a compressed latent: queries, keys and values projected
+down, two causal convolutions over q and k together, a q-k mean added back
+across the grouping, half the value heads read from the previous token,
+L2-normed q and k with a learned temperature, partial rotary). Routers of
+the **sparse expert layer**: sigmoid scores, expert bias, group-limited
+top-k with one shared expert; or a small MLP on a down-projection whose
+hidden state is handed from one layer's router to the next, softmax, top
+1, and a last output that means *no expert* (the token skips the layer).
+A stack whose layers are alike arrives as one tree with the layers on the
+leading axis of every leaf and is scanned (``lax.scan``: one layer is
+compiled); a mixed stack arrives as a list and is unrolled. Causal
+throughout. The window (B, L, F) of the ``HistoryStore`` is
 tokenised on the device (TabFormer-style: column j of a record is token
 j * bins + its quantile bin), so a verdict is one L * F token pass read
 out at the newest record's last token.
@@ -37,12 +48,14 @@ from here); the parameter tree is the one its ``make_params`` draws.
 
 Precision: matrices bfloat16, products accumulated in float32, the
 residual stream, norms, gates, softmax and the router in float32 (the
-router at ``highest``: a token near a tie must choose as the model does),
-the KDA state and everything inside a chunk in float32.
+router and its carried state at ``highest``: a token near a tie must
+choose as the model does), the KDA state and everything inside a chunk,
+CCA's convolution sums and L2 norms in float32.
 
 Device scopes (``jax.named_scope``, so a capture's operations carry them):
-``lm.embed``, ``kda``, ``mla``, ``dense_ffn``, ``moe.route``,
-``moe.experts``, ``moe.shared``, ``lm.head``.
+``lm.embed``, ``kda``, ``mla``, ``cca`` (inside it ``cca.conv`` and
+``cca.attend``), ``dense_ffn``, ``moe.route``, ``moe.experts``,
+``moe.shared``, ``lm.head``.
 """
 
 from __future__ import annotations
@@ -99,20 +112,53 @@ class HybridConfig:
     fraud_id: int
     legit_id: int
     shift: float
+    # what ``model_type`` ``zaya`` sets; the defaults are the first model's
+    kv_heads: int = 0  # CCA: key-value heads under ``heads`` query heads
+    rotary_dim: int = 0  # CCA: the leading dims of a head that are rotated
+    router: str = "grouped_sigmoid"  # or "carried_mlp": top 1, may skip
+    scaled_residual: bool = False  # learned scale and bias on both branches
+    tied_head: bool = False  # the head is the embedding
 
     @classmethod
     def from_dict(cls, m: Mapping[str, Any]) -> "HybridConfig":
         """From a configuration under the published key names (plus the
         cut: ``layers_kept``, ``experts_held``, ``num_experts_routed_over``,
         and the deployment's ``bins``, ``kda_chunk`` and ``readout``)."""
+        held = m["experts_held"]
+        if int(held["count"]) != int(m["num_experts"]):
+            raise ValueError("experts_held.count is not num_experts")
+        ours = dict(
+            heads=int(m["num_attention_heads"]), head_dim=int(m["head_dim"]),
+            eps=float(m["rms_norm_eps"]),
+            routed=int(m["num_experts_routed_over"]),
+            held_first=int(held["first"]), held_count=int(held["count"]),
+            per_token=int(m["num_experts_per_tok"]), bins=int(m["bins"]),
+            fraud_id=int(m["readout"]["fraud_id"]),
+            legit_id=int(m["readout"]["legit_id"]),
+            shift=float(m["readout"]["shift"]))
+        if m.get("model_type") == "zaya":
+            kinds = {m["layer_types"][i] for i in m["layers_kept"]}
+            if kinds != {"hybrid"} or ours["per_token"] != 1 \
+                    or ours["heads"] % int(m["num_key_value_heads"]):
+                raise ValueError("zaya: layers of type hybrid, one expert a "
+                                 "token, query heads a multiple of the "
+                                 "key-value heads")
+            rope = m["rope_parameters"]["hybrid"]
+            return cls(
+                nope=0, rope=0, v_dim=0, kv_rank=0, kda_lower_bound=0.0,
+                kda_chunk=0, groups=1, groups_kept=1, routed_scale=1.0,
+                rope_theta=float(rope["rope_theta"]),
+                layers=(("cca", "moe"),) * len(m["layers_kept"]),
+                kv_heads=int(m["num_key_value_heads"]),
+                rotary_dim=int(ours["head_dim"] * float(
+                    rope["partial_rotary_factor"])),
+                router="carried_mlp", scaled_residual=True,
+                tied_head=bool(m["tie_word_embeddings"]), **ours)
         period, dense = int(m["layer_group_size"]), int(
             m["first_k_dense_replace"])
         layers = tuple(("mla" if (i + 1) % period == 0 else "kda",
                         "dense" if i < dense else "moe")
                        for i in m["layers_kept"])
-        held = m["experts_held"]
-        if int(held["count"]) != int(m["num_experts"]):
-            raise ValueError("experts_held.count is not num_experts")
         if int(m["v_head_dim"]) != int(m["head_dim"]):
             raise ValueError("KDA heads are head_dim wide in keys and values")
         chunk = int(m.get("kda_chunk", 64))
@@ -120,21 +166,13 @@ class HybridConfig:
             raise ValueError("kda_chunk / kda_lower_bound outside what the "
                              "chunked scan holds in float32")
         return cls(
-            heads=int(m["num_attention_heads"]),
-            head_dim=int(m["head_dim"]), nope=int(m["qk_nope_head_dim"]),
+            nope=int(m["qk_nope_head_dim"]),
             rope=int(m["qk_rope_head_dim"]), v_dim=int(m["v_head_dim"]),
             kv_rank=int(m["kv_lora_rank"]),
             kda_lower_bound=float(m["kda_lower_bound"]), kda_chunk=chunk,
-            rope_theta=float(m["rope_theta"]), eps=float(m["rms_norm_eps"]),
-            layers=layers, routed=int(m["num_experts_routed_over"]),
-            held_first=int(held["first"]), held_count=int(held["count"]),
-            per_token=int(m["num_experts_per_tok"]), groups=int(m["n_group"]),
-            groups_kept=int(m["topk_group"]),
-            routed_scale=float(m["routed_scaling_factor"]),
-            bins=int(m["bins"]),
-            fraud_id=int(m["readout"]["fraud_id"]),
-            legit_id=int(m["readout"]["legit_id"]),
-            shift=float(m["readout"]["shift"]))
+            rope_theta=float(m["rope_theta"]), layers=layers,
+            groups=int(m["n_group"]), groups_kept=int(m["topk_group"]),
+            routed_scale=float(m["routed_scaling_factor"]), **ours)
 
     @property
     def moe_layers(self) -> int:
@@ -351,6 +389,32 @@ def _rotary(x, position, theta: float):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
+def _causal_attention(q, k, v, real, scale: float, dtype, scores: str,
+                      mix: str):
+    """Causal softmax attention over real keys, a row at a time in query
+    blocks (a block's keys end where it ends, so the scores of a row are
+    never whole in memory): ``scores`` is the einsum of a row's q and k
+    that ends in ``qk``, ``mix`` the one of the weights and v."""
+    t = q.shape[1]
+    blocks = MLA_QUERY_BLOCKS if t % MLA_QUERY_BLOCKS == 0 and t >= 512 else 1
+    step = t // blocks
+
+    def one_row(row):
+        q1, k1, v1, real1 = row  # (T, heads.., d) x 3, (T,)
+        outs = []
+        for lo in range(0, t, step):
+            hi = lo + step
+            s = jnp.einsum(scores, q1[lo:hi], k1[:hi],
+                           preferred_element_type=F32) * scale
+            w = jax.nn.softmax(jnp.where(
+                _attendable(real1[:hi], lo, hi), s, MASKED), axis=-1)
+            outs.append(jnp.einsum(mix, w.astype(dtype), v1[:hi],
+                                   preferred_element_type=F32))
+        return jnp.concatenate(outs, 0).astype(dtype)
+
+    return jax.lax.map(one_row, (q, k, v, real))
+
+
 def _attendable(real_keys, lo: int, hi: int):
     """(hi - lo, hi) bool: query lo + i may read key j: a real token at or
     before it."""
@@ -378,25 +442,86 @@ def mla(p, z, real, position, cfg: HybridConfig, dtype):
         jnp.broadcast_to(k_r[:, :, None, :], (b, t, h, rope))],
         -1).astype(dtype)
     v = up[..., nope:].astype(dtype)
-    scale = 1.0 / math.sqrt(nope + rope)
-    blocks = MLA_QUERY_BLOCKS if t % MLA_QUERY_BLOCKS == 0 and t >= 512 else 1
-    step = t // blocks
-
-    def one_row(row):
-        q1, k1, v1, real1 = row  # (T, H, d), (T,)
-        outs = []
-        for lo in range(0, t, step):  # a block's keys end where it ends
-            hi = lo + step
-            s = jnp.einsum("qhd,khd->hqk", q1[lo:hi], k1[:hi],
-                           preferred_element_type=F32) * scale
-            w = jax.nn.softmax(jnp.where(
-                _attendable(real1[:hi], lo, hi)[None], s, MASKED), axis=-1)
-            outs.append(jnp.einsum("hqk,khd->qhd", w.astype(dtype), v1[:hi],
-                                   preferred_element_type=F32))
-        return jnp.concatenate(outs, 0).astype(dtype)
-
-    o = jax.lax.map(one_row, (q, k, v, real))
+    o = _causal_attention(q, k, v, real, 1.0 / math.sqrt(nope + rope), dtype,
+                          "qhd,khd->hqk", "hqk,khd->qhd")
     return _mm(o.reshape(b, t, h * vd), p["wo"], dtype)
+
+
+# -- CCA ------------------------------------------------------------------------
+
+def _back(x, keep):
+    """x_(t-1) along axis 1 of ``x`` (B, T, ...); zeros before a row's
+    first real token: ``keep`` (B, T, 1...) is 0 on padding."""
+    x = x * keep
+    return jnp.pad(x, ((0, 0), (1, 0)) + ((0, 0),) * (x.ndim - 2))[:, :-1]
+
+
+def _causal_taps(u, keep, taps, tap):
+    """sum over lags of tap(u_(t - lag), taps[-1 - lag]): the last tap on
+    the current token."""
+    out = tap(u, taps[-1])
+    for lag in range(1, taps.shape[0]):
+        u = _back(u, keep)
+        out = out + tap(u, taps[-1 - lag])
+    return out
+
+
+def cca(p, z, real, position, cfg: HybridConfig, dtype):
+    """(B, T, hidden) normed input -> (B, T, hidden) mixer output. As in
+    ``kda`` the projections are kept as (B, T, heads, D), so that a shift
+    by one token moves whole tiles."""
+    b, t, _ = z.shape
+    h, g, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
+    per, rot = h // g, cfg.rotary_dim
+    keep = real[:, :, None, None].astype(F32)
+    zc = z.astype(dtype)
+
+    def heads(w):  # (hidden, n * D) -> (B, T, n, D), float32 accumulation
+        return jnp.einsum("bti,ihd->bthd", zc,
+                          w.astype(dtype).reshape(w.shape[0], -1, hd),
+                          preferred_element_type=F32)
+
+    with jax.named_scope("cca.conv"):
+        q_lat, k_lat, v = heads(p["wq"]), heads(p["wk"]), heads(p["wv"])
+        # the later half of the value heads read the previous token: no
+        # bias, so the shifted product is the product of the shifted input
+        now = g - g // 2
+        v = jnp.concatenate([v[:, :, :now], _back(v[:, :, now:], keep)], 2)
+        u = jnp.concatenate([q_lat, k_lat], 2)  # (B, T, H + G, D)
+        c0 = _causal_taps(
+            u, keep, p["conv0"].reshape(-1, h + g, hd),
+            lambda x, w: x * w) + p["conv0_b"].reshape(h + g, hd)
+
+        def per_head(x, w):  # heads as the leading batch axis of the product
+            x = jnp.moveaxis(x, 2, 0).reshape(h + g, b * t, hd)
+            return jnp.moveaxis(jnp.einsum(
+                "hnd,hde->hne", x, w, preferred_element_type=F32).reshape(
+                    h + g, b, t, hd), 0, 2)
+
+        c1 = _causal_taps(
+            c0.astype(dtype), keep.astype(dtype), p["conv1"].astype(dtype),
+            per_head) + p["conv1_b"].reshape(h + g, hd)
+        q_heads = q_lat.reshape(b, t, g, per, hd)
+        q = c1[:, :, :h].reshape(b, t, g, per, hd) + (
+            q_heads + k_lat[:, :, :, None]) * 0.5
+        k = c1[:, :, h:] + (q_heads.mean(3) + k_lat) * 0.5
+
+        def unit(x):
+            return x * (math.sqrt(hd) * jax.lax.rsqrt(
+                jnp.sum(x * x, -1, keepdims=True) + L2_EPS))
+
+        def turned(x):  # (B, T, n, D): the leading ``rot`` dims rotated
+            return jnp.concatenate([_rotary(
+                x[..., :rot], position, cfg.rope_theta), x[..., rot:]], -1)
+
+        q = turned(unit(q).reshape(b, t, h, hd)).reshape(
+            b, t, g, per, hd).astype(dtype)
+        k = turned(unit(k) * p["tau"][:, None]).astype(dtype)
+        v = v.astype(dtype)
+    with jax.named_scope("cca.attend"):
+        o = _causal_attention(q, k, v, real, 1.0 / math.sqrt(hd), dtype,
+                              "qgpd,kgd->gpqk", "gpqk,kgd->qgpd")
+    return _mm(o.reshape(b, t, h * hd), p["wo"], dtype)
 
 
 # -- the expert layer -------------------------------------------------------------
@@ -428,6 +553,32 @@ def route(p, z, real, cfg: HybridConfig):
     w = w / w.sum(-1, keepdims=True) * cfg.routed_scale
     return (jnp.where(real[:, None], chosen, -1).astype(jnp.int32),
             jnp.where(real[:, None], w, 0.0))
+
+
+def route_carried(p, z, r, real, cfg: HybridConfig):
+    """The router that hands its state on: ``(expert (N, 1) int32, weight
+    (N, 1) float32, r (N, R))`` for tokens ``z`` (N, hidden) and the state
+    ``r`` the layer before handed over (zeros at the first layer). An MLP
+    on a down-projection plus ``gamma * r``, softmax over the routed
+    outputs, the largest of probability plus balancing bias; the last
+    output is *skip*, an expert nobody holds. A padding token gets expert
+    -1, weight 0, and hands ``r`` on as it came. Float32 at ``highest``
+    throughout."""
+    def mm(x, w):
+        return jnp.matmul(x, w.astype(F32), precision=HIGHEST)
+
+    m = p["router"]
+    state = mm(z.astype(F32), m["down"]) + m["down_b"] + m["gamma"] * r
+    a = _rms(state, m["norm"], cfg.eps)
+    a = jax.nn.gelu(mm(a, m["w1"]) + m["b1"], approximate=False)
+    a = jax.nn.gelu(mm(a, m["w2"]) + m["b2"], approximate=False)
+    prob = jax.nn.softmax(mm(a, m["w3"]), axis=-1)
+    chosen = jnp.argmax(prob + p["bias"], axis=-1)
+    w = jnp.sum(jnp.where(chosen[:, None] == jnp.arange(cfg.routed), prob,
+                          0.0), axis=-1)
+    return (jnp.where(real, chosen, -1).astype(jnp.int32)[:, None],
+            jnp.where(real, w, 0.0)[:, None],
+            jnp.where(real[:, None], state, r))
 
 
 def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
@@ -497,28 +648,78 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
     return y, pairs, served
 
 
-def moe(p, z, real, cfg: HybridConfig, dtype):
-    """``(y, pairs (held,), served, row_pairs (B,))`` of one expert layer."""
+def moe(p, z, r, real, cfg: HybridConfig, dtype):
+    """``(y, r, counts)`` of one expert layer: the held experts' part (and
+    the shared expert's, where the layer has one), the router's state for
+    the next layer (``r`` as it came where the router carries none), and
+    ``pairs`` (held,), ``served``, ``row_pairs`` (B,), ``skipped`` (real
+    tokens whose choice was *skip*), and under the carrying router
+    ``row_choice`` (B, routed): each row's tokens by routed output."""
     b, t, d = z.shape
     flat = z.reshape(b * t, d)
+    carried = cfg.router == "carried_mlp"
     with jax.named_scope("moe.route"):
-        chosen, w = route(p, flat, real.reshape(-1), cfg)
+        if carried:
+            chosen, w, r = route_carried(p, flat, r, real.reshape(-1), cfg)
+        else:
+            chosen, w = route(p, flat, real.reshape(-1), cfg)
     with jax.named_scope("moe.experts"):
         y, pairs, served = held_experts(p["experts"], flat, chosen, w, cfg,
                                         dtype)
-    with jax.named_scope("moe.shared"):
-        y = y + _swiglu(p["shared"], flat, dtype)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + _swiglu(p["shared"], flat, dtype)
     local = chosen - cfg.held_first
-    row_pairs = jnp.sum(((local >= 0) & (local < cfg.held_count)).reshape(
-        b, -1), axis=1, dtype=jnp.int32)
-    return y.reshape(b, t, d), pairs, served, row_pairs
+    counts = {
+        "pairs": pairs, "served": served,
+        "row_pairs": jnp.sum(((local >= 0) & (local < cfg.held_count))
+                             .reshape(b, -1), axis=1, dtype=jnp.int32),
+        "skipped": jnp.sum(chosen == cfg.routed - 1, dtype=jnp.int32)
+        if carried else jnp.zeros((), jnp.int32)}
+    if carried:
+        counts["row_choice"] = jnp.sum(
+            chosen.reshape(b, t, 1) == jnp.arange(cfg.routed), axis=1,
+            dtype=jnp.int32)
+    return y.reshape(b, t, d), r, counts
 
 
 # -- the model ----------------------------------------------------------------------
 
+def _add(scaling, x, y):
+    """The residual rule: plain, or with the branch's learned scale and
+    bias on the stream and on the sublayer's output."""
+    if scaling is None:
+        return x + y
+    return scaling["s_r"] * (x + scaling["b_r"]) + scaling["s_o"] * (
+        y + scaling["b_o"])
+
+
+def _layer(p, x, r, kind, real, position, cfg: HybridConfig, dtype):
+    """One layer of kind ``(mixer, feed-forward)``: ``(x, r, counts)``,
+    ``counts`` None where the layer has no experts."""
+    mixer, ffn = kind
+    z = _rms(x, p["norm1"], cfg.eps)
+    with jax.named_scope(mixer):
+        if mixer == "kda":
+            y = kda(p["mixer"], z, real, cfg, dtype)
+        elif mixer == "mla":
+            y = mla(p["mixer"], z, real, position, cfg, dtype)
+        else:
+            y = cca(p["mixer"], z, real, position, cfg, dtype)
+    x = _add(p.get("res1"), x, y)
+    z = _rms(x, p["norm2"], cfg.eps)
+    if ffn == "dense":
+        with jax.named_scope("dense_ffn"):
+            return _add(p.get("res2"), x, _swiglu(p["ffn"], z, dtype)), r, None
+    y, r, counts = moe(p["ffn"], z, r, real, cfg, dtype)
+    return _add(p.get("res2"), x, y), r, counts
+
+
 def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
                   dtype=jnp.bfloat16):
-    """``(x (B, T, hidden) float32 before the final norm, aux)``."""
+    """``(x (B, T, hidden) float32 before the final norm, aux)``. What
+    passes from layer to layer is the stream ``x`` and the router's state
+    ``r`` (None where the router carries none)."""
     b, length, cols = hist.shape
     t = length * cols
     filled = filled.astype(jnp.int32)
@@ -529,40 +730,55 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
     with jax.named_scope("lm.embed"):
         ids = tokenise(params["edges"], hist.astype(F32), cfg.bins)
         x = params["embed"][ids].astype(F32)
-    pairs, served, row_pairs = [], jnp.zeros((), jnp.int32), jnp.zeros(
-        (b,), jnp.int32)
-    for (mixer, ffn), p in zip(cfg.layers, params["layers"]):
-        z = _rms(x, p["norm1"], cfg.eps)
-        if mixer == "kda":
-            with jax.named_scope("kda"):
-                x = x + kda(p["mixer"], z, real, cfg, dtype)
-        else:
-            with jax.named_scope("mla"):
-                x = x + mla(p["mixer"], z, real, position, cfg, dtype)
-        z = _rms(x, p["norm2"], cfg.eps)
-        if ffn == "dense":
-            with jax.named_scope("dense_ffn"):
-                x = x + _swiglu(p["ffn"], z, dtype)
-        else:
-            y, layer_pairs, layer_served, layer_rows = moe(
-                p["ffn"], z, real, cfg, dtype)
-            x = x + y
-            pairs.append(layer_pairs)
-            served = served + layer_served
-            row_pairs = row_pairs + layer_rows
-    aux = {"pairs": (jnp.stack(pairs) if pairs else jnp.zeros(
-               (0, cfg.held_count), jnp.int32)),
-           "pairs_served": served,
+    layers = params["layers"]
+    r = None
+    if cfg.router == "carried_mlp":
+        r = jnp.zeros((b * t, _router_width(layers)), F32)
+    if isinstance(layers, Mapping):  # layers alike, stacked: one is compiled
+        def step(carry, p):
+            x, r, counts = _layer(p, *carry, cfg.layers[0], real, position,
+                                  cfg, dtype)
+            return (x, r), counts
+
+        (x, r), counts = jax.lax.scan(step, (x, r), layers)
+    else:
+        each = []
+        for kind, p in zip(cfg.layers, layers):
+            x, r, one = _layer(p, x, r, kind, real, position, cfg, dtype)
+            if one is not None:
+                each.append(one)
+        counts = jax.tree.map(lambda *leaves: jnp.stack(leaves), *each) \
+            if each else None
+    if counts is None:
+        counts = {"pairs": jnp.zeros((0, cfg.held_count), jnp.int32),
+                  "served": jnp.zeros((0,), jnp.int32),
+                  "row_pairs": jnp.zeros((0, b), jnp.int32),
+                  "skipped": jnp.zeros((0,), jnp.int32)}
+    aux = {"pairs": counts["pairs"],  # (expert layers, held)
+           "pairs_served": counts["served"].sum(dtype=jnp.int32),
            "routed_tokens": jnp.sum(real, dtype=jnp.int32),
-           "row_pairs": row_pairs}
+           "skipped_tokens": counts["skipped"].sum(dtype=jnp.int32),
+           "row_pairs": counts["row_pairs"].sum(0, dtype=jnp.int32)}
+    if "row_choice" in counts:
+        aux["row_choice"] = jnp.swapaxes(counts["row_choice"], 0, 1)
     return x, aux
 
 
+def _router_width(layers) -> int:
+    one = layers if isinstance(layers, Mapping) else layers[0]
+    return one["ffn"]["router"]["gamma"].shape[-1]
+
+
 def slice_logits(params: Params, x, cfg: HybridConfig, dtype=jnp.bfloat16):
-    """Final norm and the untied head over the vocabulary slice."""
+    """Final norm and the head over the vocabulary slice: untied, or the
+    embedding itself."""
     with jax.named_scope("lm.head"):
-        return _mm(_rms(x, params["final_norm"], cfg.eps), params["head"],
-                   dtype)
+        z = _rms(x, params["final_norm"], cfg.eps)
+        if cfg.tied_head:
+            return jnp.einsum("...i,vi->...v", z.astype(dtype),
+                              params["embed"].astype(dtype),
+                              preferred_element_type=F32)
+        return _mm(z, params["head"], dtype)
 
 
 @partial(jax.jit, static_argnames=("cfg", "compute_dtype"))
@@ -582,7 +798,9 @@ def apply_serving(params: Params, hist, filled, cfg: HybridConfig,
     at the newest record's last token. ``aux``: ``logits`` (B, vocab) at
     that token, ``pairs`` (expert layers, held) pairs served per held
     expert, ``pairs_served`` (what the tile loop multiplied),
-    ``routed_tokens`` (the batch's real tokens) and ``row_pairs`` (B,)."""
+    ``routed_tokens`` (the batch's real tokens), ``skipped_tokens``
+    (token-layers whose choice was *skip*), ``row_pairs`` (B,) and, under
+    the carrying router, ``row_choice`` (B, expert layers, routed)."""
     x, aux = hidden_states(params, hist, filled, cfg, compute_dtype)
     z = slice_logits(params, x[:, -1], cfg, compute_dtype)
     aux["logits"] = z
@@ -593,11 +811,11 @@ def apply_serving(params: Params, hist, filled, cfg: HybridConfig,
 def make_observer(registry: Any):
     """``observe(aux) -> stats`` for one resolved dispatch: the family's
     counters (``moe_pairs_served_total``, ``moe_pairs_routed_total``,
-    ``moe_routed_tokens_total``, ``lm_tokens_total``, the gauge
-    ``moe_expert_pairs_max`` per expert layer, and the sum and count of the
-    busiest-over-mean expert load per dispatch and layer), and what
-    ``seq.wait``
-    carries: ``pairs_served``, ``routed_tokens``, ``max_expert_pairs``."""
+    ``moe_routed_tokens_total``, ``moe_skipped_tokens_total``,
+    ``lm_tokens_total``, the gauge ``moe_expert_pairs_max`` per expert
+    layer, and the sum and count of the busiest-over-mean expert load per
+    dispatch and layer), and what ``seq.wait`` carries: ``pairs_served``,
+    ``skipped_tokens``, ``routed_tokens``, ``max_expert_pairs``."""
     served = registry.counter(
         "moe_pairs_served_total",
         "(token, held expert) pairs the expert layers multiplied")
@@ -608,6 +826,10 @@ def make_observer(registry: Any):
     routed = registry.counter(
         "moe_routed_tokens_total", "real tokens routed (per dispatch, not "
         "per expert layer)")
+    skipped = registry.counter(
+        "moe_skipped_tokens_total",
+        "token-layers whose routing chose no expert (the skip output); with "
+        "every expert held, served + skipped = routed tokens x expert layers")
     tokens = registry.counter(
         "lm_tokens_total", "real tokens through the backbone")
     busiest = registry.gauge(
@@ -628,6 +850,7 @@ def make_observer(registry: Any):
         served.inc(int(aux["pairs_served"]))
         routed_pairs.inc(total)
         routed.inc(n_tokens)
+        skipped.inc(int(aux["skipped_tokens"]))
         tokens.inc(n_tokens)
         top = pairs.max(axis=1) if pairs.size else pairs.sum(axis=1)
         per_layer = pairs.sum(axis=1)
@@ -639,6 +862,7 @@ def make_observer(registry: Any):
                             / per_layer[live]).sum()))
             layer_dispatches.inc(int(live.sum()))
         return {"pairs_served": int(aux["pairs_served"]),
+                "skipped_tokens": int(aux["skipped_tokens"]),
                 "routed_tokens": n_tokens,
                 "max_expert_pairs": int(top.max()) if pairs.size else 0}
 
@@ -664,5 +888,6 @@ def register() -> None:
         describe=lambda cfg: {
             "experts_held": [cfg.held_first, cfg.held_first + cfg.held_count],
             "experts_routed_over": cfg.routed,
+            "router": cfg.router,
             "layers": [list(kind) for kind in cfg.layers]},
-        swappable=False))
+        config_from=HybridConfig.from_dict, swappable=False))

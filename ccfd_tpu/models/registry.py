@@ -43,7 +43,10 @@ class HistorySpec:
     parameter tree that arrives without a name is the family's.
     ``mesh_logits`` is the logits function the mesh / sequence-parallel
     path jits (None: the family is not served over a mesh).
-    ``describe(config)`` adds to ``executable_grid()``. ``swappable``:
+    ``describe(config)`` adds to ``executable_grid()``. ``config_from``
+    builds the family's settings from a configuration under the served
+    model's published key names (a deployment that knows the family by
+    name only). ``swappable``:
     whether ``swap_params`` may stage a second tree beside the served
     one."""
 
@@ -54,6 +57,7 @@ class HistorySpec:
     make_observer: Callable[[Any], Callable[[dict], dict]] | None = None
     mesh_logits: Callable[..., jax.Array] | None = None
     describe: Callable[[Any], dict] | None = None
+    config_from: Callable[[Any], Any] | None = None
     swappable: bool = True
 
 
@@ -140,7 +144,7 @@ from ccfd_tpu.ops import seq_quant as _seq_quant  # noqa: E402
 
 _seq_quant.register()
 
-# hybrid_moe: the KDA + MLA + sparse-expert backbone over a tokenised
+# hybrid_moe: the KDA / MLA / CCA + sparse-expert backbones over a tokenised
 # window (models/hybrid_moe.py), a third history family behind SeqScorer
 from ccfd_tpu.models import hybrid_moe as _hybrid_moe  # noqa: E402
 

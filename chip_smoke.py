@@ -375,6 +375,24 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
         "max_abs_diff": check.close("hybrid_moe B=16 L=8", s.score(
             rows, list(range(10_000, 10_016))), direct, 1e-6),
         "grid": s.executable_grid()}
+    # the family's second model (CCA, the carried router with its skip,
+    # scaled residuals, tied head; layers stacked and scanned): the same seam
+    from benchmark.reference import cca_moe_f32
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "zaya1_small_config.json")) as f:
+        small = json.load(f)
+    z_cfg = hybrid_moe.HybridConfig.from_dict(small)
+    zp = cca_moe_f32.make_params(small)
+    s = SeqScorer(zp, length=8, batch_sizes=(16,), family="hybrid_moe",
+                  family_config=z_cfg, max_customers=64)
+    s.warmup()
+    direct = np.asarray(hybrid_moe.apply_serving(
+        zp, hist, np.ones(16, np.int32), z_cfg, jnp.bfloat16)[0])
+    zoo["hybrid_moe.zaya"] = {
+        "max_abs_diff": check.close("hybrid_moe (zaya) B=16 L=8", s.score(
+            rows, list(range(10_000, 10_016))), direct, 1e-6),
+        "grid": s.executable_grid()}
 
     # the fused-decision grid over the flagship: score + threshold + rules
     # in one executable per bucket, against the staged seam

@@ -112,9 +112,11 @@ def test_each_layer_kind_agrees_with_the_reference(small, params, kind,
             want = np.take_along_axis(np.asarray(want_w), want_order, 1)
             if kind == "moe":
                 want, want_pairs = ref.moe(p, x, real, small)
-                got, pairs, served, row_pairs = hm.moe(p, x, real, cfg, F32)
-                assert int(served) == int(pairs.sum()) == want_pairs
-                assert int(row_pairs.sum()) == want_pairs
+                got, _, counts = hm.moe(p, x, None, real, cfg, F32)
+                assert int(counts["served"]) == int(
+                    counts["pairs"].sum()) == want_pairs
+                assert int(counts["row_pairs"].sum()) == want_pairs
+                assert int(counts["skipped"]) == 0
     keep = np.asarray(real)[..., None] if np.ndim(got) == 3 else True
     assert np.allclose(np.asarray(got) * keep, np.asarray(want) * keep,
                        atol=2e-4, rtol=2e-4)
@@ -157,9 +159,9 @@ def test_the_four_shares_add_up_to_the_uncut_layer(small, params):
             mine = dict(p, experts={k: v[4 * share:4 * share + 4]
                                     for k, v in p["experts"].items()})
             cfg = hm.HybridConfig.from_dict(dict(small, experts_held=held))
-            got, share_pairs, served, _ = hm.moe(mine, x, real, cfg, F32)
+            got, _, counts = hm.moe(mine, x, None, real, cfg, F32)
             total = total + (got - shared)
-            pairs += int(served)
+            pairs += int(counts["served"])
     assert pairs == all_pairs == int(real.sum()) * small[
         "num_experts_per_tok"]
     keep = np.asarray(real)[..., None]
