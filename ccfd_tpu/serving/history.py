@@ -78,7 +78,8 @@ dispatches resolved and its commit landed (the runtime reads the host
 buffer on its own thread after the call returned; a batch the shadow tap
 or the canary gate keeps is its own and never recycled); every L bucket
 is static so XLA sees fixed (bucket, L, F) shapes; the model runs bf16
-with f32 accumulation.
+with f32 accumulation. Where its rows are whole device tiles a batch
+crosses as the flat view of the same bytes (``_Program``).
 """
 from __future__ import annotations
 
@@ -103,6 +104,11 @@ DEFAULT_STRIPES = 8
 # CCFD_SEQ_LEN_BUCKETS), the same opt-in posture as the CoDel deadline
 DEFAULT_LEN_BUCKETS: tuple = ()
 DEFAULT_INFLIGHT = 2
+# a float32 array lives on the chip in tiles of 8 sublanes x 128 lanes over
+# its last two dimensions: a host array whose minor dimension is 128 and
+# whose rows are a whole number of tiles is in that order already
+_WIRE_LANES = 128
+_WIRE_TILE = 8 * _WIRE_LANES
 
 
 # bytes of slab mapped at a time: a block is one lazily zeroed mapping
@@ -625,22 +631,53 @@ class DeferredScores:
 
 class _Program:
     """A family's device program as ``SeqScorer._make_apply`` built it,
-    and what its own trace says of each (L bucket, B bucket) executable:
-    whether the full-attention block holds the kernel that keeps the
-    scores on the chip (``ops/seq_attention.py``). Read the first time it
-    is asked (a look-up in the jit's trace cache once the executable has
-    run), from the memo afterwards; a swap to another variant builds
-    another program, so the memo never outlives what it describes."""
+    the form in which a dispatch's history batch crosses to the device,
+    and what the program's own trace says of each (L bucket, B bucket)
+    executable.
 
-    __slots__ = ("fn", "reads_filled", "num_features", "_held")
+    **The wire.** ``fn(params, hist, ...)`` takes (B, L, F) float32. Sent
+    in that shape the runtime transposes every batch on a host thread
+    before the copy: F = 30 is no multiple of the chip's 128 lanes, so the
+    device keeps such a parameter features-outermost (PERF.md section 5:
+    6.5 ms of a 1,024-row batch's 14.9). A window of ``L * F`` values that
+    is a whole number of (8, 128) tiles is sent as the
+    (B, L * F / 128, 128) view of the same memory instead, whose tiles
+    are 4 kB of consecutive host bytes each, and ``flat``, ONE jitted
+    program around ``fn``, restores (B, L, F) on the device as its first
+    operation: the same values in the same order, so the same
+    probabilities bit for bit. Decided per executable from the window's
+    shape (``flat_wire``); any other shape, a batch that is on the device
+    already, and every batch of a scorer with a mesh (``flat`` is None:
+    ``_put_hist`` places the rows itself) go as (B, L, F).
 
-    def __init__(self, fn: Any, reads_filled: bool, num_features: int):
+    **The trace.** Whether the full-attention block holds the kernel that
+    keeps the scores on the chip (``ops/seq_attention.py``), asked at the
+    shape that is dispatched. Read the first time it is asked (a look-up
+    in the jit's trace cache once the executable has run), from the memo
+    afterwards; a swap to another variant builds another program, so the
+    memo never outlives what it describes."""
+
+    __slots__ = ("fn", "flat", "reads_filled", "num_features", "_held")
+
+    def __init__(self, fn: Any, reads_filled: bool, num_features: int,
+                 flat: bool = False):
         self.fn = fn
+        self.flat = _behind_flat_wire(fn, num_features) if flat else None
         self.reads_filled = reads_filled
         self.num_features = num_features
         self._held: dict = {}
 
+    def flat_wire(self, lb: int) -> bool:
+        """Whether a host batch of ``lb``-record windows crosses flat."""
+        return (self.flat is not None
+                and lb * self.num_features % _WIRE_TILE == 0)
+
     def __call__(self, params: Any, hist: Any, *extra: Any):
+        if isinstance(hist, np.ndarray) and self.flat_wire(hist.shape[1]):
+            # a view where the batch is contiguous (a staging batch's rows,
+            # a ladder window's own copy): no byte moves on the host
+            return self.flat(params, hist.reshape(
+                len(hist), -1, _WIRE_LANES), *extra)
         return self.fn(params, hist, *extra)
 
     def holds_attn_kernel(self, params: Any, lb: int, b: int) -> bool:
@@ -652,10 +689,25 @@ class _Program:
 
             shape = jax.ShapeDtypeStruct
             extra = (shape((b,), np.int32),) if self.reads_filled else ()
+            fn, hist = ((self.flat, (b, lb * self.num_features
+                                     // _WIRE_LANES, _WIRE_LANES))
+                        if self.flat_wire(lb)
+                        else (self.fn, (b, lb, self.num_features)))
             got = self._held[(lb, b)] = held_by(
-                self.fn, params,
-                shape((b, lb, self.num_features), np.float32), *extra)
+                fn, params, shape(hist, np.float32), *extra)
         return got
+
+
+def _behind_flat_wire(fn: Any, num_features: int):
+    """``fn`` as one jitted program that takes its history batch in the
+    flat wire form and restores (B, L, F) first (``_Program``). The
+    reshape is inside the program: a dispatch stays one executable."""
+    import jax
+
+    def flat_wire(params, wire, *extra):
+        return fn(params, wire.reshape(len(wire), -1, num_features), *extra)
+
+    return jax.jit(flat_wire)
 
 
 def _holds_attn_kernel(apply_fn: Any, params: Any, lb: int, b: int) -> bool:
@@ -663,6 +715,13 @@ def _holds_attn_kernel(apply_fn: Any, params: Any, lb: int, b: int) -> bool:
     or a drill's gate around it) has no trace to read and holds none."""
     holds = getattr(apply_fn, "holds_attn_kernel", None)
     return holds is not None and holds(params, lb, b)
+
+
+def _takes_flat_wire(apply_fn: Any, lb: int) -> bool:
+    """``_Program.flat_wire``; a stand-in for the program says nothing of
+    the wire behind it."""
+    flat_wire = getattr(apply_fn, "flat_wire", None)
+    return flat_wire is not None and flat_wire(lb)
 
 
 class SeqScorer:
@@ -673,7 +732,11 @@ class SeqScorer:
     every dispatch resolved (see module docstring). For a caller that
     takes a deferred result the window of ``inflight`` open dispatches
     spans consecutive calls: batch k+1 is gathered, enqueued and on its
-    way to the device while the device computes batch k."""
+    way to the device while the device computes batch k. What crosses to
+    the device a dispatch is the padded (bucket, L, F) float32 batch, as
+    it is or, where ``L * F`` fills whole (8, 128) tiles and there is no
+    mesh, as the flat view of the same memory (``_Program``), and where
+    the family masks its padding the rows' ``filled`` depths."""
 
     def __init__(
         self,
@@ -850,6 +913,7 @@ class SeqScorer:
         self._g_customers = None
         self._h_assembly = self._h_dispatch = None
         self._c_bucket = self._c_bucket_rows = self._c_attn_kernel = None
+        self._c_flat_wire = None
         self._g_inflight = self._c_anon = self._c_stale = None
         self._c_overlapped = None
         self._c_swap_refused = None
@@ -881,6 +945,13 @@ class SeqScorer:
                 "seq dispatches of executables whose full-attention block "
                 "holds the kernel that keeps the scores on the chip (beside "
                 "seq_bucket_dispatch_total: the rest attended through XLA)",
+            )
+            self._c_flat_wire = registry.counter(
+                "seq_flat_wire_dispatch_total",
+                "seq dispatches whose history batch crossed to the device "
+                "in the device's own tile order, (B, L * F / 128, 128) "
+                "(beside seq_bucket_dispatch_total: the rest crossed as "
+                "(B, L, F) and were transposed by the runtime on the host)",
             )
             self._c_bucket_rows = registry.counter(
                 "seq_bucket_rows_total",
@@ -978,7 +1049,8 @@ class SeqScorer:
     def _make_apply(self, family: Any):
         """The family's device program, from its registered spec:
         ``fn(params, hist)`` or, where the family reads the padding,
-        ``fn(params, hist, filled)``."""
+        ``fn(params, hist, filled)``; on one device also the same program
+        behind the flat wire (``_Program``)."""
         import jax
 
         dtype = self._dtype
@@ -991,7 +1063,7 @@ class SeqScorer:
                           num_features=self.store.num_features)
         if self.mesh is None:
             return program(family.make_apply(dtype, plen,
-                                             self._family_config))
+                                             self._family_config), flat=True)
         if family.mesh_logits is None:
             raise ValueError(
                 f"history family {family.name!r} is not served over a mesh")
@@ -1121,9 +1193,10 @@ class SeqScorer:
             self._run_grid(self._apply, self.params, self._family)
 
     def executable_grid(self) -> dict:
-        """The (L, B) executable grid with per-executable dispatch counts
-        and whether the executable's full attention is the kernel — the
-        seq family's entry in the device telemetry inventory."""
+        """The (L, B) executable grid with per-executable dispatch counts,
+        whether the executable's full attention is the kernel and whether
+        its history batch crosses flat — the seq family's entry in the
+        device telemetry inventory."""
         with self._params_lock:
             params, apply_fn = self.params, self._apply
         grid = []
@@ -1132,7 +1205,8 @@ class SeqScorer:
                 entry: dict = {
                     "l_bucket": int(lb), "b_bucket": int(b),
                     "attn_kernel": _holds_attn_kernel(
-                        apply_fn, params, lb, b)}
+                        apply_fn, params, lb, b),
+                    "flat_wire": _takes_flat_wire(apply_fn, lb)}
                 if self._c_bucket is not None:
                     entry["dispatches"] = int(self._c_bucket.value(
                         {"l_bucket": str(lb), "b_bucket": str(b)}))
@@ -1442,10 +1516,12 @@ class SeqScorer:
                     batch.t_asm += ph.seconds
                     attn_kernel = _holds_attn_kernel(
                         apply_fn, params, lb, bucket)
+                    flat_wire = _takes_flat_wire(apply_fn, lb)
                     with phase("seq.enqueue", bytes=sub.nbytes,
                                b_bucket=bucket, l_bucket=lb,
                                tokens=tokens,
-                               attn_kernel=int(attn_kernel)) as ph:
+                               attn_kernel=int(attn_kernel),
+                               flat_wire=int(flat_wire)) as ph:
                         # device-fault dispatch seam (runtime/faults.py):
                         # device_hang / compile_stall drill the heal ladder
                         # through the seq path's own dispatch loop
@@ -1463,6 +1539,8 @@ class SeqScorer:
                             "l_bucket": str(lb), "b_bucket": str(bucket)})
                         if attn_kernel:
                             self._c_attn_kernel.inc()
+                        if flat_wire:
+                            self._c_flat_wire.inc()
                         self._c_bucket_rows.inc(
                             m, labels={"l_bucket": str(lb)})
                     self._bound_window(batch, older)

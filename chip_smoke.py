@@ -346,6 +346,13 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
         grid = s.executable_grid()["grid"]
         check(f"{label} executables hold the attention kernel",
               all(g["attn_kernel"] for g in grid))
+        # which wire this proof crossed (serving/history.py::_Program): 128
+        # records are 3.75 tiles a row, so these cross as (B, L, F)
+        for g in grid:
+            print(f"  {label} B={g['b_bucket']} L={g['l_bucket']}: history "
+                  "batch crosses as " + ("(B, L*F/128, 128), flat"
+                                         if g["flat_wire"] else "(B, L, F)"),
+                  flush=True)
         zoo[label] = {"max_abs_diff": max(diffs), "grid": grid}
 
     # hybrid_moe (KDA + MLA + sparse experts over a tokenised window): the

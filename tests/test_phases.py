@@ -159,10 +159,27 @@ def test_a_capture_around_the_scorer_holds_its_phases(tmp_path):
         assert e[3]["bytes"] == 16 * 8 * 30 * 4
         assert (e[3]["b_bucket"], e[3]["l_bucket"]) == (16, 8)
         assert e[3]["attn_kernel"] == 0  # 8 records: no length it tiles
+        assert e[3]["flat_wire"] == 0  # 240 values a row: no whole tile
     assert [e[3]["padded_rows"] for e in cap.named("seq.pad")] == [0, 0, 8]
     assert sum(e[3]["rows"] for e in cap.named("seq.wait")) == 40
     (commit,) = cap.named("seq.commit")
     assert (commit[3]["customers"], commit[3]["stale"]) == (10, 0)
+
+
+def test_the_enqueue_phase_says_which_wire_the_batch_crossed(tmp_path):
+    """512 records of 30 values are 15 whole (8, 128) tiles a row: the
+    batch crosses flat, the phase carries it, and ``bytes`` is still the
+    batch's own size."""
+    scorer = SeqScorer(seq_mod.init(jax.random.PRNGKey(0)), length=512,
+                       batch_sizes=(4,), registry=Registry())
+    scorer.warmup()
+    with Capture(tmp_path) as cap:
+        scorer.score(rows(3), ids=["a", "b", "c"])
+    (enqueue,) = cap.named("seq.enqueue")
+    assert enqueue[3]["flat_wire"] == 1
+    assert enqueue[3]["attn_kernel"] == 1
+    assert enqueue[3]["bytes"] == 4 * 512 * 30 * 4
+    assert (enqueue[3]["b_bucket"], enqueue[3]["l_bucket"]) == (4, 512)
 
 
 def test_a_deferred_batch_waits_and_commits_inside_the_next_call(tmp_path):
