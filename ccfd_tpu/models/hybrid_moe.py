@@ -2,22 +2,30 @@
 a customer's tokenised transaction window: the third history family.
 
 A decoder-only language model of several layer kinds in one stack, driven
-by the published ``config.json`` keys of the model it serves (nothing here
-is one model's numbers; ``model_type`` picks the mixers, the router and the
-residual rule). Mixers: **KDA** (gated delta-rule linear attention with a
-short causal convolution, served as a chunked scan), **MLA** (latent keys
-and values with a decoupled rotary part) and **CCA** (grouped-query softmax
+by the published ``config.json`` keys of the model it serves. Nothing here
+is one model's numbers: a model is a tuple of ``(mixer, feed-forward)``
+kinds, each mixer and each router an entry of a table (``MIXERS``,
+``ROUTERS``: name -> function) with settings of its own, read from the
+published keys by one small reader a ``model_type`` (``READERS``); what no
+layer of a model uses is not in that model's settings. Mixers: **KDA**
+(gated delta-rule linear attention with a short causal convolution, served
+as a chunked scan), **MLA** (latent keys and values with a decoupled rotary
+part; queries at full rank or through a normed low-rank latent; the four
+parts RMS-normed or not; rotary pairs by halves or interleaved, plain or
+YaRN frequencies with its softmax scale) and **CCA** (grouped-query softmax
 attention inside a compressed latent: queries, keys and values projected
 down, two causal convolutions over q and k together, a q-k mean added back
 across the grouping, half the value heads read from the previous token,
 L2-normed q and k with a learned temperature, partial rotary). Routers of
-the **sparse expert layer**: sigmoid scores, expert bias, group-limited
-top-k with one shared expert; or a small MLP on a down-projection whose
-hidden state is handed from one layer's router to the next, softmax, top
-1, and a last output that means *no expert* (the token skips the layer).
-A stack whose layers are alike arrives as one tree with the layers on the
-leading axis of every leaf and is scanned (``lax.scan``: one layer is
-compiled); a mixed stack arrives as a list and is unrolled. Causal
+the **sparse expert layer**: ``top_k`` (sigmoid or softmax scores over all
+routed experts, an optional expert bias for the choice, group-limited or
+not, weights renormalised over the chosen) with or without a shared
+expert; or ``carried_mlp``, a small MLP on a down-projection whose hidden
+state is handed from one layer's router to the next, softmax, top 1, and a
+last output that means *no expert* (the token skips the layer).
+A stack whose layers are alike may arrive as one tree with the layers on
+the leading axis of every leaf and is then scanned (``lax.scan``: one layer
+is compiled); a stack that arrives as a list is unrolled. Causal
 throughout. The window (B, L, F) of the ``HistoryStore`` is
 tokenised on the device (TabFormer-style: column j of a record is token
 j * bins + its quantile bin), so a verdict is one L * F token pass read
@@ -27,8 +35,9 @@ out at the newest record's last token.
 (``HybridConfig.held_first`` / ``held_count``), routes over all of the
 published experts and computes its own experts' part of the result: what
 expert parallelism asks of the program. A token's pairs with absent
-experts are left out and the partial sum goes on; on one chip the layer
-runs without its exchange and nothing stands in for the absent chips.
+experts are left out (and counted: ``pairs_absent``) and the partial sum
+goes on; on one chip the layer runs without its exchange and nothing
+stands in for the absent chips.
 **Dropless**: the (token, held expert) pairs are sorted by expert and each
 expert's group is cut into tiles of ``MOE_TILE`` rows; a loop whose trip
 count is the number of tiles the batch really has multiplies each tile with
@@ -43,8 +52,9 @@ state passes it unchanged; it routes to no expert. A row's verdict is
 therefore the same at every window length that holds its history.
 
 The equations, with the key each symbol is read from, are in the plain
-reference ``benchmark/reference/hybrid_moe_f32.py`` (which imports nothing
-from here); the parameter tree is the one its ``make_params`` draws.
+references ``benchmark/reference/hybrid_moe_f32.py``, ``cca_moe_f32.py``
+and ``mla_moe_f32.py`` (which import nothing from here); the parameter
+tree is the one their ``make_params`` draw.
 
 Precision: matrices bfloat16, products accumulated in float32, the
 residual stream, norms, gates, softmax and the router in float32 (the
@@ -53,9 +63,10 @@ choose as the model does), the KDA state and everything inside a chunk,
 CCA's convolution sums and L2 norms in float32.
 
 Device scopes (``jax.named_scope``, so a capture's operations carry them):
-``lm.embed``, ``kda``, ``mla``, ``cca`` (inside it ``cca.conv`` and
-``cca.attend``), ``dense_ffn``, ``moe.route``, ``moe.experts``,
-``moe.shared``, ``lm.head``.
+``lm.embed``, ``kda``, ``mla`` (inside it ``mla.project``: every
+projection, the norms and the rotary, and ``mla.attend``), ``cca`` (inside
+it ``cca.conv`` and ``cca.attend``), ``dense_ffn``, ``moe.route``,
+``moe.experts``, ``moe.shared``, ``lm.head``.
 """
 
 from __future__ import annotations
@@ -86,36 +97,151 @@ KDA_SUB = 16  # kda_lower_bound * KDA_SUB must stay inside float32's exponent
 MLA_QUERY_BLOCKS = 4
 
 
-@dataclasses.dataclass(frozen=True)
-class HybridConfig:
-    """The model's settings, hashable so that a jit takes them as static."""
+# -- settings: one small class a kind, read from the published keys ------------
 
+@dataclasses.dataclass(frozen=True)
+class Kda:
     heads: int
     head_dim: int
+    lower_bound: float
+    chunk: int
+
+    @classmethod
+    def read(cls, m: Mapping[str, Any]) -> "Kda":
+        if int(m["v_head_dim"]) != int(m["head_dim"]):
+            raise ValueError("KDA heads are head_dim wide in keys and values")
+        chunk = int(m.get("kda_chunk", 64))
+        if chunk % KDA_SUB or abs(float(m["kda_lower_bound"])) * KDA_SUB > 85:
+            raise ValueError("kda_chunk / kda_lower_bound outside what the "
+                             "chunked scan holds in float32")
+        return cls(int(m["num_attention_heads"]), int(m["head_dim"]),
+                   float(m["kda_lower_bound"]), chunk)
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """``rope_parameters`` of type ``yarn``: the frequencies' stretch, the
+    softmax scale it brings, and the llama-4 query scale past
+    ``original`` positions."""
+
+    factor: float
+    original: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+    query_beta: float
+
+    @staticmethod
+    def m(factor: float, scale: float) -> float:
+        return 0.1 * scale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class Mla:
+    heads: int
     nope: int
     rope: int
     v_dim: int
     kv_rank: int
-    kda_lower_bound: float
-    kda_chunk: int
-    rope_theta: float
+    q_rank: int | None  # None: q = x W_q at full rank
+    part_norms: bool  # q_n, q_r, k_n, k_r each RMS-normed over its width
+    interleaved: bool  # rotary pairs (2i, 2i + 1); else the two halves
+    theta: float
+    yarn: Yarn | None = None
+
+    @property
+    def scale(self) -> float:
+        """What the scores are multiplied by before the softmax."""
+        plain = 1.0 / math.sqrt(self.nope + self.rope)
+        if self.yarn is None or not self.yarn.mscale_all_dim:
+            return plain
+        return plain * Yarn.m(self.yarn.factor, self.yarn.mscale_all_dim) ** 2
+
+    @property
+    def turn_scale(self) -> float:
+        """What cos and sin are multiplied by."""
+        y = self.yarn
+        if y is None:
+            return 1.0
+        if y.mscale and y.mscale_all_dim:
+            return Yarn.m(y.factor, y.mscale) / Yarn.m(y.factor,
+                                                        y.mscale_all_dim)
+        return Yarn.m(y.factor, 1.0)
+
+    @classmethod
+    def read(cls, m: Mapping[str, Any]) -> "Mla":
+        rope = m.get("rope_parameters") or {}
+        yarn = None
+        if rope.get("rope_type", rope.get("type")) == "yarn":
+            yarn = Yarn(float(rope["factor"]),
+                        int(rope["original_max_position_embeddings"]),
+                        float(rope.get("beta_fast", 32)),
+                        float(rope.get("beta_slow", 1)),
+                        float(rope.get("mscale", 0)),
+                        float(rope.get("mscale_all_dim", 0)),
+                        float(rope.get("llama_4_scaling_beta", 0)))
+        rank = m.get("q_lora_rank")
+        return cls(int(m["num_attention_heads"]), int(m["qk_nope_head_dim"]),
+                   int(m["qk_rope_head_dim"]), int(m["v_head_dim"]),
+                   int(m["kv_lora_rank"]), None if rank is None else int(rank),
+                   bool(m.get("use_qk_norm", False)),
+                   bool(m.get("rope_interleave", False)),
+                   float(rope["rope_theta"] if "rope_theta" in rope
+                         else m["rope_theta"]), yarn)
+
+
+@dataclasses.dataclass(frozen=True)
+class Cca:
+    heads: int
+    kv_heads: int  # key-value heads under ``heads`` query heads
+    head_dim: int
+    rotary_dim: int  # the leading dims of a head that are rotated
+    theta: float
+
+    @classmethod
+    def read(cls, m: Mapping[str, Any]) -> "Cca":
+        rope = m["rope_parameters"]["hybrid"]
+        heads, hd = int(m["num_attention_heads"]), int(m["head_dim"])
+        if heads % int(m["num_key_value_heads"]):
+            raise ValueError("CCA: query heads a multiple of the key-value "
+                             "heads")
+        return cls(heads, int(m["num_key_value_heads"]), hd,
+                   int(hd * float(rope["partial_rotary_factor"])),
+                   float(rope["rope_theta"]))
+
+
+@dataclasses.dataclass(frozen=True)
+class TopK:
+    """The router that scores every routed expert and keeps the largest
+    ``per_token`` (of ``HybridConfig``), inside the best groups where the
+    model limits them."""
+
+    score: str  # "sigmoid" | "softmax"
+    bias: bool  # an expert bias added for the choice, not the weight
+    groups: int
+    groups_kept: int
+    scale: float
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    """The model's settings, hashable so that a jit takes them as static:
+    the stack, each of its kinds' own settings, and what all models share."""
+
     eps: float
     layers: tuple[tuple[str, str], ...]  # (mixer, feed-forward) per layer
-    routed: int  # experts the router scores: the published count
+    mixers: tuple[tuple[str, Any], ...]  # (name in MIXERS, its settings)
+    router: str  # name in ROUTERS
+    routing: Any  # that router's settings (None: it has none of its own)
+    routed: int  # outputs the router scores: the published count
     held_first: int
     held_count: int
     per_token: int
-    groups: int
-    groups_kept: int
-    routed_scale: float
     bins: int
     fraud_id: int
     legit_id: int
     shift: float
-    # what ``model_type`` ``zaya`` sets; the defaults are the first model's
-    kv_heads: int = 0  # CCA: key-value heads under ``heads`` query heads
-    rotary_dim: int = 0  # CCA: the leading dims of a head that are rotated
-    router: str = "grouped_sigmoid"  # or "carried_mlp": top 1, may skip
     scaled_residual: bool = False  # learned scale and bias on both branches
     tied_head: bool = False  # the head is the embedding
 
@@ -124,59 +250,80 @@ class HybridConfig:
         """From a configuration under the published key names (plus the
         cut: ``layers_kept``, ``experts_held``, ``num_experts_routed_over``,
         and the deployment's ``bins``, ``kda_chunk`` and ``readout``)."""
+        kind = m.get("model_type", "ling")
+        if kind not in READERS:
+            raise ValueError(f"hybrid_moe reads model_type {sorted(READERS)}"
+                             f", not {kind!r}")
         held = m["experts_held"]
-        if int(held["count"]) != int(m["num_experts"]):
-            raise ValueError("experts_held.count is not num_experts")
-        ours = dict(
-            heads=int(m["num_attention_heads"]), head_dim=int(m["head_dim"]),
+        return cls(
             eps=float(m["rms_norm_eps"]),
             routed=int(m["num_experts_routed_over"]),
             held_first=int(held["first"]), held_count=int(held["count"]),
             per_token=int(m["num_experts_per_tok"]), bins=int(m["bins"]),
             fraud_id=int(m["readout"]["fraud_id"]),
             legit_id=int(m["readout"]["legit_id"]),
-            shift=float(m["readout"]["shift"]))
-        if m.get("model_type") == "zaya":
-            kinds = {m["layer_types"][i] for i in m["layers_kept"]}
-            if kinds != {"hybrid"} or ours["per_token"] != 1 \
-                    or ours["heads"] % int(m["num_key_value_heads"]):
-                raise ValueError("zaya: layers of type hybrid, one expert a "
-                                 "token, query heads a multiple of the "
-                                 "key-value heads")
-            rope = m["rope_parameters"]["hybrid"]
-            return cls(
-                nope=0, rope=0, v_dim=0, kv_rank=0, kda_lower_bound=0.0,
-                kda_chunk=0, groups=1, groups_kept=1, routed_scale=1.0,
-                rope_theta=float(rope["rope_theta"]),
-                layers=(("cca", "moe"),) * len(m["layers_kept"]),
-                kv_heads=int(m["num_key_value_heads"]),
-                rotary_dim=int(ours["head_dim"] * float(
-                    rope["partial_rotary_factor"])),
-                router="carried_mlp", scaled_residual=True,
-                tied_head=bool(m["tie_word_embeddings"]), **ours)
-        period, dense = int(m["layer_group_size"]), int(
-            m["first_k_dense_replace"])
-        layers = tuple(("mla" if (i + 1) % period == 0 else "kda",
-                        "dense" if i < dense else "moe")
-                       for i in m["layers_kept"])
-        if int(m["v_head_dim"]) != int(m["head_dim"]):
-            raise ValueError("KDA heads are head_dim wide in keys and values")
-        chunk = int(m.get("kda_chunk", 64))
-        if chunk % KDA_SUB or abs(float(m["kda_lower_bound"])) * KDA_SUB > 85:
-            raise ValueError("kda_chunk / kda_lower_bound outside what the "
-                             "chunked scan holds in float32")
-        return cls(
-            nope=int(m["qk_nope_head_dim"]),
-            rope=int(m["qk_rope_head_dim"]), v_dim=int(m["v_head_dim"]),
-            kv_rank=int(m["kv_lora_rank"]),
-            kda_lower_bound=float(m["kda_lower_bound"]), kda_chunk=chunk,
-            rope_theta=float(m["rope_theta"]), layers=layers,
-            groups=int(m["n_group"]), groups_kept=int(m["topk_group"]),
-            routed_scale=float(m["routed_scaling_factor"]), **ours)
+            shift=float(m["readout"]["shift"]), **READERS[kind](m))
+
+    def mixer(self, name: str) -> Any:
+        """The settings of the mixer kind ``name``."""
+        return dict(self.mixers)[name]
 
     @property
     def moe_layers(self) -> int:
         return sum(1 for _, ffn in self.layers if ffn == "moe")
+
+
+def _held_all_of(m: Mapping[str, Any], key: str) -> None:
+    if int(m["experts_held"]["count"]) != int(m[key]):
+        raise ValueError(f"experts_held.count is not {key}")
+
+
+def _read_ling(m: Mapping[str, Any]) -> dict:
+    """Ling-3.0: KDA with every ``layer_group_size``-th layer MLA, leading
+    dense layers, sigmoid scores with a bias inside the best groups."""
+    _held_all_of(m, "num_experts")
+    period, dense = int(m["layer_group_size"]), int(
+        m["first_k_dense_replace"])
+    layers = tuple(("mla" if (i + 1) % period == 0 else "kda",
+                    "dense" if i < dense else "moe")
+                   for i in m["layers_kept"])
+    return dict(
+        layers=layers, mixers=(("kda", Kda.read(m)), ("mla", Mla.read(m))),
+        router="top_k", routing=TopK(
+            "sigmoid", True, int(m["n_group"]), int(m["topk_group"]),
+            float(m["routed_scaling_factor"])))
+
+
+def _read_zaya(m: Mapping[str, Any]) -> dict:
+    """ZAYA1: CCA and the carried router in every layer, top 1, scaled
+    residuals."""
+    _held_all_of(m, "num_experts")
+    kinds = {m["layer_types"][i] for i in m["layers_kept"]}
+    if kinds != {"hybrid"} or int(m["num_experts_per_tok"]) != 1:
+        raise ValueError("zaya: layers of type hybrid, one expert a token")
+    return dict(
+        layers=(("cca", "moe"),) * len(m["layers_kept"]),
+        mixers=(("cca", Cca.read(m)),), router="carried_mlp", routing=None,
+        scaled_residual=True, tied_head=bool(m["tie_word_embeddings"]))
+
+
+def _read_mistral4(m: Mapping[str, Any]) -> dict:
+    """Mistral-Small-4: MLA with low-rank queries and YaRN in every layer,
+    softmax scores over all routed experts, no group limit, no bias."""
+    _held_all_of(m, "n_routed_experts")
+    if int(m["first_k_dense_replace"]) or int(m["n_group"]) != 1 \
+            or int(m["n_shared_experts"]) != 1 or not m["norm_topk_prob"]:
+        raise ValueError("mistral4: no leading dense layer, one group, one "
+                         "shared expert, weights renormalised")
+    return dict(
+        layers=(("mla", "moe"),) * len(m["layers_kept"]),
+        mixers=(("mla", Mla.read(m)),), router="top_k", routing=TopK(
+            "softmax", False, 1, 1, float(m["routed_scaling_factor"])),
+        tied_head=bool(m["tie_word_embeddings"]))
+
+
+READERS = {"ling": _read_ling, "zaya": _read_zaya,
+           "mistral4": _read_mistral4}
 
 
 def owns(params: Any) -> bool:
@@ -324,7 +471,8 @@ def kda(p, z, real, cfg: HybridConfig, dtype):
     the output projection stays in that layout: a chunk is then a slice
     of a major axis, and so is the convolution's shift."""
     b, t, _ = z.shape
-    h, dk = cfg.heads, cfg.head_dim
+    s = cfg.mixer("kda")
+    h, dk = s.heads, s.head_dim
     keep = real[:, :, None, None].astype(F32)
     zc = z.astype(dtype)
 
@@ -343,12 +491,12 @@ def kda(p, z, real, cfg: HybridConfig, dtype):
     q = (unit(branch(p["wq"], p["conv_q"])) * dk ** -0.5).astype(dtype)
     k = unit(branch(p["wk"], p["conv_k"])).astype(dtype)
     v = branch(p["wv"], p["conv_v"]).astype(dtype)
-    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+    g = s.lower_bound * jax.nn.sigmoid(
         jnp.exp(p["a_log"])[:, None]
         * (heads(p["wg"], F32) + p["dt_bias"].reshape(h, dk))) * keep
     beta = jax.nn.sigmoid(_mm(z, p["wb"], dtype)) * keep[..., 0]
 
-    c = cfg.kda_chunk
+    c = s.chunk
     lead = -t % c  # padding tokens on the left pass the state unchanged
     n = (t + lead) // c
 
@@ -378,14 +526,44 @@ def kda(p, z, real, cfg: HybridConfig, dtype):
 
 # -- MLA ------------------------------------------------------------------------
 
-def _rotary(x, position, theta: float):
-    half = x.shape[-1] // 2
+def _frequencies(theta: float, width: int, yarn: Yarn | None = None):
+    """(width / 2,) rotary frequencies theta^(-2i / width); under YaRN the
+    slow ones divided by ``factor``, the fast ones kept, a linear ramp
+    between the dims that turn ``beta_fast`` and ``beta_slow`` times inside
+    the ``original`` positions."""
+    half = width // 2
     freq = theta ** (-jnp.arange(half, dtype=F32) / half)
+    if yarn is None:
+        return freq
+
+    def dim_of(turns: float) -> float:
+        return width * math.log(yarn.original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = min(max(math.floor(dim_of(yarn.beta_fast)), 0), half - 1)
+    high = min(max(math.ceil(dim_of(yarn.beta_slow)), 0), half - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=F32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return ramp * freq / yarn.factor + (1.0 - ramp) * freq
+
+
+def _rotary(x, position, freq, interleaved: bool = False,
+            scale: float = 1.0):
+    """``x`` (B, T, [heads,] width) turned by its ``position`` (B, T). The
+    pairs are the two halves of the width, or neighbours (2i, 2i + 1); the
+    result is laid out by halves either way (q and k alike, so their
+    product is the same)."""
+    half = x.shape[-1] // 2
     angle = position.astype(F32)[..., None] * freq
     if x.ndim == 4:
         angle = angle[:, :, None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    a, b = x[..., :half], x[..., half:]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    if interleaved:
+        a, b = x[..., 0::2], x[..., 1::2]
+    else:
+        a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
@@ -423,28 +601,50 @@ def _attendable(real_keys, lo: int, hi: int):
 
 
 def mla(p, z, real, position, cfg: HybridConfig, dtype):
+    """(B, T, hidden) normed input -> (B, T, hidden) mixer output; the
+    query path, the norms, the rotary and the softmax scale are the
+    settings' (``Mla``)."""
     b, t, _ = z.shape
-    h, nope, rope, vd = cfg.heads, cfg.nope, cfg.rope, cfg.v_dim
-    q = _mm(z, p["wq"], dtype).reshape(b, t, h, nope + rope)
-    down = _mm(z, p["wdkv"], dtype)
-    c = _rms(down[..., :cfg.kv_rank], p["c_norm"], cfg.eps)
-    up = _mm(c, p["wukv"], dtype).reshape(b, t, h, nope + vd)
-    # the rotary part rides beside the rest as extra width of q and k:
-    # one product gives q_n k_n^T + q_r k_r^T
-    q = jnp.concatenate([
-        _rms(q[..., :nope], p["qn_norm"], cfg.eps),
-        _rotary(_rms(q[..., nope:], p["qr_norm"], cfg.eps), position,
-                cfg.rope_theta)], -1).astype(dtype)
-    k_r = _rotary(_rms(down[..., cfg.kv_rank:], p["kr_norm"], cfg.eps),
-                  position, cfg.rope_theta)
-    k = jnp.concatenate([
-        _rms(up[..., :nope], p["kn_norm"], cfg.eps),
-        jnp.broadcast_to(k_r[:, :, None, :], (b, t, h, rope))],
-        -1).astype(dtype)
-    v = up[..., nope:].astype(dtype)
-    o = _causal_attention(q, k, v, real, 1.0 / math.sqrt(nope + rope), dtype,
-                          "qhd,khd->hqk", "hqk,khd->qhd")
-    return _mm(o.reshape(b, t, h * vd), p["wo"], dtype)
+    s = cfg.mixer("mla")
+    h, nope, rope, vd = s.heads, s.nope, s.rope, s.v_dim
+    with jax.named_scope("mla.project"):
+        if s.q_rank is None:
+            q = _mm(z, p["wq"], dtype)
+        else:
+            q = _mm(_rms(_mm(z, p["wdq"], dtype), p["q_norm"], cfg.eps),
+                    p["wuq"], dtype)
+        q = q.reshape(b, t, h, nope + rope)
+        down = _mm(z, p["wdkv"], dtype)
+        c = _rms(down[..., :s.kv_rank], p["c_norm"], cfg.eps)
+        up = _mm(c, p["wukv"], dtype).reshape(b, t, h, nope + vd)
+        parts = {"qn": q[..., :nope], "qr": q[..., nope:],
+                 "kn": up[..., :nope], "kr": down[..., s.kv_rank:]}
+        if s.part_norms:
+            parts = {name: _rms(x, p[name + "_norm"], cfg.eps)
+                     for name, x in parts.items()}
+        freq = _frequencies(s.theta, rope, s.yarn)
+
+        def turned(x):
+            return _rotary(x, position, freq, s.interleaved, s.turn_scale)
+
+        # the rotary part rides beside the rest as extra width of q and k:
+        # one product gives q_n k_n^T + q_r k_r^T
+        q = jnp.concatenate([parts["qn"], turned(parts["qr"])], -1)
+        if s.yarn is not None and s.yarn.query_beta and t > s.yarn.original:
+            # 1 at every position below ``original``: a window that short
+            # is traced without it
+            q = q * (1.0 + s.yarn.query_beta * jnp.log1p(jnp.floor(
+                position.astype(F32) / s.yarn.original)))[:, :, None, None]
+        k = jnp.concatenate([
+            parts["kn"], jnp.broadcast_to(
+                turned(parts["kr"])[:, :, None, :], (b, t, h, rope))], -1)
+        q, k = q.astype(dtype), k.astype(dtype)
+        v = up[..., nope:].astype(dtype)
+    with jax.named_scope("mla.attend"):
+        o = _causal_attention(q, k, v, real, s.scale, dtype,
+                              "qhd,khd->hqk", "hqk,khd->qhd")
+    with jax.named_scope("mla.project"):
+        return _mm(o.reshape(b, t, h * vd), p["wo"], dtype)
 
 
 # -- CCA ------------------------------------------------------------------------
@@ -471,8 +671,10 @@ def cca(p, z, real, position, cfg: HybridConfig, dtype):
     ``kda`` the projections are kept as (B, T, heads, D), so that a shift
     by one token moves whole tiles."""
     b, t, _ = z.shape
-    h, g, hd = cfg.heads, cfg.kv_heads, cfg.head_dim
-    per, rot = h // g, cfg.rotary_dim
+    s = cfg.mixer("cca")
+    h, g, hd = s.heads, s.kv_heads, s.head_dim
+    per, rot = h // g, s.rotary_dim
+    freq = _frequencies(s.theta, rot)
     keep = real[:, :, None, None].astype(F32)
     zc = z.astype(dtype)
 
@@ -512,7 +714,7 @@ def cca(p, z, real, position, cfg: HybridConfig, dtype):
 
         def turned(x):  # (B, T, n, D): the leading ``rot`` dims rotated
             return jnp.concatenate([_rotary(
-                x[..., :rot], position, cfg.rope_theta), x[..., rot:]], -1)
+                x[..., :rot], position, freq), x[..., rot:]], -1)
 
         q = turned(unit(q).reshape(b, t, h, hd)).reshape(
             b, t, g, per, hd).astype(dtype)
@@ -528,31 +730,42 @@ def cca(p, z, real, position, cfg: HybridConfig, dtype):
 
 def route(p, z, real, cfg: HybridConfig):
     """``(experts (N, k) int32, weights (N, k) float32)`` over all routed
-    experts for tokens ``z`` (N, hidden); a padding token gets expert -1
-    and weight 0."""
-    per = cfg.routed // cfg.groups
-    s = jax.nn.sigmoid(jnp.matmul(z.astype(F32), p["router"].astype(F32),
-                                  precision=HIGHEST))
-    choice = s + p["bias"]
-    by_group = choice.reshape(-1, cfg.groups, per)
+    experts for tokens ``z`` (N, hidden), by the ``TopK`` settings; a
+    padding token gets expert -1 and weight 0."""
+    rule = cfg.routing
+    logits = jnp.matmul(z.astype(F32), p["router"].astype(F32),
+                        precision=HIGHEST)
+    s = jax.nn.sigmoid(logits) if rule.score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    choice = s + p["bias"] if rule.bias else s
+    if rule.groups > 1:
+        choice = _inside_the_best_groups(choice, rule, cfg.routed)
+    _, chosen = jax.lax.top_k(choice, cfg.per_token)
+    # s at the chosen, as a masked sum: a gather of (N, k) from (N, routed)
+    # costs more on this device than the compare over routed
+    w = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(cfg.routed),
+                          s[:, None, :], 0.0), axis=-1)
+    w = w / w.sum(-1, keepdims=True) * rule.scale
+    return (jnp.where(real[:, None], chosen, -1).astype(jnp.int32),
+            jnp.where(real[:, None], w, 0.0))
+
+
+def _inside_the_best_groups(choice, rule: TopK, routed: int):
+    """``choice`` (N, routed) with -inf outside the ``groups_kept`` groups
+    whose two largest add up to most."""
+    per = routed // rule.groups
+    by_group = choice.reshape(-1, rule.groups, per)
     best = by_group.max(-1, keepdims=True)
     at_best = jnp.argmax(by_group, -1)[..., None] == jnp.arange(per)
     score = best[..., 0] + jnp.where(at_best, -jnp.inf, by_group).max(-1)
     # a group is kept if fewer than ``groups_kept`` groups beat it (an
     # equal score beats it from a lower index, as top_k orders ties)
+    at = jnp.arange(rule.groups)
     ahead = (score[:, None, :] > score[:, :, None]) | (
         (score[:, None, :] == score[:, :, None])
-        & (jnp.arange(cfg.groups)[None, :] < jnp.arange(cfg.groups)[:, None]))
-    open_ = ahead.sum(-1) < cfg.groups_kept
-    masked = jnp.where(jnp.repeat(open_, per, axis=1), choice, -jnp.inf)
-    _, chosen = jax.lax.top_k(masked, cfg.per_token)
-    # s at the chosen, as a masked sum: a gather of (N, k) from (N, routed)
-    # costs more on this device than the compare over routed
-    w = jnp.sum(jnp.where(chosen[..., None] == jnp.arange(cfg.routed),
-                          s[:, None, :], 0.0), axis=-1)
-    w = w / w.sum(-1, keepdims=True) * cfg.routed_scale
-    return (jnp.where(real[:, None], chosen, -1).astype(jnp.int32),
-            jnp.where(real[:, None], w, 0.0))
+        & (at[None, :] < at[:, None]))
+    open_ = ahead.sum(-1) < rule.groups_kept
+    return jnp.where(jnp.repeat(open_, per, axis=1), choice, -jnp.inf)
 
 
 def route_carried(p, z, r, real, cfg: HybridConfig):
@@ -652,17 +865,14 @@ def moe(p, z, r, real, cfg: HybridConfig, dtype):
     """``(y, r, counts)`` of one expert layer: the held experts' part (and
     the shared expert's, where the layer has one), the router's state for
     the next layer (``r`` as it came where the router carries none), and
-    ``pairs`` (held,), ``served``, ``row_pairs`` (B,), ``skipped`` (real
-    tokens whose choice was *skip*), and under the carrying router
-    ``row_choice`` (B, routed): each row's tokens by routed output."""
+    ``pairs`` (held,), ``served``, ``absent`` (chosen pairs whose expert
+    another chip holds, the skip among them), ``row_pairs`` (B,),
+    ``skipped`` (real tokens whose choice was *skip*) and ``row_choice``
+    (B, routed): each row's chosen pairs by routed output."""
     b, t, d = z.shape
     flat = z.reshape(b * t, d)
-    carried = cfg.router == "carried_mlp"
     with jax.named_scope("moe.route"):
-        if carried:
-            chosen, w, r = route_carried(p, flat, r, real.reshape(-1), cfg)
-        else:
-            chosen, w = route(p, flat, real.reshape(-1), cfg)
+        chosen, w, r = ROUTERS[cfg.router](p, flat, r, real.reshape(-1), cfg)
     with jax.named_scope("moe.experts"):
         y, pairs, served = held_experts(p["experts"], flat, chosen, w, cfg,
                                         dtype)
@@ -670,17 +880,29 @@ def moe(p, z, r, real, cfg: HybridConfig, dtype):
         with jax.named_scope("moe.shared"):
             y = y + _swiglu(p["shared"], flat, dtype)
     local = chosen - cfg.held_first
+    mine = (local >= 0) & (local < cfg.held_count)
     counts = {
         "pairs": pairs, "served": served,
-        "row_pairs": jnp.sum(((local >= 0) & (local < cfg.held_count))
-                             .reshape(b, -1), axis=1, dtype=jnp.int32),
+        "absent": jnp.sum((chosen >= 0) & ~mine, dtype=jnp.int32),
+        "row_pairs": jnp.sum(mine.reshape(b, -1), axis=1, dtype=jnp.int32),
         "skipped": jnp.sum(chosen == cfg.routed - 1, dtype=jnp.int32)
-        if carried else jnp.zeros((), jnp.int32)}
-    if carried:
-        counts["row_choice"] = jnp.sum(
-            chosen.reshape(b, t, 1) == jnp.arange(cfg.routed), axis=1,
-            dtype=jnp.int32)
+        if cfg.router == "carried_mlp" else jnp.zeros((), jnp.int32),
+        "row_choice": jnp.sum(
+            chosen.reshape(b, -1, 1) == jnp.arange(cfg.routed), axis=1,
+            dtype=jnp.int32)}
     return y.reshape(b, t, d), r, counts
+
+
+ROUTERS = {  # name -> f(p, z, r, real, cfg): (chosen, weights, r)
+    "top_k": lambda p, z, r, real, cfg: (*route(p, z, real, cfg), r),
+    "carried_mlp": route_carried,
+}
+MIXERS = {  # name -> f(p, z, real, position, cfg, dtype)
+    "kda": lambda p, z, real, position, cfg, dtype: kda(p, z, real, cfg,
+                                                        dtype),
+    "mla": mla,
+    "cca": cca,
+}
 
 
 # -- the model ----------------------------------------------------------------------
@@ -700,12 +922,7 @@ def _layer(p, x, r, kind, real, position, cfg: HybridConfig, dtype):
     mixer, ffn = kind
     z = _rms(x, p["norm1"], cfg.eps)
     with jax.named_scope(mixer):
-        if mixer == "kda":
-            y = kda(p["mixer"], z, real, cfg, dtype)
-        elif mixer == "mla":
-            y = mla(p["mixer"], z, real, position, cfg, dtype)
-        else:
-            y = cca(p["mixer"], z, real, position, cfg, dtype)
+        y = MIXERS[mixer](p["mixer"], z, real, position, cfg, dtype)
     x = _add(p.get("res1"), x, y)
     z = _rms(x, p["norm2"], cfg.eps)
     if ffn == "dense":
@@ -750,17 +967,18 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
         counts = jax.tree.map(lambda *leaves: jnp.stack(leaves), *each) \
             if each else None
     if counts is None:
+        none = jnp.zeros((0,), jnp.int32)
         counts = {"pairs": jnp.zeros((0, cfg.held_count), jnp.int32),
-                  "served": jnp.zeros((0,), jnp.int32),
+                  "served": none, "absent": none, "skipped": none,
                   "row_pairs": jnp.zeros((0, b), jnp.int32),
-                  "skipped": jnp.zeros((0,), jnp.int32)}
+                  "row_choice": jnp.zeros((0, b, cfg.routed), jnp.int32)}
     aux = {"pairs": counts["pairs"],  # (expert layers, held)
            "pairs_served": counts["served"].sum(dtype=jnp.int32),
+           "pairs_absent": counts["absent"].sum(dtype=jnp.int32),
            "routed_tokens": jnp.sum(real, dtype=jnp.int32),
            "skipped_tokens": counts["skipped"].sum(dtype=jnp.int32),
-           "row_pairs": counts["row_pairs"].sum(0, dtype=jnp.int32)}
-    if "row_choice" in counts:
-        aux["row_choice"] = jnp.swapaxes(counts["row_choice"], 0, 1)
+           "row_pairs": counts["row_pairs"].sum(0, dtype=jnp.int32),
+           "row_choice": jnp.swapaxes(counts["row_choice"], 0, 1)}
     return x, aux
 
 
@@ -798,9 +1016,10 @@ def apply_serving(params: Params, hist, filled, cfg: HybridConfig,
     at the newest record's last token. ``aux``: ``logits`` (B, vocab) at
     that token, ``pairs`` (expert layers, held) pairs served per held
     expert, ``pairs_served`` (what the tile loop multiplied),
+    ``pairs_absent`` (chosen pairs whose expert is held elsewhere),
     ``routed_tokens`` (the batch's real tokens), ``skipped_tokens``
-    (token-layers whose choice was *skip*), ``row_pairs`` (B,) and, under
-    the carrying router, ``row_choice`` (B, expert layers, routed)."""
+    (token-layers whose choice was *skip*), ``row_pairs`` (B,) and
+    ``row_choice`` (B, expert layers, routed)."""
     x, aux = hidden_states(params, hist, filled, cfg, compute_dtype)
     z = slice_logits(params, x[:, -1], cfg, compute_dtype)
     aux["logits"] = z
@@ -811,10 +1030,11 @@ def apply_serving(params: Params, hist, filled, cfg: HybridConfig,
 def make_observer(registry: Any):
     """``observe(aux) -> stats`` for one resolved dispatch: the family's
     counters (``moe_pairs_served_total``, ``moe_pairs_routed_total``,
-    ``moe_routed_tokens_total``, ``moe_skipped_tokens_total``,
-    ``lm_tokens_total``, the gauge ``moe_expert_pairs_max`` per expert
-    layer, and the sum and count of the busiest-over-mean expert load per
-    dispatch and layer), and what ``seq.wait`` carries: ``pairs_served``,
+    ``moe_pairs_absent_total``, ``moe_routed_tokens_total``,
+    ``moe_skipped_tokens_total``, ``lm_tokens_total``, the gauge
+    ``moe_expert_pairs_max`` per expert layer, and the sum and count of the
+    busiest-over-mean expert load per dispatch and layer), and what
+    ``seq.wait`` carries: ``pairs_served``, ``pairs_absent``,
     ``skipped_tokens``, ``routed_tokens``, ``max_expert_pairs``."""
     served = registry.counter(
         "moe_pairs_served_total",
@@ -823,6 +1043,11 @@ def make_observer(registry: Any):
         "moe_pairs_routed_total",
         "(token, held expert) pairs the routing chose; equals the served "
         "count because no pair is dropped")
+    absent = registry.counter(
+        "moe_pairs_absent_total",
+        "chosen (token, expert) pairs whose expert another chip holds (the "
+        "skip output among them); served + absent = experts per token x "
+        "routed tokens x expert layers")
     routed = registry.counter(
         "moe_routed_tokens_total", "real tokens routed (per dispatch, not "
         "per expert layer)")
@@ -849,6 +1074,7 @@ def make_observer(registry: Any):
         total = int(pairs.sum())
         served.inc(int(aux["pairs_served"]))
         routed_pairs.inc(total)
+        absent.inc(int(aux["pairs_absent"]))
         routed.inc(n_tokens)
         skipped.inc(int(aux["skipped_tokens"]))
         tokens.inc(n_tokens)
@@ -862,6 +1088,7 @@ def make_observer(registry: Any):
                             / per_layer[live]).sum()))
             layer_dispatches.inc(int(live.sum()))
         return {"pairs_served": int(aux["pairs_served"]),
+                "pairs_absent": int(aux["pairs_absent"]),
                 "skipped_tokens": int(aux["skipped_tokens"]),
                 "routed_tokens": n_tokens,
                 "max_expert_pairs": int(top.max()) if pairs.size else 0}
@@ -889,5 +1116,8 @@ def register() -> None:
             "experts_held": [cfg.held_first, cfg.held_first + cfg.held_count],
             "experts_routed_over": cfg.routed,
             "router": cfg.router,
-            "layers": [list(kind) for kind in cfg.layers]},
+            "layers": [list(kind) for kind in cfg.layers],
+            "kinds": {name: dataclasses.asdict(settings) for name, settings
+                      in (*cfg.mixers, (cfg.router, cfg.routing))
+                      if settings is not None}},
         config_from=HybridConfig.from_dict, swappable=False))

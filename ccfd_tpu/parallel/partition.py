@@ -206,8 +206,13 @@ def hybrid_moe_rules(expert_axis: str = "ep") -> list[tuple[str, P]]:
     chip runs attention on its own micro-batches). What one chip of the
     group holds is one shard of this layout; serving over the whole mesh
     also needs the exchange of routed tokens, which the program does not
-    have yet."""
+    have yet. A stack of identical layers may arrive as one tree with the
+    layers on every leaf's leading axis (``layers/ffn/...``, scanned) or
+    as a list (``layers/<i>/ffn/...``): the experts' axis is the second
+    or the first."""
     return [
+        (r"^layers/ffn/experts/(gate|up|down)",
+         P(None, expert_axis, None, None)),
         (r"ffn/experts/(gate|up|down)", P(expert_axis, None, None)),
         (r"^embed$", P(expert_axis, None)),
         (r"^head$", P(None, expert_axis)),

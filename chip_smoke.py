@@ -401,6 +401,30 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             rows, list(range(10_000, 10_016))), direct, 1e-6),
         "grid": s.executable_grid()}
 
+    # the family's third model (MLA in every layer with low-rank queries,
+    # interleaved YaRN rotary, softmax top 4 over 32 experts of which 8 are
+    # held, a shared expert, untied head): the same seam, and every chosen
+    # pair served here or counted as another chip's
+    from benchmark.reference import mla_moe_f32
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "mistral4_small_config.json")) as f:
+        small = json.load(f)
+    m_cfg = hybrid_moe.HybridConfig.from_dict(small)
+    mp = mla_moe_f32.make_params(small)
+    s = SeqScorer(mp, length=8, batch_sizes=(16,), family="hybrid_moe",
+                  family_config=m_cfg, max_customers=64)
+    s.warmup()
+    direct, aux = hybrid_moe.apply_serving(
+        mp, hist, np.ones(16, np.int32), m_cfg, jnp.bfloat16)
+    check("hybrid_moe (mistral4) served + absent pairs = 4 a token and "
+          "layer", int(aux["pairs_served"]) + int(aux["pairs_absent"])
+          == 4 * int(aux["routed_tokens"]) * m_cfg.moe_layers)
+    zoo["hybrid_moe.mistral4"] = {
+        "max_abs_diff": check.close("hybrid_moe (mistral4) B=16 L=8", s.score(
+            rows, list(range(10_000, 10_016))), np.asarray(direct), 1e-6),
+        "grid": s.executable_grid()}
+
     # the fused-decision grid over the flagship: score + threshold + rules
     # in one executable per bucket, against the staged seam
     thr = Config().fraud_threshold

@@ -343,12 +343,12 @@ def test_a_listed_stack_gives_what_the_scanned_one_gives(params, cfg, rows):
 
 
 def test_the_settings_come_from_the_published_keys(small, cfg):
-    assert (cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.rotary_dim) == (
-        8, 2, 16, 8)
+    assert cfg.mixers == (("cca", hm.Cca(
+        heads=8, kv_heads=2, head_dim=16, rotary_dim=8, theta=5e6)),)
     assert cfg.layers == (("cca", "moe"),) * 3 and cfg.moe_layers == 3
     assert (cfg.routed, cfg.held_count, cfg.per_token) == (17, 16, 1)
-    assert cfg.router == "carried_mlp" and cfg.scaled_residual
-    assert cfg.tied_head and cfg.rope_theta == 5e6 and cfg.eps == 1e-5
+    assert cfg.router == "carried_mlp" and cfg.routing is None
+    assert cfg.scaled_residual and cfg.tied_head and cfg.eps == 1e-5
     assert registry.get_history("hybrid_moe").config_from(small) == cfg
     with pytest.raises(ValueError, match="zaya"):
         hm.HybridConfig.from_dict(dict(small, num_experts_per_tok=2))
