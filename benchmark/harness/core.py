@@ -56,6 +56,11 @@ class Outcome:
     stream: dict | None = None
 
 
+def check_line(name: str, value: Any, op: str, limit: Any, ok: bool) -> str:
+    return (f"CHECK {name}: {value!r} {op} {limit!r}"
+            f" -> {'ok' if ok else 'FAIL'}")
+
+
 class Checks:
     """Every number compared, printed beside its limit as it is compared."""
 
@@ -75,12 +80,20 @@ class Checks:
 
     def _add(self, name, value, op, limit, ok) -> None:
         self.rows.append((name, value, op, limit, ok))
-        print(f"CHECK {name}: {value!r} {op} {limit!r}"
-              f" -> {'ok' if ok else 'FAIL'}", flush=True)
+        print(check_line(name, value, op, limit, ok), flush=True)
 
     @property
     def ok(self) -> bool:
         return all(r[4] for r in self.rows)
+
+    def compared(self) -> dict:
+        """``{name: [value, op, limit, ok]}`` of every number compared,
+        for the result line."""
+        def plain(v):
+            return v.item() if isinstance(v, np.generic) else v
+
+        return {name: [plain(value), op, plain(limit), ok]
+                for name, value, op, limit, ok in self.rows}
 
 
 def percentile(sample: np.ndarray, q: float) -> float:
@@ -204,8 +217,9 @@ def check_outputs(checks: Checks, config: dict, outcome: Outcome, *,
     """The served probabilities against the configuration's plain
     reference on the same seeded rows, each number beside the limit the
     configuration's file gives it. The reference module says which served
-    verdicts it holds against what (``served_and_expected``) and by which
-    numbers (``compare``)."""
+    verdicts it holds against what (``served_and_expected``), by which
+    numbers (``compare``) and, where it has them, which misplaced answers
+    those numbers are shown to catch (``miss_controls``)."""
     ref_doc = config["reference"]
     ref = manifest_mod.load_kind("reference", ref_doc["module"])
     t = time.perf_counter()
@@ -214,8 +228,22 @@ def check_outputs(checks: Checks, config: dict, outcome: Outcome, *,
     numbers = ref.compare(served, expect)
     checks.at_least("rows_compared", len(served),
                     int(ref_doc["min_rows_compared"]))
-    for name, limit in ref_doc["limits"].items():
+    limits = ref_doc["limits"]
+    for name, limit in limits.items():
         checks.at_most(name, numbers[name], float(limit))
+    # what decides nothing: the numbers ``compare`` gives that this
+    # configuration sets no limit on, and every number again with the
+    # answers misplaced (``miss_controls``, where the reference has them),
+    # which puts the room above each limit on record beside the room below
+    for name, value in numbers.items():
+        if name not in limits:
+            print(f"INFO compared {name}: {value!r}", flush=True)
+    miss_controls = getattr(ref, "miss_controls", None)
+    if miss_controls is not None:
+        for label, (s, e) in miss_controls(served, expect).items():
+            print(f"INFO miss_control {label}: " + ", ".join(
+                f"{k} {v!r}" for k, v in ref.compare(s, e).items()),
+                flush=True)
     print(f"INFO reference {ref_doc['module']}: {note}, "
           f"{time.perf_counter() - t:.2f}s", flush=True)
 
@@ -352,6 +380,7 @@ def run_cell(cell: manifest_mod.Cell, *, seed: int, seconds: float,
         result["breakdown"] = obs["trace"].breakdown()
         metrics = cell.per_layer
     result["metrics"] = read_metrics(cell, metrics, obs)
+    result["compared"] = checks.compared()  # last in the line
     shutil.rmtree(workdir, ignore_errors=True)
     return result
 

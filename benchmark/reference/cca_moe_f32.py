@@ -83,8 +83,10 @@ import numpy as np
 from benchmark.reference import hybrid_moe_f32 as shared
 from benchmark.reference import table
 from benchmark.reference.hybrid_moe_f32 import (  # noqa: F401 - the
-    # deployment finds these on the module the configuration names
-    aux_path, histories, preload_rows, sampled, verdict_logit)
+    # deployment and the harness find these on the module the
+    # configuration names
+    aux_path, histories, miss_controls, preload_rows, sampled,
+    verdict_logit)
 from benchmark.reference.mlp_f32 import sigmoid
 
 F32 = jnp.float32
@@ -429,24 +431,36 @@ class Served:
 
 
 def compare(served: Served, expect: dict) -> dict:
-    """``mean_abs_dlogit`` and ``max_abs_dp`` of the verdict (the served
-    probability against the reference's), ``max_abs_dlogit_slice`` over
-    every logit at the verdict position, and ``choice_rel_diff``: how far
-    the served rows' routing is from the reference's routing of the same
-    rows, as the least share of token-layers that chose otherwise (half the
-    summed absolute difference of the counts by row, layer and routed
-    output, the skip among them, over the token-layers). Not exact: the two
-    hidden states differ by the served precision, so a token near a tie
-    chooses otherwise (PERF.md has the readings)."""
+    """Against the reference: ``mean_abs_dlogit`` and ``max_abs_dp`` of the
+    verdict (the served probability against the reference's),
+    ``max_abs_dlogit_slice`` over every logit at the verdict position,
+    ``max_row_rms_dlogit_slice`` and ``mean_row_rms_dlogit_slice`` (a
+    row's root mean square difference over those logits: the widest row,
+    which keeps one wrong row in and one outlying logit out, and the mean
+    over rows), and ``choice_rel_diff``: how far the served rows' routing
+    is from the reference's routing of the same rows, as the least share
+    of token-layers that chose otherwise (half the summed absolute
+    difference of the counts by row, layer and routed output, the skip
+    among them, over the token-layers). Not exact: the two hidden states
+    differ by the served precision, so a token near a tie chooses otherwise
+    (PERF.md has the readings). Against the run itself ``max_abs_dp_own``:
+    the served probability, which the router acted on, against the
+    sigmoid of the verdict logit of the logits kept for the same row,
+    which came by the tap; rounding, unless the verdict is another
+    row's."""
     model = served.model
     z_ref = np.asarray(verdict_logit(expect["logits"], model), np.float64)
     z = np.asarray(verdict_logit(served.logits, model), np.float64)
     p = np.asarray(served.proba, np.float64)
+    d = np.asarray(served.logits, np.float64) - expect["logits"]
+    row_rms = np.sqrt(np.mean(d * d, axis=-1))
     return {
         "mean_abs_dlogit": float(np.mean(np.abs(z - z_ref))),
         "max_abs_dp": float(np.max(np.abs(p - sigmoid(z_ref)))),
-        "max_abs_dlogit_slice": float(np.max(np.abs(
-            np.asarray(served.logits, np.float64) - expect["logits"]))),
+        "max_abs_dp_own": float(np.max(np.abs(p - sigmoid(z)))),
+        "max_abs_dlogit_slice": float(np.max(np.abs(d))),
+        "max_row_rms_dlogit_slice": float(np.max(row_rms)),
+        "mean_row_rms_dlogit_slice": float(np.mean(row_rms)),
         "choice_rel_diff": float(
             np.abs(served.choice - expect["choice"]).sum()
             / max(1, 2 * int(expect["choice"].sum()))),

@@ -593,6 +593,21 @@ class Served:
         return len(self.proba)
 
 
+def miss_controls(served, expect: dict) -> dict:
+    """The comparison's own controls, ``{label: (served, expect)}``, for
+    ``check_outputs`` to run ``compare`` on and print beside the run's
+    numbers (they decide nothing): ``rolled`` holds served row i against
+    the expectation for row i + 1, what a misordered answer is, and
+    ``proba_rolled`` hands row i the verdict served for row i + 1 over
+    its own logits, an altered answer. Whatever the expectation holds as
+    one number for all rows stays as it is."""
+    rolled = {k: np.roll(v, -1, axis=0) if isinstance(v, np.ndarray) else v
+              for k, v in expect.items()}
+    return {"rolled": (served, rolled),
+            "proba_rolled": (dataclasses.replace(
+                served, proba=np.roll(served.proba, -1)), expect)}
+
+
 def compare(served: Served, expect: dict) -> dict:
     """``mean_abs_dlogit`` and ``max_abs_dp`` of the verdict (the served
     probability against the reference's), ``max_abs_dlogit_slice`` over

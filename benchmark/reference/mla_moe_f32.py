@@ -77,8 +77,10 @@ from benchmark.reference.cca_moe_f32 import (  # noqa: F401 - ``Served``
     # configuration names
     Served, _stacked_normal_bf16, compare)
 from benchmark.reference.hybrid_moe_f32 import (  # noqa: F401 - the
-    # deployment finds these on the module the configuration names
-    aux_path, histories, preload_rows, sampled, verdict_logit)
+    # deployment and the harness find these on the module the
+    # configuration names
+    aux_path, histories, miss_controls, preload_rows, sampled,
+    verdict_logit)
 
 F32 = jnp.float32
 MASKED = -1e30

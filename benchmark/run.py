@@ -12,11 +12,12 @@ traffic generators that are processes import no JAX.
 The last line of standard output is the result, one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
 metrics, or with ``--trace 1`` its per-layer metrics), ``device`` and with
-``--trace 1`` ``breakdown``. Without a TPU, with fewer chips than the cell
-asks for, or without the program beside it, it exits non-zero and prints
-no result. ``--control 1`` (never passed by the driver) serves the
-configuration's lower-precision control, which has to come out not
-correct.
+``--trace 1`` ``breakdown``, and last ``compared``: every number compared
+as ``[value, op, limit, ok]``, which the last lines of standard error
+repeat. Without a TPU, with fewer chips than the cell asks for, or without
+the program beside it, it exits non-zero and prints no result.
+``--control 1`` (never passed by the driver) serves the configuration's
+lower-precision control, which has to come out not correct.
 """
 
 from __future__ import annotations
@@ -67,6 +68,9 @@ def main(argv: list[str] | None = None) -> int:
         cell, seed=args.seed, seconds=args.seconds, trace=bool(args.trace),
         t_start=T_START, root=ROOT, control=bool(args.control), marks=marks)
     print(json.dumps(result), flush=True)
+    for name, row in result["compared"].items():
+        print(core.check_line(name, *row), file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

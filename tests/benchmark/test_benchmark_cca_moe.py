@@ -238,12 +238,68 @@ def test_a_whole_run_is_correct_until_the_timed_path_is_broken(
     assert "CHECK served_plus_skipped_minus_routed: 0.0 == 0 -> ok" in printed
     assert "CHECK customers_in_store_minus_preloaded: 0 == 0 -> ok" in printed
     assert "CHECK served_model: 'hybrid_moe' == 'hybrid_moe' -> ok" in printed
+    # the four numbers decide as before; the shared comparison's new ones
+    # and the miss controls are printed and decide nothing
+    assert {"max_abs_dp", "max_abs_dlogit_slice"} <= set(result["compared"])
+    assert not {"max_abs_dp_own", "max_row_rms_dlogit_slice"} & set(
+        result["compared"])
+    for line in ("INFO compared max_abs_dp_own: ",
+                 "INFO compared max_row_rms_dlogit_slice: ",
+                 "INFO miss_control rolled: mean_abs_dlogit ",
+                 "INFO miss_control proba_rolled: mean_abs_dlogit "):
+        assert line in printed
     failed = [line for line in printed.splitlines() if line.endswith("FAIL")]
     if want:
         assert not failed
     else:  # every other number held
         assert failed and all(any(word in line for word in failing)
                               for line in failed), failed
+
+
+# -- the comparison, which kafka_history_mistral4's reference shares --------------------------
+
+@pytest.mark.parametrize("control,moved,still", [
+    ("rolled", ("max_row_rms_dlogit_slice", "mean_row_rms_dlogit_slice",
+                "max_abs_dlogit_slice", "mean_abs_dlogit", "max_abs_dp",
+                "choice_rel_diff"), ("max_abs_dp_own",)),
+    ("proba_rolled", ("max_abs_dp_own", "max_abs_dp"),
+     ("max_row_rms_dlogit_slice", "mean_row_rms_dlogit_slice",
+      "max_abs_dlogit_slice", "mean_abs_dlogit", "choice_rel_diff"))])
+def test_compare_gives_the_numbers_that_cannot_swing_beside_the_four(
+        control, moved, still):
+    """On made-up rows (8 of a vocabulary of 640; no model runs): a served
+    set against its own expectation reads rounding in every number, and
+    each miss control moves what it is there to move and nothing else."""
+    from benchmark.reference import cca_moe_f32 as ref
+    from benchmark.reference import mla_moe_f32
+    from benchmark.reference.mlp_f32 import sigmoid
+
+    assert mla_moe_f32.compare is ref.compare
+    assert mla_moe_f32.miss_controls is ref.miss_controls
+    with open(os.path.join(HERE, "zaya1_small_config.json")) as f:
+        model = json.load(f)
+    rng = np.random.default_rng(35)
+    expect = {"logits": rng.standard_normal((8, 640)),
+              "choice": rng.integers(0, 40, (8, 2, 5))}
+    kept = expect["logits"].astype(np.float32)
+    served = ref.Served(
+        logits=kept, choice=expect["choice"].copy(), model=model,
+        proba=sigmoid(ref.verdict_logit(kept, model)).astype(np.float32))
+    own = ref.compare(served, expect)
+    assert set(own) == set(moved) | set(still)
+    assert all(value < 1e-6 for value in own.values()), own
+    numbers = ref.compare(*ref.miss_controls(served, expect)[control])
+    assert all(numbers[name] > 1e-3 for name in moved), numbers
+    assert all(numbers[name] == own[name] for name in still), numbers
+    if control == "rolled":  # a row's root mean square, its largest, their mean
+        d = kept.astype(np.float64) - np.roll(expect["logits"], -1, axis=0)
+        rms = np.sqrt((d * d).mean(-1))
+        assert numbers["max_row_rms_dlogit_slice"] == pytest.approx(rms.max())
+        assert numbers["mean_row_rms_dlogit_slice"] == pytest.approx(
+            rms.mean())
+    else:
+        assert numbers["max_abs_dp_own"] == pytest.approx(np.abs(
+            served.proba - np.roll(served.proba, -1)).max(), rel=1e-4)
 
 
 # -- costs: hand counts at a small shape ------------------------------------------------

@@ -185,6 +185,10 @@ def test_a_whole_run_is_correct_until_the_timed_path_is_broken(
     assert result["attempted"] > 0 and result["failed"] == 0
     assert "CHECK pairs_routed_minus_served: 0.0 == 0 -> ok" in printed
     assert "CHECK customers_in_store_minus_preloaded: 0 == 0 -> ok" in printed
+    # the comparison's own controls, printed with this reference's numbers
+    for line in ("INFO miss_control rolled: mean_abs_dlogit ",
+                 "INFO miss_control proba_rolled: mean_abs_dlogit "):
+        assert line in printed
     failed = [line for line in printed.splitlines() if line.endswith("FAIL")]
     if want:
         assert not failed

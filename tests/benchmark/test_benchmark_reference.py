@@ -244,12 +244,22 @@ def test_a_whole_run_is_correct_until_the_timed_path_is_broken(
                            control=control)
     printed = capsys.readouterr().out
     assert result["correct"] is want, printed
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
+    json.dumps(result)  # the line can be printed
+    # every number compared beside its limit, the line's verdict their sum
+    assert all(core.check_line(name, *row) in printed
+               for name, row in result["compared"].items())
+    assert result["correct"] is all(
+        ok for *_, ok in result["compared"].values())
+    assert {"compiles_in_window", "rows_compared",
+            "mean_abs_dlogit"} <= set(result["compared"])
     assert set(result["metrics"]) == {m.name for m in cell.end_to_end}
     assert result["attempted"] > 0
     assert (result["failed"] > 0) == (sabotage is _drop_starts)
     assert "CHECK mean_abs_dlogit" in printed  # each number beside its limit
+    # these references hold no logits by row: no miss control, and no error
+    assert "INFO miss_control" not in printed
     failed = [l for l in printed.splitlines() if l.endswith("FAIL")]
     if control:  # the served model is the control's: that check is its name
         failed = [l for l in failed if "served_model" not in l]
