@@ -79,6 +79,8 @@ from typing import Any, Mapping
 import jax
 import jax.numpy as jnp
 
+from ccfd_tpu.ops import causal_attention
+
 Params = Mapping[str, Any]
 
 F32 = jnp.float32
@@ -91,10 +93,10 @@ HIGHEST = jax.lax.Precision.HIGHEST
 KDA_PRECISION = jax.lax.Precision.HIGH
 KDA_INSIDE = jax.lax.Precision.DEFAULT
 L2_EPS = 1e-6
-MASKED = -1e30
+MASKED = causal_attention.MASKED  # a key that is padding or after the query
 MOE_TILE = 256  # rows of one expert's group multiplied at a time
 KDA_SUB = 16  # kda_lower_bound * KDA_SUB must stay inside float32's exponent
-MLA_QUERY_BLOCKS = 4
+PLAIN_QUERY_BLOCKS = 4  # query blocks a row on the plain causal-attention path
 
 
 # -- settings: one small class a kind, read from the published keys ------------
@@ -567,14 +569,48 @@ def _rotary(x, position, freq, interleaved: bool = False,
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
-def _causal_attention(q, k, v, real, scale: float, dtype, scores: str,
-                      mix: str):
-    """Causal softmax attention over real keys, a row at a time in query
-    blocks (a block's keys end where it ends, so the scores of a row are
-    never whole in memory): ``scores`` is the einsum of a row's q and k
-    that ends in ``qk``, ``mix`` the one of the weights and v."""
+def _causal_attention(q, k, v, real, scale: float, dtype):
+    """Causal softmax attention over real keys: ``q`` (B, T, H, D) against
+    ``k`` (B, T, H, D) and ``v`` (B, T, H, Dv), or grouped queries
+    (B, T, G, per, D) against (B, T, G, D) and (B, T, G, Dv); ``real``
+    (B, T); the result in ``q``'s layout, Dv wide, in ``dtype``. Two paths,
+    one mathematics at one precision, chosen while the program is traced
+    from the operands' shapes, their dtype and the backend
+    (``ops/causal_attention.py::kernel_fits``): where a head's query-key
+    width and its value width each fill whole 128-lane tiles and the window
+    tiles into the kernel's blocks, the Pallas kernel, whose scores stay in
+    VMEM and which never visits a key block above the diagonal (operands
+    handed over by head, (B, H, T, D): the transposes fold into the fusions
+    around them); every other shape, :func:`_plain_causal_attention`, the
+    plain definition the tests compare against. The kernel has no
+    derivative; nothing differentiates this family (it is served only)."""
+    b, t = q.shape[:2]
+
+    def by_head_shape(x):  # (B, T, heads.., d) -> (B, heads, T, d)
+        return b, math.prod(x.shape[2:-1]), t, x.shape[-1]
+
+    def by_head(x):
+        return x.reshape(b, t, -1, x.shape[-1]).transpose(0, 2, 1, 3)
+
+    if not causal_attention.kernel_fits(
+            by_head_shape(q), by_head_shape(k), by_head_shape(v), q.dtype):
+        return _plain_causal_attention(q, k, v, real, scale, dtype)
+    o = causal_attention.fused_causal_attention(
+        by_head(q), by_head(k), by_head(v), real, scale=scale,
+        dtype=jnp.dtype(dtype), interpret=jax.default_backend() != "tpu")
+    return o.transpose(0, 2, 1, 3).reshape(q.shape[:-1] + v.shape[-1:])
+
+
+def _plain_causal_attention(q, k, v, real, scale: float, dtype):
+    """The plain path of :func:`_causal_attention`, through XLA: a row at
+    a time in query blocks (a block's keys end where it ends, so the scores
+    of a row are never whole in memory)."""
     t = q.shape[1]
-    blocks = MLA_QUERY_BLOCKS if t % MLA_QUERY_BLOCKS == 0 and t >= 512 else 1
+    # a row's q and k to scores that end in ``qk``; the weights and v
+    scores, mix = (("qgpd,kgd->gpqk", "gpqk,kgd->qgpd") if q.ndim == 5
+                   else ("qhd,khd->hqk", "hqk,khd->qhd"))
+    blocks = (PLAIN_QUERY_BLOCKS
+              if t % PLAIN_QUERY_BLOCKS == 0 and t >= 512 else 1)
     step = t // blocks
 
     def one_row(row):
@@ -641,8 +677,7 @@ def mla(p, z, real, position, cfg: HybridConfig, dtype):
         q, k = q.astype(dtype), k.astype(dtype)
         v = up[..., nope:].astype(dtype)
     with jax.named_scope("mla.attend"):
-        o = _causal_attention(q, k, v, real, s.scale, dtype,
-                              "qhd,khd->hqk", "hqk,khd->qhd")
+        o = _causal_attention(q, k, v, real, s.scale, dtype)
     with jax.named_scope("mla.project"):
         return _mm(o.reshape(b, t, h * vd), p["wo"], dtype)
 
@@ -721,8 +756,7 @@ def cca(p, z, real, position, cfg: HybridConfig, dtype):
         k = turned(unit(k) * p["tau"][:, None]).astype(dtype)
         v = v.astype(dtype)
     with jax.named_scope("cca.attend"):
-        o = _causal_attention(q, k, v, real, 1.0 / math.sqrt(hd), dtype,
-                              "qgpd,kgd->gpqk", "gpqk,kgd->qgpd")
+        o = _causal_attention(q, k, v, real, 1.0 / math.sqrt(hd), dtype)
     return _mm(o.reshape(b, t, h * hd), p["wo"], dtype)
 
 

@@ -650,10 +650,12 @@ class _Program:
     already, and every batch of a scorer with a mesh (``flat`` is None:
     ``_put_hist`` places the rows itself) go as (B, L, F).
 
-    **The trace.** Whether the full-attention block holds the kernel that
-    keeps the scores on the chip (``ops/seq_attention.py``), asked at the
-    shape that is dispatched. Read the first time it is asked (a look-up
-    in the jit's trace cache once the executable has run), from the memo
+    **The trace.** Whether the program's attention holds a kernel that
+    keeps the scores on the chip (``seq`` / ``seq_q8``'s full-attention
+    block: ``ops/seq_attention.py``; ``hybrid_moe``'s causal attention:
+    ``ops/causal_attention.py``), asked at the shape that is dispatched.
+    Read the first time it is asked (a look-up in the jit's trace cache
+    once the executable has run), from the memo
     afterwards; a swap to another variant builds another program, so the
     memo never outlives what it describes."""
 
@@ -685,7 +687,7 @@ class _Program:
         if got is None:
             import jax
 
-            from ccfd_tpu.ops.seq_attention import held_by
+            from ccfd_tpu.ops import causal_attention, seq_attention
 
             shape = jax.ShapeDtypeStruct
             extra = (shape((b,), np.int32),) if self.reads_filled else ()
@@ -693,8 +695,9 @@ class _Program:
                                      // _WIRE_LANES, _WIRE_LANES))
                         if self.flat_wire(lb)
                         else (self.fn, (b, lb, self.num_features)))
-            got = self._held[(lb, b)] = held_by(
-                fn, params, shape(hist, np.float32), *extra)
+            got = self._held[(lb, b)] = seq_attention.held_by(
+                fn, params, shape(hist, np.float32), *extra,
+                names=(seq_attention.KERNEL, causal_attention.KERNEL))
         return got
 
 
@@ -942,8 +945,8 @@ class SeqScorer:
             )
             self._c_attn_kernel = registry.counter(
                 "seq_attention_kernel_dispatch_total",
-                "seq dispatches of executables whose full-attention block "
-                "holds the kernel that keeps the scores on the chip (beside "
+                "seq dispatches of executables whose attention holds a "
+                "kernel that keeps the scores on the chip (beside "
                 "seq_bucket_dispatch_total: the rest attended through XLA)",
             )
             self._c_flat_wire = registry.counter(
@@ -1194,7 +1197,7 @@ class SeqScorer:
 
     def executable_grid(self) -> dict:
         """The (L, B) executable grid with per-executable dispatch counts,
-        whether the executable's full attention is the kernel and whether
+        whether the executable's attention is a kernel and whether
         its history batch crosses flat — the seq family's entry in the
         device telemetry inventory."""
         with self._params_lock:

@@ -425,6 +425,36 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             rows, list(range(10_000, 10_016))), np.asarray(direct), 1e-6),
         "grid": s.executable_grid()}
 
+    # the family's causal attention at head widths the small presets lack
+    # (128 wide; 768 tokens = two blocks of 384): Mosaic compiles the
+    # kernel (ops/causal_attention.py) for plain heads and for grouped
+    # queries, and the device's answer is the plain path's at every real
+    # position, one row padded on the left past the first block
+    from ccfd_tpu.ops import causal_attention, seq_attention
+
+    rng = np.random.default_rng(37)
+    real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [400]]))
+    for label, q_shape in (("plain heads", (2, 768, 2, 128)),
+                           ("grouped queries", (2, 768, 2, 2, 128))):
+        q, k, v = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                   for shape in (q_shape, (2, 768, 2, 128), (2, 768, 2, 128)))
+
+        def attend(q, k, v, real):
+            return hybrid_moe._causal_attention(q, k, v, real, 0.09,
+                                                jnp.bfloat16)
+
+        check(f"hybrid_moe causal attention, {label}: the program holds "
+              "the kernel", seq_attention.held_by(
+                  attend, q, k, v, real, names=(causal_attention.KERNEL,)))
+        keep = np.asarray(real).reshape((2, 768) + (1,) * (len(q_shape) - 2))
+        zoo[f"causal_attention.{label.split()[0]}"] = {
+            "max_abs_diff": check.close(
+                f"hybrid_moe causal attention, {label}: kernel vs plain path",
+                np.asarray(jax.jit(attend)(q, k, v, real), np.float32) * keep,
+                np.asarray(hybrid_moe._plain_causal_attention(
+                    q, k, v, real, 0.09, jnp.bfloat16), np.float32) * keep,
+                0.04)}
+
     # the fused-decision grid over the flagship: score + threshold + rules
     # in one executable per bucket, against the staged seam
     thr = Config().fraud_threshold
