@@ -32,7 +32,19 @@ SLOs need per-stage critical-path visibility, not endpoint histograms):
   wait). It always opens a ``jax.profiler.TraceAnnotation`` of the same
   name, so the phase is an event on the host plane of ANY device capture,
   beside ``XLA Ops`` on one timeline, whoever started the capture; under an
-  active span it is also a child :class:`Span` in the same sink.
+  active span it is also a child :class:`Span` in the same sink. The
+  phases that exist: on the pipelined router's loop thread, which they
+  cover end to end, ``router.signals``, ``router.poll``, ``router.admit``,
+  ``router.decode``, ``router.submit`` (the loop's side of the hand-over),
+  ``router.await`` (blocked on the score worker), ``router.force`` (a
+  deferred result made ready here), ``router.route``, ``router.commit``;
+  on its score worker ``router.score`` (with ``handoff_ns`` and
+  ``idle_ns``: the hand-over between the two threads, read where it
+  happens) > ``seq.score`` > ``seq.gather``, ``seq.pad``,
+  ``seq.enqueue``, ``seq.wait`` (> ``seq.fetch``, ``seq.tap`` where the
+  family hands back ``aux``), ``seq.commit``. One batch, one ordinal:
+  ``batch`` on its ``router.*`` phases, ``seq_batch`` on what the scorer
+  does for it, whichever call or thread that is.
 
 Span context is tracked per-thread via ``contextvars``; pipelined code that
 hops threads (the router's score worker) passes ``parent=`` explicitly.
@@ -460,7 +472,8 @@ class phase:  # noqa: N801 - reads as a statement: ``with phase("seq.pad"):``
     thread's CPU time inside it (``cpu_ns``; wall minus CPU is time spent
     off the CPU — on this path, waiting for the interpreter lock or the
     device). With no capture running the annotation costs under a
-    microsecond and the CPU clock is not read.
+    microsecond (the whole phase, with its stats and two clock reads,
+    about two) and the CPU clock is not read.
 
     Under an active span — ``parent`` given with its ``tracer`` (the
     router's stages, parented on the in-flight batch span that hops

@@ -582,6 +582,10 @@ class Router:
             "single-router case); compare against "
             "router_coalesced_dispatches_total to see fan-in",
         )
+        # batches begun (a poll that brought records): the ordinal every
+        # router.* phase of one batch carries, so a capture's two host
+        # lines are joined by it and not by order
+        self._batches = 0
         self._stop = threading.Event()
         # checkpoint barrier (runtime/recovery.py): pause() parks the run
         # loop at a batch boundary — consumed records fully routed into the
@@ -627,7 +631,7 @@ class Router:
                 offs[tp] = nxt
         return offs
 
-    def _commit_routed(self, offs: dict | None) -> None:
+    def _commit_routed(self, offs: dict | None, batch: int = 0) -> None:
         """Commit a fully-disposed batch's offsets (manual mode only).
 
         A fence (the group rebalanced since this batch was polled) is
@@ -637,7 +641,7 @@ class Router:
         errors likewise leave the batch uncommitted (it redelivers)."""
         if not self._commit_after_route or offs is None:
             return
-        with phase("router.commit", partitions=len(offs)):
+        with phase("router.commit", partitions=len(offs), batch=batch):
             try:
                 self._tx_consumer.commit(offs)
             except StaleEpochError:
@@ -648,23 +652,26 @@ class Router:
     # -- loop stages (composed by step() and the pipelined run loop) -------
     def _drain_signals(self) -> None:
         """Notification-counter drain + customer-response signal forwarding."""
-        for rec in self._notif_watcher.poll(self.max_batch, 0.0):
-            self._c_notif_out.inc()
+        with phase("router.signals") as ph:
+            for rec in self._notif_watcher.poll(self.max_batch, 0.0):
+                self._c_notif_out.inc()
 
-        for rec in self._resp_consumer.poll(self.max_batch, 0.0):
-            payload = rec.value or {}
-            approved = bool(payload.get("approved"))
-            self._c_notif_in.inc(
-                labels={"response": "approved" if approved else "non_approved"}
-            )
-            pid = payload.get("process_id")
-            if pid is not None:
-                try:
-                    self.engine.signal(int(pid), CUSTOMER_RESPONSE_SIGNAL, payload)
-                except Exception:
-                    # remote engine briefly unreachable: the rest of the
-                    # already-consumed response batch must still forward
-                    self._c_signal_err.inc()
+            responses = self._resp_consumer.poll(self.max_batch, 0.0)
+            ph.set(responses=len(responses))
+            for rec in responses:
+                payload = rec.value or {}
+                approved = bool(payload.get("approved"))
+                self._c_notif_in.inc(labels={
+                    "response": "approved" if approved else "non_approved"})
+                pid = payload.get("process_id")
+                if pid is not None:
+                    try:
+                        self.engine.signal(int(pid), CUSTOMER_RESPONSE_SIGNAL,
+                                           payload)
+                    except Exception:
+                        # remote engine briefly unreachable: the rest of the
+                        # already-consumed response batch must still forward
+                        self._c_signal_err.inc()
 
     def _poll_batch(self, poll_timeout_s: float) -> list:
         """Size x deadline micro-batching (SURVEY.md §7 stage 3): after the
@@ -738,43 +745,47 @@ class Router:
             attrs["worker"] = self.worker_id
         return self.tracer.start("router.batch", parent=parent, attrs=attrs)
 
-    def _stage(self, name: str, batch_span, rows: int) -> phase:
+    def _stage(self, name: str, batch_span, rows: int, **stats) -> phase:
         """One stage of a micro-batch: always an event in a device
         capture, and under a tracer a child span of the batch's."""
         return phase(name, self.tracer,
                      batch_span.context if batch_span is not None else None,
-                     rows=rows)
+                     rows=rows, **stats)
 
     def _decode_batch(
-        self, records: list, batch_span=None
+        self, records: list, batch_span=None, batch: int = 0
     ) -> tuple[np.ndarray, list, np.ndarray]:
         n = len(records)
-        self._c_in.inc(n)
-        self._h_batch.observe(n)
-        self._c_worker_batch.inc(labels=self._worker_labels)
         t0 = time.perf_counter()
-        with self._stage("router.decode", batch_span, n):
+        # the stage whole, as the profiler's router.decode times it: the
+        # counters, the decode, the timestamps and the queueing delay
+        with self._stage("router.decode", batch_span, n, batch=batch):
+            self._c_in.inc(n)
+            self._h_batch.observe(n)
+            self._c_worker_batch.inc(labels=self._worker_labels)
             x, txs, bad = decode_records(records)
-        if bad:
-            self._c_decode_err.inc(bad)
-        # produce timestamps ride along so _route can observe the
-        # end-to-end decision latency (producer -> process start)
-        ts = np.fromiter((r.timestamp for r in records), np.float64, n)
-        if self._profiler is not None or batch_span is not None:
-            # bus queueing delay: how long this batch's rows waited on the
-            # topic before the poll (mean across the batch — the component
-            # that sums with service/dispatch to the decision latency)
-            # ccfd-lint: disable=monotonic-durations -- record timestamps are wall-clock by contract (cross-process); max(0,...) clamps an NTP step
-            queue_s = max(0.0, time.time() - float(ts.mean()))
-            if batch_span is not None:
-                # ride the span too: the profiler's span-ingestion path
-                # (and offline trace analysis) reads it from the attrs
-                batch_span.attrs["queue_s"] = queue_s
-            if self._profiler is not None:
-                self._profiler.observe("bus", queue_s=queue_s, rows=n)
-                self._profiler.observe(
-                    "router.decode",
-                    service_s=time.perf_counter() - t0, batch=n, rows=n)
+            if bad:
+                self._c_decode_err.inc(bad)
+            # produce timestamps ride along so _route can observe the
+            # end-to-end decision latency (producer -> process start)
+            ts = np.fromiter((r.timestamp for r in records), np.float64, n)
+            if self._profiler is not None or batch_span is not None:
+                # bus queueing delay: how long this batch's rows waited on
+                # the topic before the poll (mean across the batch — the
+                # component that sums with service/dispatch to the
+                # decision latency)
+                # ccfd-lint: disable=monotonic-durations -- record timestamps are wall-clock by contract (cross-process); max(0,...) clamps an NTP step
+                queue_s = max(0.0, time.time() - float(ts.mean()))
+                if batch_span is not None:
+                    # ride the span too: the profiler's span-ingestion
+                    # path (and offline trace analysis) reads it from the
+                    # attrs
+                    batch_span.attrs["queue_s"] = queue_s
+                if self._profiler is not None:
+                    self._profiler.observe("bus", queue_s=queue_s, rows=n)
+                    self._profiler.observe(
+                        "router.decode",
+                        service_s=time.perf_counter() - t0, batch=n, rows=n)
         return x, txs, ts
 
     # -- decision provenance -----------------------------------------------
@@ -814,6 +825,17 @@ class Router:
         self._c_in.inc(shed)
         self._c_shed.inc(shed)
         return records[shed:] if granted else []
+
+    def _begin_batch(self, records: list) -> tuple[int, dict | None, list]:
+        """One poll's records become a batch: its ordinal, its commit
+        positions and the records admission lets through."""
+        self._batches += 1
+        batch = self._batches
+        with phase("router.admit", batch=batch, rows=len(records)) as ph:
+            offs = self._tx_offsets(records)
+            kept = self._admit(records)
+            ph.set(admitted=len(kept), shed=len(records) - len(kept))
+        return batch, offs, kept
 
     def _admit(self, records: list) -> list:
         """Admission for one poll's records. With the overload plane armed
@@ -967,8 +989,9 @@ class Router:
         return self._score2(x, txs)
 
     def _score_batch(self, x: np.ndarray, txs: list,
-                     batch_span=None, meta=None) -> tuple:
-        with self._stage("router.score", batch_span, len(txs)) as ph:
+                     batch_span=None, meta=None, **stats) -> tuple:
+        with self._stage("router.score", batch_span, len(txs),
+                         **stats) as ph:
             if self._degrade:
                 return self._score_tiered(x, txs, span=ph.span, meta=meta)
             return self._score_direct(x, txs, span=ph.span, meta=meta)
@@ -980,20 +1003,20 @@ class Router:
         records = self._poll_batch(poll_timeout_s)
         if not records:
             return 0
-        offs = self._tx_offsets(records)
-        records = self._admit(records)
+        batch, offs, records = self._begin_batch(records)
         if not records:
             # fully shed: every record is disposed (counted), the batch
             # is complete — commit it
-            self._commit_routed(offs)
+            self._commit_routed(offs, batch)
             return 0
         batch_sp = None
         meta = self._audit_meta(records)
         try:
             batch_sp = self._begin_batch_span(records)
-            x, txs, ts = self._decode_batch(records, batch_sp)
+            x, txs, ts = self._decode_batch(records, batch_sp, batch)
             t0 = time.perf_counter()
-            proba, fired = self._score_batch(x, txs, batch_sp, meta)
+            proba, fired = self._score_batch(x, txs, batch_sp, meta,
+                                             batch=batch)
             score_s = time.perf_counter() - t0
             self._h_score_s.observe(
                 score_s,
@@ -1007,11 +1030,11 @@ class Router:
                 self._profiler.observe("router.score", dispatch_s=score_s,
                                        batch=len(txs), rows=len(txs))
             n = self._route(x, txs, proba, ts, batch_span=batch_sp,
-                            meta=meta, fired=fired)
+                            meta=meta, fired=fired, batch=batch)
             # commit ONLY after every record has a terminal disposition
             # (routed/shed/errored); a crash above leaves the batch
             # uncommitted, so it redelivers instead of vanishing
-            self._commit_routed(offs)
+            self._commit_routed(offs, batch)
             return n
         except BaseException:
             # a crashed batch is exactly the trace an operator needs:
@@ -1026,7 +1049,8 @@ class Router:
 
     def _route(self, x: np.ndarray, txs: list, proba: np.ndarray,
                ts: np.ndarray | None = None, batch_span=None,
-               meta=None, fired: np.ndarray | None = None) -> int:
+               meta=None, fired: np.ndarray | None = None,
+               batch: int = 0) -> int:
         t0 = time.perf_counter() if self._profiler is not None else 0.0
         try:
             # under a tracer the phase's span is ACTIVE on this thread:
@@ -1034,7 +1058,8 @@ class Router:
             # engine produces inside them, process/fraud.py notify) read
             # current_context() to join the trace — an unactivated span
             # would orphan the engine/notify leg
-            with self._stage("router.route", batch_span, len(txs)) as ph:
+            with self._stage("router.route", batch_span, len(txs),
+                             batch=batch) as ph:
                 return self._route_inner(x, txs, proba, ts, batch_span,
                                          ph.span, meta, fired)
         finally:
@@ -1320,37 +1345,63 @@ class Router:
                 self._profiler.observe("router.score", dispatch_s=score_s,
                                        batch=rows, rows=rows)
 
-        def timed_score(x: np.ndarray, txs: list, batch_sp,
-                        meta) -> tuple:
+        returned_ns = 0  # the worker's clock when its last call returned
+
+        def timed_score(x: np.ndarray, txs: list, batch_sp, meta,
+                        batch: int, submitted_ns: int) -> tuple:
             # time INSIDE the worker so the histogram records the scorer
             # round trip, not dispatch + however long the loop polled
             # (a deferred result's round trip ends when it is ready:
             # finish observes it). batch_sp (and the audit meta) ride
             # along explicitly — the worker thread has no ambient trace
             # context (contextvars are per-thread), and batch-scoped audit
-            # state must never live on self while two batches are in flight
-            t0 = time.perf_counter()
-            proba, fired = self._score_batch(x, txs, batch_sp, meta)
+            # state must never live on self while two batches are in flight.
+            # The hand-over is measured where it happens: ``handoff_ns``
+            # from the loop's submit to this first statement (both threads
+            # and the feeder share one interpreter lock), ``idle_ns`` since
+            # this worker's last return (0 for its first batch)
+            nonlocal returned_ns
+            start_ns = time.perf_counter_ns()
+            t0 = start_ns / 1e9
+            try:
+                proba, fired = self._score_batch(
+                    x, txs, batch_sp, meta, batch=batch,
+                    handoff_ns=start_ns - submitted_ns,
+                    idle_ns=start_ns - returned_ns if returned_ns else 0)
+            finally:
+                returned_ns = time.perf_counter_ns()
             if not _deferred(proba):
-                observe_score(time.perf_counter() - t0, batch_sp, len(txs))
+                observe_score(returned_ns / 1e9 - t0, batch_sp, len(txs))
             return proba, fired, t0
 
         def finish(pending: tuple, newer: tuple | None = None) -> None:
-            pfut, px, ptxs, pts, psp, pmeta, poffs = pending
+            pfut, px, ptxs, pts, psp, pmeta, poffs, pn = pending
             try:
                 try:
-                    proba, fired, t0 = pfut.result()
-                    if _deferred(proba):
-                        if newer is not None:
+                    # the loop blocked on the score worker: for the whole
+                    # dispatch where the scorer resolves inside its call,
+                    # for the worker's call for the NEWER batch where it
+                    # defers
+                    with self._stage("router.await", psp, len(ptxs),
+                                     batch=pn) as ph:
+                        proba, fired, t0 = pfut.result()
+                        deferred = _deferred(proba)
+                        ph.set(deferred=int(deferred))
+                        if deferred and newer is not None:
                             # the worker readies these scores inside its
                             # call for the newer batch, which is already
                             # submitted: forcing them before it got there
                             # would take the scorer from it and put the
                             # two batches back in series
                             wait((newer[0],))
-                        deferred, proba = proba, np.asarray(proba)
-                        observe_score(deferred.ready_at - t0, psp,
-                                      len(ptxs))
+                    if deferred:
+                        # ready already unless this is the stream's last
+                        # batch, light load or a pause point: then the
+                        # scorer's wait and commit run here, on this thread
+                        with self._stage("router.force", psp, len(ptxs),
+                                         batch=pn):
+                            scores, proba = proba, np.asarray(proba)
+                        observe_score(scores.ready_at - t0, psp, len(ptxs))
                 except Exception:
                     # a transient scorer failure (e.g. remote model timeout)
                     # drops this batch, not the routing loop. The drop IS
@@ -1360,11 +1411,11 @@ class Router:
                     self._c_score_err.inc(len(ptxs))
                     if psp is not None:
                         psp.status = "error"
-                    self._commit_routed(poffs)
+                    self._commit_routed(poffs, pn)
                     return
                 self._route(px, ptxs, proba, pts, batch_span=psp,
-                            meta=pmeta, fired=fired)
-                self._commit_routed(poffs)
+                            meta=pmeta, fired=fired, batch=pn)
+                self._commit_routed(poffs, pn)
             except BaseException:
                 if psp is not None:  # _route crashed: force-keep the trace
                     psp.status = "error"
@@ -1375,7 +1426,8 @@ class Router:
                     self.tracer.finish(psp)
 
         ex = ThreadPoolExecutor(1, thread_name_prefix="ccfd-router-score")
-        pending: tuple | None = None  # (future, x, txs, ts, batch_span)
+        # (future, x, txs, ts, batch_span, meta, offsets, ordinal)
+        pending: tuple | None = None
         try:
             while not self._stop.is_set():
                 if self._pause_req.is_set():
@@ -1400,27 +1452,34 @@ class Router:
                 records = self._poll_batch(
                     0.0 if pending is not None else poll_timeout_s
                 )
-                offs = self._tx_offsets(records)
                 if records:
                     # bounded in-flight: batch k-1's rows are still
                     # reserved (consumed-but-unrouted) while k is being
                     # submitted — the budget reserve inside _admit
                     # accounts for them (and, under ParallelRouter, for
                     # every other worker's in-flight rows too)
-                    records = self._admit(records)
+                    batch, offs, records = self._begin_batch(records)
                     if not records:
                         # fully shed: disposed (counted) — commit now
-                        self._commit_routed(offs)
+                        self._commit_routed(offs, batch)
                 fut = None
                 if records:
                     batch_sp = None
                     meta = self._audit_meta(records)
                     try:
                         batch_sp = self._begin_batch_span(records)
-                        x, txs, ts = self._decode_batch(records, batch_sp)
-                        if defer:
-                            txs = DeferrableRecords(txs)
-                        fut = ex.submit(timed_score, x, txs, batch_sp, meta)
+                        x, txs, ts = self._decode_batch(records, batch_sp,
+                                                        batch)
+                        # the loop's side of the hand-over: the mark, the
+                        # executor's queue, waking the worker and getting
+                        # the interpreter back from it
+                        with self._stage("router.submit", batch_sp,
+                                         len(txs), batch=batch):
+                            if defer:
+                                txs = DeferrableRecords(txs)
+                            fut = ex.submit(
+                                timed_score, x, txs, batch_sp, meta, batch,
+                                time.perf_counter_ns())
                     except BaseException:
                         # reserved rows must not leak out of a crashed
                         # loop (with a SHARED budget the leak would
@@ -1433,7 +1492,7 @@ class Router:
                             self.tracer.finish(batch_sp)
                         raise
                 done, pending = pending, (
-                    (fut, x, txs, ts, batch_sp, meta, offs)
+                    (fut, x, txs, ts, batch_sp, meta, offs, batch)
                     if fut is not None else None)
                 if done is not None:
                     try:
@@ -1444,7 +1503,7 @@ class Router:
                         # (shared-budget leak-proofing), count it as
                         # dropped, and keep its trace
                         if pending is not None:
-                            _, _, ptxs, _, psp, _pm, _po = pending
+                            _, _, ptxs, _, psp, _pm, _po, _pn = pending
                             pending = None
                             self._budget.release(len(ptxs))
                             self._c_score_err.inc(len(ptxs))

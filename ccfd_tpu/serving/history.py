@@ -569,12 +569,16 @@ class _Batch:
     batch keeps so that a later call, or whoever forces its scores, can
     resolve it, commit it, or stage it again."""
 
-    __slots__ = ("x", "ids", "out", "scores", "error", "t_asm", "t_disp",
-                 "gen", "merged", "pending", "taken", "kept", "n_anon")
+    __slots__ = ("x", "ids", "seq", "out", "scores", "error", "t_asm",
+                 "t_disp", "gen", "merged", "pending", "taken", "kept",
+                 "n_anon")
 
-    def __init__(self, x: np.ndarray, ids: list):
+    def __init__(self, x: np.ndarray, ids: list, seq: int = 0):
         self.x = x
         self.ids = ids
+        # the scorer's count of batches: every phase of this batch carries
+        # it (``seq_batch``), whichever call or thread resolves it
+        self.seq = seq
         self.out = np.empty((len(x),), np.float32)
         self.scores: DeferredScores | None = None  # of a deferred batch
         # what an older batch's dispatch raised while a later call waited
@@ -808,6 +812,9 @@ class SeqScorer:
         # whoever stages, settles or forces one, for as long as it does
         self._open: deque = deque()
         self._lock = threading.Lock()
+        # a batch's ordinal on its phases (``seq_batch``); ``next`` of a
+        # count is GIL-atomic, as the workers of a pool need it
+        self._batch_ids = itertools.count(1)
         # device telemetry plane (observability/device.py): the seq
         # dispatch ships (B, L, F) history batches whose transfer happens
         # INSIDE the jitted call, so only the bytes are separately
@@ -1272,17 +1279,26 @@ class SeqScorer:
 
         Every stretch of the call is a :class:`phase` (``seq.gather``,
         ``seq.pad``, ``seq.enqueue``, ``seq.wait``, ``seq.commit`` inside
-        ``seq.score``), so a device capture shows what the host did
-        beside what the device did; ``seq_assembly_seconds`` is the
+        ``seq.score``; inside ``seq.wait`` of a family that hands back
+        ``aux``, after the program's end, ``seq.fetch`` around the copy of
+        its leaves to the host and ``seq.tap`` around the observer and
+        ``aux_tap``), so a device capture shows what the host did beside
+        what the device did; ``seq.score`` and what resolves a batch
+        (``seq.wait``, ``seq.fetch``, ``seq.tap``, ``seq.commit``) carry
+        the batch's ordinal ``seq_batch``, so a wait inside the next
+        batch's call says whose it is; ``seq_assembly_seconds`` is the
         batch's gather + pad, ``seq_dispatch_seconds`` its enqueue + wait,
         from the same clock reads."""
         n = len(x)
         if n == 0:
             return np.zeros((0,), np.float32)
-        with phase("seq.score", rows=n, open_batches=0, overlapped=0):
-            return self._score(x, ids)
+        seq = next(self._batch_ids)
+        with phase("seq.score", rows=n, open_batches=0, overlapped=0,
+                   seq_batch=seq):
+            return self._score(x, ids, seq)
 
-    def _score(self, x: np.ndarray, ids: list | None) -> np.ndarray:
+    def _score(self, x: np.ndarray, ids: list | None,
+               seq: int) -> np.ndarray:
         if self._open:
             with self._lock:
                 while self._open:
@@ -1292,7 +1308,7 @@ class SeqScorer:
         # (full-L) history batch so the challenger scores the SAME
         # contexts the champion just did (one flag read when idle)
         tap, gate = self._armed()
-        batch = _Batch(x, [None] * len(x) if ids is None else ids)
+        batch = _Batch(x, [None] * len(x) if ids is None else ids, seq)
         self._stage(batch, (), keep_hist=tap is not None or gate is not None)
         self._settle(batch)
         out = batch.out
@@ -1333,11 +1349,13 @@ class SeqScorer:
         ready; this one stays open, for the next call or for whoever
         forces its scores (``_force``). One caller at a time: the lock is
         held for the whole call."""
-        with phase("seq.score", rows=len(x)) as ph, self._lock:
+        seq = next(self._batch_ids)
+        with phase("seq.score", rows=len(x), seq_batch=seq) as ph, \
+                self._lock:
             older = tuple(self._open)
             overlapped = any(b.pending for b in older)
             ph.set(open_batches=len(older), overlapped=int(overlapped))
-            batch = _Batch(x, ids)
+            batch = _Batch(x, ids, seq)
             batch.scores = DeferredScores(self, batch)
             self._open.append(batch)
             try:
@@ -1582,7 +1600,8 @@ class SeqScorer:
             self._g_inflight.set(float(
                 sum(len(b.pending) for b in self._open)))
         if batch.gen is not None:
-            with phase("seq.commit", customers=len(batch.merged)) as ph:
+            with phase("seq.commit", customers=len(batch.merged),
+                       seq_batch=batch.seq) as ph:
                 committed = self.store.commit((batch.gen, batch.merged))
                 ph.set(stale=int(not committed))
             if not committed and self._c_stale is not None:
@@ -1605,14 +1624,21 @@ class SeqScorer:
         rows; the blocking wait (the dispatch time overlap failed to
         hide) goes to the batch's dispatch time."""
         dev, idx, m, tokens = batch.pending.popleft()
-        with phase("seq.wait", rows=m, tokens=tokens) as ph:
+        seq = batch.seq
+        with phase("seq.wait", rows=m, tokens=tokens, seq_batch=seq) as ph:
             if isinstance(dev, tuple):  # (proba, aux): the family's counts
+                # the program's end as the host sees it: what no child
+                # phase covers of seq.wait is the wait for the program
                 proba = np.asarray(dev[0])
-                aux = {k: np.asarray(v) for k, v in dev[1].items()}
-                if self._observe is not None:
-                    ph.set(**self._observe(aux))
-                if self.aux_tap is not None:
-                    self.aux_tap(idx, m, aux)
+                with phase("seq.fetch", leaves=len(dev[1]),
+                           seq_batch=seq) as fetch:
+                    aux = {k: np.asarray(v) for k, v in dev[1].items()}
+                    fetch.set(bytes=sum(v.nbytes for v in aux.values()))
+                with phase("seq.tap", rows=m, seq_batch=seq):
+                    if self._observe is not None:
+                        ph.set(**self._observe(aux))
+                    if self.aux_tap is not None:
+                        self.aux_tap(idx, m, aux)
             else:
                 proba = np.asarray(dev)
         batch.out[idx] = proba[:m]

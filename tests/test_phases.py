@@ -1,7 +1,11 @@
 """``trace.phase``: the served path's phases are events of any profiler
 capture (router loop, score worker, scorer, store, device wait), child
 spans under an active span, and the one clock read behind the scorer's two
-histograms."""
+histograms. A pipelined router's loop line is closed (``router.signals``,
+``router.admit``, ``router.submit``, ``router.await``, ``router.force``
+beside poll, decode, route and commit),
+the hand-over to the score worker is on ``router.score``, ``seq.wait``
+names the copy-out and the tap, and a batch's phases share an ordinal."""
 
 import glob
 import os
@@ -74,6 +78,83 @@ def tiny_scorer(registry=None, batch_sizes=(16,)):
 
 def rows(n, seed=0):
     return np.random.default_rng(seed).normal(size=(n, 30)).astype(np.float32)
+
+
+@pytest.fixture(scope="module")
+def aux_family():
+    """A history family whose program hands back ``aux`` beside the
+    probabilities, as ``hybrid_moe`` does, at no compile to speak of."""
+    import jax.numpy as jnp
+
+    from ccfd_tpu.models import registry
+
+    def make_apply(dtype, pos_length, config):
+        @jax.jit
+        def fn(params, hist):
+            last = hist[:, -1, :]
+            return jax.nn.sigmoid(last.sum(-1)), {
+                "logits": last * 2.0,
+                "row_pairs": jnp.arange(len(hist), dtype=jnp.int32)}
+
+        return fn
+
+    name = "phases_aux"
+    registry.register_history(registry.HistorySpec(
+        name=name, owns=lambda params: False, make_apply=make_apply,
+        make_observer=lambda reg: lambda aux: {
+            "pairs_served": int(aux["row_pairs"].sum())}))
+    yield name
+    del registry._HISTORY[name]
+
+
+def aux_scorer(family, registry=None):
+    return SeqScorer({}, length=8, batch_sizes=(16,),
+                     compute_dtype="float32", registry=registry,
+                     family=family)
+
+
+def inside(events, span):
+    return [e for e in events if span[1] <= e[1] and e[2] <= span[2]]
+
+
+def run_pipelined(tmp_path, score_fn, n_records=256, max_batch=64):
+    """``n_records`` keyed records through a pipelined router with no
+    tracer, inside a capture."""
+    cfg = Config(fraud_threshold=0.99)
+    broker = Broker()
+    engine = build_engine(cfg, broker, Registry())
+    router = Router(cfg, broker, score_fn, engine, Registry(),
+                    max_batch=max_batch)
+    assert router.tracer is None
+    records = [{FEATURE_NAMES[j]: float(j % 5) for j in range(30)}
+               | {"id": i % 7, "customer_id": i % 7}
+               for i in range(n_records)]
+    with Capture(tmp_path) as cap:
+        thread = router.start(poll_timeout_s=0.01, pipeline=True)
+        try:
+            broker.produce_batch(cfg.kafka_topic, records)
+            deadline = time.monotonic() + 60.0
+            consumed = router.registry.counter("transaction_incoming_total")
+            routed = router.registry.counter("transaction_outgoing_total")
+            while (routed.total() < len(records)
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
+            assert consumed.value() == routed.total() == len(records)
+        finally:
+            router.stop()
+            thread.join(timeout=30)
+            assert not thread.is_alive()
+    return cap
+
+
+@pytest.fixture(scope="module")
+def deferring_capture(tmp_path_factory):
+    """The history scorer behind a pipelined router: it defers, so the
+    stream's last batch (and any the loop finished with none newer in
+    flight) is forced on the loop thread."""
+    scorer = tiny_scorer(Registry(), batch_sizes=(16, 128))
+    scorer.warmup()
+    return run_pipelined(tmp_path_factory.mktemp("deferring"), scorer)
 
 
 @pytest.mark.parametrize("explicit", [False, True])
@@ -204,64 +285,184 @@ def test_a_deferred_batch_waits_and_commits_inside_the_next_call(tmp_path):
     assert [e[3]["open_batches"] for e in scores] == [0, 1, 1]
     assert [e[3]["overlapped"] for e in scores] == [0, 1, 1]
 
-    def inside(name, span):
-        return [e for e in cap.named(name)
-                if span[1] <= e[1] and e[2] <= span[2]]
-
-    assert not inside("seq.wait", scores[0])
-    assert not inside("seq.commit", scores[0])
+    assert not inside(cap.named("seq.wait"), scores[0])
+    assert not inside(cap.named("seq.commit"), scores[0])
     for k in (1, 2):
-        (wait,) = inside("seq.wait", scores[k])
-        (commit,) = inside("seq.commit", scores[k])
-        (enqueue,) = inside("seq.enqueue", scores[k])
+        (wait,) = inside(cap.named("seq.wait"), scores[k])
+        (commit,) = inside(cap.named("seq.commit"), scores[k])
+        (enqueue,) = inside(cap.named("seq.enqueue"), scores[k])
         assert wait[3]["rows"] == commit[3]["customers"] == sizes[k - 1]
         assert enqueue[2] <= wait[1] <= wait[2] <= commit[1]
+        # a wait inside the NEXT batch's call says whose it is
+        assert wait[3]["seq_batch"] == commit[3]["seq_batch"] == k
     forced = [e for e in cap.named("seq.wait")
               if not any(s[1] <= e[1] and e[2] <= s[2] for s in scores)]
     assert [e[3]["rows"] for e in forced] == [sizes[-1]]
+    assert [e[3]["seq_batch"] for e in scores] == [1, 2, 3]
+    assert forced[0][3]["seq_batch"] == 3
 
 
-def test_a_pipelined_router_with_no_tracer_shows_in_a_capture(tmp_path):
-    cfg = Config(fraud_threshold=0.99)
-    broker = Broker()
-    engine = build_engine(cfg, broker, Registry())
-    scorer = tiny_scorer(Registry(), batch_sizes=(16, 128))
-    scorer.warmup()
-    router = Router(cfg, broker, scorer, engine, Registry(), max_batch=64)
-    assert router.tracer is None
-    records = [{FEATURE_NAMES[j]: float(j % 5) for j in range(30)}
-               | {"id": i % 7, "customer_id": i % 7} for i in range(256)]
-    with Capture(tmp_path) as cap:
-        thread = router.start(poll_timeout_s=0.01, pipeline=True)
-        try:
-            broker.produce_batch(cfg.kafka_topic, records)
-            deadline = time.monotonic() + 60.0
-            consumed = router.registry.counter("transaction_incoming_total")
-            routed = router.registry.counter("transaction_outgoing_total")
-            while (routed.total() < len(records)
-                   and time.monotonic() < deadline):
-                time.sleep(0.01)
-            assert consumed.value() == routed.total() == len(records)
-        finally:
-            router.stop()
-            thread.join(timeout=30)
-            assert not thread.is_alive()
+def test_a_pipelined_router_with_no_tracer_shows_in_a_capture(
+        deferring_capture):
+    cap, n = deferring_capture, 256
     worker = cap.line_of("router.score")
     loop = cap.line_of("router.decode")
     assert loop is cap.line_of("router.route") is cap.line_of("router.poll")
     assert worker is not loop
     assert worker is cap.line_of("seq.score")
     for name in ("router.score", "router.decode", "router.route"):
-        assert sum(e[3]["rows"] for e in cap.named(name)) == len(records)
-    assert sum(e[3]["rows"] for e in cap.named("router.poll")) == len(records)
+        assert sum(e[3]["rows"] for e in cap.named(name)) == n
+    assert sum(e[3]["rows"] for e in cap.named("router.poll")) == n
     for e in cap.named("seq.score"):  # the scorer's call, inside the stage
-        assert any(s[1] <= e[1] and e[2] <= s[2]
-                   for s in cap.named("router.score"))
+        assert any(inside([e], s) for s in cap.named("router.score"))
 
 
-def test_the_scorers_histograms_are_fed_from_the_phases_clock_reads():
+LOOP_PHASES = ("router.signals", "router.poll", "router.admit",
+               "router.decode", "router.submit", "router.await",
+               "router.force", "router.route", "router.commit")
+
+
+def test_the_loops_line_is_closed_and_its_phases_never_overlap(
+        deferring_capture):
+    cap = deferring_capture
+    loop = cap.line_of("router.decode")
+    for name in ("router.signals", "router.admit", "router.submit",
+                 "router.await", "router.force"):
+        assert cap.line_of(name) is loop, name
+    admits = cap.named("router.admit")
+    assert sum(e[3]["rows"] for e in admits) == 256
+    assert all(e[3]["admitted"] == e[3]["rows"] and e[3]["shed"] == 0
+               for e in admits)
+    awaits = cap.named("router.await")
+    assert sum(e[3]["rows"] for e in awaits) == 256
+    assert {e[3]["deferred"] for e in awaits} == {1}
+    outer = sorted((e for e in loop if e[0] in LOOP_PHASES),
+                   key=lambda e: e[1])
+    assert {e[0] for e in outer} >= set(LOOP_PHASES) - {"router.commit"}
+    for a, b in zip(outer, outer[1:]):
+        assert a[2] <= b[1], (a[0], b[0])
+    # whatever else the line holds is the scorer's, under a force
+    for e in loop:
+        if e[0] not in LOOP_PHASES:
+            assert e[0] in ("seq.wait", "seq.commit")
+            assert inside([e], next(f for f in cap.named("router.force")
+                                    if f[1] <= e[1] <= f[2]))
+
+
+def test_a_forced_batch_waits_and_commits_under_router_force(
+        deferring_capture):
+    """The stream's last batch at least: its ``seq.wait`` and
+    ``seq.commit`` run on the loop thread and have a parent now."""
+    cap = deferring_capture
+    scores = cap.named("seq.score")
+    outside = [e for e in cap.named("seq.wait") + cap.named("seq.commit")
+               if not any(inside([e], s) for s in scores)]
+    assert outside, "no batch was forced"
+    forces = cap.named("router.force")
+    loop = cap.line_of("router.decode")
+    for e in outside:
+        assert e in loop and any(inside([e], f) for f in forces), e[0]
+    last = inside(outside, max(forces, key=lambda f: f[1]))
+    assert [e[0] for e in last].count("seq.commit") == 1
+    assert [e[0] for e in last].count("seq.wait") >= 1
+    assert len({e[3]["seq_batch"] for e in last}) == 1
+
+
+def test_the_hand_over_is_measured_on_router_score(deferring_capture):
+    cap = deferring_capture
+    scores = sorted(cap.named("router.score"), key=lambda e: e[1])
+    assert len(scores) >= 4
+    for e in scores:
+        assert e[3]["handoff_ns"] >= 0 and e[3]["idle_ns"] >= 0
+    assert scores[0][3]["idle_ns"] == 0  # the worker's first batch
+    for prev, e in zip(scores, scores[1:]):
+        # the worker's own clock reads sit just inside the gap between
+        # the two annotations
+        assert 0 < e[3]["idle_ns"] <= e[1] - prev[2]
+    decodes = {e[3]["batch"]: e for e in cap.named("router.decode")}
+    for e in scores:  # submitted after its decode, begun after its submit
+        assert e[3]["handoff_ns"] <= e[1] - decodes[e[3]["batch"]][2]
+
+
+def test_one_ordinal_on_all_of_a_batchs_router_phases(deferring_capture):
+    cap = deferring_capture
+    per_name = {name: [e[3]["batch"] for e in sorted(
+        cap.named(name), key=lambda e: e[1])]
+        for name in LOOP_PHASES[2:] + ("router.score",)}
+    n = len(per_name["router.admit"])
+    assert per_name["router.admit"] == list(range(1, n + 1))
+    for name in ("router.decode", "router.submit", "router.score",
+                 "router.await", "router.route"):
+        assert per_name[name] == list(range(1, n + 1)), name
+    assert set(per_name["router.force"]) <= set(range(1, n + 1))
+    assert n in per_name["router.force"]  # the stream's last batch
+    for name in LOOP_PHASES[:2]:  # once a turn of the loop, batch or none
+        assert "batch" not in cap.named(name)[0][3]
+    # the two lines join by containment: a batch's router.score holds one
+    # seq.score, and the scorer's ordinal runs beside the router's
+    for e in cap.named("router.score"):
+        (call,) = inside(cap.named("seq.score"), e)
+        assert call[3]["seq_batch"] == e[3]["batch"]
+
+
+def test_a_scorer_that_resolves_in_its_call_is_awaited_not_forced(tmp_path):
+    cap = run_pipelined(tmp_path, lambda x: np.full(len(x), 0.25,
+                                                    np.float32), 128, 32)
+    awaits = cap.named("router.await")
+    assert awaits and {e[3]["deferred"] for e in awaits} == {0}
+    assert not cap.named("router.force")
+    assert cap.line_of("router.await") is cap.line_of("router.decode")
+    batches = sorted(e[3]["batch"] for e in cap.named("router.score"))
+    assert batches == sorted(e[3]["batch"] for e in awaits)
+    assert sum(e[3]["rows"] for e in awaits) == 128
+
+
+def test_seq_wait_names_the_copy_out_and_the_tap(tmp_path, aux_family):
     reg = Registry()
-    scorer = tiny_scorer(reg)
+    scorer = aux_scorer(aux_family, reg)
+    scorer.warmup()
+    kept = []
+    scorer.aux_tap = lambda idx, m, aux: kept.append(aux)
+    with Capture(tmp_path) as cap:
+        scorer.score(rows(20), ids=[f"c{i}" for i in range(20)])
+    waits = cap.named("seq.wait")
+    assert [e[3]["rows"] for e in waits] == [16, 4] and len(kept) == 2
+    for wait, aux in zip(waits, kept):
+        (fetch,) = inside(cap.named("seq.fetch"), wait)
+        (tap,) = inside(cap.named("seq.tap"), wait)
+        assert fetch[2] <= tap[1]
+        assert fetch[3]["leaves"] == 2
+        assert fetch[3]["bytes"] == sum(v.nbytes for v in aux.values()) \
+            == 16 * 30 * 4 + 16 * 4
+        assert tap[3]["rows"] == wait[3]["rows"]
+        # what the observer returns stays on seq.wait
+        assert wait[3]["pairs_served"] == sum(range(16))
+        assert "pairs_served" not in tap[3]
+        assert fetch[3]["seq_batch"] == tap[3]["seq_batch"] \
+            == wait[3]["seq_batch"] == 1
+    assert len(cap.named("seq.fetch")) == len(cap.named("seq.tap")) == 2
+
+
+def test_seq_wait_of_a_family_without_aux_holds_neither(tmp_path):
+    scorer = tiny_scorer(Registry())
+    scorer.warmup()
+    with Capture(tmp_path) as cap:
+        scorer.score(rows(20), ids=[f"c{i}" for i in range(20)])
+        scorer.score(rows(3), ids=["x", "y", "z"])
+    assert len(cap.named("seq.wait")) == 3
+    assert not cap.named("seq.fetch") and not cap.named("seq.tap")
+    assert [e[3]["seq_batch"] for e in cap.named("seq.score")] == [1, 2]
+    assert [e[3]["seq_batch"] for e in cap.named("seq.wait")] == [1, 1, 2]
+    assert [e[3]["seq_batch"] for e in cap.named("seq.commit")] == [1, 2]
+
+
+@pytest.mark.parametrize("with_aux", [False, True])
+def test_the_scorers_histograms_are_fed_from_the_phases_clock_reads(
+        with_aux, aux_family):
+    """Also where ``seq.wait`` has children: ``seq_dispatch_seconds`` is
+    enqueue + the whole wait, the copy-out and the tap inside it."""
+    reg = Registry()
+    scorer = aux_scorer(aux_family, reg) if with_aux else tiny_scorer(reg)
     sink = SpanSink(sample=1.0)
     tr = Tracer(Registry(), sink=sink)
     batches = [rows(40, seed=1), rows(7, seed=2), rows(16, seed=3)]
@@ -283,3 +484,9 @@ def test_the_scorers_histograms_are_fed_from_the_phases_clock_reads():
     assert sum(d["name"] == "seq.score" for d in spans) == len(batches)
     assert total("seq.score") >= (assembly.sum() + dispatch.sum()
                                   + total("seq.commit"))
+    by_id = {d["span_id"]: d for d in spans}
+    children = [d for d in spans if d["name"] in ("seq.fetch", "seq.tap")]
+    assert len(children) == (2 * 5 if with_aux else 0)  # 3 + 1 + 1 waits
+    for d in children:  # each a child span of its seq.wait
+        assert by_id[d["parent_id"]]["name"] == "seq.wait"
+    assert total("seq.fetch", "seq.tap") <= total("seq.wait")
