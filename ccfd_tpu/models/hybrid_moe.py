@@ -39,10 +39,16 @@ experts are left out (and counted: ``pairs_absent``) and the partial sum
 goes on; on one chip the layer runs without its exchange and nothing
 stands in for the absent chips.
 **Dropless**: the (token, held expert) pairs are sorted by expert and each
-expert's group is cut into tiles of ``MOE_TILE`` rows; a loop whose trip
-count is the number of tiles the batch really has multiplies each tile with
-its expert's matrices. No capacity factor, no pair dropped, work in
-proportion to the pairs served.
+expert's group is cut into row tiles, so a tile belongs to one expert.
+Where hidden and expert width fill whole 128-lane tiles, on one device, the
+tiles are multiplied by the grouped-matmul kernels of
+``ops/grouped_experts.py``, which address the stacked matrices in place by
+each tile's expert and keep an expert's block on the chip across its
+tiles, ``MOE_CHUNK`` rows a call; every other shape, and a mesh, keep the
+plain loop, whose trip count is the number of tiles the batch really has
+and whose trip cuts one expert's matrices out of the stack for ``MOE_TILE``
+rows (``held_experts`` chooses while the program is traced). No capacity
+factor, no pair dropped, work in proportion to the pairs served.
 
 **Padding.** ``filled`` (B,) is each row's count of real records
 (right-aligned, as ``StagingBatch`` stages them). Positions count from a
@@ -79,7 +85,7 @@ from typing import Any, Mapping
 import jax
 import jax.numpy as jnp
 
-from ccfd_tpu.ops import causal_attention
+from ccfd_tpu.ops import causal_attention, grouped_experts
 
 Params = Mapping[str, Any]
 
@@ -94,7 +100,8 @@ KDA_PRECISION = jax.lax.Precision.HIGH
 KDA_INSIDE = jax.lax.Precision.DEFAULT
 L2_EPS = 1e-6
 MASKED = causal_attention.MASKED  # a key that is padding or after the query
-MOE_TILE = 256  # rows of one expert's group multiplied at a time
+MOE_TILE = 256  # rows of one expert's group a trip of the plain loop multiplies
+MOE_CHUNK = 4096  # rows the expert kernels are handed at a time
 KDA_SUB = 16  # kda_lower_bound * KDA_SUB must stay inside float32's exponent
 PLAIN_QUERY_BLOCKS = 4  # query blocks a row on the plain causal-attention path
 
@@ -829,22 +836,44 @@ def route_carried(p, z, r, real, cfg: HybridConfig):
 
 
 def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
-                 tile: int = MOE_TILE):
+                 tile: int | None = None):
     """The held experts' part of the layer for tokens ``z`` (N, hidden):
     ``(y (N, hidden) float32, pairs (held,) int32, served int32)``.
 
     Every (token, slot) whose expert is held is a pair; pairs are sorted
     by expert (stable, so a group keeps token order) and each expert's
-    group is cut into tiles of ``tile`` rows, the last one part empty. The
-    loop runs once per tile that exists: gather the tile's tokens, the
-    expert's SwiGLU, and write the rows where the tile stands in a buffer
-    laid out tile after tile. After the loop every token adds up its own
-    slots' rows, scaled by the routing weights (a gather: on this device a
-    row-wise scatter-add into the result costs several times as much).
-    ``served`` counts the pairs the loop multiplied; it equals
-    ``pairs.sum()`` because no pair is dropped."""
+    group is cut into tiles of ``tile`` rows, the last one part empty; the
+    tiles' rows stand in a buffer laid out tile after tile. Two bodies fill
+    it, one mathematics at one precision, chosen while the program is
+    traced from the experts' widths, the dtype, the backend and where the
+    weights lie (``ops/grouped_experts.py::kernel_fits``):
+
+    - where hidden and expert width fill whole 128-lane tiles, on one
+      device: the Pallas kernels of ``ops/grouped_experts.py``, a grouped
+      matmul over the sorted rows that addresses the stacked matrices in
+      place and keeps an expert's block on the chip across its tiles. The
+      rows are gathered and multiplied ``MOE_CHUNK`` at a time (a loop
+      whose trip count is the number of chunks that hold a pair), so the
+      gathered tokens and the gated products take a chunk's memory and not
+      the buffer's; ``tile`` comes from the pairs an expert expects
+      (``row_tile``);
+    - every other shape, and a mesh: the plain loop, which runs once per
+      tile that exists, gathers the tile's tokens, cuts the expert's three
+      matrices out of the stack and writes the expert's SwiGLU of the rows
+      (``tile`` = ``MOE_TILE``). It is the definition the tests hold the
+      kernels against.
+
+    After either every token adds up its own slots' rows, scaled by the
+    routing weights (a gather: on this device a row-wise scatter-add into
+    the result costs several times as much). ``served`` counts the pairs
+    of the tiles that were multiplied; it equals ``pairs.sum()`` because
+    no pair is dropped."""
     n, k = chosen.shape
     held = cfg.held_count
+    kernel = grouped_experts.kernel_fits(ex["gate"], dtype)
+    if tile is None:
+        tile = grouped_experts.row_tile(n * k / cfg.routed) if kernel \
+            else MOE_TILE
     local = chosen - cfg.held_first
     mine = (local >= 0) & (local < held)
     group = jnp.where(mine, local, held).reshape(-1)  # ``held``: not here
@@ -856,7 +885,8 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
     first_tile = tile_end - tiles
     first_pair = jnp.cumsum(pairs) - pairs
     # by tile: its expert, and where its pairs start in ``order``
-    most = (n * k + tile - 1) // tile + held
+    chunk = max(MOE_CHUNK // tile, 1) if kernel else 1  # tiles a trip
+    most = -(-((n * k + tile - 1) // tile + held) // chunk) * chunk
     expert_of = jnp.minimum(jnp.searchsorted(
         tile_end, jnp.arange(most), side="right"), held - 1).astype(jnp.int32)
     inside = (jnp.arange(most, dtype=jnp.int32) - first_tile[expert_of]) * tile
@@ -865,21 +895,36 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
     order_padded = jnp.pad(order, (0, tile))
     zc = z.astype(dtype)
 
-    def one_tile(j, carry):
-        rows, served = carry
-        e = expert_of[j]
+    def one_tile(j, rows):
         slot = jax.lax.dynamic_slice(order_padded, (start_of[j],), (tile,))
         part = _swiglu({name: jax.lax.dynamic_index_in_dim(
-            ex[name], e, 0, keepdims=False) for name in ("gate", "up", "down")},
-            zc[slot // k], dtype)
-        rows = jax.lax.dynamic_update_slice(rows, part.astype(dtype),
+            ex[name], expert_of[j], 0, keepdims=False)
+            for name in ("gate", "up", "down")}, zc[slot // k], dtype)
+        return jax.lax.dynamic_update_slice(rows, part.astype(dtype),
                                             (j * tile, 0))
-        return rows, served + live_of[j]
 
-    rows, served = jax.lax.fori_loop(
-        0, tile_end[-1], one_tile,
-        (jnp.zeros((most * tile, z.shape[-1]), dtype),
-         jnp.zeros((), jnp.int32)))
+    def one_chunk(c, rows):
+        first = c * chunk
+        slot = jax.vmap(lambda start: jax.lax.dynamic_slice(
+            order_padded, (start,), (tile,)))(jax.lax.dynamic_slice(
+                start_of, (first,), (chunk,))).reshape(-1)
+        return grouped_experts.grouped_swiglu(
+            zc[slot // k], ex["gate"], ex["up"], ex["down"],
+            jax.lax.dynamic_slice(expert_of, (first,), (chunk,)),
+            jax.lax.dynamic_slice(live_of, (first,), (chunk,)),
+            jnp.minimum(tile_end[-1] - first, chunk), rows, first, tile=tile,
+            interpret=jax.default_backend() != "tpu")
+
+    shape = (most * tile, z.shape[-1])
+    if kernel:  # only rows a kernel wrote are read: no buffer of zeros
+        rows = jax.lax.fori_loop(
+            0, (tile_end[-1] + chunk - 1) // chunk, one_chunk,
+            grouped_experts.uninitialised(shape, dtype))
+    else:
+        rows = jax.lax.fori_loop(0, tile_end[-1], one_tile,
+                                 jnp.zeros(shape, dtype))
+    served = jnp.sum(jnp.where(jnp.arange(most) < tile_end[-1], live_of, 0),
+                     dtype=jnp.int32)
     # where each (token, slot) stands in ``rows``: its rank among the
     # sorted pairs, moved by the empty ends of the groups before it
     rank = jnp.argsort(order).astype(jnp.int32)  # the inverse permutation

@@ -161,13 +161,19 @@ def _equations(jaxpr):
                     yield from _equations(inner)
 
 
+def kernels_of(program, *args) -> frozenset:
+    """The names of the Pallas kernels in the program that
+    ``program(*args)`` traces to: its jaxpr is read, so the answer is what
+    the trace chose and not a second reckoning of it. ``args`` may be
+    shapes (``jax.ShapeDtypeStruct``); for a jitted program traced at them
+    before, this costs a look-up in its trace cache."""
+    return frozenset(
+        eqn.params.get("name")
+        for eqn in _equations(jax.make_jaxpr(program)(*args).jaxpr)
+        if eqn.primitive.name == "pallas_call")
+
+
 def held_by(program, *args, names: tuple = (KERNEL,)) -> bool:
-    """Whether the program that ``program(*args)`` traces to holds a kernel
-    of one of these names (this module's; a caller adds the other
-    families'): its jaxpr is read, so the answer is what the trace chose
-    and not a second reckoning of it. ``args`` may be shapes
-    (``jax.ShapeDtypeStruct``); for a jitted program traced at them before,
-    this costs a look-up in its trace cache."""
-    return any(
-        eqn.primitive.name == "pallas_call" and eqn.params.get("name") in names
-        for eqn in _equations(jax.make_jaxpr(program)(*args).jaxpr))
+    """Whether :func:`kernels_of` the program holds a kernel of one of
+    these names (this module's; a caller adds the other families')."""
+    return not kernels_of(program, *args).isdisjoint(names)

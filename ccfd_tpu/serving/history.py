@@ -657,11 +657,13 @@ class _Program:
     **The trace.** Whether the program's attention holds a kernel that
     keeps the scores on the chip (``seq`` / ``seq_q8``'s full-attention
     block: ``ops/seq_attention.py``; ``hybrid_moe``'s causal attention:
-    ``ops/causal_attention.py``), asked at the shape that is dispatched.
-    Read the first time it is asked (a look-up in the jit's trace cache
-    once the executable has run), from the memo
-    afterwards; a swap to another variant builds another program, so the
-    memo never outlives what it describes."""
+    ``ops/causal_attention.py``) and whether its held experts multiply
+    through the grouped kernels (``ops/grouped_experts.py``), asked at the
+    shape that is dispatched. The program's jaxpr is read the first time
+    either is asked (a look-up in the jit's trace cache once the
+    executable has run), the memo afterwards; a swap to another variant
+    builds another program, so the memo never outlives what it
+    describes."""
 
     __slots__ = ("fn", "flat", "reads_filled", "num_features", "_held")
 
@@ -687,11 +689,16 @@ class _Program:
         return self.fn(params, hist, *extra)
 
     def holds_attn_kernel(self, params: Any, lb: int, b: int) -> bool:
+        return self.kernels_held(params, lb, b)[0]
+
+    def kernels_held(self, params: Any, lb: int, b: int) -> tuple[bool, bool]:
+        """(attention kernel, expert kernels) of the (lb, b) executable."""
         got = self._held.get((lb, b))
         if got is None:
             import jax
 
-            from ccfd_tpu.ops import causal_attention, seq_attention
+            from ccfd_tpu.ops import (causal_attention, grouped_experts,
+                                      seq_attention)
 
             shape = jax.ShapeDtypeStruct
             extra = (shape((b,), np.int32),) if self.reads_filled else ()
@@ -699,9 +706,12 @@ class _Program:
                                      // _WIRE_LANES, _WIRE_LANES))
                         if self.flat_wire(lb)
                         else (self.fn, (b, lb, self.num_features)))
-            got = self._held[(lb, b)] = seq_attention.held_by(
-                fn, params, shape(hist, np.float32), *extra,
-                names=(seq_attention.KERNEL, causal_attention.KERNEL))
+            held = seq_attention.kernels_of(
+                fn, params, shape(hist, np.float32), *extra)
+            got = self._held[(lb, b)] = (
+                not held.isdisjoint((seq_attention.KERNEL,
+                                     causal_attention.KERNEL)),
+                not held.isdisjoint(grouped_experts.KERNELS))
         return got
 
 
@@ -717,11 +727,13 @@ def _behind_flat_wire(fn: Any, num_features: int):
     return jax.jit(flat_wire)
 
 
-def _holds_attn_kernel(apply_fn: Any, params: Any, lb: int, b: int) -> bool:
-    """``_Program.holds_attn_kernel``; a stand-in for the program (a test's
-    or a drill's gate around it) has no trace to read and holds none."""
-    holds = getattr(apply_fn, "holds_attn_kernel", None)
-    return holds is not None and holds(params, lb, b)
+def _kernels_held(apply_fn: Any, params: Any, lb: int,
+                  b: int) -> tuple[bool, bool]:
+    """``_Program.kernels_held``: (attention kernel, expert kernels); a
+    stand-in for the program (a test's or a drill's gate around it) has no
+    trace to read and holds none."""
+    held = getattr(apply_fn, "kernels_held", None)
+    return held(params, lb, b) if held is not None else (False, False)
 
 
 def _takes_flat_wire(apply_fn: Any, lb: int) -> bool:
@@ -923,7 +935,7 @@ class SeqScorer:
         self._g_customers = None
         self._h_assembly = self._h_dispatch = None
         self._c_bucket = self._c_bucket_rows = self._c_attn_kernel = None
-        self._c_flat_wire = None
+        self._c_expert_kernel = self._c_flat_wire = None
         self._g_inflight = self._c_anon = self._c_stale = None
         self._c_overlapped = None
         self._c_swap_refused = None
@@ -955,6 +967,13 @@ class SeqScorer:
                 "seq dispatches of executables whose attention holds a "
                 "kernel that keeps the scores on the chip (beside "
                 "seq_bucket_dispatch_total: the rest attended through XLA)",
+            )
+            self._c_expert_kernel = registry.counter(
+                "seq_expert_kernel_dispatch_total",
+                "seq dispatches of executables whose held experts multiply "
+                "through the grouped-matmul kernels (beside "
+                "seq_bucket_dispatch_total: the rest looped over tiles "
+                "through XLA, or have no experts)",
             )
             self._c_flat_wire = registry.counter(
                 "seq_flat_wire_dispatch_total",
@@ -1204,7 +1223,8 @@ class SeqScorer:
 
     def executable_grid(self) -> dict:
         """The (L, B) executable grid with per-executable dispatch counts,
-        whether the executable's attention is a kernel and whether
+        whether the executable's attention is a kernel, whether its held
+        experts multiply through the grouped kernels and whether
         its history batch crosses flat — the seq family's entry in the
         device telemetry inventory."""
         with self._params_lock:
@@ -1212,10 +1232,12 @@ class SeqScorer:
         grid = []
         for lb in self.len_buckets:
             for b in self.batch_sizes:
+                attn_kernel, expert_kernel = _kernels_held(
+                    apply_fn, params, lb, b)
                 entry: dict = {
                     "l_bucket": int(lb), "b_bucket": int(b),
-                    "attn_kernel": _holds_attn_kernel(
-                        apply_fn, params, lb, b),
+                    "attn_kernel": attn_kernel,
+                    "expert_kernel": expert_kernel,
                     "flat_wire": _takes_flat_wire(apply_fn, lb)}
                 if self._c_bucket is not None:
                     entry["dispatches"] = int(self._c_bucket.value(
@@ -1535,13 +1557,14 @@ class SeqScorer:
                         ph.set(rows=m, b_bucket=bucket,
                                padded_rows=bucket - m)
                     batch.t_asm += ph.seconds
-                    attn_kernel = _holds_attn_kernel(
+                    attn_kernel, expert_kernel = _kernels_held(
                         apply_fn, params, lb, bucket)
                     flat_wire = _takes_flat_wire(apply_fn, lb)
                     with phase("seq.enqueue", bytes=sub.nbytes,
                                b_bucket=bucket, l_bucket=lb,
                                tokens=tokens,
                                attn_kernel=int(attn_kernel),
+                               expert_kernel=int(expert_kernel),
                                flat_wire=int(flat_wire)) as ph:
                         # device-fault dispatch seam (runtime/faults.py):
                         # device_hang / compile_stall drill the heal ladder
@@ -1560,6 +1583,8 @@ class SeqScorer:
                             "l_bucket": str(lb), "b_bucket": str(bucket)})
                         if attn_kernel:
                             self._c_attn_kernel.inc()
+                        if expert_kernel:
+                            self._c_expert_kernel.inc()
                         if flat_wire:
                             self._c_flat_wire.inc()
                         self._c_bucket_rows.inc(

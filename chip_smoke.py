@@ -43,6 +43,7 @@ Times printed here are set-up and wall times for the record, not metrics.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import json
 import os
@@ -454,6 +455,45 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
                 np.asarray(hybrid_moe._plain_causal_attention(
                     q, k, v, real, 0.09, jnp.bfloat16), np.float32) * keep,
                 0.04)}
+
+    # the family's held experts at widths the small presets lack (hidden
+    # 256, experts 384 wide; three pairs in four another chip's): Mosaic
+    # compiles the grouped kernels (ops/grouped_experts.py), and the
+    # device's answer is the plain tile loop's with the same counts
+    from ccfd_tpu.ops import grouped_experts
+
+    e_cfg = dataclasses.replace(m_cfg, held_first=8, held_count=8, routed=32,
+                                per_token=4)
+    ex = {name: jnp.asarray(rng.normal(size=shape) / shape[1] ** 0.5,
+                            jnp.bfloat16)
+          for name, shape in (("gate", (8, 256, 384)), ("up", (8, 256, 384)),
+                              ("down", (8, 384, 256)))}
+    tokens = jnp.asarray(rng.normal(size=(3000, 256)), jnp.float32)
+    chosen = jnp.asarray(np.stack([rng.permutation(32)[:4]
+                                   for _ in range(3000)]), jnp.int32)
+    weight = jnp.asarray(rng.uniform(0.1, 1.0, size=(3000, 4)), jnp.float32)
+
+    def experts(ex, tokens, chosen, weight):
+        return hybrid_moe.held_experts(ex, tokens, chosen, weight, e_cfg,
+                                       jnp.bfloat16)
+
+    check("hybrid_moe held experts: the program holds the kernels",
+          seq_attention.kernels_of(experts, ex, tokens, chosen, weight)
+          == frozenset(grouped_experts.KERNELS))
+    y, pairs, served = jax.jit(experts)(ex, tokens, chosen, weight)
+    fits, grouped_experts.kernel_fits = (grouped_experts.kernel_fits,
+                                         lambda *_: False)
+    try:
+        want, want_pairs, want_served = jax.jit(experts)(
+            ex, tokens, chosen, weight)
+    finally:
+        grouped_experts.kernel_fits = fits
+    check("hybrid_moe held experts: pairs and served as the loop counts "
+          "them", np.array_equal(pairs, want_pairs)
+          and int(served) == int(want_served) == int(pairs.sum()))
+    zoo["grouped_experts"] = {"max_abs_diff": check.close(
+        "hybrid_moe held experts: kernels vs tile loop", np.asarray(y),
+        np.asarray(want), 0.04)}
 
     # the fused-decision grid over the flagship: score + threshold + rules
     # in one executable per bucket, against the staged seam

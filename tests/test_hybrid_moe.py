@@ -16,6 +16,7 @@ from benchmark.reference import hybrid_moe_f32 as ref
 from benchmark.reference import table
 from ccfd_tpu.models import hybrid_moe as hm
 from ccfd_tpu.models import registry
+from ccfd_tpu.ops import grouped_experts, seq_attention
 from ccfd_tpu.serving.history import SeqScorer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -122,12 +123,25 @@ def test_each_layer_kind_agrees_with_the_reference(small, params, kind,
                        atol=2e-4, rtol=2e-4)
 
 
-def test_slice_logits_agree_at_every_position(small, params, rows):
+@pytest.mark.parametrize("experts", ["small", "lane_wide"])
+def test_slice_logits_agree_at_every_position(small, params, rows, experts):
+    """The whole model against the reference; ``lane_wide``: hidden and
+    expert widths of 128, so the held experts multiply through the
+    grouped kernels (``ops/grouped_experts.py``, interpreted here) where
+    the small preset's 64 x 32 keep the tile loop, one layer of each kind."""
+    if experts == "lane_wide":
+        small, params = _three_layers(small, params)
+        small = dict(small, hidden_size=128, moe_intermediate_size=128)
+        params = ref.make_params(small)
+    cfg = hm.HybridConfig.from_dict(small)
     hist, filled = _windows(rows, [8, 3, 1], 8)
     want, want_pairs = ref.forward(params, small, hist, filled,
                                    every_position=True)
-    got, aux = hm.logits_everywhere(
-        params, hist, filled, hm.HybridConfig.from_dict(small), F32)
+    assert seq_attention.held_by(
+        lambda p, h, f: hm.logits_everywhere(p, h, f, cfg, F32), params,
+        hist, filled, names=grouped_experts.KERNELS) == (
+            experts == "lane_wide")
+    got, aux = hm.logits_everywhere(params, hist, filled, cfg, F32)
     real = np.asarray(ref.real_tokens(jnp.asarray(filled), 8, 30))
     assert np.abs(np.asarray(got) - np.asarray(want))[real].max() < 2e-3
     assert np.array_equal(np.asarray(aux["pairs"]).sum(1), want_pairs)
@@ -135,7 +149,8 @@ def test_slice_logits_agree_at_every_position(small, params, rows):
     # bfloat16 serving stays near it (a token near a tie may choose another
     # expert, so the widest gap is wide; the mean is not): one layer of
     # each kind
-    model, fewer = _three_layers(small, params)
+    model, fewer = (small, params) if experts == "lane_wide" \
+        else _three_layers(small, params)
     want, _ = ref.forward(fewer, model, hist, filled, every_position=True)
     served, _ = hm.logits_everywhere(
         fewer, hist, filled, hm.HybridConfig.from_dict(model), jnp.bfloat16)

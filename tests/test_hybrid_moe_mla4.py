@@ -21,6 +21,7 @@ import pytest
 from benchmark.reference import mla_moe_f32 as ref
 from benchmark.reference import table
 from ccfd_tpu.models import hybrid_moe as hm
+from ccfd_tpu.ops import grouped_experts, seq_attention
 from ccfd_tpu.models import registry
 from ccfd_tpu.serving.history import SeqScorer
 
@@ -89,9 +90,20 @@ def _with_mla(cfg, **changes):
     (jnp.bfloat16, None, 0.05),  # as served: a token near a tie may choose
     # another expert, so the widest gap is wide; the mean is not
 ])
+@pytest.mark.parametrize("experts", ["small", "lane_wide"])
 def test_logits_agree_with_the_reference_at_every_position(
-        small, params, cfg, rows, dtype, worst, mean):
+        small, params, cfg, rows, dtype, worst, mean, experts):
+    """``lane_wide``: hidden and expert widths of 128, so the held experts
+    multiply through the grouped kernels (``ops/grouped_experts.py``,
+    interpreted here); the small preset's 64 x 32 keep the tile loop."""
+    if experts == "lane_wide":
+        small = dict(small, hidden_size=128, moe_intermediate_size=128)
+        params, cfg = ref.make_params(small), hm.HybridConfig.from_dict(small)
     hist, filled = _windows(rows, [8, 3, 1])
+    assert seq_attention.held_by(
+        lambda p, h, f: hm.logits_everywhere(p, h, f, cfg, dtype), params,
+        hist, filled, names=grouped_experts.KERNELS) == (
+            experts == "lane_wide")
     want, want_choice = ref.forward(params, small, hist, filled,
                                     every_position=True)
     with jax.default_matmul_precision("highest"):
