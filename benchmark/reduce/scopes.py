@@ -33,13 +33,11 @@ import os
 import re
 
 from benchmark.reduce import host_spans
-from benchmark.reduce.trace import DEVICE_PLANE
+from benchmark.reduce.trace import CONTAINER, DEVICE_PLANE
 
 MODULE_LINE = "XLA Modules"
 METADATA_PLANE = "/host:metadata"
 _INSTRUCTION = re.compile(r"^%([\w.\-]+)")
-# an operation that only holds others (its event spans theirs): not work
-_CONTAINER = re.compile(r"[\s)}\]](while|call|conditional)\(")
 
 
 # -- protobuf wire format ------------------------------------------------------
@@ -179,7 +177,7 @@ def load(path: str, op_line: str = "XLA Ops") -> ScopedCapture:
             if op_name is None:
                 m = _INSTRUCTION.match(name)
                 op_name = scope_of[name] = (
-                    "<container>" if _CONTAINER.search(name)
+                    "<container>" if CONTAINER.search(name)
                     else op_names.get(m.group(1) if m else name, ""))
             if op_name == "<container>":
                 continue

@@ -1,5 +1,6 @@
 """From a profiler trace to the device's numbers: busy and idle time,
-kernel time, the operations that took most time, the idle gaps by what the
+kernel time, the operations that took most time (containers left out: a
+``while`` holds the operations listed beside it), the idle gaps by what the
 host was doing, and a kernel's share of its roofline.
 
 The JAX profiler writes ``<dir>/plugins/profile/<time>/<host>.xplane.pb``;
@@ -27,6 +28,10 @@ import os
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+# an operation that only holds others (a scan, a tile loop: its event spans
+# its body's events on the same line): its time is its children's, not work
+CONTAINER = re.compile(r"[\s)}\]](while|call|conditional)\(")
+CHILDLESS = "container without children in the capture: "  # a name's mark
 PEAKS_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "peaks.json")
 
@@ -109,12 +114,16 @@ class TraceSummary:
     window_s: float  # the traced window, first event to last, all planes
     busy_s: float  # union of device operations, mean over the chips
     n_devices: int
-    op_seconds: dict  # device operation -> summed seconds, all chips
+    # device operation -> summed seconds, all chips; a container whose
+    # children the capture lists is in ``container_seconds`` instead, one
+    # whose children it does not list stays here under a marked name
+    op_seconds: dict
     kernel_s: float  # summed seconds of the operations matching the patterns
     kernel_events: int
     span_rows: int  # rows of the score spans inside the trace
     span_count: int
     gap_seconds: dict  # what the host was doing -> idle seconds
+    container_seconds: dict = dataclasses.field(default_factory=dict)
 
     @property
     def idle_share_pct(self) -> float:
@@ -142,6 +151,8 @@ def reduce(planes: dict, *, op_line: str, kernel_patterns: list[str],
     hi = max(e.end_ns for e in every)
     pats = [re.compile(p) for p in kernel_patterns]
     op_seconds: dict = {}
+    container_seconds: dict = {}
+    named: dict = {}  # event name -> (short name, container, kernel)
     busy = []
     kernel_s, kernel_events = 0.0, 0
     busy_intervals: list[tuple[float, float]] = []
@@ -150,10 +161,23 @@ def reduce(planes: dict, *, op_line: str, kernel_patterns: list[str],
         ivals = [(e.start_ns, e.end_ns) for e in ops]
         busy.append(union_s(ivals))
         busy_intervals.extend(ivals)
-        for e in ops:
-            key = short_name(e.name)
-            op_seconds[key] = op_seconds.get(key, 0.0) + e.dur_ns / 1e9
-            if any(p.search(e.name) for p in pats):
+        # a container starts no later than its children and ends no
+        # earlier: in this order the event after it is its first child
+        ops = sorted(ops, key=lambda e: (e.start_ns, -e.dur_ns))
+        for at, e in enumerate(ops):
+            if e.name not in named:  # few names, many events
+                named[e.name] = (short_name(e.name),
+                                 bool(CONTAINER.search(e.name)),
+                                 any(p.search(e.name) for p in pats))
+            key, container, kernel = named[e.name]
+            into = op_seconds
+            if container:
+                if at + 1 < len(ops) and ops[at + 1].start_ns < e.end_ns:
+                    into = container_seconds
+                else:
+                    key = CHILDLESS + key
+            into[key] = into.get(key, 0.0) + e.dur_ns / 1e9
+            if kernel:
                 kernel_s += e.dur_ns / 1e9
                 kernel_events += 1
     if not busy_intervals:
@@ -181,7 +205,7 @@ def reduce(planes: dict, *, op_line: str, kernel_patterns: list[str],
         window_s=window_s, busy_s=busy_s,
         n_devices=len(device), op_seconds=op_seconds, kernel_s=kernel_s,
         kernel_events=kernel_events, span_rows=rows, span_count=len(spans),
-        gap_seconds=gap_seconds)
+        gap_seconds=gap_seconds, container_seconds=container_seconds)
 
 
 def peaks_of(device_kind: str) -> dict:

@@ -55,17 +55,49 @@ WANT = {
 }
 
 
-def _read(metric: str, obs: dict):
+def _read(metric: str, obs: dict, **override):
     with open(os.path.join(ROOT, "benchmark", "layer_metrics",
                            metric + ".json")) as f:
         doc = json.load(f)
     return manifest.load_kind("readers", doc["reader"]).read(
-        obs, doc["args"])
+        obs, dict(doc["args"], **override))
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS)
 def test_each_new_metric_gives_the_number_worked_out_by_hand(metric):
-    assert _read(metric, OBS) == pytest.approx(WANT[metric], rel=1e-9)
+    # the recording's microseconds stand for a run's milliseconds: its
+    # gathers are held against no clock step
+    override = ({"cpu_clock_step_ms": 0.0}
+                if metric == "gather_offcpu_pct.sat" else {})
+    assert _read(metric, OBS, **override) == pytest.approx(
+        WANT[metric], rel=1e-9)
+
+
+@pytest.mark.parametrize("wall_ns,cpu_ns,want", [
+    # the ledger's zaya1_* reading (PR 39): 1.9 x as much CPU time as wall
+    # time is charged, a raw share of -90.21: a share reads 0
+    (20.0e6, 38.042e6, 0.0),
+    # a slice's gathers of 1.4 ms against a clock that steps by 10 ms
+    (1.4e6, 0.0, None), (1.4e6, 10.0e6, None),
+    (430e6, 315e6, 100 * (1 - 315 / 430)), (20.0e6, 0.0, 100.0),
+    (0.0, 0.0, None)])
+def test_the_offcpu_share_is_a_share_and_needs_a_step_of_the_cpu_clock(
+        wall_ns, cpu_ns, want):
+    got = span_offcpu.share_pct(wall_ns, cpu_ns, 10.0e6)
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+def test_the_gathers_metric_states_the_clocks_step_and_its_cells():
+    """With its own file's step the recording's 430 us of gathers read
+    nothing; it is listed where a gather takes milliseconds a batch."""
+    assert _read("gather_offcpu_pct.sat", OBS) is None
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"]
+                     if m["name"] == "gather_offcpu_pct.sat")
+    assert {"history_saturated", "history_sparse_saturated"} <= set(
+        entry["workloads"])
+    assert not {"ling3_window_saturated", "zaya1_window_saturated",
+                "mistral4_window_saturated"} & set(entry["workloads"])
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS)

@@ -33,8 +33,9 @@ def _real_config():
 
 # -- the manifest ------------------------------------------------------------------
 
+@benchmark_manifests.manifest_level
 def test_the_manifest_resolves_the_cell_with_every_file_it_names():
-    cell = manifest.Manifest(ROOT).resolve(CELL)
+    cell = benchmark_manifests.repo_manifest().resolve(CELL)
     assert cell.chips == 1 and cell.deployment_kind == "kafka_history_lm"
     assert cell.generator_kind == "bus"
     assert {m.name for m in cell.end_to_end} == {"tx_s", "setup_s"}
@@ -42,8 +43,14 @@ def test_the_manifest_resolves_the_cell_with_every_file_it_names():
     assert {"backbone_roofline.sat", "expert_roofline.sat",
             "kda_roofline.sat", "mla_roofline.sat", "moe_device_share.sat",
             "pairs_per_token.sat", "expert_load_max_over_mean.sat",
-            "device_idle.sat", "idle_wait_pct.sat"} <= reported
-    assert "kernel_roofline.sat" not in reported
+            "device_idle.sat", "idle_wait_pct.sat", "period_ms.sat",
+            "fetch_ms.sat", "idle_fetch_pct.sat"} <= reported
+    # what is another model's alone, or reads nothing here, stays away
+    assert not reported & {"kernel_roofline.sat", "cca_roofline.sat",
+                           "cca_device_share.sat", "skip_share.sat",
+                           "router_device_share.sat", "mla_device_share.sat",
+                           "absent_pairs_per_token.sat",
+                           "gather_offcpu_pct.sat"}
     for m in cell.per_layer:  # every reader a metric's file names is there
         manifest.load_kind("readers", cell.metric_docs[m.name]["reader"])
     for kind in ("deployments", "reference"):

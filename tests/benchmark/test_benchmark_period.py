@@ -10,15 +10,17 @@ by hand from the events listed at the top of
 ``benchmark/reduce/fixtures/period_three_batches.textproto`` (microseconds
 of a 1000 us slice; two whole periods, 50 to 650).
 
-The seven metrics' manifest entries wait in ``period_entries.json`` beside
-this file (its ``what`` says why): the cells are resolved here through
-``BENCHMARK.json`` with those entries appended."""
+The seven metrics are entries of the repo's ``BENCHMARK.json`` since PR
+40, each with the list of its cells: the cells are resolved here through
+it."""
 
+import dataclasses
 import json
 import os
 
 import pytest
 
+import benchmark_manifests
 from benchmark.harness import core, manifest
 from benchmark.readers import phase_mean, worker_period
 from benchmark.reduce import host_spans, trace
@@ -53,7 +55,7 @@ WANT = {
 NEW_METRICS = list(WANT)
 NEW_READERS = NEW_METRICS[:5]  # the last two are data for readers of PR 24
 IDLE = ["idle_assembly_pct.sat", "idle_enqueue_pct.sat", "idle_wait_pct.sat",
-        "idle_other_pct.sat", "idle_starved_pct.sat"]
+        "idle_fetch_pct.sat", "idle_other_pct.sat", "idle_starved_pct.sat"]
 CELLS = ["history_saturated", "history_sparse_saturated",
          "ling3_window_saturated", "zaya1_window_saturated",
          "mistral4_window_saturated"]
@@ -75,26 +77,6 @@ def _read(metric: str, obs: dict):
     doc = _doc(metric)
     return manifest.load_kind("readers", doc["reader"]).read(
         obs, doc["args"])
-
-
-def _entries() -> list[dict]:
-    with open(os.path.join(HERE, "period_entries.json")) as f:
-        return json.load(f)["per_layer"]
-
-
-@pytest.fixture(scope="module")
-def man(tmp_path_factory):
-    """``BENCHMARK.json`` with the seven entries appended, over the repo's
-    ``benchmark/``."""
-    tmp = str(tmp_path_factory.mktemp("period"))
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    doc["per_layer"] += _entries()
-    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
-        json.dump(doc, f)
-    os.symlink(os.path.join(ROOT, "benchmark"),
-               os.path.join(tmp, "benchmark"))
-    return manifest.Manifest(tmp)
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS)
@@ -162,7 +144,7 @@ def test_the_programs_idle_ns_agrees_with_the_gap(capsys):
         "route 0.018 commit 0.002 unowned 0.002 | pairs 2"]
 
 
-def test_the_five_idle_shares_still_add_up_with_the_nested_phases():
+def test_the_six_idle_shares_add_up_with_the_nested_phases():
     planes = trace.load(OBS["capture"], "bench.score")
     summary = trace.reduce(planes, op_line="XLA Ops", kernel_patterns=[".*"])
     assert summary.idle_share_pct == pytest.approx(11.5)
@@ -170,11 +152,13 @@ def test_the_five_idle_shares_still_add_up_with_the_nested_phases():
     assert sum(shares.values()) == pytest.approx(summary.idle_share_pct,
                                                  rel=1e-9)
     # seq.wait innermost: 225-230, 520-530, 820-835 and, after the tap,
-    # 870-875; the copy-out and the tap count under "other", beside
-    # seq.score 875-878 and seq.commit 878-880
+    # 870-875; the copy-out and the tap are counted once, under their own
+    # name: "other" keeps seq.score 875-878 and seq.commit 878-880
     assert shares["idle_wait_pct.sat"] == pytest.approx(3.5)
-    assert shares["idle_other_pct.sat"] == pytest.approx(7.5 + 0.5)
-    assert _read("idle_fetch_pct.sat", OBS) <= shares["idle_other_pct.sat"]
+    assert shares["idle_fetch_pct.sat"] == pytest.approx(7.5)
+    assert shares["idle_other_pct.sat"] == pytest.approx(0.5)
+    assert {"seq.fetch", "seq.tap"} <= set(
+        _doc("idle_other_pct.sat")["args"]["except"])
 
 
 def test_a_loop_line_that_is_not_closed_is_not_read():
@@ -186,8 +170,9 @@ def test_a_loop_line_that_is_not_closed_is_not_read():
 
 
 @pytest.mark.parametrize("cell", CELLS)
-def test_every_cell_resolves_with_the_new_metrics_where_listed(man, cell):
-    resolved = man.resolve(cell)
+@benchmark_manifests.manifest_level
+def test_every_cell_resolves_with_the_new_metrics_where_listed(cell):
+    resolved = benchmark_manifests.repo_manifest().resolve(cell)
     got = {m.name for m in resolved.per_layer}
     assert set(NEW_READERS) <= got
     copy_out = {"fetch_ms.sat", "idle_fetch_pct.sat"}
@@ -198,33 +183,39 @@ def test_every_cell_resolves_with_the_new_metrics_where_listed(man, cell):
             "readers", resolved.metric_docs[name]["reader"]).read)
 
 
-def test_the_entries_are_for_the_end_of_the_list_and_change_nothing(man):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        repo = json.load(f)
-    entries = _entries()
-    assert [e["name"] for e in entries] == NEW_METRICS
-    assert man.doc["per_layer"][:len(repo["per_layer"])] == repo["per_layer"]
-    layers = {m["layer"] for m in repo["per_layer"]}
-    for e in entries:
+@benchmark_manifests.manifest_level
+def test_the_seven_entries_are_listed_each_with_its_cells():
+    """By name, wherever in the list they stand. None leaves its cells
+    open: an entry without a list would have to be reported by every later
+    cell that reports ``tx_s``, whatever its program marks."""
+    doc = benchmark_manifests.repo_doc()
+    per_layer = {m["name"]: m for m in doc["per_layer"]}
+    layers = {m["layer"] for m in doc["per_layer"]
+              if m["name"] not in NEW_METRICS}
+    for name in NEW_METRICS:
+        e = per_layer[name]
         assert set(e) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
         assert e["layer"] in layers and e["moves"] == "tx_s"
         assert e["better"] == "lower"
-    # the repo's list is as PR 32 left its end, which one of its tests holds
-    assert [m["workloads"] for m in repo["per_layer"][-5:]] == [
-        ["mistral4_window_saturated"]] * 5
-    assert not {m["name"] for m in repo["per_layer"]} & set(NEW_METRICS)
+        listed = set(CELLS if name in NEW_READERS else CELLS[2:])
+        assert listed <= set(e["workloads"])
+        assert not set(CELLS) - listed & set(e["workloads"])
 
 
 @pytest.mark.parametrize("cell", ["history_saturated",
                                   "zaya1_window_saturated"])
-def test_the_parents_traced_run_leaves_the_new_metrics_out(man, cell):
+def test_the_parents_traced_run_has_nothing_to_read_for_the_new_metrics(cell):
     """``read_metrics`` as the traced run calls it, over a capture of the
-    program before PR 38: nothing raises; the five of the new readers are
-    left out of the line, the two data metrics read 0."""
-    resolved = man.resolve(cell)
+    program before PR 38: the five of the new readers find nothing, which
+    is an error in a cell the manifest lists (and left out of the line
+    where an entry leaves its cells open); the two data metrics read 0."""
+    resolved = benchmark_manifests.repo_manifest().resolve(cell)
     new = [m for m in resolved.per_layer if m.name in NEW_METRICS]
-    got = core.read_metrics(resolved, new, BEFORE)
+    with pytest.raises(RuntimeError, match="period_ms.sat"):
+        core.read_metrics(resolved, new, BEFORE)
+    anywhere = [dataclasses.replace(m, workloads=None) for m in new]
+    got = core.read_metrics(resolved, anywhere, BEFORE)
     want = ({"fetch_ms.sat", "idle_fetch_pct.sat"}
             if cell.startswith("zaya1") else set())
     assert set(got) == want

@@ -67,6 +67,50 @@ def test_breakdown_names_the_operations_that_took_most_time(summary):
     assert seconds == sorted(seconds, reverse=True)
 
 
+SCOPED = os.path.join(ROOT, "benchmark", "reduce", "fixtures",
+                      "scoped_dispatches.textproto")
+WHILE = ("%while.2 = (s32[], bf16[8,8]{1,0}) while((s32[], bf16[8,8]{1,0}) "
+         "%tuple.9), condition=%cond, body=%body")
+FUSION = "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %x), kind=kLoop"
+
+
+def test_breakdown_leaves_out_a_container_whose_children_are_listed():
+    """``scoped_dispatches.textproto``: in each of two programs a
+    ``%while.2`` of 100 us around two ``%fusion.3`` of 60 and 40 us. The
+    list names operations: the loop's time is its children's."""
+    s = trace.reduce(trace.load(SCOPED, "bench.score"), op_line="XLA Ops",
+                     kernel_patterns=["custom-call"])
+    assert not any("while" in name for name in s.op_seconds)
+    assert not any("while" in name
+                   for name, _ in s.breakdown()["device_ops"])
+    (name, seconds), = s.container_seconds.items()
+    assert name.startswith("%while.2 = ") and seconds == pytest.approx(200e-6)
+    assert s.op_seconds["%fusion.3 fusion f32[256,2560]"] == pytest.approx(
+        200e-6)
+    # 2 x 350 us of operations and the 50 us before the first program
+    assert sum(s.op_seconds.values()) == pytest.approx(s.busy_s) == \
+        pytest.approx(750e-6)
+
+
+def test_a_container_without_children_in_the_capture_is_kept_and_marked():
+    ops = [trace.Event(FUSION, 0.0, 10.0, {}),
+           trace.Event(WHILE, 10.0, 100.0, {}),
+           trace.Event(FUSION, 110.0, 10.0, {})]
+    planes = {"/device:TPU:0": {"XLA Ops": ops}, "/host:CPU": {"python3": [
+        trace.Event("bench.score", 0.0, 200.0, {"rows": 1})]}}
+    s = trace.reduce(planes, op_line="XLA Ops", kernel_patterns=[".*"])
+    assert s.container_seconds == {}
+    marked = trace.CHILDLESS + trace.short_name(WHILE)
+    assert s.op_seconds[marked] == pytest.approx(100e-9)
+    assert s.breakdown()["device_ops"][0] == [marked, pytest.approx(100e-9)]
+    # with a child inside it, the same loop leaves the list
+    inside = [*ops, trace.Event(FUSION, 20.0, 30.0, {})]
+    planes["/device:TPU:0"]["XLA Ops"] = inside
+    s = trace.reduce(planes, op_line="XLA Ops", kernel_patterns=[".*"])
+    assert list(s.container_seconds) == [trace.short_name(WHILE)]
+    assert list(s.op_seconds) == [trace.short_name(FUSION)]
+
+
 @pytest.mark.parametrize("patterns,kernel_ns,want_pct", [
     # 4203 rows x 147,004 operations at 197e12/s = 3.1363 us: compute-bound
     # (the 715,980 bytes take 0.874 us at 819e9/s)
