@@ -23,10 +23,18 @@ not, weights renormalised over the chosen) with or without a shared
 expert; or ``carried_mlp``, a small MLP on a down-projection whose hidden
 state is handed from one layer's router to the next, softmax, top 1, and a
 last output that means *no expert* (the token skips the layer).
+**The residual path** is a table too (``RESIDUALS``): ``plain`` (x + f(x)),
+``scaled`` (a learned scale and bias on the stream and on the sublayer's
+output) or ``mhc``, manifold-constrained hyper-connections: a token's state
+between sublayers is ``streams`` rows of the hidden width, and every
+sublayer reads one mix of them and writes to all of them through three
+maps computed from the token's own streams, the stream-to-stream one made
+doubly stochastic by Sinkhorn-Knopp steps (:func:`_mhc`).
 A stack whose layers are alike may arrive as one tree with the layers on
 the leading axis of every leaf and is then scanned (``lax.scan``: one layer
-is compiled); a stack that arrives as a list is unrolled. Causal
-throughout. The window (B, L, F) of the ``HistoryStore`` is
+is compiled); a stack that arrives as a list is unrolled, and an entry of
+the list may itself be such a tree of alike layers (leading dense layers
+listed, the expert layers behind them scanned). Causal throughout. The window (B, L, F) of the ``HistoryStore`` is
 tokenised on the device (TabFormer-style: column j of a record is token
 j * bins + its quantile bin), so a verdict is one L * F token pass read
 out at the newest record's last token.
@@ -58,21 +66,25 @@ state passes it unchanged; it routes to no expert. A row's verdict is
 therefore the same at every window length that holds its history.
 
 The equations, with the key each symbol is read from, are in the plain
-references ``benchmark/reference/hybrid_moe_f32.py``, ``cca_moe_f32.py``
-and ``mla_moe_f32.py`` (which import nothing from here); the parameter
-tree is the one their ``make_params`` draw.
+references ``benchmark/reference/hybrid_moe_f32.py``, ``cca_moe_f32.py``,
+``mla_moe_f32.py`` and ``mhc_moe_f32.py`` (which import nothing from
+here); the parameter tree is the one their ``make_params`` draw.
 
 Precision: matrices bfloat16, products accumulated in float32, the
 residual stream, norms, gates, softmax and the router in float32 (the
 router and its carried state at ``highest``: a token near a tie must
-choose as the model does), the KDA state and everything inside a chunk,
-CCA's convolution sums and L2 norms in float32.
+choose as the model does; the hyper-connections' maps too: an error in the
+stream-to-stream map is every later sublayer's), the KDA state and
+everything inside a chunk, CCA's convolution sums and L2 norms in float32.
 
 Device scopes (``jax.named_scope``, so a capture's operations carry them):
 ``lm.embed``, ``kda``, ``mla`` (inside it ``mla.project``: every
 projection, the norms and the rotary, and ``mla.attend``), ``cca`` (inside
 it ``cca.conv`` and ``cca.attend``), ``dense_ffn``, ``moe.route``,
-``moe.experts``, ``moe.shared``, ``lm.head``.
+``moe.experts``, ``moe.shared``, ``lm.head``, and ``hc`` around everything
+the ``mhc`` rule adds (inside it ``hc.maps``: the flattened norm, the
+product, the sigmoids and Sinkhorn; ``hc.mix``: the sublayer's input from
+the streams and the streams' update), never around the sublayer itself.
 """
 
 from __future__ import annotations
@@ -234,6 +246,29 @@ class TopK:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mhc:
+    """The ``mhc`` residual rule: how many streams a token's state has, the
+    Sinkhorn-Knopp steps that make the stream-to-stream map doubly
+    stochastic, the eps under their sums, and what its logits are clamped
+    to before the exponential."""
+
+    streams: int
+    sinkhorn_iters: int
+    eps: float
+    clamp: tuple[float, float]
+
+    @classmethod
+    def read(cls, m: Mapping[str, Any]) -> "Mhc":
+        keys = ("hc_mult", "hc_sinkhorn_iters", "hc_eps",
+                "mhc_h_res_clamp_min", "mhc_h_res_clamp_max")
+        if any(key not in m for key in keys):
+            raise ValueError(f"the mhc residual rule reads {keys}")
+        return cls(int(m["hc_mult"]), int(m["hc_sinkhorn_iters"]),
+                   float(m["hc_eps"]), (float(m["mhc_h_res_clamp_min"]),
+                                        float(m["mhc_h_res_clamp_max"])))
+
+
+@dataclasses.dataclass(frozen=True)
 class HybridConfig:
     """The model's settings, hashable so that a jit takes them as static:
     the stack, each of its kinds' own settings, and what all models share."""
@@ -251,7 +286,8 @@ class HybridConfig:
     fraud_id: int
     legit_id: int
     shift: float
-    scaled_residual: bool = False  # learned scale and bias on both branches
+    residual: str = "plain"  # name in RESIDUALS
+    residual_settings: Any = None  # that rule's settings (None: it has none)
     tied_head: bool = False  # the head is the embedding
 
     @classmethod
@@ -313,7 +349,7 @@ def _read_zaya(m: Mapping[str, Any]) -> dict:
     return dict(
         layers=(("cca", "moe"),) * len(m["layers_kept"]),
         mixers=(("cca", Cca.read(m)),), router="carried_mlp", routing=None,
-        scaled_residual=True, tied_head=bool(m["tie_word_embeddings"]))
+        residual="scaled", tied_head=bool(m["tie_word_embeddings"]))
 
 
 def _read_mistral4(m: Mapping[str, Any]) -> dict:
@@ -331,8 +367,37 @@ def _read_mistral4(m: Mapping[str, Any]) -> dict:
         tied_head=bool(m["tie_word_embeddings"]))
 
 
+def _read_xing4(m: Mapping[str, Any]) -> dict:
+    """Xing4.0: MLA with low-rank queries and YaRN in every layer (the
+    source gives YaRN as ``rope_scaling`` beside a top-level ``rope_theta``
+    and has no ``rope_interleave``: pairs (2i, 2i + 1), as the key family
+    lays them out), leading dense layers, sigmoid scores with a bias for
+    the choice over all routed experts, one shared expert, the ``mhc``
+    residual path."""
+    _held_all_of(m, "n_routed_experts")
+    if int(m["n_group"]) != 1 or int(m["topk_group"]) != 1 \
+            or int(m["n_shared_experts"]) != 1 or not m["norm_topk_prob"] \
+            or m["scoring_func"] != "sigmoid" \
+            or m["topk_method"] != "noaux_tc":
+        raise ValueError("xing4_0: one group, one shared expert, sigmoid "
+                         "scores with the noaux_tc bias, weights "
+                         "renormalised")
+    dense = int(m["first_k_dense_replace"])
+    rope = dict(m["rope_scaling"], rope_theta=m["rope_theta"])
+    return dict(
+        layers=tuple(("mla", "dense" if i < dense else "moe")
+                     for i in m["layers_kept"]),
+        mixers=(("mla", Mla.read(dict(
+            m, rope_parameters=rope,
+            rope_interleave=m.get("rope_interleave", True)))),),
+        router="top_k", routing=TopK(
+            "sigmoid", True, 1, 1, float(m["routed_scaling_factor"])),
+        residual="mhc", residual_settings=Mhc.read(m),
+        tied_head=bool(m["tie_word_embeddings"]))
+
+
 READERS = {"ling": _read_ling, "zaya": _read_zaya,
-           "mistral4": _read_mistral4}
+           "mistral4": _read_mistral4, "xing4_0": _read_xing4}
 
 
 def owns(params: Any) -> bool:
@@ -986,36 +1051,127 @@ MIXERS = {  # name -> f(p, z, real, position, cfg, dtype)
 
 # -- the model ----------------------------------------------------------------------
 
-def _add(scaling, x, y):
-    """The residual rule: plain, or with the branch's learned scale and
-    bias on the stream and on the sublayer's output."""
-    if scaling is None:
-        return x + y
-    return scaling["s_r"] * (x + scaling["b_r"]) + scaling["s_o"] * (
-        y + scaling["b_o"])
+def _plain(p, x, sublayer, real, cfg):
+    """x + f(x). Every rule: ``(x, what the sublayer hands on beside its
+    output, the rule's defect or None)`` for the stream ``x``, the rule's
+    own parameters ``p`` of this sublayer (``res1`` / ``res2`` of a layer;
+    None where it has none) and ``sublayer(z) -> (y, extra)``, which norms
+    its input itself."""
+    y, extra = sublayer(x)
+    return x + y, extra, None
+
+
+def _scaled(p, x, sublayer, real, cfg):
+    """A learned scale and bias on the stream and on the sublayer's
+    output."""
+    y, extra = sublayer(x)
+    return p["s_r"] * (x + p["b_r"]) + p["s_o"] * (y + p["b_o"]), extra, None
+
+
+def _sinkhorn(logits, s: Mhc):
+    """(..., n, n) logits -> a matrix whose rows and columns each sum to 1
+    up to the last steps' residue: exp of the clamped logits, then
+    ``sinkhorn_iters`` times the rows over their sums, the columns over
+    theirs."""
+    m = jnp.exp(jnp.clip(logits, *s.clamp))
+    for _ in range(s.sinkhorn_iters):
+        m = m / (m.sum(-1, keepdims=True) + s.eps)
+        m = m / (m.sum(-2, keepdims=True) + s.eps)
+    return m
+
+
+def mhc_maps(p, x, s: Mhc, eps: float):
+    """The three maps of one sublayer from the streams ``x`` (B, T, n, C):
+    ``(h_pre (B, T, n), h_post (B, T, n), h_res (B, T, n, n))``. With u the
+    RMS-normed flattened streams (no weight: one would fold into ``phi``'s
+    rows), [pre, post, res] = alpha * (u phi) + b by thirds; h_pre =
+    sigmoid, h_post = 2 sigmoid, h_res = Sinkhorn-Knopp. Float32 at
+    ``highest``. The streams are never flattened (on the chip n is not
+    beside C in memory, and a flat view would be a copy of them): the
+    product is the sum of each stream's with its rows of ``phi``, and the
+    norm's scale is applied to the product's 2n + n^2 values."""
+    b, t, n, c = x.shape
+    scale = jax.lax.rsqrt(jnp.mean(x * x, (-2, -1))[..., None] + eps)
+    m = jnp.einsum("btnc,nco->bto", x, p["phi"].astype(F32).reshape(
+        n, c, -1), precision=HIGHEST) * scale
+    alpha, bias = p["alpha"], p["b"]
+    pre = alpha[0] * m[..., :n] + bias[:n]
+    post = alpha[1] * m[..., n:2 * n] + bias[n:2 * n]
+    res = (alpha[2] * m[..., 2 * n:] + bias[2 * n:]).reshape(b, t, n, n)
+    return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), _sinkhorn(res, s)
+
+
+def _mhc(p, x, sublayer, real, cfg):
+    """Manifold-constrained hyper-connections around one sublayer: the
+    streams ``x`` (B, T, n, C) -> z = h_pre x (C wide) -> y = f(z) -> x' =
+    h_res x + h_post^T y (stream j gets h_post[j] y). z and h_res x are
+    one product of the streams with the (n + 1, n) matrix [h_res; h_pre]:
+    one pass over them, before the sublayer. The defect is the largest
+    abs(row or column sum of h_res - 1) over the real tokens: what a
+    Sinkhorn cut short, or a clamp that bites, leaves."""
+    n = x.shape[2]
+    with jax.named_scope("hc"):
+        with jax.named_scope("hc.maps"):
+            h_pre, h_post, h_res = mhc_maps(p, x, cfg.residual_settings,
+                                            cfg.eps)
+            off = jnp.maximum(jnp.abs(h_res.sum(-1) - 1.0),
+                              jnp.abs(h_res.sum(-2) - 1.0)).max(-1)
+            defect = jnp.max(jnp.where(real, off, 0.0))
+        with jax.named_scope("hc.mix"):
+            both = jnp.concatenate([h_res, h_pre[:, :, None, :]], axis=2)
+            mixed = jnp.sum(both[..., None] * x[:, :, None], axis=3)
+    y, extra = sublayer(mixed[:, :, n])
+    with jax.named_scope("hc"), jax.named_scope("hc.mix"):
+        x = mixed[:, :, :n] + h_post[..., None] * y[:, :, None, :]
+    return x, extra, defect
+
+
+RESIDUALS = {  # name -> f(p, x, sublayer, real, cfg): (x, extra, defect)
+    "plain": _plain,
+    "scaled": _scaled,
+    "mhc": _mhc,
+}
 
 
 def _layer(p, x, r, kind, real, position, cfg: HybridConfig, dtype):
-    """One layer of kind ``(mixer, feed-forward)``: ``(x, r, counts)``,
-    ``counts`` None where the layer has no experts."""
+    """One layer of kind ``(mixer, feed-forward)``: ``(x, r, counts,
+    defect)``, ``counts`` None where the layer has no experts, ``defect``
+    None where the residual rule has none."""
     mixer, ffn = kind
-    z = _rms(x, p["norm1"], cfg.eps)
-    with jax.named_scope(mixer):
-        y = MIXERS[mixer](p["mixer"], z, real, position, cfg, dtype)
-    x = _add(p.get("res1"), x, y)
-    z = _rms(x, p["norm2"], cfg.eps)
-    if ffn == "dense":
-        with jax.named_scope("dense_ffn"):
-            return _add(p.get("res2"), x, _swiglu(p["ffn"], z, dtype)), r, None
-    y, r, counts = moe(p["ffn"], z, r, real, cfg, dtype)
-    return _add(p.get("res2"), x, y), r, counts
+    rule = RESIDUALS[cfg.residual]
+
+    def mix(x):
+        z = _rms(x, p["norm1"], cfg.eps)
+        with jax.named_scope(mixer):
+            return MIXERS[mixer](p["mixer"], z, real, position, cfg,
+                                 dtype), None
+
+    def feed(x):
+        z = _rms(x, p["norm2"], cfg.eps)
+        if ffn == "dense":
+            with jax.named_scope("dense_ffn"):
+                return _swiglu(p["ffn"], z, dtype), (r, None)
+        y, state, counts = moe(p["ffn"], z, r, real, cfg, dtype)
+        return y, (state, counts)
+
+    x, _, first = rule(p.get("res1"), x, mix, real, cfg)
+    x, (r, counts), second = rule(p.get("res2"), x, feed, real, cfg)
+    return x, r, counts, None if first is None else jnp.maximum(first,
+                                                                second)
+
+
+def _stacked(p) -> int | None:
+    """How many alike layers the tree ``p`` carries on every leaf's
+    leading axis; None for one layer's tree."""
+    return p["norm1"].shape[0] if p["norm1"].ndim == 2 else None
 
 
 def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
                   dtype=jnp.bfloat16):
-    """``(x (B, T, hidden) float32 before the final norm, aux)``. What
-    passes from layer to layer is the stream ``x`` and the router's state
-    ``r`` (None where the router carries none)."""
+    """``(x before the final norm, aux)``: x (B, T, hidden) float32, or
+    the residual rule's streams (B, T, n, hidden) (``slice_logits`` sums
+    them). What passes from layer to layer is the stream ``x`` and the
+    router's state ``r`` (None where the router carries none)."""
     b, length, cols = hist.shape
     t = length * cols
     filled = filled.astype(jnp.int32)
@@ -1026,25 +1182,45 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
     with jax.named_scope("lm.embed"):
         ids = tokenise(params["edges"], hist.astype(F32), cfg.bins)
         x = params["embed"][ids].astype(F32)
+        if cfg.residual == "mhc":  # the embedding in every stream
+            x = jnp.broadcast_to(x[:, :, None, :], (
+                b, t, cfg.residual_settings.streams, x.shape[-1]))
     layers = params["layers"]
     r = None
     if cfg.router == "carried_mlp":
         r = jnp.zeros((b * t, _router_width(layers)), F32)
-    if isinstance(layers, Mapping):  # layers alike, stacked: one is compiled
-        def step(carry, p):
-            x, r, counts = _layer(p, *carry, cfg.layers[0], real, position,
-                                  cfg, dtype)
-            return (x, r), counts
+    # a tree of alike layers, stacked, is scanned (one is compiled); a
+    # list is unrolled, and an entry of it may be such a stack
+    each, first = [], 0  # a layer's or a stack's (counts, defect), stacked?
+    for p in [layers] if isinstance(layers, Mapping) else layers:
+        n = _stacked(p)
+        kind = cfg.layers[first]
+        if n is None:
+            x, r, *out = _layer(p, x, r, kind, real, position, cfg, dtype)
+        elif any(k != kind for k in cfg.layers[first:first + n]):
+            raise ValueError("layers stacked in one tree are of one kind")
+        else:
+            def step(carry, p):
+                x, r, *out = _layer(p, *carry, kind, real, position, cfg,
+                                    dtype)
+                return (x, r), out
 
-        (x, r), counts = jax.lax.scan(step, (x, r), layers)
-    else:
-        each = []
-        for kind, p in zip(cfg.layers, layers):
-            x, r, one = _layer(p, x, r, kind, real, position, cfg, dtype)
-            if one is not None:
-                each.append(one)
-        counts = jax.tree.map(lambda *leaves: jnp.stack(leaves), *each) \
-            if each else None
+            (x, r), out = jax.lax.scan(step, (x, r), p)
+        each.append((out, n is not None))
+        first += n or 1
+
+    def over_layers(which: int):
+        """Every layer's ``out[which]`` with the layers leading."""
+        parts = [(out[which], stacked) for out, stacked in each
+                 if out[which] is not None]
+        if not parts:
+            return None
+        trees, stacked = zip(*parts)
+        return jax.tree.map(lambda *leaves: jnp.concatenate([
+            leaf if whole else jnp.expand_dims(leaf, 0)
+            for leaf, whole in zip(leaves, stacked)]), *trees)
+
+    counts, defect = over_layers(0), over_layers(1)
     if counts is None:
         none = jnp.zeros((0,), jnp.int32)
         counts = {"pairs": jnp.zeros((0, cfg.held_count), jnp.int32),
@@ -1058,6 +1234,8 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
            "skipped_tokens": counts["skipped"].sum(dtype=jnp.int32),
            "row_pairs": counts["row_pairs"].sum(0, dtype=jnp.int32),
            "row_choice": jnp.swapaxes(counts["row_choice"], 0, 1)}
+    if defect is not None:  # the rule's, over all sublayers
+        aux["hc_defect"] = defect.max()
     return x, aux
 
 
@@ -1068,8 +1246,11 @@ def _router_width(layers) -> int:
 
 def slice_logits(params: Params, x, cfg: HybridConfig, dtype=jnp.bfloat16):
     """Final norm and the head over the vocabulary slice: untied, or the
-    embedding itself."""
+    embedding itself; of the sum of the streams where the residual rule
+    keeps several (``x`` (..., n, hidden))."""
     with jax.named_scope("lm.head"):
+        if cfg.residual == "mhc":
+            x = x.sum(-2)
         z = _rms(x, params["final_norm"], cfg.eps)
         if cfg.tied_head:
             return jnp.einsum("...i,vi->...v", z.astype(dtype),
@@ -1098,7 +1279,9 @@ def apply_serving(params: Params, hist, filled, cfg: HybridConfig,
     ``pairs_absent`` (chosen pairs whose expert is held elsewhere),
     ``routed_tokens`` (the batch's real tokens), ``skipped_tokens``
     (token-layers whose choice was *skip*), ``row_pairs`` (B,) and
-    ``row_choice`` (B, expert layers, routed)."""
+    ``row_choice`` (B, expert layers, routed); under the ``mhc`` residual
+    rule also ``hc_defect``, the largest abs(row or column sum of a
+    stream-to-stream map - 1) over the real tokens and the sublayers."""
     x, aux = hidden_states(params, hist, filled, cfg, compute_dtype)
     z = slice_logits(params, x[:, -1], cfg, compute_dtype)
     aux["logits"] = z
@@ -1114,7 +1297,10 @@ def make_observer(registry: Any):
     ``moe_expert_pairs_max`` per expert layer, and the sum and count of the
     busiest-over-mean expert load per dispatch and layer), and what
     ``seq.wait`` carries: ``pairs_served``, ``pairs_absent``,
-    ``skipped_tokens``, ``routed_tokens``, ``max_expert_pairs``."""
+    ``skipped_tokens``, ``routed_tokens``, ``max_expert_pairs``; where the
+    program hands back ``hc_defect`` (the ``mhc`` residual rule), that too,
+    and the gauge ``lm_hc_defect_max``, the largest since the process
+    began."""
     served = registry.counter(
         "moe_pairs_served_total",
         "(token, held expert) pairs the expert layers multiplied")
@@ -1146,6 +1332,10 @@ def make_observer(registry: Any):
     layer_dispatches = registry.counter(
         "moe_layer_dispatches_total",
         "expert layers run, over all dispatches (those that served a pair)")
+    defect = registry.gauge(
+        "lm_hc_defect_max",
+        "largest abs(row or column sum - 1) of a hyper-connection's "
+        "stream-to-stream map over real tokens, sublayers and dispatches")
 
     def observe(aux: dict) -> dict:
         pairs = aux["pairs"]  # (expert layers, held)
@@ -1166,11 +1356,15 @@ def make_observer(registry: Any):
             skew.inc(float((top[live] * pairs.shape[1]
                             / per_layer[live]).sum()))
             layer_dispatches.inc(int(live.sum()))
-        return {"pairs_served": int(aux["pairs_served"]),
-                "pairs_absent": int(aux["pairs_absent"]),
-                "skipped_tokens": int(aux["skipped_tokens"]),
-                "routed_tokens": n_tokens,
-                "max_expert_pairs": int(top.max()) if pairs.size else 0}
+        stats = {"pairs_served": int(aux["pairs_served"]),
+                 "pairs_absent": int(aux["pairs_absent"]),
+                 "skipped_tokens": int(aux["skipped_tokens"]),
+                 "routed_tokens": n_tokens,
+                 "max_expert_pairs": int(top.max()) if pairs.size else 0}
+        if "hc_defect" in aux:
+            stats["hc_defect"] = float(aux["hc_defect"])
+            defect.set(max(defect.value(), stats["hc_defect"]))
+        return stats
 
     return observe
 
@@ -1195,8 +1389,10 @@ def register() -> None:
             "experts_held": [cfg.held_first, cfg.held_first + cfg.held_count],
             "experts_routed_over": cfg.routed,
             "router": cfg.router,
+            "residual": cfg.residual,
             "layers": [list(kind) for kind in cfg.layers],
             "kinds": {name: dataclasses.asdict(settings) for name, settings
-                      in (*cfg.mixers, (cfg.router, cfg.routing))
+                      in (*cfg.mixers, (cfg.router, cfg.routing),
+                          (cfg.residual, cfg.residual_settings))
                       if settings is not None}},
         config_from=HybridConfig.from_dict, swappable=False))

@@ -426,6 +426,33 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             rows, list(range(10_000, 10_016))), np.asarray(direct), 1e-6),
         "grid": s.executable_grid()}
 
+    # the family's fourth model (four residual streams mixed by
+    # Sinkhorn-normalised maps around every sublayer, a leading dense layer
+    # listed before the scanned expert layers, sigmoid top 4 with a bias
+    # over 16 experts, all held): the same seam, no pair absent, and the
+    # stream-to-stream maps doubly stochastic to the steps' residue
+    from benchmark.reference import mhc_moe_f32
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "xing4_small_config.json")) as f:
+        small = json.load(f)
+    x_cfg = hybrid_moe.HybridConfig.from_dict(small)
+    xp = mhc_moe_f32.make_params(small)
+    s = SeqScorer(xp, length=8, batch_sizes=(16,), family="hybrid_moe",
+                  family_config=x_cfg, max_customers=64)
+    s.warmup()
+    direct, aux = hybrid_moe.apply_serving(
+        xp, hist, np.ones(16, np.int32), x_cfg, jnp.bfloat16)
+    check("hybrid_moe (xing4_0) four pairs a token and expert layer, none "
+          "absent, defect under 0.2", int(aux["pairs_served"])
+          == 4 * int(aux["routed_tokens"]) * x_cfg.moe_layers
+          and int(aux["pairs_absent"]) == 0
+          and 0 < float(aux["hc_defect"]) < 0.2)
+    zoo["hybrid_moe.xing4_0"] = {
+        "max_abs_diff": check.close("hybrid_moe (xing4_0) B=16 L=8", s.score(
+            rows, list(range(10_000, 10_016))), np.asarray(direct), 1e-6),
+        "grid": s.executable_grid()}
+
     # the family's causal attention at head widths the small presets lack
     # (128 wide; 768 tokens = two blocks of 384): Mosaic compiles the
     # kernel (ops/causal_attention.py) for plain heads and for grouped

@@ -360,7 +360,7 @@ def test_the_settings_come_from_the_published_keys(small, cfg):
     assert cfg.layers == (("cca", "moe"),) * 3 and cfg.moe_layers == 3
     assert (cfg.routed, cfg.held_count, cfg.per_token) == (17, 16, 1)
     assert cfg.router == "carried_mlp" and cfg.routing is None
-    assert cfg.scaled_residual and cfg.tied_head and cfg.eps == 1e-5
+    assert cfg.residual == "scaled" and cfg.tied_head and cfg.eps == 1e-5
     assert registry.get_history("hybrid_moe").config_from(small) == cfg
     with pytest.raises(ValueError, match="zaya"):
         hm.HybridConfig.from_dict(dict(small, num_experts_per_tok=2))

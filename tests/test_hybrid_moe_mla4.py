@@ -5,7 +5,7 @@ models/hybrid_moe.py) against its plain reference
 (benchmark/reference/mla_moe_f32.py) at the small preset, seeded weights, on
 the CPU: the whole model in both precisions, each part alone, the rotary's
 frequencies against a table worked out by hand, padding, causality, stacked
-against listed, the four shares of the experts, the settings of all three
+against listed, the four shares of the experts, the settings of all four
 models of the family, and the served path through ``SeqScorer``."""
 
 import dataclasses
@@ -417,7 +417,7 @@ def test_a_listed_stack_gives_what_the_scanned_one_gives(small, params, cfg,
         assert np.array_equal(np.asarray(aux[key]), np.asarray(other[key]))
 
 
-# -- the settings, of all three models ---------------------------------------------------
+# -- the settings, of all four models -----------------------------------------------------
 
 def _ling_wants(cfg):
     assert [name for name, _ in cfg.mixers] == ["kda", "mla"]
@@ -428,7 +428,7 @@ def _ling_wants(cfg):
         None, True, False, None)
     assert cfg.router == "top_k" and cfg.routing == hm.TopK(
         "sigmoid", True, 4, 2, 2.5)
-    assert not cfg.scaled_residual and not cfg.tied_head
+    assert cfg.residual == "plain" and not cfg.tied_head
     assert {kind for kind, _ in cfg.layers} == {"kda", "mla"}
     assert ("kda", "dense") in cfg.layers
 
@@ -437,7 +437,7 @@ def _zaya_wants(cfg):
     assert cfg.mixers == (("cca", hm.Cca(
         heads=8, kv_heads=2, head_dim=16, rotary_dim=8, theta=5e6)),)
     assert cfg.router == "carried_mlp" and cfg.routing is None
-    assert cfg.scaled_residual and cfg.tied_head
+    assert cfg.residual == "scaled" and cfg.tied_head
     assert cfg.layers == (("cca", "moe"),) * 3
 
 
@@ -449,20 +449,38 @@ def _mistral4_wants(cfg):
             mscale=1.0, mscale_all_dim=1.0, query_beta=0.1))),)
     assert cfg.router == "top_k" and cfg.routing == hm.TopK(
         "softmax", False, 1, 1, 1.0)
-    assert not cfg.scaled_residual and not cfg.tied_head
+    assert cfg.residual == "plain" and not cfg.tied_head
     assert cfg.layers == (("mla", "moe"),) * 3 and cfg.moe_layers == 3
     assert (cfg.routed, cfg.held_first, cfg.held_count, cfg.per_token) == (
         32, 0, 8, 4)
 
 
+def _xing4_wants(cfg):
+    assert cfg.mixers == (("mla", hm.Mla(
+        heads=4, nope=8, rope=8, v_dim=16, kv_rank=16, q_rank=32,
+        part_norms=False, interleaved=True, theta=1e4, yarn=hm.Yarn(
+            factor=64.0, original=64, beta_fast=32.0, beta_slow=1.0,
+            mscale=1.0, mscale_all_dim=1.0, query_beta=0.0))),)
+    assert cfg.router == "top_k" and cfg.routing == hm.TopK(
+        "sigmoid", True, 1, 1, 2.0)
+    assert cfg.residual == "mhc" and cfg.residual_settings == hm.Mhc(
+        streams=4, sinkhorn_iters=20, eps=1e-6, clamp=(-30.0, 30.0))
+    assert not cfg.tied_head
+    assert cfg.layers == (("mla", "dense"),) + (("mla", "moe"),) * 3
+    assert (cfg.routed, cfg.held_first, cfg.held_count, cfg.per_token) == (
+        16, 0, 16, 4)
+
+
 @pytest.mark.parametrize("preset,wants", [
     ("ling3_small_config.json", _ling_wants),
     ("zaya1_small_config.json", _zaya_wants),
-    ("mistral4_small_config.json", _mistral4_wants)])
+    ("mistral4_small_config.json", _mistral4_wants),
+    ("xing4_small_config.json", _xing4_wants)])
 def test_a_model_is_its_kinds_settings_and_what_all_share(preset, wants):
     """``from_dict`` reads each model through its own small reader: the
-    stack, one settings object a mixer kind and a router, and nothing of
-    another model's (no field of ``HybridConfig`` is one model's)."""
+    stack, one settings object a mixer kind, a router and a residual rule,
+    and nothing of another model's (no field of ``HybridConfig`` is one
+    model's)."""
     model = _config("tests", "benchmark", preset)
     cfg = hm.HybridConfig.from_dict(model)
     wants(cfg)
@@ -470,16 +488,18 @@ def test_a_model_is_its_kinds_settings_and_what_all_share(preset, wants):
     assert hash(cfg) == hash(hm.HybridConfig.from_dict(model))
     assert {name for name, _ in cfg.mixers} == {m for m, _ in cfg.layers}
     assert all(name in hm.MIXERS for name, _ in cfg.mixers)
-    assert cfg.router in hm.ROUTERS
+    assert cfg.router in hm.ROUTERS and cfg.residual in hm.RESIDUALS
     assert (cfg.eps, cfg.bins) == (model["rms_norm_eps"], model["bins"])
     shared = {f.name for f in dataclasses.fields(cfg)}
     assert shared == {
         "eps", "layers", "mixers", "router", "routing", "routed",
         "held_first", "held_count", "per_token", "bins", "fraud_id",
-        "legit_id", "shift", "scaled_residual", "tied_head"}
+        "legit_id", "shift", "residual", "residual_settings", "tied_head"}
     described = registry.get_history("hybrid_moe").describe(cfg)
+    assert described["residual"] == cfg.residual
     assert set(described["kinds"]) == {name for name, _ in cfg.mixers} | (
-        {cfg.router} if cfg.routing is not None else set())
+        {cfg.router} if cfg.routing is not None else set()) | (
+        {cfg.residual} if cfg.residual_settings is not None else set())
     json.dumps(described)
 
 
