@@ -80,7 +80,11 @@ everything inside a chunk, CCA's convolution sums and L2 norms in float32.
 Device scopes (``jax.named_scope``, so a capture's operations carry them):
 ``lm.embed``, ``kda``, ``mla`` (inside it ``mla.project``: every
 projection, the norms and the rotary, and ``mla.attend``), ``cca`` (inside
-it ``cca.conv`` and ``cca.attend``), ``dense_ffn``, ``moe.route``,
+it ``cca.conv`` and ``cca.attend``; both ``attend`` scopes hold
+:func:`_causal_attention`: the Pallas kernel where q and k are whole
+128-lane tiles or whole tiles and a half wide, the values whole tiles and
+the window a multiple of 128 tokens, the plain path at every other
+shape), ``dense_ffn``, ``moe.route``,
 ``moe.experts``, ``moe.shared``, ``lm.head``, and ``hc`` around everything
 the ``mhc`` rule adds (inside it ``hc.maps``: the flattened norm, the
 product, the sigmoids and Sinkhorn; ``hc.mix``: the sublayer's input from
@@ -649,13 +653,16 @@ def _causal_attention(q, k, v, real, scale: float, dtype):
     one mathematics at one precision, chosen while the program is traced
     from the operands' shapes, their dtype and the backend
     (``ops/causal_attention.py::kernel_fits``): where a head's query-key
-    width and its value width each fill whole 128-lane tiles and the window
-    tiles into the kernel's blocks, the Pallas kernel, whose scores stay in
-    VMEM and which never visits a key block above the diagonal (operands
-    handed over by head, (B, H, T, D): the transposes fold into the fusions
-    around them); every other shape, :func:`_plain_causal_attention`, the
-    plain definition the tests compare against. The kernel has no
-    derivative; nothing differentiates this family (it is served only)."""
+    width is whole 128-lane tiles or whole tiles and a half (MLA's 128 + 64
+    = 192), its value width whole tiles, and the window tiles into the
+    kernel's blocks, the Pallas kernel, whose scores stay in VMEM and which
+    never visits a key block above the diagonal (operands handed over by
+    head, (B, H, T, D): a transposing copy each unless XLA folds it into
+    the fusion beside it); every other shape (heads of 16 or 64, values of
+    64, a window that is no multiple of 128 tokens),
+    :func:`_plain_causal_attention`, the plain definition the tests compare
+    against. The kernel has no derivative; nothing differentiates this
+    family (it is served only)."""
     b, t = q.shape[:2]
 
     def by_head_shape(x):  # (B, T, heads.., d) -> (B, heads, T, d)

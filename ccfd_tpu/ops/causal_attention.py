@@ -15,8 +15,15 @@ again by p v, through HBM. Here they live and die in VMEM:
   because the transposes then fold into the fusions that make q, k, v and
   read the output, where the other view costs three relayout copies a layer;
 - the grid runs over (row, query head, query block); a (row, key head)'s
-  whole k and v are 2 x T x 128 values and stay in VMEM while the grid
+  whole k and v (T x D and T x Dv values) stay in VMEM while the grid
   walks that head's query blocks (and the other query heads of its group);
+- q and k may be a whole number of 128-lane tiles wide or end in half a
+  tile (MLA's 128 + 64 rotary = 192: the block's last dimension is the
+  array's, Mosaic keeps the 64 lanes beside them out of the product); the
+  values, and so the output, fill whole tiles. The half tile costs the MXU
+  a whole pass (3.80 ms a call of (8, 32, 1,920) at 192 against 2.88 at
+  128, and 3.80 at 256), so padding q and k to 256 or handing them over in
+  lane-tile pieces buys nothing in the kernel and costs copies around it;
 - inside a grid step a loop over square key blocks with a running max and
   sum; the blocks wholly above the diagonal are not visited (the causal
   half of the work), the one on the diagonal is masked by position, and a
@@ -44,7 +51,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-LANE = 128  # a head's width fills whole lane tiles, T whole blocks of them
+LANE = 128  # values fill whole lane tiles, T whole blocks of them
+HALF = LANE // 2  # q and k: whole tiles, or whole tiles and a half (128 + 64)
 BLOCKS = (640, 512, 384, 256, 128)  # square: queries and keys of a step
 # float32 scores of one (query block, key block): with the probabilities
 # beside them in float32 and in v's dtype this stays well inside the 16 MiB
@@ -60,8 +68,10 @@ def block_for(tokens: int, width: int, v_width: int, itemsize: int) -> int | Non
     """Tokens a side of the square (query block, key block) a grid step
     works on: the largest of ``BLOCKS`` that tiles the window and whose
     scores fit the budget; None where the kernel does not run (a window
-    that is no multiple of the lane width, or whose k and v do not fit)."""
-    if tokens % LANE or 2 * tokens * (width + v_width) * itemsize > KEYS_BYTES:
+    that is no multiple of the lane width, or whose k and v do not fit:
+    a row of k counted as the whole lane tiles VMEM gives it)."""
+    held = -(-width // LANE) * LANE
+    if tokens % LANE or 2 * tokens * (held + v_width) * itemsize > KEYS_BYTES:
         return None
     for side in BLOCKS:
         if tokens % side == 0 and side * side * 4 <= SCORE_BYTES:
@@ -71,11 +81,13 @@ def block_for(tokens: int, width: int, v_width: int, itemsize: int) -> int | Non
 
 def kernel_fits(q_shape: tuple, k_shape: tuple, v_shape: tuple, dtype) -> bool:
     """Whether ``_causal_attention`` runs the kernel on operands of these
-    by-head shapes (B, H, T, D), (B, G, T, D), (B, G, T, Dv): heads whose
-    query-key width and value width each fill whole lane tiles, query
-    heads that divide evenly over the key heads, a window the kernel tiles
-    within its VMEM budget, and a backend it runs on (Mosaic on the TPU,
-    the interpreter on the CPU)."""
+    by-head shapes (B, H, T, D), (B, G, T, D), (B, G, T, Dv): a query-key
+    width of whole lane tiles or whole tiles and a half (128, 192, 256 ..),
+    a value width of whole lane tiles, query heads that divide evenly over
+    the key heads, a window the kernel tiles within its VMEM budget, and a
+    backend it runs on (Mosaic on the TPU, the interpreter on the CPU).
+    Refused, and so on the plain path: heads of 16 or 64, values of 64, a
+    window that is no multiple of 128 tokens."""
     if not len(q_shape) == len(k_shape) == len(v_shape) == 4:
         return False
     (b, h, t, d), (_, g, _, dv) = q_shape, v_shape
@@ -84,7 +96,7 @@ def kernel_fits(q_shape: tuple, k_shape: tuple, v_shape: tuple, dtype) -> bool:
         tuple(k_shape) == (b, g, t, d)
         and tuple(v_shape[:3]) == (b, g, t)
         and h % g == 0
-        and d % LANE == 0 and dv % LANE == 0
+        and d >= LANE and d % HALF == 0 and dv % LANE == 0
         and dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
         and block_for(t, d, dv, dtype.itemsize) is not None
         and jax.default_backend() in ("tpu", "cpu")
@@ -135,8 +147,9 @@ def fused_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """``q`` (B, H, T, D), ``k`` (B, G, T, D), ``v`` (B, G, T, Dv), ``real``
     (B, T) bool -> (B, H, T, Dv) in ``dtype``: token t's softmax over the
     real tokens at or before it, of ``scale`` q k^T. Only shapes
-    :func:`kernel_fits` admits; ``side`` overrides :func:`block_for` (a
-    test's way to several blocks in a short window)."""
+    :func:`kernel_fits` admits (D a multiple of ``HALF`` from ``LANE`` up, Dv
+    of ``LANE``); ``side`` overrides :func:`block_for` (a test's way to
+    several blocks in a short window)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 

@@ -454,18 +454,22 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
         "grid": s.executable_grid()}
 
     # the family's causal attention at head widths the small presets lack
-    # (128 wide; 768 tokens = two blocks of 384): Mosaic compiles the
-    # kernel (ops/causal_attention.py) for plain heads and for grouped
-    # queries, and the device's answer is the plain path's at every real
-    # position, one row padded on the left past the first block
+    # (128 wide, and MLA's 128 + 64 = 192 in q and k against values of
+    # 128; 768 tokens = two blocks of 384): Mosaic compiles the kernel
+    # (ops/causal_attention.py) for plain heads, for grouped queries and
+    # for a block that ends in half a lane tile, and the device's answer
+    # is the plain path's at every real position, one row padded on the
+    # left past the first block
     from ccfd_tpu.ops import causal_attention, seq_attention
 
     rng = np.random.default_rng(37)
     real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [400]]))
     for label, q_shape in (("plain heads", (2, 768, 2, 128)),
-                           ("grouped queries", (2, 768, 2, 2, 128))):
+                           ("grouped queries", (2, 768, 2, 2, 128)),
+                           ("half-tile heads", (2, 768, 2, 192))):
         q, k, v = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
-                   for shape in (q_shape, (2, 768, 2, 128), (2, 768, 2, 128)))
+                   for shape in (q_shape, (2, 768, 2, q_shape[-1]),
+                                 (2, 768, 2, 128)))
 
         def attend(q, k, v, real):
             return hybrid_moe._causal_attention(q, k, v, real, 0.09,

@@ -4,13 +4,18 @@ path, and which shapes select which. The small presets of
 ``tests/benchmark/*_small_config.json`` have heads of 16 and never hold the
 kernel, so here each model that attends through ``_causal_attention`` gets
 one tile-wide preset (``mistral4``-like: 2 heads of 64 + 64 against values
-of 128; ``zaya1``-like: 2 groups x 2 queries x 128), the small preset with
+of 128; ``zaya1``-like: 2 groups x 2 queries x 128; ``xing4``- and
+``ling3``-like: 2 heads of 128 + 64 = 192, a tile and a half, against
+values of 128), the small preset with
 its head widths replaced: the kernel at every real position for plain heads
-and grouped queries, with padding on the left, a window of padding alone,
+and grouped queries, at a query-key width of one lane tile and of a tile
+and a half, with padding on the left, a window of padding alone,
 one block and several; causality; what the program's own jaxpr says it
-holds; the whole models against their float32 references; and the served
+holds, and that the ``pallas_call`` is one and the same at both widths;
+the whole models against their float32 references; and the served
 path through ``SeqScorer`` with its engagement counters."""
 
+import functools
 import json
 import math
 import os
@@ -20,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.reference import cca_moe_f32, mla_moe_f32, table
+from benchmark.reference import (cca_moe_f32, hybrid_moe_f32, mhc_moe_f32,
+                                 mla_moe_f32, table)
 from ccfd_tpu.models import hybrid_moe as hm
 from ccfd_tpu.ops import causal_attention as ca
 from ccfd_tpu.ops import seq_attention
@@ -33,6 +39,12 @@ COLS = 30
 # from 64 records on: the shortest the served path can hold the kernel at
 SERVED_LENGTH = 64
 
+# a tile and a half in q and k (128 + 64 rotary) against values of 128: the
+# MLA of ``xing4`` (every layer: one dense, one with experts) and of
+# ``ling3`` (one layer in six: a KDA layer beside it)
+WIDE_192 = {"num_attention_heads": 2, "num_key_value_heads": 2,
+            "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+            "v_head_dim": 128, "num_hidden_layers": 2}
 TILE_WIDE = {
     "mistral4": (mla_moe_f32, "mistral4_small_config.json", {
         "num_attention_heads": 2, "num_key_value_heads": 2,
@@ -43,7 +55,13 @@ TILE_WIDE = {
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
         "num_hidden_layers": 2, "layers_kept": [0, 1],
         "layer_types": ["hybrid", "hybrid"]}),
+    "xing4": (mhc_moe_f32, "xing4_small_config.json",
+              {**WIDE_192, "layers_kept": [1, 2]}),
+    "ling3": (hybrid_moe_f32, "ling3_small_config.json",
+              {**WIDE_192, "head_dim": 128, "layers_kept": [2, 5]}),
 }
+WIDTHS = pytest.mark.parametrize("width", [128, 192],
+                                 ids=["tile", "tile_and_a_half"])
 
 
 @pytest.fixture(scope="module", params=sorted(TILE_WIDE))
@@ -96,14 +114,16 @@ def _holds_kernel(fn, *shapes) -> bool:
                          ids=["one_block", "two_blocks", "three_blocks"])
 @pytest.mark.parametrize("grouped", [False, True],
                          ids=["plain_heads", "grouped_queries"])
+@WIDTHS
 def test_the_kernel_equals_the_plain_path_at_every_real_position(
-        grouped, t, side, dtype, tol):
+        width, grouped, t, side, dtype, tol):
     """Rows with no padding, with padding that ends inside the first block
     and inside a later one, and a row of padding alone (finite, read by
-    nobody)."""
+    nobody); q and k one lane tile wide, and a tile and a half against
+    values of one."""
     pads = (0, 37, 130, t)
-    q, k, v, real = _operands(grouped, t, pads, dtype)
-    scale = 1.0 / math.sqrt(128)
+    q, k, v, real = _operands(grouped, t, pads, dtype, width=width)
+    scale = 1.0 / math.sqrt(width)
     with jax.default_matmul_precision("highest"):
         want = hm._plain_causal_attention(q, k, v, real, scale, dtype)
         got = _kernel(q, k, v, real, scale, dtype, side)
@@ -125,9 +145,10 @@ def test_values_wider_than_the_keys_come_out_at_their_width():
 
 @pytest.mark.parametrize("grouped", [False, True],
                          ids=["plain_heads", "grouped_queries"])
-def test_a_later_token_moves_no_earlier_output(grouped):
+@WIDTHS
+def test_a_later_token_moves_no_earlier_output(width, grouped):
     """Across a block's edge too: token 200 of 256 in blocks of 128."""
-    q, k, v, real = _operands(grouped, 256, (0, 20), BF16)
+    q, k, v, real = _operands(grouped, 256, (0, 20), BF16, width=width)
     before = _kernel(q, k, v, real, 0.1, BF16, 128)
     after = _kernel(q.at[:, 200].add(1.0), k.at[:, 200].add(-2.0),
                     v.at[:, 200].add(3.0), real, 0.1, BF16, 128)
@@ -139,11 +160,13 @@ def test_a_later_token_moves_no_earlier_output(grouped):
 
 @pytest.mark.parametrize("grouped", [False, True],
                          ids=["plain_heads", "grouped_queries"])
-def test_the_selection_runs_the_kernel_where_it_fits(grouped):
+@WIDTHS
+def test_the_selection_runs_the_kernel_where_it_fits(width, grouped):
     """``_causal_attention`` itself, at a window of two of its own blocks
     (768 = 2 x 384)."""
-    q, k, v, real = _operands(grouped, 768, (0, 400), BF16, seed=2)
-    assert ca.block_for(768, 128, 128, 2) == 384
+    q, k, v, real = _operands(grouped, 768, (0, 400), BF16, seed=2,
+                              width=width)
+    assert ca.block_for(768, width, 128, 2) == 384
     want = hm._plain_causal_attention(q, k, v, real, 0.09, BF16)
     got = jax.jit(hm._causal_attention, static_argnums=(4, 5))(
         q, k, v, real, 0.09, BF16)
@@ -164,8 +187,15 @@ def _shape(*dims, dtype=BF16):
     # the tile-wide presets
     ((2, 256, 2, 128), (2, 256, 2, 128), (2, 256, 2, 128), True),
     ((2, 256, 2, 2, 128), (2, 256, 2, 128), (2, 256, 2, 128), True),
-    # ling3's MLA: 128 + 64 wide in q and k
-    ((8, 1920, 32, 192), (8, 1920, 32, 192), (8, 1920, 32, 128), False),
+    # ling3's MLA (one layer in six) and xing4's (every layer): 128 + 64
+    # wide in q and k, a tile and a half, against values of 128
+    ((8, 1920, 32, 192), (8, 1920, 32, 192), (8, 1920, 32, 128), True),
+    ((8, 1920, 32, 192), (8, 1920, 32, 192), (8, 1920, 32, 128), True),
+    ((2, 256, 2, 192), (2, 256, 2, 192), (2, 256, 2, 128), True),
+    ((2, 256, 2, 2, 320), (2, 256, 2, 320), (2, 256, 2, 128), True),
+    # q and k under one tile, or ending in no half tile
+    ((2, 256, 2, 64), (2, 256, 2, 64), (2, 256, 2, 128), False),
+    ((2, 256, 2, 160), (2, 256, 2, 160), (2, 256, 2, 128), False),
     # the small presets: heads of 16
     ((3, 240, 4, 16), (3, 240, 4, 16), (3, 240, 4, 16), False),
     ((3, 256, 2, 4, 16), (3, 256, 2, 16), (3, 256, 2, 16), False),
@@ -173,8 +203,11 @@ def _shape(*dims, dtype=BF16):
     ((2, 200, 2, 128), (2, 200, 2, 128), (2, 200, 2, 128), False),
     # values that fill no lane tile
     ((2, 256, 2, 128), (2, 256, 2, 128), (2, 256, 2, 64), False),
-], ids=["mistral4", "zaya1", "wide_mla", "wide_cca", "ling3_192", "heads_16",
-        "grouped_16", "t_200", "v_64"])
+    ((2, 256, 2, 192), (2, 256, 2, 192), (2, 256, 2, 64), False),
+    ((2, 256, 2, 192), (2, 256, 2, 192), (2, 256, 2, 192), False),
+], ids=["mistral4", "zaya1", "wide_mla", "wide_cca", "ling3_192", "xing4_192",
+        "wide_192", "grouped_320", "qk_64", "qk_160", "heads_16",
+        "grouped_16", "t_200", "v_64", "qk_192_v_64", "qk_192_v_192"])
 def test_the_programs_jaxpr_says_which_path_was_taken(q, k, v, kernel):
     def attend(q, k, v, real):
         return hm._causal_attention(q, k, v, real, 0.1, BF16)
@@ -200,6 +233,89 @@ def test_the_block_comes_from_the_window(tokens, width, itemsize, side):
     assert ca.block_for(tokens, width, width, itemsize) == side
 
 
+@pytest.mark.parametrize("tokens,width,itemsize,side", [
+    (1920, 192, 2, 640), (1920, 192, 4, 640), (768, 192, 2, 384),
+    (256, 320, 4, 256),
+    # a row of k 192 wide is held as 256 lanes: 2 x 3,072 x (256 + 128) x 4
+    # bytes pass the budget that 2 x 3,072 x (192 + 128) x 4 would meet
+    (3072, 192, 4, None), (3072, 128, 4, 512),
+])
+def test_a_half_tile_of_keys_is_budgeted_as_a_whole_one(tokens, width,
+                                                       itemsize, side):
+    assert ca.block_for(tokens, width, 128, itemsize) == side
+
+
+@pytest.mark.parametrize("width,v_width,fits", [
+    (128, 128, True), (192, 128, True), (256, 128, True), (320, 128, True),
+    (192, 256, True), (64, 128, False), (96, 128, False), (160, 128, False),
+    (200, 128, False), (192, 64, False), (192, 192, False), (16, 16, False),
+])
+def test_which_widths_the_kernel_takes(width, v_width, fits):
+    """q and k: whole lane tiles, or whole tiles and a half, from one tile
+    up; the values (the output's lanes): whole tiles, as before."""
+    q, k, v = (2, 4, 256, width), (2, 2, 256, width), (2, 2, 256, v_width)
+    for dtype in (BF16, F32):
+        assert ca.kernel_fits(q, k, v, dtype) is fits
+    assert not ca.kernel_fits(q, k, v, jnp.float16)
+
+
+# -- one pallas_call at both widths ------------------------------------------------
+
+@functools.cache
+def _pallas_call(width, dtype=BF16):
+    """The kernel's equation in the jaxpr of a dispatch of 8 windows of
+    1,920 tokens, 32 heads."""
+    jaxpr = jax.make_jaxpr(lambda q, k, v, real: ca.fused_causal_attention(
+        q, k, v, real, 0.09, jnp.dtype(dtype)))(
+        _shape(8, 32, 1920, width, dtype=dtype),
+        _shape(8, 32, 1920, width, dtype=dtype),
+        _shape(8, 32, 1920, 128, dtype=dtype),
+        _shape(8, 1920, dtype=jnp.bool_))
+    calls = [e for e in seq_attention._equations(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return calls[0]
+
+
+def _body(call) -> list:
+    """The kernel body's primitives in order, the loop's body inside."""
+    return [e.primitive.name
+            for e in seq_attention._equations(call.params["jaxpr"])]
+
+
+@WIDTHS
+def test_the_pallas_call_is_pinned_at_both_widths(width):
+    """What the accepted 128-wide programs hold, spelled out: the name, the
+    grid, the operands and their blocks, the cost the scheduler is told.
+    The tile-and-a-half case stands beside it with the query-key width as
+    its one difference, and the two bodies are one list of primitives, so
+    an edit that moves one and not the other shows here."""
+    call = _pallas_call(width)
+    grid = call.params["grid_mapping"]
+    assert call.params["name"] == ca.KERNEL == "causal_attention"
+    assert grid.grid == (8, 32, 3)
+    assert (grid.num_inputs, grid.num_outputs, len(call.invars)) == (4, 1, 4)
+    assert [tuple(getattr(b, "block_size", b) for b in m.block_shape)
+            for m in grid.block_mappings] == [
+        (1, 1, 640, width), (1, 1, 1920, width), (1, 1, 1920, 128),
+        (1, 3, 640), (1, 1, 640, 128)]
+    assert [v.aval.shape for v in call.invars] == [
+        (8, 32, 1920, width), (8, 32, 1920, width), (8, 32, 1920, 128),
+        (8, 3, 640)]
+    assert [(a.shape, a.dtype) for a in call.params["out_avals"]] == [
+        ((8, 32, 1920, 128), jnp.dtype(BF16))]
+    assert not call.params["input_output_aliases"]
+    visited = 6 * 640 * 640  # six of nine blocks
+    cost = call.params["cost_estimate"]
+    assert cost.flops == 2 * 8 * 32 * visited * (width + 128)
+    assert cost.transcendentals == 8 * 32 * visited
+    assert cost.bytes_accessed == 8 * 1920 * 32 * 2 * (2 * width + 2 * 128)
+    body = _body(call)
+    assert body == _body(_pallas_call(128))
+    assert body.count("dot_general") == 4 and body.count("exp") == 4
+    assert body.count("while") == 1
+
+
 # -- the real shapes, compiled for the chip that is described and not attached -------
 
 @pytest.fixture(scope="module")
@@ -215,21 +331,23 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("heads,groups", [(32, 32), (8, 2)],
-                         ids=["mistral4", "zaya1"])
+@pytest.mark.parametrize("heads,groups,width", [
+    (32, 32, 128), (8, 2, 128), (32, 32, 192)],
+    ids=["mistral4", "zaya1", "xing4"])
 def test_mosaic_compiles_the_kernel_at_a_dispatch_of_the_real_models(
-        one_chip, heads, groups):
-    """8 windows of 1,920 tokens, heads of 128, bfloat16, blocks of 640:
-    what the interpreter cannot refuse (tiling, VMEM) the chip's compiler
-    can, and nothing runs."""
+        one_chip, heads, groups, width):
+    """8 windows of 1,920 tokens, heads of 128 (``xing4``, and ``ling3``'s
+    one such layer: 192 in q and k), bfloat16, blocks of 640: what the
+    interpreter cannot refuse (tiling, VMEM, a block that ends in half a
+    lane tile) the chip's compiler can, and nothing runs."""
     def shape(*dims, dtype=BF16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    assert ca.block_for(1920, 128, 128, 2) == 640
+    assert ca.block_for(1920, width, 128, 2) == 640
     compiled = jax.jit(
         lambda q, k, v, real: ca.fused_causal_attention(
             q, k, v, real, 0.09, jnp.dtype(BF16))).lower(
-        shape(8, heads, 1920, 128), shape(8, groups, 1920, 128),
+        shape(8, heads, 1920, width), shape(8, groups, 1920, width),
         shape(8, groups, 1920, 128), shape(8, 1920, dtype=jnp.bool_)
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -252,10 +370,13 @@ def rows():
 
 def test_the_mixer_holds_the_kernel_and_equals_the_reference(model):
     """``mla`` / ``cca`` alone at 256 tokens, one row padded on the left,
-    in float32: the kernel against the reference's full masked softmax."""
+    in float32: the kernel against the reference's full masked softmax
+    (``ling3``: the preset's second layer, its first is KDA)."""
     ref, config, params, cfg = model
-    kind = cfg.layers[0][0]
-    p = ref.layer_of(params, 1)["mixer"]
+    at, kind = next((i, mixer) for i, (mixer, _) in enumerate(cfg.layers)
+                    if mixer in ("mla", "cca"))
+    p = (ref.layer_of(params, at) if hasattr(ref, "layer_of")
+         else params["layers"][at])["mixer"]
     rng = np.random.default_rng(3)
     t, pads = 256, np.asarray([0, 37])
     x = jnp.asarray(rng.normal(size=(2, t, config["hidden_size"])), F32)
@@ -268,7 +389,11 @@ def test_the_mixer_holds_the_kernel_and_equals_the_reference(model):
 
     assert _holds_kernel(mixer, p, x, real, position)
     with jax.default_matmul_precision("highest"):
-        want = getattr(ref, kind)(p, x, real, position, config)
+        if hasattr(ref, kind):
+            want = getattr(ref, kind)(p, x, real, position, config)
+        else:  # ``mhc_moe_f32`` calls ``mla_moe_f32``'s with its own reading
+            want = mla_moe_f32._mla(p, x, real, position,
+                                    **ref.mla_dims(config))
         got = mixer(p, x, real, position)
     assert np.abs(np.asarray(got) - np.asarray(want))[
         np.asarray(real)].max() < 2e-4
@@ -282,7 +407,7 @@ def test_the_model_equals_the_reference_through_the_kernel(
     short history and a single record."""
     ref, config, params, cfg = model
     hist, filled = _windows(rows, [64, 9, 1], SERVED_LENGTH)
-    want, want_choice = ref.forward(params, config, hist, filled)
+    want, want_counts = ref.forward(params, config, hist, filled)
     assert _holds_kernel(
         lambda p, h, f: hm.apply_serving(p, h, f, cfg=cfg,
                                          compute_dtype=dtype),
@@ -294,7 +419,11 @@ def test_the_model_equals_the_reference_through_the_kernel(
     assert gap.mean() < mean
     if worst is not None:
         assert gap.max() < worst
-        assert np.array_equal(np.asarray(aux["row_choice"]), want_choice)
+        # each row's chosen pairs by layer and expert; ``hybrid_moe_f32``
+        # (``ling3``) counts the served pairs a layer
+        got_counts = (aux["row_choice"] if want_counts.ndim == 3
+                      else aux["pairs"].sum(1))
+        assert np.array_equal(np.asarray(got_counts), want_counts)
 
 
 def test_a_keyed_stream_through_the_scorer_attends_with_the_kernel(

@@ -345,13 +345,15 @@ def test_the_real_configuration_reads_at_its_published_widths():
 
 
 @pytest.mark.parametrize("windows", [4, 8])
-def test_the_real_programs_hold_the_expert_kernels_and_not_attentions(
+def test_the_real_programs_hold_the_expert_kernels_and_the_attention_kernel(
         windows):
     """What ``executable_grid()`` reads back for both served programs, from
     their own jaxprs at the published shapes (nothing is drawn or run):
     hidden 3,584 and expert width 1,024 fill lane tiles, so the held
     experts multiply through the grouped kernels; a query-key width of 128
-    + 64 = 192 does not, so every layer attends on the plain path."""
+    + 64 = 192 is a lane tile and a half against values of 128, so every
+    layer attends through the causal-attention kernel (the plain path
+    until PR 42), and none through ``seq``'s."""
     from ccfd_tpu.ops import grouped_experts, seq_attention
 
     real = _config("benchmark", "configs", "kafka_history_xing4.json")
@@ -363,11 +365,8 @@ def test_the_real_programs_hold_the_expert_kernels_and_not_attentions(
     def program(p, h, f):
         return hm.apply_serving(p, h, f, cfg, jnp.bfloat16)
 
-    assert seq_attention.held_by(program, shapes, hist, filled,
-                                 names=grouped_experts.KERNELS)
-    assert not seq_attention.held_by(
-        program, shapes, hist, filled,
-        names=("causal_attention", "seq_attention"))
+    assert seq_attention.kernels_of(program, shapes, hist, filled) == {
+        "causal_attention", *grouped_experts.KERNELS}
 
 
 def _count(shapes) -> int:
