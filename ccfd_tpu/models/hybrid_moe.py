@@ -1,5 +1,6 @@
-"""Hybrid linear-attention / latent-attention / sparse-expert backbone over
-a customer's tokenised transaction window: the third history family.
+"""Hybrid linear-attention / state-space / latent-attention / sparse-expert
+backbone over a customer's tokenised transaction window: the third history
+family.
 
 A decoder-only language model of several layer kinds in one stack, driven
 by the published ``config.json`` keys of the model it serves. Nothing here
@@ -16,16 +17,25 @@ YaRN frequencies with its softmax scale) and **CCA** (grouped-query softmax
 attention inside a compressed latent: queries, keys and values projected
 down, two causal convolutions over q and k together, a q-k mean added back
 across the grouping, half the value heads read from the previous token,
-L2-normed q and k with a learned temperature, partial rotary). Routers of
-the **sparse expert layer**: ``top_k`` (sigmoid or softmax scores over all
+L2-normed q and k with a learned temperature, partial rotary), **Mamba-2**
+(``mamba2``: the selective state-space mixer: one projection to a gate, the
+convolved x, B and C and a step dt a head; a causal depthwise convolution
+with a bias; per head S_t = e^(dt_t A) S_(t-1) + dt_t x_t B_t^T, y_t = S_t
+C_t + D x_t with B and C shared by the heads of a group; the gate, then an
+RMS norm over all inner values; served as a chunked scan whose chunk is the
+deployment's ``scan_chunk``) and **GQA** (``gqa``: plain grouped-query
+causal softmax attention without positions, the scale the model's own).
+Routers of the **sparse expert layer**: ``top_k`` (sigmoid or softmax
+scores over all
 routed experts, an optional expert bias for the choice, group-limited or
 not, weights renormalised over the chosen) with or without a shared
 expert; or ``carried_mlp``, a small MLP on a down-projection whose hidden
 state is handed from one layer's router to the next, softmax, top 1, and a
 last output that means *no expert* (the token skips the layer).
 **The residual path** is a table too (``RESIDUALS``): ``plain`` (x + f(x)),
-``scaled`` (a learned scale and bias on the stream and on the sublayer's
-output) or ``mhc``, manifold-constrained hyper-connections: a token's state
+``multiplied`` (x + m f(x), m one constant of the model), ``scaled`` (a
+learned scale and bias on the stream and on the sublayer's output) or
+``mhc``, manifold-constrained hyper-connections: a token's state
 between sublayers is ``streams`` rows of the hidden width, and every
 sublayer reads one mix of them and writes to all of them through three
 maps computed from the token's own streams, the stream-to-stream one made
@@ -34,7 +44,11 @@ A stack whose layers are alike may arrive as one tree with the layers on
 the leading axis of every leaf and is then scanned (``lax.scan``: one layer
 is compiled); a stack that arrives as a list is unrolled, and an entry of
 the list may itself be such a tree of alike layers (leading dense layers
-listed, the expert layers behind them scanned). Causal throughout. The window (B, L, F) of the ``HistoryStore`` is
+listed, the expert layers behind them scanned; or two stacks of alike
+layers around a single one of another kind). A model may multiply its
+embedding and divide its logits by constants (``embed_scale``,
+``logit_divisor``). Causal throughout. The window (B, L, F) of the
+``HistoryStore`` is
 tokenised on the device (TabFormer-style: column j of a record is token
 j * bins + its quantile bin), so a verdict is one L * F token pass read
 out at the newest record's last token.
@@ -62,20 +76,25 @@ factor, no pair dropped, work in proportion to the pairs served.
 (right-aligned, as ``StagingBatch`` stages them). Positions count from a
 row's first real token; padding keys are masked in MLA; a padding token
 has beta = 0, alpha = 1 and sends zeros into the convolution, so the KDA
-state passes it unchanged; it routes to no expert. A row's verdict is
+state passes it unchanged (under ``mamba2``: dt = 0, so its decay is 1 and
+it puts nothing in; zeros into the convolution; a masked key under
+``gqa``); it routes to no expert. A row's verdict is
 therefore the same at every window length that holds its history.
 
 The equations, with the key each symbol is read from, are in the plain
 references ``benchmark/reference/hybrid_moe_f32.py``, ``cca_moe_f32.py``,
-``mla_moe_f32.py`` and ``mhc_moe_f32.py`` (which import nothing from
-here); the parameter tree is the one their ``make_params`` draw.
+``mla_moe_f32.py``, ``mhc_moe_f32.py`` and ``ssm_moe_f32.py`` (which import
+nothing from here); the parameter tree is the one their ``make_params`` draw.
 
 Precision: matrices bfloat16, products accumulated in float32, the
 residual stream, norms, gates, softmax and the router in float32 (the
 router and its carried state at ``highest``: a token near a tie must
 choose as the model does; the hyper-connections' maps too: an error in the
 stream-to-stream map is every later sublayer's), the KDA state and
-everything inside a chunk, CCA's convolution sums and L2 norms in float32.
+everything inside a chunk, CCA's convolution sums and L2 norms in float32;
+Mamba-2's convolution, steps, decays, state and everything inside a chunk
+too (a decay is always the exponential of a difference of running sums of
+log-decays that is <= 0, never a quotient of two exponentials).
 
 Device scopes (``jax.named_scope``, so a capture's operations carry them):
 ``lm.embed``, ``kda``, ``mla`` (inside it ``mla.project``: every
@@ -84,7 +103,11 @@ it ``cca.conv`` and ``cca.attend``; both ``attend`` scopes hold
 :func:`_causal_attention`: the Pallas kernel where q and k are whole
 128-lane tiles or whole tiles and a half wide, the values whole tiles and
 the window a multiple of 128 tokens, the plain path at every other
-shape), ``dense_ffn``, ``moe.route``,
+shape), ``mamba`` (the ``mamba2`` mixer; inside it ``mamba.project``: the
+in- and out-projections, ``mamba.conv``, ``mamba.scan``: steps, decays and
+the chunked scan, ``mamba.gate``: the gate and its norm), ``gqa`` (inside it
+``gqa.project`` and ``gqa.attend``, which holds :func:`_causal_attention`
+too), ``dense_ffn``, ``moe.route``,
 ``moe.experts``, ``moe.shared``, ``lm.head``, and ``hc`` around everything
 the ``mhc`` rule adds (inside it ``hc.maps``: the flattened norm, the
 product, the sigmoids and Sinkhorn; ``hc.mix``: the sublayer's input from
@@ -107,8 +130,8 @@ Params = Mapping[str, Any]
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
-# inside a KDA chunk: what touches the carried state multiplies float32
-# operands as three bfloat16 passes; what stays inside the chunk (the
+# inside a KDA or a Mamba-2 chunk: what touches the carried state multiplies
+# float32 operands as three bfloat16 passes; what stays inside the chunk (the
 # pairwise matrices and their products with u) as one; the triangular
 # inverse at ``highest`` (few operations, and the solve's error is every
 # later token's)
@@ -237,6 +260,67 @@ class Cca:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba2:
+    """The selective state-space mixer: ``heads`` heads of ``head_dim``
+    with a state of ``head_dim`` x ``state`` each, B and C shared by the
+    heads of a group, a causal depthwise convolution of ``conv`` taps; and
+    the deployment's ``chunk``, the tokens a step of the served scan takes
+    at once (no part of the result)."""
+
+    heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv: int
+    chunk: int
+
+    @classmethod
+    def read(cls, m: Mapping[str, Any]) -> "Mamba2":
+        heads, hd = int(m["mamba_n_heads"]), int(m["mamba_d_head"])
+        groups = int(m["mamba_n_groups"])
+        if heads * hd != int(m["mamba_expand"]) * int(m["hidden_size"]) \
+                or heads % groups or m["mamba_proj_bias"] \
+                or not m["mamba_conv_bias"]:
+            raise ValueError("mamba2: heads x head width = mamba_expand x "
+                             "hidden, heads a multiple of the groups, no "
+                             "bias on the projections, one on the "
+                             "convolution")
+        chunk = int(m.get("scan_chunk", m["mamba_chunk_size"]))
+        if chunk < 1:
+            raise ValueError("scan_chunk is a count of tokens")
+        return cls(heads, hd, int(m["mamba_d_state"]), groups,
+                   int(m["mamba_d_conv"]), chunk)
+
+    def chunk_for(self, tokens: int) -> int:
+        """The chunk of a window of ``tokens``: a window shorter than the
+        chunk is one chunk; a longer one is padded on the left to whole
+        chunks."""
+        return min(self.chunk, tokens)
+
+
+@dataclasses.dataclass(frozen=True)
+class Gqa:
+    """Plain grouped-query softmax attention without positions."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    scale: float  # what the scores are multiplied by before the softmax
+
+    @classmethod
+    def read(cls, m: Mapping[str, Any]) -> "Gqa":
+        heads, kv = int(m["num_attention_heads"]), int(
+            m["num_key_value_heads"])
+        if heads % kv or int(m["hidden_size"]) % heads \
+                or m["position_embedding_type"] != "nope" \
+                or m["attention_bias"]:
+            raise ValueError("gqa: query heads a multiple of the key-value "
+                             "heads, no positions, no bias")
+        return cls(heads, kv, int(m["hidden_size"]) // heads,
+                   float(m["attention_multiplier"]))
+
+
+@dataclasses.dataclass(frozen=True)
 class TopK:
     """The router that scores every routed expert and keeps the largest
     ``per_token`` (of ``HybridConfig``), inside the best groups where the
@@ -273,6 +357,14 @@ class Mhc:
 
 
 @dataclasses.dataclass(frozen=True)
+class Multiplied:
+    """The ``multiplied`` residual rule: the one constant on every
+    sublayer's output."""
+
+    multiplier: float
+
+
+@dataclasses.dataclass(frozen=True)
 class HybridConfig:
     """The model's settings, hashable so that a jit takes them as static:
     the stack, each of its kinds' own settings, and what all models share."""
@@ -293,12 +385,15 @@ class HybridConfig:
     residual: str = "plain"  # name in RESIDUALS
     residual_settings: Any = None  # that rule's settings (None: it has none)
     tied_head: bool = False  # the head is the embedding
+    embed_scale: float = 1.0  # on every token's embedding
+    logit_divisor: float = 1.0  # under the logits
 
     @classmethod
     def from_dict(cls, m: Mapping[str, Any]) -> "HybridConfig":
         """From a configuration under the published key names (plus the
         cut: ``layers_kept``, ``experts_held``, ``num_experts_routed_over``,
-        and the deployment's ``bins``, ``kda_chunk`` and ``readout``)."""
+        and the deployment's ``bins``, ``kda_chunk`` / ``scan_chunk`` and
+        ``readout``)."""
         kind = m.get("model_type", "ling")
         if kind not in READERS:
             raise ValueError(f"hybrid_moe reads model_type {sorted(READERS)}"
@@ -400,8 +495,35 @@ def _read_xing4(m: Mapping[str, Any]) -> dict:
         tied_head=bool(m["tie_word_embeddings"]))
 
 
+def _read_granite(m: Mapping[str, Any]) -> dict:
+    """granite-4.0-h: by ``layer_types`` a Mamba-2 mixer or, in every
+    tenth layer or so, grouped-query attention without positions; top-k
+    by logit with a softmax over the chosen logits (``TopK`` softmax over
+    all, renormalised over the chosen, is that softmax), one shared
+    expert, a tied head, and four constants: on the embedding, on every
+    sublayer's output, under the logits and as the softmax scale."""
+    _held_all_of(m, "num_local_experts")
+    if m["normalization_function"] != "rmsnorm" or m["hidden_act"] != "silu":
+        raise ValueError("granitemoehybrid: RMS norms, SiLU gates")
+    kinds = {"mamba": "mamba2", "attention": "gqa"}
+    layers = tuple((kinds[m["layer_types"][i]], "moe")
+                   for i in m["layers_kept"])
+    settings = {"mamba2": Mamba2, "gqa": Gqa}
+    return dict(
+        layers=layers, mixers=tuple(
+            (name, settings[name].read(m))
+            for name in sorted({mixer for mixer, _ in layers})),
+        router="top_k", routing=TopK("softmax", False, 1, 1, 1.0),
+        residual="multiplied",
+        residual_settings=Multiplied(float(m["residual_multiplier"])),
+        tied_head=bool(m["tie_word_embeddings"]),
+        embed_scale=float(m["embedding_multiplier"]),
+        logit_divisor=float(m["logits_scaling"]))
+
+
 READERS = {"ling": _read_ling, "zaya": _read_zaya,
-           "mistral4": _read_mistral4, "xing4_0": _read_xing4}
+           "mistral4": _read_mistral4, "xing4_0": _read_xing4,
+           "granitemoehybrid": _read_granite}
 
 
 def owns(params: Any) -> bool:
@@ -839,6 +961,121 @@ def cca(p, z, real, position, cfg: HybridConfig, dtype):
     return _mm(o.reshape(b, t, h * hd), p["wo"], dtype)
 
 
+# -- Mamba-2 and plain grouped-query attention -------------------------------------
+
+def _ssd(x, bm, cm, dt, a, c: int):
+    """The selective state-space recurrence S_t = e^(a_t) S_(t-1) + dt_t
+    x_t B_t^T, y_t = S_t C_t, for every (row, head) at once, a chunk of
+    ``c`` tokens at a time: ``(y (B, T, H, P), the most negative running
+    sum of a inside a chunk)``. ``x`` (B, T, H, P), ``bm`` and ``cm`` (B, T, G,
+    N), ``dt`` and the log-decays ``a`` <= 0 (B, T, H), all float32; the H
+    heads are G groups of H / G that share B and C. With R_t the running
+    sum of a inside the chunk: y_t = sum_(s<=t) (C_t . B_s) e^(R_t - R_s)
+    dt_s x_s + e^(R_t) S_0 C_t, S_0 the state the chunks before left,
+    which moves on by S_0 <- e^(R_last) S_0 + sum_s e^(R_last - R_s) dt_s
+    x_s B_s^T. A decay is always the exponential of a difference of
+    running sums that is <= 0 (masked before the exponential where s > t),
+    never a quotient of two exponentials. A window is padded on the left
+    to whole chunks: a padding token has dt = a = 0 and passes the state
+    unchanged."""
+    b, t, h, p = x.shape
+    g, n = bm.shape[2:]
+    k = h // g
+    lead = -t % c
+    nc = (t + lead) // c
+
+    def chunks(v, *shape):  # (B, T, ...) -> (B, NC, C, *shape)
+        v = jnp.pad(v, ((0, 0), (lead, 0)) + ((0, 0),) * (v.ndim - 2))
+        return v.reshape(b, nc, c, *shape)
+
+    u = chunks(x * dt[..., None], g, k, p)  # dt_s x_s
+    bm, cm = chunks(bm, g, n), chunks(cm, g, n)
+    run = jnp.cumsum(chunks(a, g, k), axis=2)  # R_t, inclusive
+    at = jnp.arange(c)
+    by_head = jnp.moveaxis(run, 2, -1)  # (B, NC, G, K, C)
+    decay = jnp.exp(jnp.where(
+        at[:, None] >= at[None, :],
+        by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+    scores = jnp.einsum("bctgn,bcsgn->bcgts", cm, bm, precision=KDA_INSIDE)
+    y = jnp.einsum("bcgkts,bcsgkp->bctgkp", scores[:, :, :, None] * decay, u,
+                   precision=KDA_INSIDE)
+    last = run[:, :, -1]  # (B, NC, G, K)
+    own = jnp.einsum("bcsgkp,bcsgn->bcgkpn",
+                     u * jnp.exp(last[:, :, None] - run)[..., None], bm,
+                     precision=KDA_PRECISION)
+
+    def carry_on(state, chunk):
+        own_c, whole = chunk
+        return state * whole[..., None, None] + own_c, state
+
+    _, start = jax.lax.scan(
+        carry_on, jnp.zeros((b, g, k, p, n), F32),
+        (jnp.moveaxis(own, 1, 0), jnp.moveaxis(jnp.exp(last), 1, 0)))
+    y = y + jnp.einsum("bctgn,cbgkpn->bctgkp", cm, start,
+                       precision=KDA_PRECISION) * jnp.exp(run)[..., None]
+    return y.reshape(b, t + lead, h, p)[:, lead:], run.min()
+
+
+def _gated_norm(y, gate, weight, eps):
+    """RMSNorm(y * SiLU(gate)) w: the gate first, the norm after it, over
+    all the values as one group."""
+    return _rms(y * jax.nn.silu(gate), weight, eps)
+
+
+def mamba2(p, z, real, cfg: HybridConfig, dtype):
+    """(B, T, hidden) normed input -> ``((B, T, hidden) mixer output, the
+    most negative running log-decay inside a chunk)``: one projection to
+    [gate | x B C | dt], the causal depthwise convolution with its bias
+    and SiLU over x, B and C together, the chunked scan (:func:`_ssd`) in
+    float32, the skip D x, the gate and after it the RMS norm over all the
+    inner values, the output projection. The convolution runs on (B, T,
+    tiles, 128): a shift by a token then moves whole tiles."""
+    b, t, _ = z.shape
+    s = cfg.mixer("mamba2")
+    h, hd, n, g = s.heads, s.head_dim, s.state, s.groups
+    inner = h * hd
+    wide = inner + 2 * g * n
+    keep = real[:, :, None].astype(F32)
+    with jax.named_scope("mamba.project"):
+        proj = _mm(z, p["w_in"], dtype)
+        gate, xbc = proj[..., :inner], proj[..., inner:inner + wide]
+        dt = proj[..., inner + wide:]
+    with jax.named_scope("mamba.conv"):
+        lane = 128 if wide % 128 == 0 else wide
+        xbc = jax.nn.silu(_short_conv(
+            (xbc * keep).reshape(b, t, -1, lane),
+            p["conv"].reshape(s.conv, -1, lane))
+            + p["conv_b"].reshape(-1, lane)).reshape(b, t, wide)
+    with jax.named_scope("mamba.scan"):
+        x = xbc[..., :inner].reshape(b, t, h, hd)
+        dt = jax.nn.softplus(dt + p["dt_bias"]) * keep
+        y, low = _ssd(x, xbc[..., inner:inner + g * n].reshape(b, t, g, n),
+                      xbc[..., inner + g * n:].reshape(b, t, g, n), dt,
+                      -jnp.exp(p["a_log"]) * dt, s.chunk_for(t))
+        y = y + p["d"][:, None] * x
+    with jax.named_scope("mamba.gate"):
+        y = _gated_norm(y.reshape(b, t, inner), gate, p["norm"], cfg.eps)
+    with jax.named_scope("mamba.project"):
+        return _mm(y, p["w_out"], dtype), low
+
+
+def gqa(p, z, real, cfg: HybridConfig, dtype):
+    """(B, T, hidden) normed input -> (B, T, hidden) mixer output: causal
+    softmax attention of ``heads`` query heads over ``kv_heads`` key-value
+    heads, no positions, no norm, the scale the model's own."""
+    b, t, _ = z.shape
+    s = cfg.mixer("gqa")
+    h, g, hd = s.heads, s.kv_heads, s.head_dim
+    with jax.named_scope("gqa.project"):
+        q = _mm(z, p["wq"], dtype).reshape(b, t, g, h // g, hd).astype(dtype)
+        k = _mm(z, p["wk"], dtype).reshape(b, t, g, hd).astype(dtype)
+        v = _mm(z, p["wv"], dtype).reshape(b, t, g, hd).astype(dtype)
+    with jax.named_scope("gqa.attend"):
+        o = _causal_attention(q, k, v, real, s.scale, dtype)
+    with jax.named_scope("gqa.project"):
+        return _mm(o.reshape(b, t, h * hd), p["wo"], dtype)
+
+
 # -- the expert layer -------------------------------------------------------------
 
 def route(p, z, real, cfg: HybridConfig):
@@ -1048,12 +1285,18 @@ ROUTERS = {  # name -> f(p, z, r, real, cfg): (chosen, weights, r)
     "top_k": lambda p, z, r, real, cfg: (*route(p, z, real, cfg), r),
     "carried_mlp": route_carried,
 }
-MIXERS = {  # name -> f(p, z, real, position, cfg, dtype)
-    "kda": lambda p, z, real, position, cfg, dtype: kda(p, z, real, cfg,
-                                                        dtype),
-    "mla": mla,
-    "cca": cca,
+MIXERS = {  # name -> f(p, z, real, position, cfg, dtype): (y, its report)
+    "kda": lambda p, z, real, position, cfg, dtype: (
+        kda(p, z, real, cfg, dtype), None),
+    "mla": lambda *args: (mla(*args), None),
+    "cca": lambda *args: (cca(*args), None),
+    "mamba2": lambda p, z, real, position, cfg, dtype: mamba2(
+        p, z, real, cfg, dtype),
+    "gqa": lambda p, z, real, position, cfg, dtype: (
+        gqa(p, z, real, cfg, dtype), None),
 }
+# a mixer's device scope, where it is not the mixer's name
+MIXER_SCOPES = {"mamba2": "mamba"}
 
 
 # -- the model ----------------------------------------------------------------------
@@ -1066,6 +1309,12 @@ def _plain(p, x, sublayer, real, cfg):
     its input itself."""
     y, extra = sublayer(x)
     return x + y, extra, None
+
+
+def _multiplied(p, x, sublayer, real, cfg):
+    """x + m f(x), m one constant of the model (its settings)."""
+    y, extra = sublayer(x)
+    return x + cfg.residual_settings.multiplier * y, extra, None
 
 
 def _scaled(p, x, sublayer, real, cfg):
@@ -1135,6 +1384,7 @@ def _mhc(p, x, sublayer, real, cfg):
 
 RESIDUALS = {  # name -> f(p, x, sublayer, real, cfg): (x, extra, defect)
     "plain": _plain,
+    "multiplied": _multiplied,
     "scaled": _scaled,
     "mhc": _mhc,
 }
@@ -1142,16 +1392,16 @@ RESIDUALS = {  # name -> f(p, x, sublayer, real, cfg): (x, extra, defect)
 
 def _layer(p, x, r, kind, real, position, cfg: HybridConfig, dtype):
     """One layer of kind ``(mixer, feed-forward)``: ``(x, r, counts,
-    defect)``, ``counts`` None where the layer has no experts, ``defect``
-    None where the residual rule has none."""
+    defect, report)``, ``counts`` None where the layer has no experts,
+    ``defect`` None where the residual rule has none, ``report`` None
+    where the mixer reports nothing."""
     mixer, ffn = kind
     rule = RESIDUALS[cfg.residual]
 
     def mix(x):
         z = _rms(x, p["norm1"], cfg.eps)
-        with jax.named_scope(mixer):
-            return MIXERS[mixer](p["mixer"], z, real, position, cfg,
-                                 dtype), None
+        with jax.named_scope(MIXER_SCOPES.get(mixer, mixer)):
+            return MIXERS[mixer](p["mixer"], z, real, position, cfg, dtype)
 
     def feed(x):
         z = _rms(x, p["norm2"], cfg.eps)
@@ -1161,10 +1411,10 @@ def _layer(p, x, r, kind, real, position, cfg: HybridConfig, dtype):
         y, state, counts = moe(p["ffn"], z, r, real, cfg, dtype)
         return y, (state, counts)
 
-    x, _, first = rule(p.get("res1"), x, mix, real, cfg)
+    x, report, first = rule(p.get("res1"), x, mix, real, cfg)
     x, (r, counts), second = rule(p.get("res2"), x, feed, real, cfg)
-    return x, r, counts, None if first is None else jnp.maximum(first,
-                                                                second)
+    return (x, r, counts,
+            None if first is None else jnp.maximum(first, second), report)
 
 
 def _stacked(p) -> int | None:
@@ -1189,6 +1439,8 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
     with jax.named_scope("lm.embed"):
         ids = tokenise(params["edges"], hist.astype(F32), cfg.bins)
         x = params["embed"][ids].astype(F32)
+        if cfg.embed_scale != 1.0:
+            x = x * cfg.embed_scale
         if cfg.residual == "mhc":  # the embedding in every stream
             x = jnp.broadcast_to(x[:, :, None, :], (
                 b, t, cfg.residual_settings.streams, x.shape[-1]))
@@ -1198,7 +1450,8 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
         r = jnp.zeros((b * t, _router_width(layers)), F32)
     # a tree of alike layers, stacked, is scanned (one is compiled); a
     # list is unrolled, and an entry of it may be such a stack
-    each, first = [], 0  # a layer's or a stack's (counts, defect), stacked?
+    # a layer's or a stack's (counts, defect, report), and: stacked?
+    each, first = [], 0
     for p in [layers] if isinstance(layers, Mapping) else layers:
         n = _stacked(p)
         kind = cfg.layers[first]
@@ -1227,7 +1480,7 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
             leaf if whole else jnp.expand_dims(leaf, 0)
             for leaf, whole in zip(leaves, stacked)]), *trees)
 
-    counts, defect = over_layers(0), over_layers(1)
+    counts, defect, report = (over_layers(i) for i in range(3))
     if counts is None:
         none = jnp.zeros((0,), jnp.int32)
         counts = {"pairs": jnp.zeros((0, cfg.held_count), jnp.int32),
@@ -1243,6 +1496,8 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
            "row_choice": jnp.swapaxes(counts["row_choice"], 0, 1)}
     if defect is not None:  # the rule's, over all sublayers
         aux["hc_defect"] = defect.max()
+    if report is not None:  # the state-space mixers', over their layers
+        aux["ssm_log_decay_min"] = report.min()
     return x, aux
 
 
@@ -1260,10 +1515,14 @@ def slice_logits(params: Params, x, cfg: HybridConfig, dtype=jnp.bfloat16):
             x = x.sum(-2)
         z = _rms(x, params["final_norm"], cfg.eps)
         if cfg.tied_head:
-            return jnp.einsum("...i,vi->...v", z.astype(dtype),
-                              params["embed"].astype(dtype),
-                              preferred_element_type=F32)
-        return _mm(z, params["head"], dtype)
+            logits = jnp.einsum("...i,vi->...v", z.astype(dtype),
+                                params["embed"].astype(dtype),
+                                preferred_element_type=F32)
+        else:
+            logits = _mm(z, params["head"], dtype)
+        if cfg.logit_divisor != 1.0:
+            logits = logits / cfg.logit_divisor
+        return logits
 
 
 @partial(jax.jit, static_argnames=("cfg", "compute_dtype"))
@@ -1288,7 +1547,12 @@ def apply_serving(params: Params, hist, filled, cfg: HybridConfig,
     (token-layers whose choice was *skip*), ``row_pairs`` (B,) and
     ``row_choice`` (B, expert layers, routed); under the ``mhc`` residual
     rule also ``hc_defect``, the largest abs(row or column sum of a
-    stream-to-stream map - 1) over the real tokens and the sublayers."""
+    stream-to-stream map - 1) over the real tokens and the sublayers;
+    where a layer mixes by ``mamba2`` also ``ssm_log_decay_min``, the most
+    negative running sum of log-decays inside a chunk of the scan over
+    tokens, heads and layers (how far e^(R_t) is from float32's smallest:
+    below -87 a chunk's late tokens no longer see the state it began
+    with, as in the recurrence itself)."""
     x, aux = hidden_states(params, hist, filled, cfg, compute_dtype)
     z = slice_logits(params, x[:, -1], cfg, compute_dtype)
     aux["logits"] = z
@@ -1307,7 +1571,9 @@ def make_observer(registry: Any):
     ``skipped_tokens``, ``routed_tokens``, ``max_expert_pairs``; where the
     program hands back ``hc_defect`` (the ``mhc`` residual rule), that too,
     and the gauge ``lm_hc_defect_max``, the largest since the process
-    began."""
+    began; where it hands back ``ssm_log_decay_min`` (a ``mamba2`` layer),
+    that and the gauge ``lm_ssm_log_decay_min``, the lowest since the
+    process began."""
     served = registry.counter(
         "moe_pairs_served_total",
         "(token, held expert) pairs the expert layers multiplied")
@@ -1344,6 +1610,11 @@ def make_observer(registry: Any):
         "largest abs(row or column sum - 1) of a hyper-connection's "
         "stream-to-stream map over real tokens, sublayers and dispatches")
 
+    decay = registry.gauge(
+        "lm_ssm_log_decay_min",
+        "most negative running sum of log-decays inside a chunk of the "
+        "state-space scan over real tokens, heads, layers and dispatches")
+
     def observe(aux: dict) -> dict:
         pairs = aux["pairs"]  # (expert layers, held)
         n_tokens = int(aux["routed_tokens"])
@@ -1371,6 +1642,9 @@ def make_observer(registry: Any):
         if "hc_defect" in aux:
             stats["hc_defect"] = float(aux["hc_defect"])
             defect.set(max(defect.value(), stats["hc_defect"]))
+        if "ssm_log_decay_min" in aux:
+            stats["ssm_log_decay_min"] = float(aux["ssm_log_decay_min"])
+            decay.set(min(decay.value(), stats["ssm_log_decay_min"]))
         return stats
 
     return observe
@@ -1382,6 +1656,10 @@ def register() -> None:
     ``swap_params`` refuses it by name (counted) instead of running the
     device out of memory."""
     from ccfd_tpu.models.registry import HistorySpec, register_history
+
+    def scan_chunk(cfg: HybridConfig, tokens: int) -> int | None:
+        settings = dict(cfg.mixers).get("mamba2")
+        return None if settings is None else settings.chunk_for(tokens)
 
     def make_apply(dtype, _pos_length, cfg: HybridConfig):
         if cfg is None:
@@ -1402,4 +1680,5 @@ def register() -> None:
                       in (*cfg.mixers, (cfg.router, cfg.routing),
                           (cfg.residual, cfg.residual_settings))
                       if settings is not None}},
-        config_from=HybridConfig.from_dict, swappable=False))
+        config_from=HybridConfig.from_dict, scan_chunk=scan_chunk,
+        swappable=False))

@@ -46,7 +46,10 @@ class HistorySpec:
     ``describe(config)`` adds to ``executable_grid()``. ``config_from``
     builds the family's settings from a configuration under the served
     model's published key names (a deployment that knows the family by
-    name only). ``swappable``:
+    name only). ``scan_chunk(config, tokens)``: the tokens a step of the
+    state-space scan takes at once in the executable of ``tokens``-token
+    windows, None where the model has no such scan (a family without the
+    function has none). ``swappable``:
     whether ``swap_params`` may stage a second tree beside the served
     one."""
 
@@ -58,6 +61,7 @@ class HistorySpec:
     mesh_logits: Callable[..., jax.Array] | None = None
     describe: Callable[[Any], dict] | None = None
     config_from: Callable[[Any], Any] | None = None
+    scan_chunk: Callable[[Any, int], int | None] | None = None
     swappable: bool = True
 
 

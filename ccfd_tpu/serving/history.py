@@ -1224,7 +1224,8 @@ class SeqScorer:
     def executable_grid(self) -> dict:
         """The (L, B) executable grid with per-executable dispatch counts,
         whether the executable's attention is a kernel, whether its held
-        experts multiply through the grouped kernels and whether
+        experts multiply through the grouped kernels, the chunk of its
+        state-space scan where the model has one, and whether
         its history batch crosses flat — the seq family's entry in the
         device telemetry inventory."""
         with self._params_lock:
@@ -1238,7 +1239,8 @@ class SeqScorer:
                     "l_bucket": int(lb), "b_bucket": int(b),
                     "attn_kernel": attn_kernel,
                     "expert_kernel": expert_kernel,
-                    "flat_wire": _takes_flat_wire(apply_fn, lb)}
+                    "flat_wire": _takes_flat_wire(apply_fn, lb),
+                    **self._scan_chunk(lb)}
                 if self._c_bucket is not None:
                     entry["dispatches"] = int(self._c_bucket.value(
                         {"l_bucket": str(lb), "b_bucket": str(b)}))
@@ -1259,6 +1261,15 @@ class SeqScorer:
                 # any traced executable actually sharded its attention
                 out["seq_parallel_engaged"] = self._sp_engaged > 0
         return out
+
+    def _scan_chunk(self, lb: int) -> dict:
+        """``{"scan_chunk": tokens a step}`` of the executables of
+        ``lb``-record windows, where the served model has a state-space
+        scan (``HistorySpec.scan_chunk``); else nothing."""
+        of = self._family.scan_chunk
+        chunk = None if of is None else of(
+            self._family_config, lb * self.store.num_features)
+        return {} if chunk is None else {"scan_chunk": int(chunk)}
 
     def _bucket(self, n: int) -> int:
         for b in self.batch_sizes:
@@ -1565,7 +1576,8 @@ class SeqScorer:
                                tokens=tokens,
                                attn_kernel=int(attn_kernel),
                                expert_kernel=int(expert_kernel),
-                               flat_wire=int(flat_wire)) as ph:
+                               flat_wire=int(flat_wire),
+                               **self._scan_chunk(lb)) as ph:
                         # device-fault dispatch seam (runtime/faults.py):
                         # device_hang / compile_stall drill the heal ladder
                         # through the seq path's own dispatch loop

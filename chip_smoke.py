@@ -453,6 +453,64 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             rows, list(range(10_000, 10_016))), np.asarray(direct), 1e-6),
         "grid": s.executable_grid()}
 
+    # the family's fifth model (a Mamba-2 state-space mixer in four layers
+    # of five, two stacks scanned around the one layer of grouped-query
+    # attention without positions, top 4 of 12 experts by logit of which 6
+    # are held, a shared expert, the four constant multipliers, tied head):
+    # the same seam, every chosen pair served here or the other chip's, the
+    # scan's chunk in the grid and its running log-decay handed back
+    from benchmark.reference import ssm_moe_f32
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "granite4h_small_config.json")) as f:
+        small = json.load(f)
+    g_cfg = hybrid_moe.HybridConfig.from_dict(small)
+    gp = ssm_moe_f32.make_params(small)
+    s = SeqScorer(gp, length=8, batch_sizes=(16,), family="hybrid_moe",
+                  family_config=g_cfg, max_customers=64)
+    s.warmup()
+    direct, aux = hybrid_moe.apply_serving(
+        gp, hist, np.ones(16, np.int32), g_cfg, jnp.bfloat16)
+    check("hybrid_moe (granitemoehybrid) served + absent pairs = 4 a token "
+          "and layer, a chunk in every executable, decays below 0",
+          int(aux["pairs_served"]) + int(aux["pairs_absent"])
+          == 4 * int(aux["routed_tokens"]) * g_cfg.moe_layers
+          and all(g["scan_chunk"] == 32 for g in s.executable_grid()["grid"])
+          and float(aux["ssm_log_decay_min"]) < 0)
+    zoo["hybrid_moe.granitemoehybrid"] = {
+        "max_abs_diff": check.close(
+            "hybrid_moe (granitemoehybrid) B=16 L=8", s.score(
+                rows, list(range(10_000, 10_016))), np.asarray(direct), 1e-6),
+        "grid": s.executable_grid()}
+
+    # the Mamba-2 mixer at widths that fill lane tiles, which that preset's
+    # 16-wide heads do not (hidden 256, 8 heads of 64, a state of 128, 768
+    # tokens, one row padded on the left past the first chunk): the served
+    # chunked scan at chunks of 128 and 384 against the recurrence a token
+    # at a time of the plain reference, both in float32 on the device
+    wide = dict(small, hidden_size=256, mamba_n_heads=8, mamba_d_head=64,
+                mamba_d_state=128, mamba_n_groups=1, layers_kept=[0],
+                layer_stack="listed")
+    wp = jax.jit(lambda: ssm_moe_f32.make_params(wide)["layers"][0][
+        "mixer"])()
+    rng = np.random.default_rng(44)
+    z = jnp.asarray(rng.normal(size=(2, 768, 256)), jnp.float32)
+    real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [200]]))
+    keep = np.asarray(real)[..., None]
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ssm_moe_f32.mamba(wp, z, real, wide))
+        for chunk in (128, 384):
+            w_cfg = hybrid_moe.HybridConfig.from_dict(
+                dict(wide, scan_chunk=chunk))
+            got, low = jax.jit(lambda p, z: hybrid_moe.mamba2(
+                p, z, real, w_cfg, jnp.float32))(wp, z)
+            zoo[f"hybrid_moe.mamba2.chunk{chunk}"] = {
+                "max_abs_diff": check.close(
+                    f"hybrid_moe mamba2 at lane-wide heads, chunk {chunk}: "
+                    "chunked scan vs recurrence", np.asarray(got) * keep,
+                    want * keep, 2e-2),
+                "log_decay_min": float(low)}
+
     # the family's causal attention at head widths the small presets lack
     # (128 wide, and MLA's 128 + 64 = 192 in q and k against values of
     # 128; 768 tokens = two blocks of 384): Mosaic compiles the kernel

@@ -385,7 +385,7 @@ def test_the_mixer_holds_the_kernel_and_equals_the_reference(model):
                                       0))
 
     def mixer(p, x, real, position):
-        return hm.MIXERS[kind](p, x, real, position, cfg, F32)
+        return hm.MIXERS[kind](p, x, real, position, cfg, F32)[0]
 
     assert _holds_kernel(mixer, p, x, real, position)
     with jax.default_matmul_precision("highest"):

@@ -235,7 +235,7 @@ def test_one_stream_with_unit_maps_is_the_plain_rule(small, params, cfg):
     scaled, _, _ = hm.RESIDUALS["scaled"](unit, x[:, :, 0], sublayer, real,
                                           one)
     assert np.allclose(np.asarray(scaled), np.asarray(want), atol=1e-6)
-    assert set(hm.RESIDUALS) == {"plain", "scaled", "mhc"}
+    assert set(hm.RESIDUALS) == {"plain", "multiplied", "scaled", "mhc"}
 
 
 # -- padding and causality ------------------------------------------------------------
@@ -435,7 +435,8 @@ def _plain_loop(params, hist, filled, cfg, dtype):
         mixer, ffn = kind
         z = hm._rms(x, p["norm1"], cfg.eps)
         with jax.named_scope(mixer):
-            y = hm.MIXERS[mixer](p["mixer"], z, real, position, cfg, dtype)
+            y, _ = hm.MIXERS[mixer](p["mixer"], z, real, position, cfg,
+                                    dtype)
         x = add(p.get("res1"), x, y)
         z = hm._rms(x, p["norm2"], cfg.eps)
         if ffn == "dense":
