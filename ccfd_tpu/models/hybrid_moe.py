@@ -124,7 +124,7 @@ from typing import Any, Mapping
 import jax
 import jax.numpy as jnp
 
-from ccfd_tpu.ops import causal_attention, grouped_experts
+from ccfd_tpu.ops import causal_attention, grouped_experts, ssd_scan
 
 Params = Mapping[str, Any]
 
@@ -1016,6 +1016,27 @@ def _ssd(x, bm, cm, dt, a, c: int):
     return y.reshape(b, t + lead, h, p)[:, lead:], run.min()
 
 
+def _state_scan(x, bm, cm, dt, a, d, c: int):
+    """:func:`_ssd` and the skip: ``(y + d x, the most negative running
+    log-decay inside a chunk)``. Two paths, one recurrence at one
+    precision, chosen while the program is traced from the operands'
+    shapes, their dtype, the backend and where they lie
+    (``ops/ssd_scan.py::kernel_fits``): where heads of 64 or 128 values
+    fill lane tiles, the state is whole lane tiles wide and the chunk tiles
+    into the kernel's blocks, on one device, the Pallas kernel, which holds
+    a (row, chunk, block of heads)'s decays and the heads' states in VMEM
+    and takes x and gives y in ``mamba2``'s own lane-dense layout; every
+    other shape (heads of 16, a state of 16, a chunk of 32: the tests'
+    presets) and a mesh, :func:`_ssd` through XLA, the definition the
+    tests hold the kernel against. The kernel has no derivative; nothing
+    differentiates this family (it is served only)."""
+    if ssd_scan.kernel_fits(x, bm, c):
+        return ssd_scan.ssd_scan(x, bm, cm, dt, a, d, chunk=c,
+                                 interpret=jax.default_backend() != "tpu")
+    y, low = _ssd(x, bm, cm, dt, a, c)
+    return y + d[:, None] * x, low
+
+
 def _gated_norm(y, gate, weight, eps):
     """RMSNorm(y * SiLU(gate)) w: the gate first, the norm after it, over
     all the values as one group."""
@@ -1049,10 +1070,10 @@ def mamba2(p, z, real, cfg: HybridConfig, dtype):
     with jax.named_scope("mamba.scan"):
         x = xbc[..., :inner].reshape(b, t, h, hd)
         dt = jax.nn.softplus(dt + p["dt_bias"]) * keep
-        y, low = _ssd(x, xbc[..., inner:inner + g * n].reshape(b, t, g, n),
-                      xbc[..., inner + g * n:].reshape(b, t, g, n), dt,
-                      -jnp.exp(p["a_log"]) * dt, s.chunk_for(t))
-        y = y + p["d"][:, None] * x
+        y, low = _state_scan(
+            x, xbc[..., inner:inner + g * n].reshape(b, t, g, n),
+            xbc[..., inner + g * n:].reshape(b, t, g, n), dt,
+            -jnp.exp(p["a_log"]) * dt, p["d"], s.chunk_for(t))
     with jax.named_scope("mamba.gate"):
         y = _gated_norm(y.reshape(b, t, inner), gate, p["norm"], cfg.eps)
     with jax.named_scope("mamba.project"):

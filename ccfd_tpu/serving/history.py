@@ -691,14 +691,16 @@ class _Program:
     def holds_attn_kernel(self, params: Any, lb: int, b: int) -> bool:
         return self.kernels_held(params, lb, b)[0]
 
-    def kernels_held(self, params: Any, lb: int, b: int) -> tuple[bool, bool]:
-        """(attention kernel, expert kernels) of the (lb, b) executable."""
+    def kernels_held(self, params: Any, lb: int,
+                     b: int) -> tuple[bool, bool, bool]:
+        """(attention kernel, expert kernels, state-space scan kernel) of
+        the (lb, b) executable."""
         got = self._held.get((lb, b))
         if got is None:
             import jax
 
             from ccfd_tpu.ops import (causal_attention, grouped_experts,
-                                      seq_attention)
+                                      seq_attention, ssd_scan)
 
             shape = jax.ShapeDtypeStruct
             extra = (shape((b,), np.int32),) if self.reads_filled else ()
@@ -711,7 +713,8 @@ class _Program:
             got = self._held[(lb, b)] = (
                 not held.isdisjoint((seq_attention.KERNEL,
                                      causal_attention.KERNEL)),
-                not held.isdisjoint(grouped_experts.KERNELS))
+                not held.isdisjoint(grouped_experts.KERNELS),
+                ssd_scan.KERNEL in held)
         return got
 
 
@@ -728,12 +731,12 @@ def _behind_flat_wire(fn: Any, num_features: int):
 
 
 def _kernels_held(apply_fn: Any, params: Any, lb: int,
-                  b: int) -> tuple[bool, bool]:
-    """``_Program.kernels_held``: (attention kernel, expert kernels); a
-    stand-in for the program (a test's or a drill's gate around it) has no
-    trace to read and holds none."""
+                  b: int) -> tuple[bool, bool, bool]:
+    """``_Program.kernels_held``: (attention kernel, expert kernels,
+    state-space scan kernel); a stand-in for the program (a test's or a
+    drill's gate around it) has no trace to read and holds none."""
     held = getattr(apply_fn, "kernels_held", None)
-    return held(params, lb, b) if held is not None else (False, False)
+    return held(params, lb, b) if held is not None else (False,) * 3
 
 
 def _takes_flat_wire(apply_fn: Any, lb: int) -> bool:
@@ -935,7 +938,8 @@ class SeqScorer:
         self._g_customers = None
         self._h_assembly = self._h_dispatch = None
         self._c_bucket = self._c_bucket_rows = self._c_attn_kernel = None
-        self._c_expert_kernel = self._c_flat_wire = None
+        self._c_expert_kernel = self._c_ssd_kernel = None
+        self._c_flat_wire = None
         self._g_inflight = self._c_anon = self._c_stale = None
         self._c_overlapped = None
         self._c_swap_refused = None
@@ -974,6 +978,14 @@ class SeqScorer:
                 "through the grouped-matmul kernels (beside "
                 "seq_bucket_dispatch_total: the rest looped over tiles "
                 "through XLA, or have no experts)",
+            )
+            self._c_ssd_kernel = registry.counter(
+                "seq_ssd_kernel_dispatch_total",
+                "seq dispatches of executables whose state-space mixers "
+                "scan through the kernel that keeps a chunk's decays and "
+                "the heads' states on the chip (beside "
+                "seq_bucket_dispatch_total: the rest scanned through XLA, "
+                "or have no such mixer)",
             )
             self._c_flat_wire = registry.counter(
                 "seq_flat_wire_dispatch_total",
@@ -1224,8 +1236,9 @@ class SeqScorer:
     def executable_grid(self) -> dict:
         """The (L, B) executable grid with per-executable dispatch counts,
         whether the executable's attention is a kernel, whether its held
-        experts multiply through the grouped kernels, the chunk of its
-        state-space scan where the model has one, and whether
+        experts multiply through the grouped kernels, whether its
+        state-space mixers scan through their kernel, the chunk of that
+        scan where the model has one, and whether
         its history batch crosses flat — the seq family's entry in the
         device telemetry inventory."""
         with self._params_lock:
@@ -1233,12 +1246,13 @@ class SeqScorer:
         grid = []
         for lb in self.len_buckets:
             for b in self.batch_sizes:
-                attn_kernel, expert_kernel = _kernels_held(
+                attn_kernel, expert_kernel, ssd_kernel = _kernels_held(
                     apply_fn, params, lb, b)
                 entry: dict = {
                     "l_bucket": int(lb), "b_bucket": int(b),
                     "attn_kernel": attn_kernel,
                     "expert_kernel": expert_kernel,
+                    "ssd_kernel": ssd_kernel,
                     "flat_wire": _takes_flat_wire(apply_fn, lb),
                     **self._scan_chunk(lb)}
                 if self._c_bucket is not None:
@@ -1568,7 +1582,7 @@ class SeqScorer:
                         ph.set(rows=m, b_bucket=bucket,
                                padded_rows=bucket - m)
                     batch.t_asm += ph.seconds
-                    attn_kernel, expert_kernel = _kernels_held(
+                    attn_kernel, expert_kernel, ssd_kernel = _kernels_held(
                         apply_fn, params, lb, bucket)
                     flat_wire = _takes_flat_wire(apply_fn, lb)
                     with phase("seq.enqueue", bytes=sub.nbytes,
@@ -1576,6 +1590,7 @@ class SeqScorer:
                                tokens=tokens,
                                attn_kernel=int(attn_kernel),
                                expert_kernel=int(expert_kernel),
+                               ssd_kernel=int(ssd_kernel),
                                flat_wire=int(flat_wire),
                                **self._scan_chunk(lb)) as ph:
                         # device-fault dispatch seam (runtime/faults.py):
@@ -1597,6 +1612,8 @@ class SeqScorer:
                             self._c_attn_kernel.inc()
                         if expert_kernel:
                             self._c_expert_kernel.inc()
+                        if ssd_kernel:
+                            self._c_ssd_kernel.inc()
                         if flat_wire:
                             self._c_flat_wire.inc()
                         self._c_bucket_rows.inc(

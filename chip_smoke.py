@@ -485,9 +485,12 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
 
     # the Mamba-2 mixer at widths that fill lane tiles, which that preset's
     # 16-wide heads do not (hidden 256, 8 heads of 64, a state of 128, 768
-    # tokens, one row padded on the left past the first chunk): the served
-    # chunked scan at chunks of 128 and 384 against the recurrence a token
-    # at a time of the plain reference, both in float32 on the device
+    # tokens, one row padded on the left past the first chunk): Mosaic
+    # compiles the scan's kernel (ops/ssd_scan.py) at chunks of 128 and 384,
+    # and the device's answer is the recurrence's a token at a time of the
+    # plain reference, both in float32 on the device
+    from ccfd_tpu.ops import seq_attention, ssd_scan
+
     wide = dict(small, hidden_size=256, mamba_n_heads=8, mamba_d_head=64,
                 mamba_d_state=128, mamba_n_groups=1, layers_kept=[0],
                 layer_stack="listed")
@@ -502,8 +505,12 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
         for chunk in (128, 384):
             w_cfg = hybrid_moe.HybridConfig.from_dict(
                 dict(wide, scan_chunk=chunk))
-            got, low = jax.jit(lambda p, z: hybrid_moe.mamba2(
-                p, z, real, w_cfg, jnp.float32))(wp, z)
+            mixer = jax.jit(lambda p, z: hybrid_moe.mamba2(
+                p, z, real, w_cfg, jnp.float32))
+            check(f"hybrid_moe mamba2 at lane-wide heads, chunk {chunk}, "
+                  "scans through the kernel", seq_attention.held_by(
+                      mixer, wp, z, names=(ssd_scan.KERNEL,)))
+            got, low = mixer(wp, z)
             zoo[f"hybrid_moe.mamba2.chunk{chunk}"] = {
                 "max_abs_diff": check.close(
                     f"hybrid_moe mamba2 at lane-wide heads, chunk {chunk}: "

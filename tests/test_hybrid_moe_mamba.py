@@ -471,9 +471,12 @@ def test_the_real_configuration_reads_at_its_published_widths():
 def test_at_the_cells_shapes_a_layer_holds_the_kernels(kind):
     """One layer of the real configuration on 2 windows of 1,920 tokens,
     traced and not run: the attention layer's jaxpr holds the
-    ``causal_attention`` kernel (32 : 8 heads of 128, 1,920 tokens), every
-    layer's the grouped expert kernels (4,096 x 768)."""
-    from ccfd_tpu.ops import causal_attention, grouped_experts, seq_attention
+    ``causal_attention`` kernel (32 : 8 heads of 128, 1,920 tokens), the
+    Mamba-2 layer's the ``ssd_scan`` kernel (128 heads of 64, a state of
+    128, chunks of 640), every layer's the grouped expert kernels
+    (4,096 x 768)."""
+    from ccfd_tpu.ops import (causal_attention, grouped_experts,
+                              seq_attention, ssd_scan)
 
     real = dict(_real_config(), layers_kept=[5 if kind == "gqa" else 0],
                 layer_stack="listed")
@@ -485,6 +488,7 @@ def test_at_the_cells_shapes_a_layer_holds_the_kernels(kind):
         jax.ShapeDtypeStruct((2,), np.int32))
     assert set(grouped_experts.KERNELS) <= held
     assert (causal_attention.KERNEL in held) == (kind == "gqa")
+    assert (ssd_scan.KERNEL in held) == (kind == "mamba2")
 
 
 # -- the served path ---------------------------------------------------------------------------------
@@ -522,6 +526,8 @@ def test_a_keyed_stream_through_the_scorer_equals_the_reference(
     assert grid["kinds"]["mamba2"]["state"] == 16
     for entry in grid["grid"]:  # a bucket shorter than the chunk is one chunk
         assert entry["scan_chunk"] == min(32, entry["l_bucket"] * COLS)
+        assert entry["ssd_kernel"] is False  # heads of 16: through XLA
+    assert reg.counter("seq_ssd_kernel_dispatch_total").total() == 0
     total = {k: reg.counter(k).total() for k in (
         "moe_pairs_served_total", "moe_pairs_routed_total",
         "moe_pairs_absent_total", "moe_routed_tokens_total",
