@@ -124,7 +124,8 @@ from typing import Any, Mapping
 import jax
 import jax.numpy as jnp
 
-from ccfd_tpu.ops import causal_attention, grouped_experts, ssd_scan
+from ccfd_tpu.ops import (causal_attention, grouped_experts, kda_scan,
+                          ssd_scan)
 
 Params = Mapping[str, Any]
 
@@ -665,38 +666,28 @@ def _kda_chunk(state, chunk, sub: int):
     return state, out
 
 
-def kda(p, z, real, cfg: HybridConfig, dtype):
-    """(B, T, hidden) normed input -> (B, T, hidden) mixer output. The
-    projections leave their result as (B, T, H, d) and everything up to
-    the output projection stays in that layout: a chunk is then a slice
-    of a major axis, and so is the convolution's shift."""
-    b, t, _ = z.shape
-    s = cfg.mixer("kda")
-    h, dk = s.heads, s.head_dim
-    keep = real[:, :, None, None].astype(F32)
-    zc = z.astype(dtype)
-
-    def heads(w, out):  # (hidden, H * d) -> (B, T, H, d), f32 accumulation
-        return jnp.einsum("bti,ihd->bthd", zc,
-                          w.astype(dtype).reshape(-1, h, dk),
-                          preferred_element_type=out)
-
-    def branch(w, taps):  # the product leaves the matmul in ``dtype``
-        return jax.nn.silu(_short_conv(
-            heads(w, dtype).astype(F32) * keep, taps.reshape(-1, h, dk)))
-
-    def unit(x):
-        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
-
-    q = (unit(branch(p["wq"], p["conv_q"])) * dk ** -0.5).astype(dtype)
-    k = unit(branch(p["wk"], p["conv_k"])).astype(dtype)
-    v = branch(p["wv"], p["conv_v"]).astype(dtype)
-    g = s.lower_bound * jax.nn.sigmoid(
-        jnp.exp(p["a_log"])[:, None]
-        * (heads(p["wg"], F32) + p["dt_bias"].reshape(h, dk))) * keep
-    beta = jax.nn.sigmoid(_mm(z, p["wb"], dtype)) * keep[..., 0]
-
-    c = s.chunk
+def _delta_scan(q, k, v, g, beta, c: int):
+    """The gated delta rule over a window from a zero state: ``q``, ``k``,
+    ``v`` (B, T, H, d) in the compute dtype, the log-decays ``g`` (B, T, H,
+    d) <= 0 and ``beta`` (B, T, H) float32 -> o (B, T, H, d) float32, the
+    state moving ``c`` tokens at a time. Two paths, one recurrence at one
+    precision, chosen while the program is traced from the operands'
+    shapes, their dtype, the backend and where they lie
+    (``ops/kda_scan.py::kernel_fits``): where a head's keys and values are
+    whole lane tiles and the chunk is one the kernel's inverse blocks, on
+    one device, the Pallas kernel, which holds a (row, block of heads)'s
+    states in VMEM across the row's chunks and takes q, k, v, g and gives
+    o by head with the tokens along the lanes, the layout XLA leaves
+    ``kda``'s projections in on the chip; every other shape (heads of 16:
+    the tests' presets) and a mesh, the loop over :func:`_kda_chunk`
+    through XLA, the definition the tests hold the kernel against. A
+    window is padded on the left to whole chunks: a padding token has g =
+    beta = 0 and passes the state unchanged. The kernel has no derivative;
+    nothing differentiates this family (it is served only)."""
+    if kda_scan.kernel_fits(q, v, c, KDA_SUB):
+        return kda_scan.kda_scan(q, k, v, g, beta, chunk=c, sub=KDA_SUB,
+                                 interpret=jax.default_backend() != "tpu")
+    b, t, h, dk = q.shape
     lead = -t % c  # padding tokens on the left pass the state unchanged
     n = (t + lead) // c
 
@@ -716,12 +707,55 @@ def kda(p, z, real, cfg: HybridConfig, dtype):
 
     _, o = jax.lax.fori_loop(0, n, one_chunk, (
         jnp.zeros((b, h, dk, dk), F32), jnp.zeros((b, n, c, h, dk), F32)))
-    o = o.reshape(b, t + lead, h, dk)[:, lead:]
-    o = _rms(o, p["o_norm"], cfg.eps)
-    o = o * jax.nn.sigmoid(_mm(z, p["wog"], dtype))[..., None]
-    return jnp.einsum("bthd,hdo->bto", o.astype(dtype),
-                      p["wo"].astype(dtype).reshape(h, dk, -1),
-                      preferred_element_type=F32)
+    return o.reshape(b, t + lead, h, dk)[:, lead:]
+
+
+def kda(p, z, real, cfg: HybridConfig, dtype):
+    """(B, T, hidden) normed input -> (B, T, hidden) mixer output. The
+    projections leave their result as (B, T, H, d) and everything up to
+    the output projection stays in that layout: a chunk is then a slice
+    of a major axis, and so is the convolution's shift."""
+    s = cfg.mixer("kda")
+    h, dk = s.heads, s.head_dim
+    keep = real[:, :, None, None].astype(F32)
+    zc = z.astype(dtype)
+
+    def heads(w, out):  # (hidden, H * d) -> (B, T, H, d), f32 accumulation
+        with jax.named_scope("kda.project"):
+            return jnp.einsum("bti,ihd->bthd", zc,
+                              w.astype(dtype).reshape(-1, h, dk),
+                              preferred_element_type=out)
+
+    def branch(w, taps):  # the product leaves the matmul in ``dtype``
+        u = heads(w, dtype)
+        with jax.named_scope("kda.conv"):
+            return jax.nn.silu(_short_conv(
+                u.astype(F32) * keep, taps.reshape(-1, h, dk)))
+
+    def unit(x):
+        with jax.named_scope("kda.conv"):
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
+
+    q = (unit(branch(p["wq"], p["conv_q"])) * dk ** -0.5).astype(dtype)
+    k = unit(branch(p["wk"], p["conv_k"])).astype(dtype)
+    v = branch(p["wv"], p["conv_v"]).astype(dtype)
+    raw = heads(p["wg"], F32)
+    with jax.named_scope("kda.project"):
+        to_beta, to_gate = _mm(z, p["wb"], dtype), _mm(z, p["wog"], dtype)
+    with jax.named_scope("kda.scan"):
+        g = s.lower_bound * jax.nn.sigmoid(
+            jnp.exp(p["a_log"])[:, None]
+            * (raw + p["dt_bias"].reshape(h, dk))) * keep
+        beta = jax.nn.sigmoid(to_beta) * keep[..., 0]
+        o = _delta_scan(q, k, v, g, beta, s.chunk)
+    with jax.named_scope("kda.gate"):
+        o = _rms(o, p["o_norm"], cfg.eps)
+        o = o * jax.nn.sigmoid(to_gate)[..., None]
+    with jax.named_scope("kda.project"):
+        return jnp.einsum("bthd,hdo->bto", o.astype(dtype),
+                          p["wo"].astype(dtype).reshape(h, dk, -1),
+                          preferred_element_type=F32)
 
 
 # -- MLA ------------------------------------------------------------------------

@@ -692,15 +692,15 @@ class _Program:
         return self.kernels_held(params, lb, b)[0]
 
     def kernels_held(self, params: Any, lb: int,
-                     b: int) -> tuple[bool, bool, bool]:
-        """(attention kernel, expert kernels, state-space scan kernel) of
-        the (lb, b) executable."""
+                     b: int) -> tuple[bool, bool, bool, bool]:
+        """(attention kernel, expert kernels, state-space scan kernel,
+        delta-rule scan kernel) of the (lb, b) executable."""
         got = self._held.get((lb, b))
         if got is None:
             import jax
 
             from ccfd_tpu.ops import (causal_attention, grouped_experts,
-                                      seq_attention, ssd_scan)
+                                      kda_scan, seq_attention, ssd_scan)
 
             shape = jax.ShapeDtypeStruct
             extra = (shape((b,), np.int32),) if self.reads_filled else ()
@@ -714,7 +714,7 @@ class _Program:
                 not held.isdisjoint((seq_attention.KERNEL,
                                      causal_attention.KERNEL)),
                 not held.isdisjoint(grouped_experts.KERNELS),
-                ssd_scan.KERNEL in held)
+                ssd_scan.KERNEL in held, kda_scan.KERNEL in held)
         return got
 
 
@@ -731,12 +731,13 @@ def _behind_flat_wire(fn: Any, num_features: int):
 
 
 def _kernels_held(apply_fn: Any, params: Any, lb: int,
-                  b: int) -> tuple[bool, bool, bool]:
+                  b: int) -> tuple[bool, bool, bool, bool]:
     """``_Program.kernels_held``: (attention kernel, expert kernels,
-    state-space scan kernel); a stand-in for the program (a test's or a
-    drill's gate around it) has no trace to read and holds none."""
+    state-space scan kernel, delta-rule scan kernel); a stand-in for the
+    program (a test's or a drill's gate around it) has no trace to read
+    and holds none."""
     held = getattr(apply_fn, "kernels_held", None)
-    return held(params, lb, b) if held is not None else (False,) * 3
+    return held(params, lb, b) if held is not None else (False,) * 4
 
 
 def _takes_flat_wire(apply_fn: Any, lb: int) -> bool:
@@ -939,7 +940,7 @@ class SeqScorer:
         self._h_assembly = self._h_dispatch = None
         self._c_bucket = self._c_bucket_rows = self._c_attn_kernel = None
         self._c_expert_kernel = self._c_ssd_kernel = None
-        self._c_flat_wire = None
+        self._c_kda_kernel = self._c_flat_wire = None
         self._g_inflight = self._c_anon = self._c_stale = None
         self._c_overlapped = None
         self._c_swap_refused = None
@@ -986,6 +987,14 @@ class SeqScorer:
                 "the heads' states on the chip (beside "
                 "seq_bucket_dispatch_total: the rest scanned through XLA, "
                 "or have no such mixer)",
+            )
+            self._c_kda_kernel = registry.counter(
+                "seq_kda_kernel_dispatch_total",
+                "seq dispatches of executables whose KDA mixers scan "
+                "through the kernel that keeps a chunk's matrices and the "
+                "heads' states on the chip (beside "
+                "seq_bucket_dispatch_total: the rest looped over chunks "
+                "through XLA, or have no such mixer)",
             )
             self._c_flat_wire = registry.counter(
                 "seq_flat_wire_dispatch_total",
@@ -1237,8 +1246,9 @@ class SeqScorer:
         """The (L, B) executable grid with per-executable dispatch counts,
         whether the executable's attention is a kernel, whether its held
         experts multiply through the grouped kernels, whether its
-        state-space mixers scan through their kernel, the chunk of that
-        scan where the model has one, and whether
+        state-space mixers and its KDA mixers scan through their kernels,
+        the chunk of the state-space scan where the model has one, and
+        whether
         its history batch crosses flat — the seq family's entry in the
         device telemetry inventory."""
         with self._params_lock:
@@ -1246,13 +1256,14 @@ class SeqScorer:
         grid = []
         for lb in self.len_buckets:
             for b in self.batch_sizes:
-                attn_kernel, expert_kernel, ssd_kernel = _kernels_held(
-                    apply_fn, params, lb, b)
+                (attn_kernel, expert_kernel, ssd_kernel,
+                 kda_kernel) = _kernels_held(apply_fn, params, lb, b)
                 entry: dict = {
                     "l_bucket": int(lb), "b_bucket": int(b),
                     "attn_kernel": attn_kernel,
                     "expert_kernel": expert_kernel,
                     "ssd_kernel": ssd_kernel,
+                    "kda_kernel": kda_kernel,
                     "flat_wire": _takes_flat_wire(apply_fn, lb),
                     **self._scan_chunk(lb)}
                 if self._c_bucket is not None:
@@ -1582,8 +1593,9 @@ class SeqScorer:
                         ph.set(rows=m, b_bucket=bucket,
                                padded_rows=bucket - m)
                     batch.t_asm += ph.seconds
-                    attn_kernel, expert_kernel, ssd_kernel = _kernels_held(
-                        apply_fn, params, lb, bucket)
+                    (attn_kernel, expert_kernel, ssd_kernel,
+                     kda_kernel) = _kernels_held(apply_fn, params, lb,
+                                                 bucket)
                     flat_wire = _takes_flat_wire(apply_fn, lb)
                     with phase("seq.enqueue", bytes=sub.nbytes,
                                b_bucket=bucket, l_bucket=lb,
@@ -1591,6 +1603,7 @@ class SeqScorer:
                                attn_kernel=int(attn_kernel),
                                expert_kernel=int(expert_kernel),
                                ssd_kernel=int(ssd_kernel),
+                               kda_kernel=int(kda_kernel),
                                flat_wire=int(flat_wire),
                                **self._scan_chunk(lb)) as ph:
                         # device-fault dispatch seam (runtime/faults.py):
@@ -1614,6 +1627,8 @@ class SeqScorer:
                             self._c_expert_kernel.inc()
                         if ssd_kernel:
                             self._c_ssd_kernel.inc()
+                        if kda_kernel:
+                            self._c_kda_kernel.inc()
                         if flat_wire:
                             self._c_flat_wire.inc()
                         self._c_bucket_rows.inc(

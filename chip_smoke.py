@@ -518,6 +518,50 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
                     want * keep, 2e-2),
                 "log_decay_min": float(low)}
 
+    # the KDA mixer at heads a lane tile wide, which the first preset's
+    # 16-wide heads are not (2 heads of 128, 768 tokens = six spans of the
+    # kernel, one row padded on the left past the first chunks): Mosaic
+    # compiles the delta rule's kernel (ops/kda_scan.py), and the device's
+    # answer is the loop's over ``_kda_chunk`` through XLA and the
+    # recurrence's a token at a time of the plain reference
+    from unittest import mock
+
+    from ccfd_tpu.ops import kda_scan
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "ling3_small_config.json")) as f:
+        wide = dict(json.load(f), num_attention_heads=2, head_dim=128,
+                    v_head_dim=128, layers_kept=[0])
+    k_cfg = hybrid_moe.HybridConfig.from_dict(wide)
+    kp = jax.jit(lambda: hybrid_moe_f32.make_params(wide)["layers"][0][
+        "mixer"])()
+    rng = np.random.default_rng(47)
+    z = jnp.asarray(rng.normal(size=(2, 768, wide["hidden_size"])),
+                    jnp.float32)
+    real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [200]]))
+    keep = np.asarray(real)[..., None]
+
+    def kda_mixer():
+        return jax.jit(lambda p, z: hybrid_moe.kda(p, z, real, k_cfg,
+                                                   jnp.bfloat16))
+
+    check("hybrid_moe kda at lane-wide heads scans through the kernel",
+          seq_attention.held_by(kda_mixer(), kp, z,
+                                names=(kda_scan.KERNEL,)))
+    got = np.asarray(kda_mixer()(kp, z)) * keep
+    with mock.patch.object(kda_scan, "kernel_fits", return_value=False):
+        through_xla = np.asarray(kda_mixer()(kp, z)) * keep
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(hybrid_moe_f32.kda(kp, z, real, wide)) * keep
+    zoo["hybrid_moe.kda.lane_wide"] = {
+        "max_abs_diff_xla": check.close(
+            "hybrid_moe kda at lane-wide heads: kernel vs the loop through "
+            "XLA", got, through_xla, 2e-2),
+        "max_abs_diff": check.close(
+            "hybrid_moe kda at lane-wide heads: kernel vs recurrence", got,
+            want, 5e-2),
+        "xla_vs_recurrence": float(np.abs(through_xla - want).max())}
+
     # the family's causal attention at head widths the small presets lack
     # (128 wide, and MLA's 128 + 64 = 192 in q and k against values of
     # 128; 768 tokens = two blocks of 384): Mosaic compiles the kernel

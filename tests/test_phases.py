@@ -242,6 +242,7 @@ def test_a_capture_around_the_scorer_holds_its_phases(tmp_path):
         assert e[3]["attn_kernel"] == 0  # 8 records: no length it tiles
         assert e[3]["expert_kernel"] == 0  # ``seq`` has no experts
         assert e[3]["ssd_kernel"] == 0  # and no state-space mixer
+        assert e[3]["kda_kernel"] == 0  # nor a KDA one
         assert e[3]["flat_wire"] == 0  # 240 values a row: no whole tile
     assert [e[3]["padded_rows"] for e in cap.named("seq.pad")] == [0, 0, 8]
     assert sum(e[3]["rows"] for e in cap.named("seq.wait")) == 40
@@ -263,6 +264,7 @@ def test_the_enqueue_phase_says_which_wire_the_batch_crossed(tmp_path):
     assert enqueue[3]["attn_kernel"] == 1
     assert enqueue[3]["expert_kernel"] == 0
     assert enqueue[3]["ssd_kernel"] == 0
+    assert enqueue[3]["kda_kernel"] == 0
     assert enqueue[3]["bytes"] == 4 * 512 * 30 * 4
     assert (enqueue[3]["b_bucket"], enqueue[3]["l_bucket"]) == (4, 512)
 
@@ -271,28 +273,39 @@ LANE_WIDE_SCAN = {"hidden_size": 256, "mamba_n_heads": 8, "mamba_d_head": 64,
                   "mamba_d_state": 128, "mamba_n_groups": 1, "scan_chunk": 128}
 
 
-@pytest.mark.parametrize("model,widths,experts,scan", [
+# two KDA layers (the dense one and one with experts) at heads a lane tile
+# wide; 8 records are 240 tokens: four chunks of 64 behind 16 of padding
+LANE_WIDE_KDA = {"num_attention_heads": 2, "head_dim": 128,
+                 "v_head_dim": 128, "layers_kept": [0, 2]}
+
+
+@pytest.mark.parametrize("model,widths,experts,scan,delta", [
     # the small presets: experts of 64 x 32 (the tile loop), Mamba-2 heads
-    # of 16 with a state of 16 (the scan through XLA)
-    ("mistral4", {}, False, False),
+    # of 16 with a state of 16 and KDA heads of 16 (the scans through XLA)
+    ("mistral4", {}, False, False, False),
     ("mistral4", {"hidden_size": 128, "moe_intermediate_size": 128}, True,
-     False),  # no ``mamba2`` layer: no scan kernel, whatever the widths
-    ("granite4h", {}, False, False),
-    ("granite4h", LANE_WIDE_SCAN, False, True),
-], ids=["small", "lane_wide", "small_scan", "lane_wide_scan"])
+     False, False),  # no ``mamba2`` layer: no scan kernel, whatever the widths
+    ("granite4h", {}, False, False, False),
+    ("granite4h", LANE_WIDE_SCAN, False, True, False),
+    ("ling3", {"layers_kept": [0, 2]}, False, False, False),
+    ("ling3", LANE_WIDE_KDA, False, False, True),
+], ids=["small", "lane_wide", "small_scan", "lane_wide_scan", "small_kda",
+        "lane_wide_kda"])
 def test_the_enqueue_phase_says_which_kernels_the_program_holds(
-        tmp_path, model, widths, experts, scan):
+        tmp_path, model, widths, experts, scan, delta):
     """A keyed stream through ``SeqScorer`` with a ``hybrid_moe`` model:
     where hidden and expert widths fill lane tiles the program holds the
     grouped kernels (``ops/grouped_experts.py``), where the Mamba-2 heads
     fill lane tiles and the state is a lane tile wide the scan's kernel
-    (``ops/ssd_scan.py``), and the inventory, the ``seq.enqueue`` phase and
-    ``seq_expert_kernel_dispatch_total`` / ``seq_ssd_kernel_dispatch_total``
+    (``ops/ssd_scan.py``), where a KDA head's keys and values are a lane
+    tile wide the delta rule's (``ops/kda_scan.py``), and the inventory,
+    the ``seq.enqueue`` phase and ``seq_expert_kernel_dispatch_total`` /
+    ``seq_ssd_kernel_dispatch_total`` / ``seq_kda_kernel_dispatch_total``
     say so of every dispatch, once a dispatch, as ``attn_kernel``'s signs
     do of the attention; the chunk stays the configuration's."""
     import json
 
-    from benchmark.reference import mla_moe_f32, ssm_moe_f32
+    from benchmark.reference import hybrid_moe_f32, mla_moe_f32, ssm_moe_f32
     from ccfd_tpu.models import hybrid_moe
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -301,7 +314,8 @@ def test_the_enqueue_phase_says_which_kernels_the_program_holds(
         config = {**json.load(f), **widths}
     if model == "mistral4":
         config.update(num_hidden_layers=2, layers_kept=[0, 1])
-    ref = {"mistral4": mla_moe_f32, "granite4h": ssm_moe_f32}[model]
+    ref = {"mistral4": mla_moe_f32, "granite4h": ssm_moe_f32,
+           "ling3": hybrid_moe_f32}[model]
     reg = Registry()
     scorer = SeqScorer(ref.make_params(config), length=8,
                        batch_sizes=(4,), compute_dtype="float32",
@@ -314,19 +328,22 @@ def test_the_enqueue_phase_says_which_kernels_the_program_holds(
             scorer.score(rows(4, seed=lo), ids=["a", "b", "a", "c"])
     enqueues = cap.named("seq.enqueue")
     chunk = config.get("scan_chunk")  # none where no layer scans
-    assert [(e[3]["expert_kernel"], e[3]["ssd_kernel"],
+    assert [(e[3]["expert_kernel"], e[3]["ssd_kernel"], e[3]["kda_kernel"],
              e[3].get("scan_chunk")) for e in enqueues] == [
-        (int(experts), int(scan), chunk)] * 2
+        (int(experts), int(scan), int(delta), chunk)] * 2
     assert [e[3]["attn_kernel"] for e in enqueues] == [0, 0]  # heads of 16
     assert [(g["b_bucket"], g["expert_kernel"], g["ssd_kernel"],
-             g["attn_kernel"], g.get("scan_chunk"), g["dispatches"])
+             g["kda_kernel"], g["attn_kernel"], g.get("scan_chunk"),
+             g["dispatches"])
             for g in scorer.executable_grid()["grid"]] == [
-        (4, experts, scan, False, chunk, 2)]
+        (4, experts, scan, delta, False, chunk, 2)]
     assert reg.counter("seq_bucket_dispatch_total").total() == 2
     assert reg.counter("seq_expert_kernel_dispatch_total").total() == (
         2 if experts else 0)
     assert reg.counter("seq_ssd_kernel_dispatch_total").total() == (
         2 if scan else 0)
+    assert reg.counter("seq_kda_kernel_dispatch_total").total() == (
+        2 if delta else 0)
 
 
 def test_a_deferred_batch_waits_and_commits_inside_the_next_call(tmp_path):
