@@ -61,15 +61,11 @@ experts are left out (and counted: ``pairs_absent``) and the partial sum
 goes on; on one chip the layer runs without its exchange and nothing
 stands in for the absent chips.
 **Dropless**: the (token, held expert) pairs are sorted by expert and each
-expert's group is cut into row tiles, so a tile belongs to one expert.
-Where hidden and expert width fill whole 128-lane tiles, on one device, the
+expert's group is cut into row tiles, so a tile belongs to one expert. The
 tiles are multiplied by the grouped-matmul kernels of
-``ops/grouped_experts.py``, which address the stacked matrices in place by
-each tile's expert and keep an expert's block on the chip across its
-tiles, ``MOE_CHUNK`` rows a call; every other shape, and a mesh, keep the
-plain loop, whose trip count is the number of tiles the batch really has
-and whose trip cuts one expert's matrices out of the stack for ``MOE_TILE``
-rows (``held_experts`` chooses while the program is traced). No capacity
+``ops/grouped_experts.py``, ``MOE_CHUNK`` rows a call, or by the plain
+loop, whose trip count is the number of tiles the batch really has
+(``held_experts`` chooses while the program is traced). No capacity
 factor, no pair dropped, work in proportion to the pairs served.
 
 **Padding.** ``filled`` (B,) is each row's count of real records
@@ -100,10 +96,7 @@ Device scopes (``jax.named_scope``, so a capture's operations carry them):
 ``lm.embed``, ``kda``, ``mla`` (inside it ``mla.project``: every
 projection, the norms and the rotary, and ``mla.attend``), ``cca`` (inside
 it ``cca.conv`` and ``cca.attend``; both ``attend`` scopes hold
-:func:`_causal_attention`: the Pallas kernel where q and k are whole
-128-lane tiles or whole tiles and a half wide, the values whole tiles and
-the window a multiple of 128 tokens, the plain path at every other
-shape), ``mamba`` (the ``mamba2`` mixer; inside it ``mamba.project``: the
+:func:`_causal_attention`), ``mamba`` (the ``mamba2`` mixer; inside it ``mamba.project``: the
 in- and out-projections, ``mamba.conv``, ``mamba.scan``: steps, decays and
 the chunked scan, ``mamba.gate``: the gate and its norm), ``gqa`` (inside it
 ``gqa.project`` and ``gqa.attend``, which holds :func:`_causal_attention`
@@ -671,22 +664,15 @@ def _delta_scan(q, k, v, g, beta, c: int):
     ``v`` (B, T, H, d) in the compute dtype, the log-decays ``g`` (B, T, H,
     d) <= 0 and ``beta`` (B, T, H) float32 -> o (B, T, H, d) float32, the
     state moving ``c`` tokens at a time. Two paths, one recurrence at one
-    precision, chosen while the program is traced from the operands'
-    shapes, their dtype, the backend and where they lie
-    (``ops/kda_scan.py::kernel_fits``): where a head's keys and values are
-    whole lane tiles and the chunk is one the kernel's inverse blocks, on
-    one device, the Pallas kernel, which holds a (row, block of heads)'s
-    states in VMEM across the row's chunks and takes q, k, v, g and gives
-    o by head with the tokens along the lanes, the layout XLA leaves
-    ``kda``'s projections in on the chip; every other shape (heads of 16:
-    the tests' presets) and a mesh, the loop over :func:`_kda_chunk`
-    through XLA, the definition the tests hold the kernel against. A
-    window is padded on the left to whole chunks: a padding token has g =
-    beta = 0 and passes the state unchanged. The kernel has no derivative;
-    nothing differentiates this family (it is served only)."""
+    precision, chosen while the program is traced
+    (``ops/kda_scan.py::kernel_fits`` says where): the Pallas kernel, or
+    the loop over :func:`_kda_chunk` through XLA, the definition the tests
+    hold the kernel against. A window is padded on the left to whole
+    chunks: a padding token has g = beta = 0 and passes the state
+    unchanged. The kernel has no derivative; nothing differentiates this
+    family (it is served only)."""
     if kda_scan.kernel_fits(q, v, c, KDA_SUB):
-        return kda_scan.kda_scan(q, k, v, g, beta, chunk=c, sub=KDA_SUB,
-                                 interpret=jax.default_backend() != "tpu")
+        return kda_scan.kda_scan(q, k, v, g, beta, chunk=c, sub=KDA_SUB)
     b, t, h, dk = q.shape
     lead = -t % c  # padding tokens on the left pass the state unchanged
     n = (t + lead) // c
@@ -807,15 +793,9 @@ def _causal_attention(q, k, v, real, scale: float, dtype):
     (B, T, G, per, D) against (B, T, G, D) and (B, T, G, Dv); ``real``
     (B, T); the result in ``q``'s layout, Dv wide, in ``dtype``. Two paths,
     one mathematics at one precision, chosen while the program is traced
-    from the operands' shapes, their dtype and the backend
-    (``ops/causal_attention.py::kernel_fits``): where a head's query-key
-    width is whole 128-lane tiles or whole tiles and a half (MLA's 128 + 64
-    = 192), its value width whole tiles, and the window tiles into the
-    kernel's blocks, the Pallas kernel, whose scores stay in VMEM and which
-    never visits a key block above the diagonal (operands handed over by
-    head, (B, H, T, D): a transposing copy each unless XLA folds it into
-    the fusion beside it); every other shape (heads of 16 or 64, values of
-    64, a window that is no multiple of 128 tokens),
+    (``ops/causal_attention.py::kernel_fits`` says where): the Pallas
+    kernel (operands handed over by head, (B, H, T, D): a transposing copy
+    each unless XLA folds it into the fusion beside it), or
     :func:`_plain_causal_attention`, the plain definition the tests compare
     against. The kernel has no derivative; nothing differentiates this
     family (it is served only)."""
@@ -832,7 +812,7 @@ def _causal_attention(q, k, v, real, scale: float, dtype):
         return _plain_causal_attention(q, k, v, real, scale, dtype)
     o = causal_attention.fused_causal_attention(
         by_head(q), by_head(k), by_head(v), real, scale=scale,
-        dtype=jnp.dtype(dtype), interpret=jax.default_backend() != "tpu")
+        dtype=jnp.dtype(dtype))
     return o.transpose(0, 2, 1, 3).reshape(q.shape[:-1] + v.shape[-1:])
 
 
@@ -1053,20 +1033,14 @@ def _ssd(x, bm, cm, dt, a, c: int):
 def _state_scan(x, bm, cm, dt, a, d, c: int):
     """:func:`_ssd` and the skip: ``(y + d x, the most negative running
     log-decay inside a chunk)``. Two paths, one recurrence at one
-    precision, chosen while the program is traced from the operands'
-    shapes, their dtype, the backend and where they lie
-    (``ops/ssd_scan.py::kernel_fits``): where heads of 64 or 128 values
-    fill lane tiles, the state is whole lane tiles wide and the chunk tiles
-    into the kernel's blocks, on one device, the Pallas kernel, which holds
-    a (row, chunk, block of heads)'s decays and the heads' states in VMEM
-    and takes x and gives y in ``mamba2``'s own lane-dense layout; every
-    other shape (heads of 16, a state of 16, a chunk of 32: the tests'
-    presets) and a mesh, :func:`_ssd` through XLA, the definition the
-    tests hold the kernel against. The kernel has no derivative; nothing
-    differentiates this family (it is served only)."""
+    precision, chosen while the program is traced
+    (``ops/ssd_scan.py::kernel_fits`` says where): the Pallas kernel, which
+    takes x and gives y in ``mamba2``'s own lane-dense layout, or
+    :func:`_ssd` through XLA, the definition the tests hold the kernel
+    against. The kernel has no derivative; nothing differentiates this
+    family (it is served only)."""
     if ssd_scan.kernel_fits(x, bm, c):
-        return ssd_scan.ssd_scan(x, bm, cm, dt, a, d, chunk=c,
-                                 interpret=jax.default_backend() != "tpu")
+        return ssd_scan.ssd_scan(x, bm, cm, dt, a, d, chunk=c)
     y, low = _ssd(x, bm, cm, dt, a, c)
     return y + d[:, None] * x, low
 
@@ -1209,19 +1183,17 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
     group is cut into tiles of ``tile`` rows, the last one part empty; the
     tiles' rows stand in a buffer laid out tile after tile. Two bodies fill
     it, one mathematics at one precision, chosen while the program is
-    traced from the experts' widths, the dtype, the backend and where the
-    weights lie (``ops/grouped_experts.py::kernel_fits``):
+    traced (``ops/grouped_experts.py::kernel_fits`` says where):
 
-    - where hidden and expert width fill whole 128-lane tiles, on one
-      device: the Pallas kernels of ``ops/grouped_experts.py``, a grouped
-      matmul over the sorted rows that addresses the stacked matrices in
-      place and keeps an expert's block on the chip across its tiles. The
-      rows are gathered and multiplied ``MOE_CHUNK`` at a time (a loop
+    - the Pallas kernels of ``ops/grouped_experts.py``, a grouped matmul
+      over the sorted rows that addresses the stacked matrices in place
+      and keeps an expert's block on the chip across its tiles. The rows
+      are gathered and multiplied ``MOE_CHUNK`` at a time (a loop
       whose trip count is the number of chunks that hold a pair), so the
       gathered tokens and the gated products take a chunk's memory and not
       the buffer's; ``tile`` comes from the pairs an expert expects
       (``row_tile``);
-    - every other shape, and a mesh: the plain loop, which runs once per
+    - the plain loop (every other shape, and a mesh), which runs once per
       tile that exists, gathers the tile's tokens, cuts the expert's three
       matrices out of the stack and writes the expert's SwiGLU of the rows
       (``tile`` = ``MOE_TILE``). It is the definition the tests hold the
@@ -1276,8 +1248,7 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
             zc[slot // k], ex["gate"], ex["up"], ex["down"],
             jax.lax.dynamic_slice(expert_of, (first,), (chunk,)),
             jax.lax.dynamic_slice(live_of, (first,), (chunk,)),
-            jnp.minimum(tile_end[-1] - first, chunk), rows, first, tile=tile,
-            interpret=jax.default_backend() != "tpu")
+            jnp.minimum(tile_end[-1] - first, chunk), rows, first, tile=tile)
 
     shape = (most * tile, z.shape[-1])
     if kernel:  # only rows a kernel wrote are read: no buffer of zeros

@@ -40,8 +40,7 @@ values it visited, finite, and read by no real token.
 
 :func:`kernel_fits` is the selection ``_causal_attention`` makes while the
 program is traced, from shapes, dtype and backend alone; the kernel has no
-derivative and must not reach ``jax.grad``. Off the TPU the same kernel
-runs under ``interpret=True``, as ``ops/seq_attention.py``'s does.
+derivative and must not reach ``jax.grad``.
 """
 
 from __future__ import annotations
@@ -50,6 +49,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from ccfd_tpu.ops import kernels
 
 LANE = 128  # values fill whole lane tiles, T whole blocks of them
 HALF = LANE // 2  # q and k: whole tiles, or whole tiles and a half (128 + 64)
@@ -84,22 +85,22 @@ def kernel_fits(q_shape: tuple, k_shape: tuple, v_shape: tuple, dtype) -> bool:
     by-head shapes (B, H, T, D), (B, G, T, D), (B, G, T, Dv): a query-key
     width of whole lane tiles or whole tiles and a half (128, 192, 256 ..),
     a value width of whole lane tiles, query heads that divide evenly over
-    the key heads, a window the kernel tiles within its VMEM budget, and a
-    backend it runs on (Mosaic on the TPU, the interpreter on the CPU).
-    Refused, and so on the plain path: heads of 16 or 64, values of 64, a
-    window that is no multiple of 128 tokens."""
+    the key heads, a window the kernel tiles within its VMEM budget, a
+    dtype the kernels serve and a backend that runs them
+    (``ops/kernels.py``; a mesh is not asked about). Refused, and so on the
+    plain path: heads of 16 or 64, values of 64, a window that is no
+    multiple of 128 tokens."""
     if not len(q_shape) == len(k_shape) == len(v_shape) == 4:
         return False
     (b, h, t, d), (_, g, _, dv) = q_shape, v_shape
-    dtype = jnp.dtype(dtype)
     return (
         tuple(k_shape) == (b, g, t, d)
         and tuple(v_shape[:3]) == (b, g, t)
         and h % g == 0
         and d >= LANE and d % HALF == 0 and dv % LANE == 0
-        and dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
-        and block_for(t, d, dv, dtype.itemsize) is not None
-        and jax.default_backend() in ("tpu", "cpu")
+        and kernels.serves(dtype)
+        and block_for(t, d, dv, jnp.dtype(dtype).itemsize) is not None
+        and kernels.backend_runs_pallas()
     )
 
 
@@ -138,12 +139,11 @@ def _kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, *, scale: float, side: int):
     o_ref[0, 0] = (mixed / total).astype(o_ref.dtype)
 
 
-@partial(jax.jit, static_argnames=("scale", "dtype", "side", "interpret"))
+@partial(jax.jit, static_argnames=("scale", "dtype", "side"))
 # ccfd-lint: hot-path
 def fused_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            real: jax.Array, scale: float, dtype,
-                           side: int | None = None,
-                           interpret: bool = False) -> jax.Array:
+                           side: int | None = None) -> jax.Array:
     """``q`` (B, H, T, D), ``k`` (B, G, T, D), ``v`` (B, G, T, Dv), ``real``
     (B, T) bool -> (B, H, T, Dv) in ``dtype``: token t's softmax over the
     real tokens at or before it, of ``scale`` q k^T. Only shapes
@@ -196,5 +196,5 @@ def fused_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 + groups * (width + v_width) * k.dtype.itemsize
                 + heads * v_width * jnp.dtype(dtype).itemsize)),
         name=KERNEL,
-        interpret=interpret,
+        interpret=kernels.interpreted(),
     )(q, k, v, mask)

@@ -40,8 +40,7 @@ arrays are addressed in place:
 program is traced, from shapes, dtype, backend and where the weights lie
 (a mesh keeps the plain loop until someone measures one); :func:`row_tile`
 and :func:`block_for` read the tile and the column blocks from the widths.
-The kernels have no derivative and must not reach ``jax.grad``. Off the TPU
-they run under ``interpret=True``, as ``ops/causal_attention.py``'s does.
+The kernels have no derivative and must not reach ``jax.grad``.
 """
 
 from __future__ import annotations
@@ -50,6 +49,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from ccfd_tpu.ops import kernels
 
 LANE = 128  # hidden and expert widths fill whole lane tiles
 ROW_TILES = (512, 256, 128)  # rows of one expert's group a grid step takes
@@ -100,22 +101,19 @@ def block_for(contract: int, width: int, operands: int,
 def kernel_fits(gate, dtype) -> bool:
     """Whether ``held_experts`` runs the kernels on experts whose stacked
     ``gate`` is this array (held, hidden, width): widths that fill whole
-    lane tiles and whose blocks fit, bfloat16 or float32, one device (the
-    operand lies on no mesh), and a backend the kernels run on (Mosaic on
-    the TPU, the interpreter on the CPU)."""
+    lane tiles and whose blocks fit, and what ``ops/kernels.py`` asks of
+    every family: a dtype the kernels serve, one device (the operand lies
+    on no mesh) and a backend that runs them."""
     if len(gate.shape) != 3:
         return False
     _, hidden, width = gate.shape
-    dtype = jnp.dtype(dtype)
-    mesh = getattr(getattr(jax.typeof(gate), "sharding", None), "mesh", None)
     itemsize = _itemsize(gate, dtype)
     return (
-        dtype in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
+        kernels.serves(dtype)
         and block_for(hidden, width, 2, itemsize) is not None
         and block_for(width, hidden, 1, itemsize) is not None
-        and (mesh is None or mesh.empty)
-        and jax.sharding.get_abstract_mesh().empty
-        and jax.default_backend() in ("tpu", "cpu")
+        and kernels.off_mesh(gate)
+        and kernels.backend_runs_pallas()
     )
 
 
@@ -214,7 +212,7 @@ def _runs(expert_of, visit):
 
 # ccfd-lint: hot-path
 def _product(x, weights, expert_of, runs, live_of, visit, dtype, *,
-             tile: int, name: str, interpret: bool, into=None, first=None):
+             tile: int, name: str, into=None, first=None):
     """``x`` (tiles x tile, contract) against the stacked ``weights`` (each
     (held, contract, width)), tile i by expert ``expert_of[i]``, the first
     ``visit`` tiles (``runs``: :func:`_runs` of them) and of each its
@@ -270,16 +268,16 @@ def _product(x, weights, expert_of, runs, live_of, visit, dtype, *,
             + weights[0].dtype.itemsize * len(weights) * (rows // tile)
             * contract * width + rows * width * jnp.dtype(dtype).itemsize),
         name=name,
-        interpret=interpret,
+        interpret=kernels.interpreted(),
     )(*scalars, *operands)
 
 
-@partial(jax.jit, static_argnames=("tile", "interpret"))
+@partial(jax.jit, static_argnames=("tile",))
 # ccfd-lint: hot-path
 def grouped_swiglu(x: jax.Array, gate: jax.Array, up: jax.Array,
                    down: jax.Array, expert_of: jax.Array, live_of: jax.Array,
                    visit: jax.Array, into: jax.Array, first: jax.Array,
-                   tile: int, interpret: bool = False) -> jax.Array:
+                   tile: int) -> jax.Array:
     """``x`` (tiles x tile, hidden) in the compute dtype, row tile i of
     expert ``expert_of[i]`` (tiles,) int32 with its first ``live_of[i]``
     (tiles,) int32 rows real: that expert's SwiGLU of the rows, by the
@@ -291,10 +289,9 @@ def grouped_swiglu(x: jax.Array, gate: jax.Array, up: jax.Array,
     up to ``SUB_ROWS``). Only shapes :func:`kernel_fits` admits."""
     runs = _runs(expert_of, visit)
     h = _product(x, (gate, up), expert_of, runs, live_of, visit, x.dtype,
-                 tile=tile, name=UP, interpret=interpret)
+                 tile=tile, name=UP)
     return _product(h, (down,), expert_of, runs, live_of, visit, into.dtype,
-                    tile=tile, name=DOWN, interpret=interpret, into=into,
-                    first=first)
+                    tile=tile, name=DOWN, into=into, first=first)
 
 
 def uninitialised(shape: tuple, dtype) -> jax.Array:
@@ -308,4 +305,4 @@ def uninitialised(shape: tuple, dtype) -> jax.Array:
     return pl.pallas_call(
         lambda o_ref: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
         out_specs=pl.BlockSpec(memory_space=pl.ANY), name=ROWS,
-        interpret=jax.default_backend() != "tpu")()
+        interpret=kernels.interpreted())()

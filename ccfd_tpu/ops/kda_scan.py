@@ -57,8 +57,7 @@ run of spans) is held in VMEM:
 
 :func:`kernel_fits` is the selection ``kda`` makes while the program is
 traced, from shapes, dtype, backend and where the operands lie; the kernel
-has no derivative and must not reach ``jax.grad``. Off the TPU it runs
-under ``interpret=True``, as ``ops/ssd_scan.py``'s does.
+has no derivative and must not reach ``jax.grad``.
 """
 
 from __future__ import annotations
@@ -68,6 +67,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ccfd_tpu.ops import kernels
 from ccfd_tpu.ops.ssd_scan import BF16, F32, LANE, _dot, _dot3, _split
 
 BASE = 8  # rows of a diagonal block the nilpotent series inverts
@@ -109,26 +109,23 @@ def kernel_fits(q, v, chunk: int, sub: int) -> bool:
     H, dk) and ``v`` (B, T, H, dv) at chunks of ``chunk`` tokens and
     strips of ``sub`` (arrays or their shapes: what is read is shape,
     dtype and where they lie): keys and values of as many whole lane
-    tiles, a chunk of ``CHUNKS`` that ``sub`` tiles, float32 or bfloat16,
-    a step's blocks and states inside ``VMEM_BYTES``, operands on no mesh,
-    and a backend the kernel runs on (Mosaic on the TPU, the interpreter
-    on the CPU). Refused, and so on the loop over ``_kda_chunk``: heads of
-    16 (the tests' presets), a chunk of 48, a mesh."""
+    tiles, a chunk of ``CHUNKS`` that ``sub`` tiles, a step's blocks and
+    states inside ``VMEM_BYTES``, and what ``ops/kernels.py`` asks of every
+    family: a dtype the kernels serve, operands on no mesh and a backend
+    that runs them. Refused, and so on the loop over ``_kda_chunk``: heads
+    of 16 (the tests' presets), a chunk of 48, a mesh."""
     if len(q.shape) != 4 or tuple(v.shape) != tuple(q.shape):
         return False
     _, t, h, d = q.shape
-    meshes = [getattr(getattr(jax.typeof(x), "sharding", None), "mesh", None)
-              for x in (q, v)]
     return (
         d % LANE == 0 and chunk in CHUNKS and sub % (2 * BASE) == 0
         and chunk % sub == 0
         and _vmem_bytes(SPAN * spans_for(-(-t // SPAN)), heads_for(h),
                         d) <= VMEM_BYTES
         and jnp.dtype(q.dtype) == jnp.dtype(v.dtype)
-        and jnp.dtype(q.dtype) in (jnp.dtype(BF16), jnp.dtype(F32))
-        and all(m is None or m.empty for m in meshes)
-        and jax.sharding.get_abstract_mesh().empty
-        and jax.default_backend() in ("tpu", "cpu")
+        and kernels.serves(q.dtype)
+        and kernels.off_mesh(q, v)
+        and kernels.backend_runs_pallas()
     )
 
 
@@ -328,10 +325,10 @@ def _kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state, *,
     jax.lax.fori_loop(0, o_ref.shape[3] // SPAN, one_span, 0)
 
 
-@partial(jax.jit, static_argnames=("chunk", "sub", "interpret", "exact"))
+@partial(jax.jit, static_argnames=("chunk", "sub", "exact"))
 # ccfd-lint: hot-path
 def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-             beta: jax.Array, chunk: int, sub: int, interpret: bool = False,
+             beta: jax.Array, chunk: int, sub: int,
              exact: bool | None = None):
     """``q``, ``k``, ``v`` (B, T, H, d), the log-decays ``g`` <= 0 (B, T,
     H, d) and ``beta`` (B, T, H) -> o (B, T, H, d) float32: the loop over
@@ -343,7 +340,7 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 
     batch, tokens, heads, width = q.shape
     if exact is None:
-        exact = interpret
+        exact = kernels.interpreted()
     if (width % LANE or chunk not in CHUNKS or sub % (2 * BASE)
             or chunk % sub
             or not k.shape == v.shape == g.shape == q.shape
@@ -391,7 +388,7 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
             bytes_accessed=batch * chunks * chunk * heads * (
                 width * (3 * q.dtype.itemsize + 8) + 4)),
         name=KERNEL,
-        interpret=interpret,
+        interpret=kernels.interpreted(),
     )(*(jnp.transpose(padded(x), (0, 2, 3, 1))
         for x in (q, k, v, g.astype(F32))), padded(beta.astype(F32)))
     return jnp.transpose(o, (0, 3, 1, 2))[:, lead:]

@@ -29,8 +29,7 @@ VMEM:
 the kernel from what its arguments show while the program is traced and
 gives every other shape ``reference_attention``, which stays the plain,
 differentiable definition (the kernel has no derivative and must not reach
-``jax.grad``). Off the TPU the same kernel runs under ``interpret=True``,
-as ``ops/fused_mlp.py``'s does.
+``jax.grad``).
 """
 
 from __future__ import annotations
@@ -41,6 +40,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ccfd_tpu.ops import kernels
 from ccfd_tpu.ops.ring_attention import reference_attention
 
 LANE = 128  # the heads of a row side by side fill exactly one lane tile
@@ -70,15 +70,16 @@ def kernel_fits(q_shape: tuple, k_shape: tuple, dtype) -> bool:
     """Whether :func:`attention` runs the kernel on (B, H, L, Dh) operands
     of these shapes: full self-attention (queries as many as keys, so not
     the readout block's single query), heads that fill one lane tile, a
-    length the kernel tiles within its VMEM budget, and a backend it runs
-    on (Mosaic on the TPU, the interpreter on the CPU)."""
+    length the kernel tiles within its VMEM budget, a dtype the kernels
+    serve and a backend that runs them (``ops/kernels.py``). A mesh is not
+    asked about: ``SeqScorer`` hands each device its rows itself."""
     return (
         len(q_shape) == 4
         and tuple(q_shape) == tuple(k_shape)
         and q_shape[1] * q_shape[3] == LANE
         and query_block(q_shape[2]) is not None
-        and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
-        and jax.default_backend() in ("tpu", "cpu")
+        and kernels.serves(dtype)
+        and kernels.backend_runs_pallas()
     )
 
 
@@ -100,10 +101,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, heads: int):
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-@partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 # ccfd-lint: hot-path
-def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    interpret: bool = False) -> jax.Array:
+def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """(B, H, L, Dh) -> (B, H, L, Dh), the contract of
     ``reference_attention``; only shapes :func:`kernel_fits` admits."""
     from jax.experimental import pallas as pl
@@ -135,7 +135,7 @@ def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             transcendentals=batch * heads * length * length,
             bytes_accessed=4 * batch * length * width * q.dtype.itemsize),
         name=KERNEL,
-        interpret=interpret,
+        interpret=kernels.interpreted(),
     )(merged(q), merged(k), merged(v))
     return out.reshape(batch, length, heads, head_dim).transpose(0, 2, 1, 3)
 
@@ -146,34 +146,6 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     while the program is traced, from shapes, dtype and backend alone."""
     with jax.named_scope(SCOPE):
         if kernel_fits(q.shape, k.shape, q.dtype):
-            return fused_attention(q, k, v,
-                                   interpret=jax.default_backend() != "tpu")
+            return fused_attention(q, k, v)
         return reference_attention(q, k, v)
 
-
-def _equations(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for value in eqn.params.values():
-            for inner in value if isinstance(value, (tuple, list)) else (value,):
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _equations(inner)
-
-
-def kernels_of(program, *args) -> frozenset:
-    """The names of the Pallas kernels in the program that
-    ``program(*args)`` traces to: its jaxpr is read, so the answer is what
-    the trace chose and not a second reckoning of it. ``args`` may be
-    shapes (``jax.ShapeDtypeStruct``); for a jitted program traced at them
-    before, this costs a look-up in its trace cache."""
-    return frozenset(
-        eqn.params.get("name")
-        for eqn in _equations(jax.make_jaxpr(program)(*args).jaxpr)
-        if eqn.primitive.name == "pallas_call")
-
-
-def held_by(program, *args, names: tuple = (KERNEL,)) -> bool:
-    """Whether :func:`kernels_of` the program holds a kernel of one of
-    these names (this module's; a caller adds the other families')."""
-    return not kernels_of(program, *args).isdisjoint(names)

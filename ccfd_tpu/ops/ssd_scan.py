@@ -45,8 +45,7 @@ a by-head layout, because a head's 64 values are half a lane tile. Here a
 
 :func:`kernel_fits` is the selection ``mamba2`` makes while the program is
 traced, from shapes, dtype, backend and where the operands lie; the kernel
-has no derivative and must not reach ``jax.grad``. Off the TPU it runs
-under ``interpret=True``, as ``ops/causal_attention.py``'s does.
+has no derivative and must not reach ``jax.grad``.
 """
 
 from __future__ import annotations
@@ -55,6 +54,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from ccfd_tpu.ops import kernels
 
 LANE = 128  # heads side by side fill lane tiles; the state is N = 128 x n wide
 SIDES = (256, 128)  # tokens a side of a square block of a chunk's triangle
@@ -90,27 +91,24 @@ def kernel_fits(x, bm, chunk: int) -> bool:
     their shapes: what is read is shape, dtype and where they lie): heads
     of 64 or 128 values (two or one a lane tile) that fill the step's lane
     tiles inside one group, a state of whole lane tiles, a chunk of whole
-    blocks whose scratch fits, float32 or bfloat16, operands on no mesh,
-    and a backend the kernel runs on (Mosaic on the TPU, the interpreter
-    on the CPU). Refused, and so on ``_ssd``: heads of 16, a state of 16, a
-    chunk of 32 (the tests' presets), a mesh."""
+    blocks whose scratch fits, and what ``ops/kernels.py`` asks of every
+    family: a dtype the kernels serve, operands on no mesh and a backend
+    that runs them. Refused, and so on ``_ssd``: heads of 16, a state of
+    16, a chunk of 32 (the tests' presets), a mesh."""
     if len(x.shape) != 4 or len(bm.shape) != 4:
         return False
     (b, t, h, p), (_, _, g, n) = x.shape, bm.shape
     if h % g or p not in (LANE // 2, LANE):
         return False
     tiles = tiles_for(h // g, p)
-    meshes = [getattr(getattr(jax.typeof(v), "sharding", None), "mesh", None)
-              for v in (x, bm)]
     return (
         tuple(bm.shape[:2]) == (b, t)
         and n % LANE == 0 and ((h // g) * p) % (tiles * LANE) == 0
         and side_for(chunk) is not None
         and _vmem_bytes(chunk, h, p, n, tiles) <= VMEM_BYTES
-        and jnp.dtype(x.dtype) in (jnp.dtype(BF16), jnp.dtype(F32))
-        and all(m is None or m.empty for m in meshes)
-        and jax.sharding.get_abstract_mesh().empty
-        and jax.default_backend() in ("tpu", "cpu")
+        and kernels.serves(x.dtype)
+        and kernels.off_mesh(x, bm)
+        and kernels.backend_runs_pallas()
     )
 
 
@@ -241,11 +239,11 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, run_ref, row_ref, d_ref, o_ref,
             o_ref[0, rows, lanes] = acc
 
 
-@partial(jax.jit, static_argnames=("chunk", "side", "interpret", "exact"))
+@partial(jax.jit, static_argnames=("chunk", "side", "exact"))
 # ccfd-lint: hot-path
 def ssd_scan(x: jax.Array, bm: jax.Array, cm: jax.Array, dt: jax.Array,
              a: jax.Array, d: jax.Array, chunk: int, side: int | None = None,
-             interpret: bool = False, exact: bool | None = None):
+             exact: bool | None = None):
     """``x`` (B, T, H, P), ``bm`` and ``cm`` (B, T, G, N), ``dt`` and the
     log-decays ``a`` <= 0 (B, T, H), the skip's ``d`` (H,) -> ``(y + d x
     (B, T, H, P) float32, the most negative running sum of a inside a
@@ -264,7 +262,7 @@ def ssd_scan(x: jax.Array, bm: jax.Array, cm: jax.Array, dt: jax.Array,
         side = side_for(chunk)
     tiles = tiles_for(per, head_dim)
     if exact is None:
-        exact = interpret
+        exact = kernels.interpreted()
     lanes = tiles * LANE
     if (side is None or chunk % side or LANE % head_dim or width % LANE
             or heads % groups or (per * head_dim) % lanes
@@ -327,7 +325,7 @@ def ssd_scan(x: jax.Array, bm: jax.Array, cm: jax.Array, dt: jax.Array,
                 heads * head_dim * (x.dtype.itemsize + 4)
                 + 2 * groups * width * bm.dtype.itemsize + 3 * heads * 4)),
         name=KERNEL,
-        interpret=interpret,
+        interpret=kernels.interpreted(),
     )(flat(x), flat(bm), flat(cm), dt, run, run.transpose(0, 2, 1),
       jnp.repeat(d.astype(F32), head_dim)[None])
     return (y[:, lead:].reshape(batch, tokens, heads, head_dim), run.min())

@@ -654,16 +654,12 @@ class _Program:
     already, and every batch of a scorer with a mesh (``flat`` is None:
     ``_put_hist`` places the rows itself) go as (B, L, F).
 
-    **The trace.** Whether the program's attention holds a kernel that
-    keeps the scores on the chip (``seq`` / ``seq_q8``'s full-attention
-    block: ``ops/seq_attention.py``; ``hybrid_moe``'s causal attention:
-    ``ops/causal_attention.py``) and whether its held experts multiply
-    through the grouped kernels (``ops/grouped_experts.py``), asked at the
-    shape that is dispatched. The program's jaxpr is read the first time
-    either is asked (a look-up in the jit's trace cache once the
-    executable has run), the memo afterwards; a swap to another variant
-    builds another program, so the memo never outlives what it
-    describes."""
+    **The trace.** Which kernel families the executable holds
+    (``ops/kernels.py::held``), asked at the shape and in the wire form
+    that is dispatched. The program's jaxpr is read the first time it is
+    asked (a look-up in the jit's trace cache once the executable has
+    run), the memo afterwards; a swap to another variant builds another
+    program, so the memo never outlives what it describes."""
 
     __slots__ = ("fn", "flat", "reads_filled", "num_features", "_held")
 
@@ -688,19 +684,13 @@ class _Program:
                 len(hist), -1, _WIRE_LANES), *extra)
         return self.fn(params, hist, *extra)
 
-    def holds_attn_kernel(self, params: Any, lb: int, b: int) -> bool:
-        return self.kernels_held(params, lb, b)[0]
-
-    def kernels_held(self, params: Any, lb: int,
-                     b: int) -> tuple[bool, bool, bool, bool]:
-        """(attention kernel, expert kernels, state-space scan kernel,
-        delta-rule scan kernel) of the (lb, b) executable."""
+    def kernels_held(self, params: Any, lb: int, b: int) -> dict:
+        """Each kernel family's key -> 0 / 1 for the (lb, b) executable."""
         got = self._held.get((lb, b))
         if got is None:
             import jax
 
-            from ccfd_tpu.ops import (causal_attention, grouped_experts,
-                                      kda_scan, seq_attention, ssd_scan)
+            from ccfd_tpu.ops import kernels
 
             shape = jax.ShapeDtypeStruct
             extra = (shape((b,), np.int32),) if self.reads_filled else ()
@@ -708,13 +698,8 @@ class _Program:
                                      // _WIRE_LANES, _WIRE_LANES))
                         if self.flat_wire(lb)
                         else (self.fn, (b, lb, self.num_features)))
-            held = seq_attention.kernels_of(
+            got = self._held[(lb, b)] = kernels.held(
                 fn, params, shape(hist, np.float32), *extra)
-            got = self._held[(lb, b)] = (
-                not held.isdisjoint((seq_attention.KERNEL,
-                                     causal_attention.KERNEL)),
-                not held.isdisjoint(grouped_experts.KERNELS),
-                ssd_scan.KERNEL in held, kda_scan.KERNEL in held)
         return got
 
 
@@ -730,14 +715,15 @@ def _behind_flat_wire(fn: Any, num_features: int):
     return jax.jit(flat_wire)
 
 
-def _kernels_held(apply_fn: Any, params: Any, lb: int,
-                  b: int) -> tuple[bool, bool, bool, bool]:
-    """``_Program.kernels_held``: (attention kernel, expert kernels,
-    state-space scan kernel, delta-rule scan kernel); a stand-in for the
-    program (a test's or a drill's gate around it) has no trace to read
-    and holds none."""
+def _kernels_held(apply_fn: Any, params: Any, lb: int, b: int) -> dict:
+    """``_Program.kernels_held``; a stand-in for the program (a test's or
+    a drill's gate around it) has no trace to read and holds none."""
     held = getattr(apply_fn, "kernels_held", None)
-    return held(params, lb, b) if held is not None else (False,) * 4
+    if held is not None:
+        return held(params, lb, b)
+    from ccfd_tpu.ops import kernels
+
+    return dict.fromkeys((f.key for f in kernels.FAMILIES), 0)
 
 
 def _takes_flat_wire(apply_fn: Any, lb: int) -> bool:
@@ -938,9 +924,8 @@ class SeqScorer:
         self._swap_gate: Any = None  # partitioner publish gate (set_swap_gate)
         self._g_customers = None
         self._h_assembly = self._h_dispatch = None
-        self._c_bucket = self._c_bucket_rows = self._c_attn_kernel = None
-        self._c_expert_kernel = self._c_ssd_kernel = None
-        self._c_kda_kernel = self._c_flat_wire = None
+        self._c_bucket = self._c_bucket_rows = self._c_flat_wire = None
+        self._c_kernels: dict = {}  # a kernel family's key -> its counter
         self._g_inflight = self._c_anon = self._c_stale = None
         self._c_overlapped = None
         self._c_swap_refused = None
@@ -967,35 +952,11 @@ class SeqScorer:
                 "seq_bucket_dispatch_total",
                 "seq dispatches by (L bucket, B bucket) executable",
             )
-            self._c_attn_kernel = registry.counter(
-                "seq_attention_kernel_dispatch_total",
-                "seq dispatches of executables whose attention holds a "
-                "kernel that keeps the scores on the chip (beside "
-                "seq_bucket_dispatch_total: the rest attended through XLA)",
-            )
-            self._c_expert_kernel = registry.counter(
-                "seq_expert_kernel_dispatch_total",
-                "seq dispatches of executables whose held experts multiply "
-                "through the grouped-matmul kernels (beside "
-                "seq_bucket_dispatch_total: the rest looped over tiles "
-                "through XLA, or have no experts)",
-            )
-            self._c_ssd_kernel = registry.counter(
-                "seq_ssd_kernel_dispatch_total",
-                "seq dispatches of executables whose state-space mixers "
-                "scan through the kernel that keeps a chunk's decays and "
-                "the heads' states on the chip (beside "
-                "seq_bucket_dispatch_total: the rest scanned through XLA, "
-                "or have no such mixer)",
-            )
-            self._c_kda_kernel = registry.counter(
-                "seq_kda_kernel_dispatch_total",
-                "seq dispatches of executables whose KDA mixers scan "
-                "through the kernel that keeps a chunk's matrices and the "
-                "heads' states on the chip (beside "
-                "seq_bucket_dispatch_total: the rest looped over chunks "
-                "through XLA, or have no such mixer)",
-            )
+            from ccfd_tpu.ops import kernels
+
+            self._c_kernels = {
+                family.key: registry.counter(family.counter, family.help)
+                for family in kernels.FAMILIES}
             self._c_flat_wire = registry.counter(
                 "seq_flat_wire_dispatch_total",
                 "seq dispatches whose history batch crossed to the device "
@@ -1034,7 +995,7 @@ class SeqScorer:
     # -- variant dispatch ---------------------------------------------------
     def _mesh_attention(self):
         """What a mesh executable attends with. By default one chip's
-        program (``ops/seq_attention.py::attention``) on each device's
+        program (``models/seq.py::serving_attention``) on each device's
         rows: under ``shard_map`` over the batch axes, because a kernel is
         not partitioned for us, so each device decides from the shapes it
         holds. Where the operator selected a sequence-parallel attention
@@ -1051,14 +1012,14 @@ class SeqScorer:
         from jax import shard_map
         from jax.sharding import PartitionSpec
 
-        from ccfd_tpu.ops.seq_attention import attention
+        from ccfd_tpu.models.seq import serving_attention
 
         mesh, axis = self.mesh, self._sp_axis
         rows = PartitionSpec(self._part_axes, None, None, None)
         # unchecked: rows are independent and nothing inside communicates;
         # the kernel's interpreter (off the TPU) does not pass the check
-        local = shard_map(attention, mesh=mesh, in_specs=(rows, rows, rows),
-                          out_specs=rows, check_vma=False)
+        local = shard_map(serving_attention, mesh=mesh, check_vma=False,
+                          in_specs=(rows, rows, rows), out_specs=rows)
         if axis is None:
             return local
         n = int(mesh.shape[axis])
@@ -1244,26 +1205,19 @@ class SeqScorer:
 
     def executable_grid(self) -> dict:
         """The (L, B) executable grid with per-executable dispatch counts,
-        whether the executable's attention is a kernel, whether its held
-        experts multiply through the grouped kernels, whether its
-        state-space mixers and its KDA mixers scan through their kernels,
-        the chunk of the state-space scan where the model has one, and
-        whether
-        its history batch crosses flat — the seq family's entry in the
-        device telemetry inventory."""
+        which kernel families the executable holds (a key each:
+        ``ops/kernels.py``), the chunk of the state-space scan where the
+        model has one, and whether its history batch crosses flat — the
+        seq family's entry in the device telemetry inventory."""
         with self._params_lock:
             params, apply_fn = self.params, self._apply
         grid = []
         for lb in self.len_buckets:
             for b in self.batch_sizes:
-                (attn_kernel, expert_kernel, ssd_kernel,
-                 kda_kernel) = _kernels_held(apply_fn, params, lb, b)
+                held = _kernels_held(apply_fn, params, lb, b)
                 entry: dict = {
                     "l_bucket": int(lb), "b_bucket": int(b),
-                    "attn_kernel": attn_kernel,
-                    "expert_kernel": expert_kernel,
-                    "ssd_kernel": ssd_kernel,
-                    "kda_kernel": kda_kernel,
+                    **{key: bool(on) for key, on in held.items()},
                     "flat_wire": _takes_flat_wire(apply_fn, lb),
                     **self._scan_chunk(lb)}
                 if self._c_bucket is not None:
@@ -1593,17 +1547,11 @@ class SeqScorer:
                         ph.set(rows=m, b_bucket=bucket,
                                padded_rows=bucket - m)
                     batch.t_asm += ph.seconds
-                    (attn_kernel, expert_kernel, ssd_kernel,
-                     kda_kernel) = _kernels_held(apply_fn, params, lb,
-                                                 bucket)
+                    held = _kernels_held(apply_fn, params, lb, bucket)
                     flat_wire = _takes_flat_wire(apply_fn, lb)
                     with phase("seq.enqueue", bytes=sub.nbytes,
                                b_bucket=bucket, l_bucket=lb,
-                               tokens=tokens,
-                               attn_kernel=int(attn_kernel),
-                               expert_kernel=int(expert_kernel),
-                               ssd_kernel=int(ssd_kernel),
-                               kda_kernel=int(kda_kernel),
+                               tokens=tokens, **held,
                                flat_wire=int(flat_wire),
                                **self._scan_chunk(lb)) as ph:
                         # device-fault dispatch seam (runtime/faults.py):
@@ -1621,14 +1569,9 @@ class SeqScorer:
                     if self._c_bucket is not None:
                         self._c_bucket.inc(labels={
                             "l_bucket": str(lb), "b_bucket": str(bucket)})
-                        if attn_kernel:
-                            self._c_attn_kernel.inc()
-                        if expert_kernel:
-                            self._c_expert_kernel.inc()
-                        if ssd_kernel:
-                            self._c_ssd_kernel.inc()
-                        if kda_kernel:
-                            self._c_kda_kernel.inc()
+                        for key, counter in self._c_kernels.items():
+                            if held[key]:
+                                counter.inc()
                         if flat_wire:
                             self._c_flat_wire.inc()
                         self._c_bucket_rows.inc(

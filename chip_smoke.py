@@ -489,7 +489,7 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     # compiles the scan's kernel (ops/ssd_scan.py) at chunks of 128 and 384,
     # and the device's answer is the recurrence's a token at a time of the
     # plain reference, both in float32 on the device
-    from ccfd_tpu.ops import seq_attention, ssd_scan
+    from ccfd_tpu.ops import kernels, ssd_scan
 
     wide = dict(small, hidden_size=256, mamba_n_heads=8, mamba_d_head=64,
                 mamba_d_state=128, mamba_n_groups=1, layers_kept=[0],
@@ -508,7 +508,7 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             mixer = jax.jit(lambda p, z: hybrid_moe.mamba2(
                 p, z, real, w_cfg, jnp.float32))
             check(f"hybrid_moe mamba2 at lane-wide heads, chunk {chunk}, "
-                  "scans through the kernel", seq_attention.held_by(
+                  "scans through the kernel", kernels.held_by(
                       mixer, wp, z, names=(ssd_scan.KERNEL,)))
             got, low = mixer(wp, z)
             zoo[f"hybrid_moe.mamba2.chunk{chunk}"] = {
@@ -546,7 +546,7 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
                                                    jnp.bfloat16))
 
     check("hybrid_moe kda at lane-wide heads scans through the kernel",
-          seq_attention.held_by(kda_mixer(), kp, z,
+          kernels.held_by(kda_mixer(), kp, z,
                                 names=(kda_scan.KERNEL,)))
     got = np.asarray(kda_mixer()(kp, z)) * keep
     with mock.patch.object(kda_scan, "kernel_fits", return_value=False):
@@ -569,7 +569,7 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     # for a block that ends in half a lane tile, and the device's answer
     # is the plain path's at every real position, one row padded on the
     # left past the first block
-    from ccfd_tpu.ops import causal_attention, seq_attention
+    from ccfd_tpu.ops import causal_attention
 
     rng = np.random.default_rng(37)
     real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [400]]))
@@ -585,7 +585,7 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
                                                 jnp.bfloat16)
 
         check(f"hybrid_moe causal attention, {label}: the program holds "
-              "the kernel", seq_attention.held_by(
+              "the kernel", kernels.held_by(
                   attend, q, k, v, real, names=(causal_attention.KERNEL,)))
         keep = np.asarray(real).reshape((2, 768) + (1,) * (len(q_shape) - 2))
         zoo[f"causal_attention.{label.split()[0]}"] = {
@@ -618,7 +618,7 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
                                        jnp.bfloat16)
 
     check("hybrid_moe held experts: the program holds the kernels",
-          seq_attention.kernels_of(experts, ex, tokens, chosen, weight)
+          kernels.kernels_of(experts, ex, tokens, chosen, weight)
           == frozenset(grouped_experts.KERNELS))
     y, pairs, served = jax.jit(experts)(ex, tokens, chosen, weight)
     fits, grouped_experts.kernel_fits = (grouped_experts.kernel_fits,

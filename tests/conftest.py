@@ -56,6 +56,18 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture()
+def as_on_the_chip(monkeypatch):
+    """A ``pallas_call`` made under it goes to Mosaic, as on the TPU, and
+    not to the interpreter this backend would give it: for a test that
+    compiles a kernel for a chip that is described and not attached. Call
+    the kernel's entry unjitted (``__wrapped__``) under it: a jit's trace
+    cache does not know the answer changed."""
+    from ccfd_tpu.ops import kernels
+
+    monkeypatch.setattr(kernels, "interpreted", lambda: False)
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _lockcheck_gate():
     """With CCFD_LOCKCHECK=1, fail the session if any lock-order

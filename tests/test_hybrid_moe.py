@@ -16,7 +16,7 @@ from benchmark.reference import hybrid_moe_f32 as ref
 from benchmark.reference import table
 from ccfd_tpu.models import hybrid_moe as hm
 from ccfd_tpu.models import registry
-from ccfd_tpu.ops import grouped_experts, seq_attention
+from ccfd_tpu.ops import grouped_experts, kernels
 from ccfd_tpu.serving.history import SeqScorer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -137,7 +137,7 @@ def test_slice_logits_agree_at_every_position(small, params, rows, experts):
     hist, filled = _windows(rows, [8, 3, 1], 8)
     want, want_pairs = ref.forward(params, small, hist, filled,
                                    every_position=True)
-    assert seq_attention.held_by(
+    assert kernels.held_by(
         lambda p, h, f: hm.logits_everywhere(p, h, f, cfg, F32), params,
         hist, filled, names=grouped_experts.KERNELS) == (
             experts == "lane_wide")

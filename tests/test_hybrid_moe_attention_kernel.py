@@ -29,7 +29,7 @@ from benchmark.reference import (cca_moe_f32, hybrid_moe_f32, mhc_moe_f32,
                                  mla_moe_f32, table)
 from ccfd_tpu.models import hybrid_moe as hm
 from ccfd_tpu.ops import causal_attention as ca
-from ccfd_tpu.ops import seq_attention
+from ccfd_tpu.ops import kernels, seq_attention
 from ccfd_tpu.serving.history import SeqScorer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -98,13 +98,12 @@ def _kernel(q, k, v, real, scale, dtype, side=None):
     """The kernel on ``_causal_attention``'s layout, at a block of its own
     choice or the test's."""
     out = ca.fused_causal_attention(_by_head(q), _by_head(k), _by_head(v),
-                                    real, scale, jnp.dtype(dtype), side=side,
-                                    interpret=True)
+                                    real, scale, jnp.dtype(dtype), side=side)
     return out.transpose(0, 2, 1, 3).reshape(q.shape[:-1] + v.shape[-1:])
 
 
 def _holds_kernel(fn, *shapes) -> bool:
-    return seq_attention.held_by(fn, *shapes, names=(ca.KERNEL,))
+    return kernels.held_by(fn, *shapes, names=(ca.KERNEL,))
 
 
 # -- the kernel against the plain path ---------------------------------------------
@@ -215,12 +214,13 @@ def test_the_programs_jaxpr_says_which_path_was_taken(q, k, v, kernel):
     real = _shape(q[0], q[1], dtype=jnp.bool_)
     assert _holds_kernel(attend, _shape(*q), _shape(*k), _shape(*v),
                          real) is kernel
-    # and the scorer's reading (``_Program.holds_attn_kernel``) looks for it
-    assert seq_attention.held_by(
+    # and the scorer's reading (``ops/kernels.py::held``) counts it as
+    # attention's, and none of it as ``seq``'s kernel
+    assert kernels.held(attend, _shape(*q), _shape(*k), _shape(*v),
+                        real)["attn_kernel"] == kernel
+    assert not kernels.held_by(
         attend, _shape(*q), _shape(*k), _shape(*v), real,
-        names=(seq_attention.KERNEL, ca.KERNEL)) is kernel
-    assert not seq_attention.held_by(
-        attend, _shape(*q), _shape(*k), _shape(*v), real)
+        names=(seq_attention.KERNEL,))
 
 
 @pytest.mark.parametrize("tokens,width,itemsize,side", [
@@ -271,7 +271,7 @@ def _pallas_call(width, dtype=BF16):
         _shape(8, 32, 1920, width, dtype=dtype),
         _shape(8, 32, 1920, 128, dtype=dtype),
         _shape(8, 1920, dtype=jnp.bool_))
-    calls = [e for e in seq_attention._equations(jaxpr.jaxpr)
+    calls = [e for e in kernels.equations(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
     return calls[0]
@@ -280,7 +280,7 @@ def _pallas_call(width, dtype=BF16):
 def _body(call) -> list:
     """The kernel body's primitives in order, the loop's body inside."""
     return [e.primitive.name
-            for e in seq_attention._equations(call.params["jaxpr"])]
+            for e in kernels.equations(call.params["jaxpr"])]
 
 
 @WIDTHS
@@ -335,7 +335,7 @@ def one_chip():
     (32, 32, 128), (8, 2, 128), (32, 32, 192)],
     ids=["mistral4", "zaya1", "xing4"])
 def test_mosaic_compiles_the_kernel_at_a_dispatch_of_the_real_models(
-        one_chip, heads, groups, width):
+        one_chip, as_on_the_chip, heads, groups, width):
     """8 windows of 1,920 tokens, heads of 128 (``xing4``, and ``ling3``'s
     one such layer: 192 in q and k), bfloat16, blocks of 640: what the
     interpreter cannot refuse (tiling, VMEM, a block that ends in half a
@@ -345,7 +345,7 @@ def test_mosaic_compiles_the_kernel_at_a_dispatch_of_the_real_models(
 
     assert ca.block_for(1920, width, 128, 2) == 640
     compiled = jax.jit(
-        lambda q, k, v, real: ca.fused_causal_attention(
+        lambda q, k, v, real: ca.fused_causal_attention.__wrapped__(
             q, k, v, real, 0.09, jnp.dtype(BF16))).lower(
         shape(8, heads, 1920, width), shape(8, groups, 1920, width),
         shape(8, groups, 1920, 128), shape(8, 1920, dtype=jnp.bool_)

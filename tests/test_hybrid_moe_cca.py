@@ -18,7 +18,7 @@ import pytest
 from benchmark.reference import cca_moe_f32 as ref
 from benchmark.reference import table
 from ccfd_tpu.models import hybrid_moe as hm
-from ccfd_tpu.ops import grouped_experts, seq_attention
+from ccfd_tpu.ops import grouped_experts, kernels
 from ccfd_tpu.models import registry
 from ccfd_tpu.serving.history import SeqScorer
 
@@ -88,7 +88,7 @@ def test_logits_agree_with_the_reference_at_every_position(
         small = dict(small, hidden_size=128, moe_intermediate_size=128)
         params, cfg = ref.make_params(small), hm.HybridConfig.from_dict(small)
     hist, filled = _windows(rows, [8, 3, 1])
-    assert seq_attention.held_by(
+    assert kernels.held_by(
         lambda p, h, f: hm.logits_everywhere(p, h, f, cfg, dtype), params,
         hist, filled, names=grouped_experts.KERNELS) == (
             experts == "lane_wide")

@@ -19,7 +19,7 @@ import pytest
 
 from ccfd_tpu.models import hybrid_moe as hm
 from ccfd_tpu.ops import grouped_experts as ge
-from ccfd_tpu.ops import seq_attention
+from ccfd_tpu.ops.kernels import held_by, kernels_of
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -70,7 +70,7 @@ def _both(ex, z, chosen, w, cfg, dtype, monkeypatch, tile=TILE):
 
 
 def _holds_kernels(fn, *args) -> bool:
-    return seq_attention.held_by(fn, *args, names=ge.KERNELS)
+    return held_by(fn, *args, names=ge.KERNELS)
 
 
 def _random_choice(rng, n, routed, k, real=None):
@@ -220,7 +220,7 @@ def test_the_programs_jaxpr_says_which_body_was_taken(base, hidden, width,
 
     assert ge.kernel_fits(ex["gate"], dtype) == kernels
     assert _holds_kernels(layer, ex, z, chosen, w) == kernels
-    held = seq_attention.kernels_of(layer, ex, z, chosen, w)
+    held = kernels_of(layer, ex, z, chosen, w)
     assert held == (frozenset(ge.KERNELS) if kernels else frozenset())
     y, pairs, served = jax.jit(layer)(ex, z, chosen, w)
     assert int(served) == int(pairs.sum()) == int(
@@ -266,7 +266,7 @@ def one_chip():
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_mosaic_compiles_the_kernels_at_a_dispatch_of_the_real_models(
-        one_chip, cell):
+        one_chip, as_on_the_chip, cell):
     """A chunk of rows against each real model's stacked experts in
     bfloat16 at the tile its dispatch picks, written in place into a
     buffer of four chunks: what the interpreter cannot
@@ -279,8 +279,9 @@ def test_mosaic_compiles_the_kernels_at_a_dispatch_of_the_real_models(
 
     compiled = jax.jit(
         lambda x, gate, up, down, expert_of, live_of, visit, into, first:
-        ge.grouped_swiglu(x, gate, up, down, expert_of, live_of, visit, into,
-                          first, tile=tile)).lower(
+        ge.grouped_swiglu.__wrapped__(
+            x, gate, up, down, expert_of, live_of, visit, into, first,
+            tile=tile)).lower(
         shape(hm.MOE_CHUNK, hidden), shape(held, hidden, width),
         shape(held, hidden, width), shape(held, width, hidden),
         shape(hm.MOE_CHUNK // tile, dtype=jnp.int32),

@@ -25,7 +25,7 @@ from benchmark.reference import (cca_moe_f32, hybrid_moe_f32, mhc_moe_f32,
                                  mla_moe_f32, ssm_moe_f32, table)
 from ccfd_tpu.models import hybrid_moe as hm
 from ccfd_tpu.ops import kda_scan as ks
-from ccfd_tpu.ops import seq_attention
+from ccfd_tpu.ops import kernels
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -93,8 +93,7 @@ def _through_xla(q, k, v, g, beta, chunk):
 
 
 def _kernel(q, k, v, g, beta, chunk=64, **how):
-    return ks.kda_scan(q, k, v, g, beta, chunk=chunk, sub=SUB,
-                       interpret=True, **how)
+    return ks.kda_scan(q, k, v, g, beta, chunk=chunk, sub=SUB, **how)
 
 
 # -- the kernel against the loop over _kda_chunk and against the recurrence ------
@@ -300,7 +299,7 @@ def test_a_step_takes_the_most_spans_that_tile_a_row(spans, step):
 
 
 def _holds_kernel(fn, *args) -> bool:
-    return seq_attention.held_by(fn, *args, names=(ks.KERNEL,))
+    return kernels.held_by(fn, *args, names=(ks.KERNEL,))
 
 
 def _shape(*dims, dtype=F32):
@@ -339,9 +338,9 @@ def _pallas_call():
     x = _shape(*SERVED, dtype=BF16)
     jaxpr = jax.make_jaxpr(
         lambda q, k, v, g, beta: ks.kda_scan(q, k, v, g, beta, chunk=64,
-                                             sub=SUB))(
+                                             sub=SUB, exact=False))(
         x, x, x, _shape(*SERVED), _shape(*SERVED[:3]))
-    calls = [e for e in seq_attention._equations(jaxpr.jaxpr)
+    calls = [e for e in kernels.equations(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
     return calls[0]
@@ -380,7 +379,7 @@ def test_the_pallas_call_is_pinned_at_the_served_shape():
     assert cost.flops == 2 * each * (
         (3 + 2) * 64 * 128 + 6 * 10 * 64 * 64 + 9 * 128 * 128 + 2 * 64 * 128)
     body = [e.primitive.name
-            for e in seq_attention._equations(call.params["jaxpr"])]
+            for e in kernels.equations(call.params["jaxpr"])]
     # a span of four heads: two chunks of four strips each and a decay
     # and a hand-over a chunk; 3 products for the sums, 8 strips, 10
     # products of the inverse in 3 passes each, and a chunk's four with the
@@ -407,7 +406,7 @@ def one_chip():
 
 @pytest.mark.parametrize("precision", [None, "highest"])
 def test_mosaic_compiles_the_kernel_at_a_dispatch_of_the_real_model(
-        one_chip, precision):
+        one_chip, as_on_the_chip, precision):
     """What the interpreter cannot refuse (tiling, VMEM, a slice off the
     sublane grid, a product whose precision a caller's
     ``default_matmul_precision("highest")`` would change if it did not
@@ -418,8 +417,8 @@ def test_mosaic_compiles_the_kernel_at_a_dispatch_of_the_real_model(
     x = shape(*SERVED, dtype=BF16)
     with jax.default_matmul_precision(precision):
         compiled = jax.jit(
-            lambda q, k, v, g, beta: ks.kda_scan(q, k, v, g, beta, chunk=64,
-                                                 sub=SUB)).lower(
+            lambda q, k, v, g, beta: ks.kda_scan.__wrapped__(
+                q, k, v, g, beta, chunk=64, sub=SUB)).lower(
             x, x, x, shape(*SERVED), shape(*SERVED[:3])).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -446,11 +445,11 @@ def _window(records=8, rows=2):
 def test_the_lane_wide_program_holds_the_kernel_and_the_small_one_does_not(
         wide):
     config, params, cfg = wide
-    assert seq_attention.kernels_of(_program(cfg), params, *_window()) == {
+    assert kernels.kernels_of(_program(cfg), params, *_window()) == {
         ks.KERNEL}
     small = _small("ling3")
     shapes = jax.eval_shape(lambda: hybrid_moe_f32.make_params(small))
-    assert not seq_attention.kernels_of(
+    assert not kernels.kernels_of(
         _program(hm.HybridConfig.from_dict(small)), shapes, *_window())
 
 
@@ -464,7 +463,7 @@ def test_a_model_without_the_mixer_holds_no_delta_kernel(name, ref):
     cfg = hm.HybridConfig.from_dict(small)
     shapes = jax.eval_shape(lambda: ref.make_params(small))
     for records in (8, 64):
-        assert ks.KERNEL not in seq_attention.kernels_of(
+        assert ks.KERNEL not in kernels.kernels_of(
             _program(cfg), shapes, *_window(records))
 
 

@@ -475,14 +475,14 @@ def test_at_the_cells_shapes_a_layer_holds_the_kernels(kind):
     Mamba-2 layer's the ``ssd_scan`` kernel (128 heads of 64, a state of
     128, chunks of 640), every layer's the grouped expert kernels
     (4,096 x 768)."""
-    from ccfd_tpu.ops import (causal_attention, grouped_experts,
-                              seq_attention, ssd_scan)
+    from ccfd_tpu.ops import (causal_attention, grouped_experts, kernels,
+                              ssd_scan)
 
     real = dict(_real_config(), layers_kept=[5 if kind == "gqa" else 0],
                 layer_stack="listed")
     cfg = hm.HybridConfig.from_dict(real)
     shapes = jax.eval_shape(lambda: ref.make_params(real))
-    held = seq_attention.kernels_of(
+    held = kernels.kernels_of(
         lambda p, h, f: hm.apply_serving(p, h, f, cfg, jnp.bfloat16), shapes,
         jax.ShapeDtypeStruct((2, 64, 30), np.float32),
         jax.ShapeDtypeStruct((2,), np.int32))

@@ -354,7 +354,7 @@ def test_the_real_programs_hold_the_expert_kernels_and_the_attention_kernel(
     + 64 = 192 is a lane tile and a half against values of 128, so every
     layer attends through the causal-attention kernel (the plain path
     until PR 42), and none through ``seq``'s."""
-    from ccfd_tpu.ops import grouped_experts, seq_attention
+    from ccfd_tpu.ops import grouped_experts, kernels
 
     real = _config("benchmark", "configs", "kafka_history_xing4.json")
     cfg = hm.HybridConfig.from_dict(real)
@@ -365,7 +365,7 @@ def test_the_real_programs_hold_the_expert_kernels_and_the_attention_kernel(
     def program(p, h, f):
         return hm.apply_serving(p, h, f, cfg, jnp.bfloat16)
 
-    assert seq_attention.kernels_of(program, shapes, hist, filled) == {
+    assert kernels.kernels_of(program, shapes, hist, filled) == {
         "causal_attention", *grouped_experts.KERNELS}
 
 

@@ -23,7 +23,7 @@ import pytest
 from benchmark.reference import (cca_moe_f32, hybrid_moe_f32, mhc_moe_f32,
                                  mla_moe_f32, ssm_moe_f32, table)
 from ccfd_tpu.models import hybrid_moe as hm
-from ccfd_tpu.ops import seq_attention
+from ccfd_tpu.ops import kernels
 from ccfd_tpu.ops import ssd_scan as ss
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -98,7 +98,7 @@ def test_the_kernel_equals_the_scan_through_xla_and_the_recurrence(
     in float32 and in bfloat16 (widened inside: decays, scores, state and
     sums are float32 either way)."""
     operands, real = _operands(t, groups=groups, dtype=dtype)
-    got, low = ss.ssd_scan(*operands, chunk=chunk, side=side, interpret=True)
+    got, low = ss.ssd_scan(*operands, chunk=chunk, side=side)
     want, want_low = _through_xla(*operands, chunk)
     assert got.shape == want.shape == operands[0].shape
     assert got.dtype == jnp.float32
@@ -121,23 +121,20 @@ def test_the_chips_bfloat16_passes_stay_near_the_float32_products(groups):
     inside a chunk, far under it on what only the state carries."""
     operands, real = _operands(256, groups=groups, pads=(0, 130))
     want = _a_token_at_a_time(*operands) * real
-    got, _ = ss.ssd_scan(*operands, chunk=128, interpret=True,
-                         exact=False)
+    got, _ = ss.ssd_scan(*operands, chunk=128, exact=False)
     gap = np.abs(np.asarray(got) * real - want)
     assert gap.mean() < 4e-3 * np.abs(want).mean()
     assert gap.max() < 0.05 * np.abs(want).max()
-    exact, _ = ss.ssd_scan(*operands, chunk=128, interpret=True)
+    exact, _ = ss.ssd_scan(*operands, chunk=128)
     assert np.abs(np.asarray(exact) * real - want).max() < 2e-4
     # the state alone: C of the first chunk zeroed, so what the second
     # chunk's tokens read of the first comes through the state's products
     x, bm, cm, dt, a, d = operands
     alone = (x, bm, cm.at[:, :128].set(0.0), dt, a, jnp.zeros_like(d))
     want = _a_token_at_a_time(*alone)[:, 128:]
-    got, _ = ss.ssd_scan(*alone, chunk=128, interpret=True,
-                         exact=False)
+    got, _ = ss.ssd_scan(*alone, chunk=128, exact=False)
     got0, _ = ss.ssd_scan(x, bm, cm.at[:, :].set(0.0), dt, a,
-                          jnp.zeros_like(d), chunk=128, interpret=True,
-                          exact=False)
+                          jnp.zeros_like(d), chunk=128, exact=False)
     assert not np.asarray(got0).any()
     assert np.abs(np.asarray(got)[:, 128:] - want).mean() < 4e-3 * np.abs(
         want).mean()
@@ -149,11 +146,10 @@ def test_the_state_is_handed_from_chunk_to_chunk():
     (x, bm, cm, dt, a, d), _ = _operands(384, pads=(0, 0))
     # slow decays, so that the first chunk still shows in the third
     a = a * 0.01
-    base, _ = ss.ssd_scan(x, bm, cm, dt, a, d, chunk=128, interpret=True)
-    early, _ = ss.ssd_scan(x.at[:, 5].add(1.0), bm, cm, dt, a, d, chunk=128,
-                           interpret=True)
+    base, _ = ss.ssd_scan(x, bm, cm, dt, a, d, chunk=128)
+    early, _ = ss.ssd_scan(x.at[:, 5].add(1.0), bm, cm, dt, a, d, chunk=128)
     late, _ = ss.ssd_scan(x.at[:, 300].add(1.0), bm.at[:, 300].add(1.0), cm,
-                          dt, a, d, chunk=128, interpret=True)
+                          dt, a, d, chunk=128)
     base, early, late = (np.asarray(v) for v in (base, early, late))
     assert np.array_equal(base[:, :5], early[:, :5])
     moved = np.abs(early - base).max(axis=(0, 2, 3))
@@ -169,13 +165,12 @@ def test_padding_passes_the_state_unchanged():
     hold where the padding is."""
     operands, _ = _operands(256, pads=(130,))
     alone = tuple(v[:, 130:] if v.ndim > 1 else v for v in operands)
-    got, _ = ss.ssd_scan(*operands, chunk=128, interpret=True)
-    want, _ = ss.ssd_scan(*alone, chunk=128, interpret=True)
+    got, _ = ss.ssd_scan(*operands, chunk=128)
+    want, _ = ss.ssd_scan(*alone, chunk=128)
     assert np.allclose(np.asarray(got)[:, 130:], np.asarray(want), atol=2e-5)
     # and a window of padding alone is the skip's D x, with no decay at all
     x, bm, cm, dt, a, d = operands
-    got, low = ss.ssd_scan(x, bm, cm, dt * 0, a * 0, d, chunk=128,
-                           interpret=True)
+    got, low = ss.ssd_scan(x, bm, cm, dt * 0, a * 0, d, chunk=128)
     assert np.allclose(np.asarray(got), np.asarray(d[:, None] * x))
     assert float(low) == 0.0
 
@@ -227,7 +222,7 @@ def test_the_block_comes_from_the_chunk(chunk, side):
 
 
 def _holds_kernel(fn, *args) -> bool:
-    return seq_attention.held_by(fn, *args, names=(ss.KERNEL,))
+    return kernels.held_by(fn, *args, names=(ss.KERNEL,))
 
 
 def _shape(*dims, dtype=F32):
@@ -266,17 +261,18 @@ def _pallas_call():
     (b, t, h, p), (_, _, g, n), chunk = SERVED
     jaxpr = jax.make_jaxpr(
         lambda x, bm, cm, dt, a, d: ss.ssd_scan(x, bm, cm, dt, a, d,
-                                                chunk=chunk))(
+                                                chunk=chunk, exact=False))(
         _shape(b, t, h, p), _shape(b, t, g, n), _shape(b, t, g, n),
         _shape(b, t, h), _shape(b, t, h), _shape(h))
-    calls = [e for e in seq_attention._equations(jaxpr.jaxpr)
+    calls = [e for e in kernels.equations(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
     return calls[0]
 
 
 def test_the_pallas_call_is_pinned_at_the_served_shape():
-    """The name, the grid, the operands and their blocks: x and y
+    """As the chip runs it (``exact=False``: its bfloat16 passes, which the
+    interpreter's default here would make float32). The name, the grid, the operands and their blocks: x and y
     lane-dense, two lane tiles (four heads) a step; B, C, dt and R a
     (row, chunk)'s, whatever the step; R a second time by head; the scores
     of a chunk, the pieces of C and B^T and every head's state in scratch;
@@ -307,7 +303,7 @@ def test_the_pallas_call_is_pinned_at_the_served_shape():
     assert call.params["cost_estimate"].transcendentals == 4 * 128 * (
         visited + 2 * 1920 * 64)
     body = [e.primitive.name
-            for e in seq_attention._equations(call.params["jaxpr"])]
+            for e in kernels.equations(call.params["jaxpr"])]
     # a step: 2 lane tiles x 2 heads x 15 blocks of decays, each an
     # exponential and a product; and per lane tile three exponentials and
     # two products of three passes with the state
@@ -332,7 +328,7 @@ def one_chip():
 
 @pytest.mark.parametrize("precision", [None, "highest"])
 def test_mosaic_compiles_the_kernel_at_a_dispatch_of_the_real_model(
-        one_chip, precision):
+        one_chip, as_on_the_chip, precision):
     """What the interpreter cannot refuse (tiling, VMEM, a load off the
     sublane grid, a bfloat16 product asked for at float32 precision: what
     a caller's ``default_matmul_precision("highest")`` would make of a
@@ -344,8 +340,8 @@ def test_mosaic_compiles_the_kernel_at_a_dispatch_of_the_real_model(
     (b, t, h, p), (_, _, g, n), chunk = SERVED
     with jax.default_matmul_precision(precision):
         compiled = jax.jit(
-            lambda x, bm, cm, dt, a, d: ss.ssd_scan(x, bm, cm, dt, a, d,
-                                                    chunk=chunk)).lower(
+            lambda x, bm, cm, dt, a, d: ss.ssd_scan.__wrapped__(
+                x, bm, cm, dt, a, d, chunk=chunk)).lower(
             shape(b, t, h, p), shape(b, t, g, n), shape(b, t, g, n),
             shape(b, t, h), shape(b, t, h), shape(h)).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -373,11 +369,11 @@ def _window(records=8, rows=2):
 def test_the_lane_wide_program_holds_the_kernel_and_the_small_one_does_not(
         wide):
     config, params, cfg = wide
-    assert seq_attention.kernels_of(_program(cfg), params, *_window()) == {
+    assert kernels.kernels_of(_program(cfg), params, *_window()) == {
         ss.KERNEL}
     small = _small("granite4h")
     shapes = jax.eval_shape(lambda: ssm_moe_f32.make_params(small))
-    assert not seq_attention.kernels_of(
+    assert not kernels.kernels_of(
         _program(hm.HybridConfig.from_dict(small)), shapes, *_window())
 
 
@@ -391,7 +387,7 @@ def test_a_model_without_the_mixer_holds_no_scan_kernel(name, ref):
     cfg = hm.HybridConfig.from_dict(small)
     shapes = jax.eval_shape(lambda: ref.make_params(small))
     for records in (8, 64):
-        assert ss.KERNEL not in seq_attention.kernels_of(
+        assert ss.KERNEL not in kernels.kernels_of(
             _program(cfg), shapes, *_window(records))
 
 

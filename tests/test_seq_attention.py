@@ -1,7 +1,9 @@
 """The seq family's attention kernel (ops/seq_attention.py): its numbers
 against ``reference_attention``, which shapes get it, and what the scorer's
-inventory and counters say of it. On the CPU the kernel runs under
-``interpret=True``, as ops/fused_mlp.py's does."""
+inventory and counters say of it. On the CPU the kernel runs under the
+interpreter (``ops/kernels.py::interpreted``)."""
+
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -10,11 +12,13 @@ import pytest
 
 from ccfd_tpu.metrics.prom import Registry
 from ccfd_tpu.models import seq
-from ccfd_tpu.ops import seq_quant
+from ccfd_tpu.ops import kernels, seq_quant
 from ccfd_tpu.ops.ring_attention import reference_attention
-from ccfd_tpu.ops.seq_attention import (attention, fused_attention, held_by,
+from ccfd_tpu.ops.seq_attention import (KERNEL, attention, fused_attention,
                                         kernel_fits, query_block)
 from ccfd_tpu.serving.history import SeqScorer
+
+held_by = partial(kernels.held_by, names=(KERNEL,))
 
 
 def _qkv(shape, dtype, seed=0):
@@ -40,7 +44,7 @@ def _qkv(shape, dtype, seed=0):
 def test_kernel_matches_reference_attention(shape, dtype, tol):
     q, k, v = _qkv(shape, dtype)
     assert kernel_fits(q.shape, k.shape, q.dtype)
-    got = fused_attention(q, k, v, interpret=True)
+    got = fused_attention(q, k, v)
     want = reference_attention(q, k, v)
     assert got.shape == want.shape and got.dtype == want.dtype
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
@@ -176,7 +180,7 @@ def test_a_variant_swap_reads_the_new_programs_trace():
     reg = Registry()
     scorer = _scorer(128, reg)
     before = scorer._apply
-    assert before.holds_attn_kernel(scorer.params, 128, 4)
+    assert before.kernels_held(scorer.params, 128, 4)["attn_kernel"]
     scorer.swap_params(seq_quant.quantize_seq(scorer.params))
     assert scorer._apply is not before
     assert all(g["attn_kernel"] for g in scorer.executable_grid()["grid"])

@@ -179,7 +179,7 @@ def test_the_wire_is_chosen_by_shape_and_mesh_and_the_inventory_says_so(
 
 def test_the_kernel_question_is_asked_at_the_dispatched_shape():
     """Warm-up, a dispatch and the inventory leave the served program with
-    one trace a rung: ``holds_attn_kernel`` looks up the executable that
+    one trace a rung: ``kernels_held`` looks up the executable that
     runs and traces no second one beside it."""
     reg = Registry()
     scorer = SeqScorer(seq_mod.init(jax.random.PRNGKey(5)), length=512,
