@@ -5,7 +5,9 @@ family.
 A decoder-only language model of several layer kinds in one stack, driven
 by the published ``config.json`` keys of the model it serves. Nothing here
 is one model's numbers: a model is a tuple of ``(mixer, feed-forward)``
-kinds, each mixer and each router an entry of a table (``MIXERS``,
+kinds (either half may be absent: a layer of one sublayer, a mixer alone or
+the experts alone, runs one norm and one pass of the residual rule), each
+mixer and each router an entry of a table (``MIXERS``,
 ``ROUTERS``: name -> function) with settings of its own, read from the
 published keys by one small reader a ``model_type`` (``READERS``); what no
 layer of a model uses is not in that model's settings. Mixers: **KDA**
@@ -22,16 +24,20 @@ L2-normed q and k with a learned temperature, partial rotary), **Mamba-2**
 convolved x, B and C and a step dt a head; a causal depthwise convolution
 with a bias; per head S_t = e^(dt_t A) S_(t-1) + dt_t x_t B_t^T, y_t = S_t
 C_t + D x_t with B and C shared by the heads of a group; the gate, then an
-RMS norm over all inner values; served as a chunked scan whose chunk is the
-deployment's ``scan_chunk``) and **GQA** (``gqa``: plain grouped-query
-causal softmax attention without positions, the scale the model's own).
+RMS norm over all inner values or inside each of the model's groups of
+them; served as a chunked scan whose chunk is the deployment's
+``scan_chunk``) and **GQA** (``gqa``: plain grouped-query causal softmax
+attention without positions, head width and scale the model's own).
 Routers of the **sparse expert layer**: ``top_k`` (sigmoid or softmax
 scores over all
 routed experts, an optional expert bias for the choice, group-limited or
 not, weights renormalised over the chosen) with or without a shared
 expert; or ``carried_mlp``, a small MLP on a down-projection whose hidden
 state is handed from one layer's router to the next, softmax, top 1, and a
-last output that means *no expert* (the token skips the layer).
+last output that means *no expert* (the token skips the layer). **An
+expert's body** (routed and shared alike) is a setting of the model
+(``EXPERT_BODIES``): SwiGLU of three matrices, or relu squared between two
+with no gate.
 **The residual path** is a table too (``RESIDUALS``): ``plain`` (x + f(x)),
 ``multiplied`` (x + m f(x), m one constant of the model), ``scaled`` (a
 learned scale and bias on the stream and on the sublayer's output) or
@@ -79,8 +85,11 @@ therefore the same at every window length that holds its history.
 
 The equations, with the key each symbol is read from, are in the plain
 references ``benchmark/reference/hybrid_moe_f32.py``, ``cca_moe_f32.py``,
-``mla_moe_f32.py``, ``mhc_moe_f32.py`` and ``ssm_moe_f32.py`` (which import
-nothing from here); the parameter tree is the one their ``make_params`` draw.
+``mla_moe_f32.py``, ``mhc_moe_f32.py``, ``ssm_moe_f32.py`` and
+``ssm_relu2_moe_f32.py`` (which import nothing from here); the parameter
+tree is the one their ``make_params`` draw (a layer's tree holds ``norm1``
+and ``mixer`` where it has a mixer, ``norm2`` and ``ffn`` where it has a
+feed-forward part).
 
 Precision: matrices bfloat16, products accumulated in float32, the
 residual stream, norms, gates, softmax and the router in float32 (the
@@ -253,13 +262,32 @@ class Cca:
                    float(rope["rope_theta"]))
 
 
+# what each family that has the mixer calls the mixer's numbers (``block``:
+# the published kernel's chunk; the two biases: the convolution's and the
+# projections')
+MAMBA_KEYS = {
+    "granitemoehybrid": dict(
+        heads="mamba_n_heads", head_dim="mamba_d_head",
+        state="mamba_d_state", groups="mamba_n_groups", conv="mamba_d_conv",
+        block="mamba_chunk_size", conv_bias="mamba_conv_bias",
+        proj_bias="mamba_proj_bias"),
+    "nemotron_h": dict(
+        heads="mamba_num_heads", head_dim="mamba_head_dim",
+        state="ssm_state_size", groups="n_groups", conv="conv_kernel",
+        block="chunk_size", conv_bias="use_conv_bias",
+        proj_bias="mamba_proj_bias"),
+}
+
+
 @dataclasses.dataclass(frozen=True)
 class Mamba2:
     """The selective state-space mixer: ``heads`` heads of ``head_dim``
-    with a state of ``head_dim`` x ``state`` each, B and C shared by the
-    heads of a group, a causal depthwise convolution of ``conv`` taps; and
-    the deployment's ``chunk``, the tokens a step of the served scan takes
-    at once (no part of the result)."""
+    (the inner width is their product, as given) with a state of
+    ``head_dim`` x ``state`` each, B and C shared by the heads of a group,
+    a causal depthwise convolution of ``conv`` taps; the deployment's
+    ``chunk``, the tokens a step of the served scan takes at once (no part
+    of the result); and ``norm_groups``, the groups of inner values the
+    gated norm norms apart (1: all of them together)."""
 
     heads: int
     head_dim: int
@@ -267,23 +295,24 @@ class Mamba2:
     groups: int
     conv: int
     chunk: int
+    norm_groups: int = 1
 
     @classmethod
-    def read(cls, m: Mapping[str, Any]) -> "Mamba2":
-        heads, hd = int(m["mamba_n_heads"]), int(m["mamba_d_head"])
-        groups = int(m["mamba_n_groups"])
-        if heads * hd != int(m["mamba_expand"]) * int(m["hidden_size"]) \
-                or heads % groups or m["mamba_proj_bias"] \
-                or not m["mamba_conv_bias"]:
-            raise ValueError("mamba2: heads x head width = mamba_expand x "
-                             "hidden, heads a multiple of the groups, no "
-                             "bias on the projections, one on the "
-                             "convolution")
-        chunk = int(m.get("scan_chunk", m["mamba_chunk_size"]))
+    def read(cls, m: Mapping[str, Any], norm_groups: int = 1) -> "Mamba2":
+        """By the key names of ``m``'s own family (``MAMBA_KEYS``)."""
+        key = MAMBA_KEYS[m["model_type"]]
+        heads, groups = int(m[key["heads"]]), int(m[key["groups"]])
+        if heads % groups or heads % norm_groups or m[key["proj_bias"]] \
+                or not m[key["conv_bias"]]:
+            raise ValueError(
+                f"mamba2: {key['heads']} a multiple of {key['groups']} (and "
+                f"of the gated norm's groups), {key['proj_bias']} false, "
+                f"{key['conv_bias']} true")
+        chunk = int(m.get("scan_chunk", m[key["block"]]))
         if chunk < 1:
             raise ValueError("scan_chunk is a count of tokens")
-        return cls(heads, hd, int(m["mamba_d_state"]), groups,
-                   int(m["mamba_d_conv"]), chunk)
+        return cls(heads, int(m[key["head_dim"]]), int(m[key["state"]]),
+                   groups, int(m[key["conv"]]), chunk, norm_groups)
 
     def chunk_for(self, tokens: int) -> int:
         """The chunk of a window of ``tokens``: a window shorter than the
@@ -302,16 +331,16 @@ class Gqa:
     scale: float  # what the scores are multiplied by before the softmax
 
     @classmethod
-    def read(cls, m: Mapping[str, Any]) -> "Gqa":
+    def read(cls, m: Mapping[str, Any], head_dim: int,
+             scale: float) -> "Gqa":
+        """The head's width and the softmax scale are the reader's: each
+        family says them its own way."""
         heads, kv = int(m["num_attention_heads"]), int(
             m["num_key_value_heads"])
-        if heads % kv or int(m["hidden_size"]) % heads \
-                or m["position_embedding_type"] != "nope" \
-                or m["attention_bias"]:
-            raise ValueError("gqa: query heads a multiple of the key-value "
-                             "heads, no positions, no bias")
-        return cls(heads, kv, int(m["hidden_size"]) // heads,
-                   float(m["attention_multiplier"]))
+        if heads % kv or m["attention_bias"]:
+            raise ValueError("gqa: num_attention_heads a multiple of "
+                             "num_key_value_heads, attention_bias false")
+        return cls(heads, kv, int(head_dim), float(scale))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -364,7 +393,9 @@ class HybridConfig:
     the stack, each of its kinds' own settings, and what all models share."""
 
     eps: float
-    layers: tuple[tuple[str, str], ...]  # (mixer, feed-forward) per layer
+    # (mixer, feed-forward) per layer; None where the layer has no such
+    # half (a layer of one sublayer: a mixer alone, or the experts alone)
+    layers: tuple[tuple[str | None, str | None], ...]
     mixers: tuple[tuple[str, Any], ...]  # (name in MIXERS, its settings)
     router: str  # name in ROUTERS
     routing: Any  # that router's settings (None: it has none of its own)
@@ -381,6 +412,7 @@ class HybridConfig:
     tied_head: bool = False  # the head is the embedding
     embed_scale: float = 1.0  # on every token's embedding
     logit_divisor: float = 1.0  # under the logits
+    expert_body: str = "swiglu"  # name in EXPERT_BODIES: routed and shared
 
     @classmethod
     def from_dict(cls, m: Mapping[str, Any]) -> "HybridConfig":
@@ -393,14 +425,16 @@ class HybridConfig:
             raise ValueError(f"hybrid_moe reads model_type {sorted(READERS)}"
                              f", not {kind!r}")
         held = m["experts_held"]
+        settings = READERS[kind](m)
+        if "eps" not in settings:  # the key most families have
+            settings["eps"] = float(m["rms_norm_eps"])
         return cls(
-            eps=float(m["rms_norm_eps"]),
             routed=int(m["num_experts_routed_over"]),
             held_first=int(held["first"]), held_count=int(held["count"]),
             per_token=int(m["num_experts_per_tok"]), bins=int(m["bins"]),
             fraud_id=int(m["readout"]["fraud_id"]),
             legit_id=int(m["readout"]["legit_id"]),
-            shift=float(m["readout"]["shift"]), **READERS[kind](m))
+            shift=float(m["readout"]["shift"]), **settings)
 
     def mixer(self, name: str) -> Any:
         """The settings of the mixer kind ``name``."""
@@ -414,6 +448,13 @@ class HybridConfig:
 def _held_all_of(m: Mapping[str, Any], key: str) -> None:
     if int(m["experts_held"]["count"]) != int(m[key]):
         raise ValueError(f"experts_held.count is not {key}")
+
+
+def _mixers_of(layers, read: Mapping[str, Any]) -> tuple:
+    """``(name, settings)`` of every mixer kind ``layers`` names, each read
+    by ``read[name]()``: what no kept layer uses is not read."""
+    return tuple((name, read[name]())
+                 for name in sorted({mixer for mixer, _ in layers if mixer}))
 
 
 def _read_ling(m: Mapping[str, Any]) -> dict:
@@ -502,11 +543,19 @@ def _read_granite(m: Mapping[str, Any]) -> dict:
     kinds = {"mamba": "mamba2", "attention": "gqa"}
     layers = tuple((kinds[m["layer_types"][i]], "moe")
                    for i in m["layers_kept"])
-    settings = {"mamba2": Mamba2, "gqa": Gqa}
+    hidden, heads = int(m["hidden_size"]), int(m["num_attention_heads"])
+    if int(m["mamba_n_heads"]) * int(m["mamba_d_head"]) != int(
+            m["mamba_expand"]) * hidden:
+        raise ValueError("granitemoehybrid: mamba2's mamba_n_heads x "
+                         "mamba_d_head = mamba_expand x hidden_size")
+    if hidden % heads or m["position_embedding_type"] != "nope":
+        raise ValueError("granitemoehybrid: gqa heads of hidden_size / "
+                         "num_attention_heads, position_embedding_type nope")
     return dict(
-        layers=layers, mixers=tuple(
-            (name, settings[name].read(m))
-            for name in sorted({mixer for mixer, _ in layers})),
+        layers=layers, mixers=_mixers_of(layers, {
+            "mamba2": lambda: Mamba2.read(m),
+            "gqa": lambda: Gqa.read(m, hidden // heads,
+                                    float(m["attention_multiplier"]))}),
         router="top_k", routing=TopK("softmax", False, 1, 1, 1.0),
         residual="multiplied",
         residual_settings=Multiplied(float(m["residual_multiplier"])),
@@ -515,9 +564,47 @@ def _read_granite(m: Mapping[str, Any]) -> dict:
         logit_divisor=float(m["logits_scaling"]))
 
 
+def _read_nemotron(m: Mapping[str, Any]) -> dict:
+    """Nemotron-H with experts: a layer is ONE sublayer, by the letter of
+    ``hybrid_override_pattern``: a Mamba-2 mixer whose gated norm norms
+    inside each of ``n_groups`` groups (M), grouped-query attention
+    ``head_dim`` wide without positions (*), or the expert layer (E):
+    sigmoid scores with a bias for the choice over all routed experts,
+    weights renormalised and scaled, experts of two matrices with relu
+    squared and no gate, one shared expert; an untied head, the one eps
+    under two names."""
+    _held_all_of(m, "n_routed_experts")
+    if int(m["n_group"]) != 1 or int(m["topk_group"]) != 1 \
+            or int(m["n_shared_experts"]) != 1 or not m["norm_topk_prob"] \
+            or m["mlp_hidden_act"] != "relu2" \
+            or m["mamba_hidden_act"] != "silu" or m["mlp_bias"] \
+            or m["use_bias"] or m["norm_eps"] != m["layer_norm_epsilon"]:
+        raise ValueError(
+            "nemotron_h: n_group 1, topk_group 1, n_shared_experts 1, "
+            "norm_topk_prob true, mlp_hidden_act relu2, mamba_hidden_act "
+            "silu, mlp_bias and use_bias false, norm_eps = "
+            "layer_norm_epsilon")
+    kinds = {"M": ("mamba2", None), "*": ("gqa", None), "E": (None, "moe")}
+    pattern = m["hybrid_override_pattern"]
+    for i in m["layers_kept"]:
+        if pattern[i] not in kinds:
+            raise ValueError(
+                f"nemotron_h: layer {i} of hybrid_override_pattern is "
+                f"{pattern[i]!r}; {' '.join(kinds)} are served")
+    layers = tuple(kinds[pattern[i]] for i in m["layers_kept"])
+    head_dim = int(m["head_dim"])
+    return dict(
+        eps=float(m["norm_eps"]), layers=layers, mixers=_mixers_of(layers, {
+            "mamba2": lambda: Mamba2.read(m, norm_groups=int(m["n_groups"])),
+            "gqa": lambda: Gqa.read(m, head_dim, head_dim ** -0.5)}),
+        router="top_k", routing=TopK(
+            "sigmoid", True, 1, 1, float(m["routed_scaling_factor"])),
+        tied_head=bool(m["tie_word_embeddings"]), expert_body="relu2")
+
+
 READERS = {"ling": _read_ling, "zaya": _read_zaya,
            "mistral4": _read_mistral4, "xing4_0": _read_xing4,
-           "granitemoehybrid": _read_granite}
+           "granitemoehybrid": _read_granite, "nemotron_h": _read_nemotron}
 
 
 def owns(params: Any) -> bool:
@@ -543,6 +630,18 @@ def _mm(x, w, dtype):
 def _swiglu(p, x, dtype):
     h = jax.nn.silu(_mm(x, p["gate"], dtype)) * _mm(x, p["up"], dtype)
     return _mm(h, p["down"], dtype)
+
+
+def _relu2(p, x, dtype):
+    h = jnp.square(jax.nn.relu(_mm(x, p["up"], dtype)))
+    return _mm(h, p["down"], dtype)
+
+
+# an expert's body (routed and shared alike), name -> (its matrices in the
+# order they are cut out of the stack, f(p, x, dtype)): SwiGLU of three
+# matrices, or relu squared between two with no gate
+EXPERT_BODIES = {"swiglu": (("gate", "up", "down"), _swiglu),
+                 "relu2": (("up", "down"), _relu2)}
 
 
 def tokenise(edges, hist, bins: int):
@@ -1045,10 +1144,15 @@ def _state_scan(x, bm, cm, dt, a, d, c: int):
     return y + d[:, None] * x, low
 
 
-def _gated_norm(y, gate, weight, eps):
-    """RMSNorm(y * SiLU(gate)) w: the gate first, the norm after it, over
-    all the values as one group."""
-    return _rms(y * jax.nn.silu(gate), weight, eps)
+def _gated_norm(y, gate, weight, eps, groups: int = 1):
+    """RMSNorm(y * SiLU(gate)) w: the gate first, the norm after it,
+    inside each of ``groups`` equal groups of the values (1: over all of
+    them together)."""
+    v = y * jax.nn.silu(gate)
+    if groups == 1:
+        return _rms(v, weight, eps)
+    apart = v.reshape(*v.shape[:-1], groups, -1)
+    return _rms(apart, weight.reshape(groups, -1), eps).reshape(v.shape)
 
 
 def mamba2(p, z, real, cfg: HybridConfig, dtype):
@@ -1056,9 +1160,10 @@ def mamba2(p, z, real, cfg: HybridConfig, dtype):
     most negative running log-decay inside a chunk)``: one projection to
     [gate | x B C | dt], the causal depthwise convolution with its bias
     and SiLU over x, B and C together, the chunked scan (:func:`_ssd`) in
-    float32, the skip D x, the gate and after it the RMS norm over all the
-    inner values, the output projection. The convolution runs on (B, T,
-    tiles, 128): a shift by a token then moves whole tiles."""
+    float32, the skip D x, the gate and after it the RMS norm over the
+    inner values (all together, or inside each of the settings'
+    ``norm_groups``), the output projection. The convolution runs on (B,
+    T, tiles, 128): a shift by a token then moves whole tiles."""
     b, t, _ = z.shape
     s = cfg.mixer("mamba2")
     h, hd, n, g = s.heads, s.head_dim, s.state, s.groups
@@ -1083,7 +1188,8 @@ def mamba2(p, z, real, cfg: HybridConfig, dtype):
             xbc[..., inner + g * n:].reshape(b, t, g, n), dt,
             -jnp.exp(p["a_log"]) * dt, p["d"], s.chunk_for(t))
     with jax.named_scope("mamba.gate"):
-        y = _gated_norm(y.reshape(b, t, inner), gate, p["norm"], cfg.eps)
+        y = _gated_norm(y.reshape(b, t, inner), gate, p["norm"], cfg.eps,
+                        s.norm_groups)
     with jax.named_scope("mamba.project"):
         return _mm(y, p["w_out"], dtype), low
 
@@ -1194,10 +1300,13 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
       the buffer's; ``tile`` comes from the pairs an expert expects
       (``row_tile``);
     - the plain loop (every other shape, and a mesh), which runs once per
-      tile that exists, gathers the tile's tokens, cuts the expert's three
-      matrices out of the stack and writes the expert's SwiGLU of the rows
-      (``tile`` = ``MOE_TILE``). It is the definition the tests hold the
-      kernels against.
+      tile that exists, gathers the tile's tokens, cuts the expert's
+      matrices out of the stack and writes the expert's body of the rows
+      (``cfg.expert_body``, an entry of ``EXPERT_BODIES``: SwiGLU of three
+      matrices, or relu squared between two; ``tile`` = ``MOE_TILE``). It
+      is the definition the tests hold the kernels against. Columns of
+      zeros that pad ``up`` with the same rows of zeros in ``down`` change
+      nothing under either body.
 
     After either every token adds up its own slots' rows, scaled by the
     routing weights (a gather: on this device a row-wise scatter-add into
@@ -1206,7 +1315,8 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
     no pair is dropped."""
     n, k = chosen.shape
     held = cfg.held_count
-    kernel = grouped_experts.kernel_fits(ex["gate"], dtype)
+    names, body = EXPERT_BODIES[cfg.expert_body]
+    kernel = grouped_experts.kernel_fits(ex["up"], dtype, len(names) - 1)
     if tile is None:
         tile = grouped_experts.row_tile(n * k / cfg.routed) if kernel \
             else MOE_TILE
@@ -1233,9 +1343,9 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
 
     def one_tile(j, rows):
         slot = jax.lax.dynamic_slice(order_padded, (start_of[j],), (tile,))
-        part = _swiglu({name: jax.lax.dynamic_index_in_dim(
+        part = body({name: jax.lax.dynamic_index_in_dim(
             ex[name], expert_of[j], 0, keepdims=False)
-            for name in ("gate", "up", "down")}, zc[slot // k], dtype)
+            for name in names}, zc[slot // k], dtype)
         return jax.lax.dynamic_update_slice(rows, part.astype(dtype),
                                             (j * tile, 0))
 
@@ -1244,8 +1354,8 @@ def held_experts(ex, z, chosen, w, cfg: HybridConfig, dtype,
         slot = jax.vmap(lambda start: jax.lax.dynamic_slice(
             order_padded, (start,), (tile,)))(jax.lax.dynamic_slice(
                 start_of, (first,), (chunk,))).reshape(-1)
-        return grouped_experts.grouped_swiglu(
-            zc[slot // k], ex["gate"], ex["up"], ex["down"],
+        return grouped_experts.GROUPED[cfg.expert_body](
+            zc[slot // k], *(ex[name] for name in names),
             jax.lax.dynamic_slice(expert_of, (first,), (chunk,)),
             jax.lax.dynamic_slice(live_of, (first,), (chunk,)),
             jnp.minimum(tile_end[-1] - first, chunk), rows, first, tile=tile)
@@ -1291,8 +1401,9 @@ def moe(p, z, r, real, cfg: HybridConfig, dtype):
         y, pairs, served = held_experts(p["experts"], flat, chosen, w, cfg,
                                         dtype)
     if "shared" in p:
+        _, body = EXPERT_BODIES[cfg.expert_body]
         with jax.named_scope("moe.shared"):
-            y = y + _swiglu(p["shared"], flat, dtype)
+            y = y + body(p["shared"], flat, dtype)
     local = chosen - cfg.held_first
     mine = (local >= 0) & (local < cfg.held_count)
     counts = {
@@ -1417,10 +1528,11 @@ RESIDUALS = {  # name -> f(p, x, sublayer, real, cfg): (x, extra, defect)
 
 
 def _layer(p, x, r, kind, real, position, cfg: HybridConfig, dtype):
-    """One layer of kind ``(mixer, feed-forward)``: ``(x, r, counts,
-    defect, report)``, ``counts`` None where the layer has no experts,
-    ``defect`` None where the residual rule has none, ``report`` None
-    where the mixer reports nothing."""
+    """One layer of kind ``(mixer, feed-forward)``, either of them None
+    in a layer of one sublayer (one norm, one pass of the residual rule):
+    ``(x, r, counts, defect, report)``, ``counts`` None where the layer
+    has no experts, ``defect`` None where the residual rule has none,
+    ``report`` None where the mixer reports nothing."""
     mixer, ffn = kind
     rule = RESIDUALS[cfg.residual]
 
@@ -1437,16 +1549,21 @@ def _layer(p, x, r, kind, real, position, cfg: HybridConfig, dtype):
         y, state, counts = moe(p["ffn"], z, r, real, cfg, dtype)
         return y, (state, counts)
 
-    x, report, first = rule(p.get("res1"), x, mix, real, cfg)
-    x, (r, counts), second = rule(p.get("res2"), x, feed, real, cfg)
-    return (x, r, counts,
-            None if first is None else jnp.maximum(first, second), report)
+    report = counts = defect = None
+    if mixer is not None:
+        x, report, defect = rule(p.get("res1"), x, mix, real, cfg)
+    if ffn is not None:
+        x, (r, counts), second = rule(p.get("res2"), x, feed, real, cfg)
+        defect = second if defect is None else jnp.maximum(defect, second)
+    return x, r, counts, defect, report
 
 
 def _stacked(p) -> int | None:
     """How many alike layers the tree ``p`` carries on every leaf's
-    leading axis; None for one layer's tree."""
-    return p["norm1"].shape[0] if p["norm1"].ndim == 2 else None
+    leading axis; None for one layer's tree (which holds ``norm1`` where
+    it has a mixer and ``norm2`` where it has a feed-forward part)."""
+    norm = p["norm1"] if "norm1" in p else p["norm2"]
+    return norm.shape[0] if norm.ndim == 2 else None
 
 
 def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
@@ -1701,7 +1818,9 @@ def register() -> None:
             "experts_routed_over": cfg.routed,
             "router": cfg.router,
             "residual": cfg.residual,
-            "layers": [list(kind) for kind in cfg.layers],
+            "layers": [[half for half in kind if half is not None]
+                       for kind in cfg.layers],
+            "expert_body": cfg.expert_body,
             "kinds": {name: dataclasses.asdict(settings) for name, settings
                       in (*cfg.mixers, (cfg.router, cfg.routing),
                           (cfg.residual, cfg.residual_settings))

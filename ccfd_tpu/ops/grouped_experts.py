@@ -1,9 +1,11 @@
-"""Pallas TPU kernels: the held experts' SwiGLU as two grouped matmuls.
+"""Pallas TPU kernels: the held experts' body as two grouped matmuls.
 
+An expert's body is the model's: SwiGLU of three matrices (``gate``, ``up``,
+``down``), or relu squared between two (``up``, ``down``) with no gate.
 ``models/hybrid_moe.py::held_experts`` sorts a layer's (token, held expert)
 pairs by expert and lays each expert's group out in row tiles, the last one
 part empty, so a tile belongs to one expert. The plain path multiplies one
-tile a trip of a ``fori_loop``: it cuts the expert's three matrices out of
+tile a trip of a ``fori_loop``: it cuts the expert's matrices out of
 the stacked ``(held, hidden, width)`` arrays and reads them again for the
 expert's next tile, and the read waits for the products. Here the stacked
 arrays are addressed in place:
@@ -31,10 +33,11 @@ arrays are addressed in place:
   prefetched scalar a tile): the rest of the tile is left unwritten, and
   nobody reads it;
 - two kernels: ``expert_up`` = silu(x gate) * (x up), both products and the
-  gate in float32, written in the compute dtype; ``expert_down`` = h down,
-  float32 accumulation, written in the compute dtype: what ``_swiglu`` and
-  the ``astype`` after it do on the plain path, in the same precision. What
-  differs is the order of the MXU's partial sums.
+  gate in float32, or relu(x up)^2 of the one product, written in the
+  compute dtype; ``expert_down`` = h down, float32 accumulation, written in
+  the compute dtype: what ``_swiglu`` / ``_relu2`` and the ``astype`` after
+  it do on the plain path, in the same precision. What differs is the order
+  of the MXU's partial sums.
 
 :func:`kernel_fits` is the selection ``held_experts`` makes while the
 program is traced, from shapes, dtype, backend and where the weights lie
@@ -98,21 +101,23 @@ def block_for(contract: int, width: int, operands: int,
     return None
 
 
-def kernel_fits(gate, dtype) -> bool:
+def kernel_fits(up, dtype, operands: int = 2) -> bool:
     """Whether ``held_experts`` runs the kernels on experts whose stacked
-    ``gate`` is this array (held, hidden, width): widths that fill whole
-    lane tiles and whose blocks fit, and what ``ops/kernels.py`` asks of
-    every family: a dtype the kernels serve, one device (the operand lies
-    on no mesh) and a backend that runs them."""
-    if len(gate.shape) != 3:
+    ``up`` is this array (held, hidden, width), ``operands`` such matrices
+    side by side in the first product (SwiGLU's gate and up: 2; relu
+    squared's up: 1): widths that fill whole lane tiles and whose blocks
+    fit, and what ``ops/kernels.py`` asks of every family: a dtype the
+    kernels serve, one device (the operand lies on no mesh) and a backend
+    that runs them."""
+    if len(up.shape) != 3:
         return False
-    _, hidden, width = gate.shape
-    itemsize = _itemsize(gate, dtype)
+    _, hidden, width = up.shape
+    itemsize = _itemsize(up, dtype)
     return (
         kernels.serves(dtype)
-        and block_for(hidden, width, 2, itemsize) is not None
+        and block_for(hidden, width, operands, itemsize) is not None
         and block_for(width, hidden, 1, itemsize) is not None
-        and kernels.off_mesh(gate)
+        and kernels.off_mesh(up)
         and kernels.backend_runs_pallas()
     )
 
@@ -125,9 +130,12 @@ def _itemsize(w, dtype) -> int:
 
 
 # ccfd-lint: hot-path
-def _kernel(*refs, operands: int, block: int, passes: int, placed: bool):
+def _kernel(*refs, operands: int, block: int, passes: int, placed: bool,
+            relu2: bool = False):
     """A grid step (column block j, row tile i): the tile times its
-    expert's ``operands`` column blocks. ``refs``: the prefetched scalars
+    expert's ``operands`` column blocks (two: silu of the first times the
+    second; one: the bare product, or with ``relu2`` its relu squared).
+    ``refs``: the prefetched scalars
     (each tile's expert, each tile's run, the first tile of the run after
     it or -1, the count of runs, each tile's live rows[, the tile the
     output starts at]), the row tile, the stacked matrices whole in HBM[, the buffer the output
@@ -181,6 +189,8 @@ def _kernel(*refs, operands: int, block: int, passes: int, placed: bool):
                  for n in range(operands)]
         if operands == 2:  # gate and up
             parts = [jax.nn.silu(parts[0]) * parts[1]]
+        elif relu2:  # up alone, no gate
+            parts = [jnp.square(jax.nn.relu(parts[0]))]
         o_ref[lo:lo + rows, :] = parts[0].astype(o_ref.dtype)
 
     tile = x_ref.shape[0]
@@ -212,13 +222,15 @@ def _runs(expert_of, visit):
 
 # ccfd-lint: hot-path
 def _product(x, weights, expert_of, runs, live_of, visit, dtype, *,
-             tile: int, name: str, into=None, first=None):
+             tile: int, name: str, into=None, first=None,
+             relu2: bool = False):
     """``x`` (tiles x tile, contract) against the stacked ``weights`` (each
     (held, contract, width)), tile i by expert ``expert_of[i]``, the first
     ``visit`` tiles (``runs``: :func:`_runs` of them) and of each its
     first ``live_of[i]`` rows, rounded up to ``SUB_ROWS``: (tiles x tile,
     width) in ``dtype``, the other rows unwritten; one matrix gives the
-    product, two give silu(x w0) * (x w1). With ``into`` (more rows, width) and ``first``
+    product (with ``relu2`` its relu squared), two give silu(x w0) * (x
+    w1). With ``into`` (more rows, width) and ``first``
     the tiles are written there from tile ``first`` on, in place, and
     ``into`` comes back."""
     from jax.experimental import pallas as pl
@@ -238,7 +250,7 @@ def _product(x, weights, expert_of, runs, live_of, visit, dtype, *,
     operands = (x, *weights) + ((into,) if placed else ())
     return pl.pallas_call(
         partial(_kernel, operands=len(weights), block=block,
-                passes=width // block, placed=placed),
+                passes=width // block, placed=placed, relu2=relu2),
         out_shape=jax.ShapeDtypeStruct(into.shape if placed
                                        else (rows, width), dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -294,8 +306,31 @@ def grouped_swiglu(x: jax.Array, gate: jax.Array, up: jax.Array,
                     tile=tile, name=DOWN, into=into, first=first)
 
 
+@partial(jax.jit, static_argnames=("tile",))
+# ccfd-lint: hot-path
+def grouped_relu2(x: jax.Array, up: jax.Array, down: jax.Array,
+                  expert_of: jax.Array, live_of: jax.Array,
+                  visit: jax.Array, into: jax.Array, first: jax.Array,
+                  tile: int) -> jax.Array:
+    """:func:`grouped_swiglu` for experts of two matrices and no gate:
+    relu(x ``up``)^2 ``down`` of each tile's rows by its expert, written
+    into ``into`` from its tile ``first`` on; the same tiles, scalars and
+    kernels (``expert_up`` with one operand squares its rectified
+    product)."""
+    runs = _runs(expert_of, visit)
+    h = _product(x, (up,), expert_of, runs, live_of, visit, x.dtype,
+                 tile=tile, name=UP, relu2=True)
+    return _product(h, (down,), expert_of, runs, live_of, visit, into.dtype,
+                    tile=tile, name=DOWN, into=into, first=first)
+
+
+# ``models/hybrid_moe.py::EXPERT_BODIES``' names -> the grouped form; each
+# takes the body's stacked matrices in that table's order
+GROUPED = {"swiglu": grouped_swiglu, "relu2": grouped_relu2}
+
+
 def uninitialised(shape: tuple, dtype) -> jax.Array:
-    """A buffer nobody has written: what ``grouped_swiglu`` writes the
+    """A buffer nobody has written: what the grouped bodies write the
     tiles into. ``held_experts`` reads only rows a kernel wrote, so the
     574 MB of zeros a layer of Mistral-Small-4 would start from are 0.7 ms
     of writes for nothing. (XLA has no such allocation; a kernel that

@@ -518,6 +518,70 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
                     want * keep, 2e-2),
                 "log_decay_min": float(low)}
 
+    # the family's sixth model (layers of one sublayer each by the pattern
+    # MEMEM*E: a Mamba-2 mixer of two groups of B and C with the gated norm
+    # inside each of two groups, grouped-query attention 16 wide where
+    # hidden / heads is 8, top 3 of 8 experts by sigmoid score and choice
+    # bias of which 4 are held, experts of two matrices with relu squared
+    # stored 32 wide where 24 are published, an untied head): the same
+    # seam, three expert layers of seven, every chosen pair served here or
+    # the other chip's, the body and the norm's groups in the grid
+    from benchmark.reference import ssm_relu2_moe_f32
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "nemotron3n_small_config.json")) as f:
+        small = json.load(f)
+    n_cfg = hybrid_moe.HybridConfig.from_dict(small)
+    n_params = ssm_relu2_moe_f32.make_params(small)
+    s = SeqScorer(n_params, length=8, batch_sizes=(16,), family="hybrid_moe",
+                  family_config=n_cfg, max_customers=64)
+    s.warmup()
+    direct, aux = hybrid_moe.apply_serving(
+        n_params, hist, np.ones(16, np.int32), n_cfg, jnp.bfloat16)
+    grid = s.executable_grid()
+    check("hybrid_moe (nemotron_h) served + absent pairs = 3 a token and "
+          "expert layer over 3 of 7 layers, relu2 and 2 norm groups in the "
+          "grid, decays below 0",
+          int(aux["pairs_served"]) + int(aux["pairs_absent"])
+          == 3 * int(aux["routed_tokens"]) * n_cfg.moe_layers
+          and n_cfg.moe_layers == 3 and grid["expert_body"] == "relu2"
+          and grid["kinds"]["mamba2"]["norm_groups"] == 2
+          and float(aux["ssm_log_decay_min"]) < 0)
+    zoo["hybrid_moe.nemotron_h"] = {
+        "max_abs_diff": check.close(
+            "hybrid_moe (nemotron_h) B=16 L=8", s.score(
+                rows, list(range(10_000, 10_016))), np.asarray(direct), 1e-6),
+        "grid": grid}
+
+    # its Mamba-2 mixer at widths that fill lane tiles (hidden 256, 16
+    # heads of 64 in 2 groups of B and C, a state of 128, the gated norm
+    # inside each of 2 groups of 512; 768 tokens, one row padded on the
+    # left past the first chunk): Mosaic compiles the scan's kernel for
+    # more than one group, and the device's answer is the recurrence's a
+    # token at a time of the plain reference, both in float32
+    wide = dict(small, hidden_size=256, mamba_num_heads=16, mamba_head_dim=64,
+                ssm_state_size=128, n_groups=2, layers_kept=[0],
+                scan_chunk=384)
+    w_cfg = hybrid_moe.HybridConfig.from_dict(wide)
+    wp = jax.jit(lambda: ssm_relu2_moe_f32.make_params(wide)["layers"][0][
+        "mixer"])()
+    rng = np.random.default_rng(49)
+    z = jnp.asarray(rng.normal(size=(2, 768, 256)), jnp.float32)
+    real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [200]]))
+    keep = np.asarray(real)[..., None]
+    mixer = jax.jit(lambda p, z: hybrid_moe.mamba2(p, z, real, w_cfg,
+                                                   jnp.float32))
+    check("hybrid_moe mamba2 in 2 groups of B and C scans through the kernel",
+          kernels.held_by(mixer, wp, z, names=(ssd_scan.KERNEL,)))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ssm_relu2_moe_f32.mamba(wp, z, real, wide))
+        got, low = mixer(wp, z)
+    zoo["hybrid_moe.mamba2.grouped"] = {
+        "max_abs_diff": check.close(
+            "hybrid_moe mamba2 in 2 groups, norm inside each: chunked scan "
+            "vs recurrence", np.asarray(got) * keep, want * keep, 2e-2),
+        "log_decay_min": float(low)}
+
     # the KDA mixer at heads a lane tile wide, which the first preset's
     # 16-wide heads are not (2 heads of 128, 768 tokens = six spans of the
     # kernel, one row padded on the left past the first chunks): Mosaic
@@ -634,6 +698,35 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     zoo["grouped_experts"] = {"max_abs_diff": check.close(
         "hybrid_moe held experts: kernels vs tile loop", np.asarray(y),
         np.asarray(want), 0.04)}
+
+    # the same layer with the other expert body (relu squared between two
+    # matrices, no gate), the stack stored 384 wide where 320 are
+    # published: the kernels over the stored stack against the tile loop
+    # over the published one
+    r_cfg = dataclasses.replace(e_cfg, expert_body="relu2")
+    published = {"up": ex["up"][..., :320], "down": ex["down"][:, :320]}
+    stored = {"up": jnp.pad(published["up"], ((0, 0), (0, 0), (0, 64))),
+              "down": jnp.pad(published["down"], ((0, 0), (0, 64), (0, 0)))}
+
+    def relu2_experts(ex, tokens, chosen, weight):
+        return hybrid_moe.held_experts(ex, tokens, chosen, weight, r_cfg,
+                                       jnp.bfloat16)
+
+    check("hybrid_moe relu2 experts: the stored stack holds the kernels, "
+          "the published one the loop",
+          kernels.kernels_of(relu2_experts, stored, tokens, chosen, weight)
+          == frozenset(grouped_experts.KERNELS)
+          and not kernels.kernels_of(relu2_experts, published, tokens,
+                                     chosen, weight))
+    y, pairs, served = jax.jit(relu2_experts)(stored, tokens, chosen, weight)
+    want, want_pairs, want_served = jax.jit(relu2_experts)(
+        published, tokens, chosen, weight)
+    check("hybrid_moe relu2 experts: pairs and served as the loop counts "
+          "them", np.array_equal(pairs, want_pairs)
+          and int(served) == int(want_served) == int(pairs.sum()))
+    zoo["grouped_experts.relu2"] = {"max_abs_diff": check.close(
+        "hybrid_moe relu2 experts: kernels over the padded stack vs tile "
+        "loop over the published", np.asarray(y), np.asarray(want), 0.04)}
 
     # the fused-decision grid over the flagship: score + threshold + rules
     # in one executable per bucket, against the staged seam

@@ -288,3 +288,29 @@ def test_mosaic_compiles_the_kernels_at_a_dispatch_of_the_real_models(
         shape(hm.MOE_CHUNK // tile, dtype=jnp.int32), shape(dtype=jnp.int32),
         shape(4 * hm.MOE_CHUNK, hidden), shape(dtype=jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_mosaic_compiles_the_relu2_kernels_at_a_dispatch_of_the_sixth_model(
+        one_chip, as_on_the_chip):
+    """Experts of two matrices, 2,688 x 1,856 stored at 1,920 columns /
+    rows (15 lane tiles, three blocks of 640 up and of 896 down), 64 held,
+    tiles of 256 rows: the one-operand kernel with its relu squared."""
+    held, hidden, width, routed, k = 64, 2688, 1920, 128, 6
+    tile = ge.row_tile(TOKENS * k / routed)
+    assert tile == 256 and ge.kernel_fits(
+        jax.ShapeDtypeStruct((held, hidden, width), BF16), BF16, 1)
+
+    def shape(*dims, dtype=BF16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda x, up, down, expert_of, live_of, visit, into, first:
+        ge.grouped_relu2.__wrapped__(
+            x, up, down, expert_of, live_of, visit, into, first,
+            tile=tile)).lower(
+        shape(hm.MOE_CHUNK, hidden), shape(held, hidden, width),
+        shape(held, width, hidden),
+        shape(hm.MOE_CHUNK // tile, dtype=jnp.int32),
+        shape(hm.MOE_CHUNK // tile, dtype=jnp.int32), shape(dtype=jnp.int32),
+        shape(4 * hm.MOE_CHUNK, hidden), shape(dtype=jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2

@@ -320,8 +320,8 @@ def test_a_program_that_drops_a_term_fails(small, params, cfg, rows,
         tree = _zeroed(params, ZEROED[omission])
     elif omission == "gate_order":  # the norm first, the gate after it
         monkeypatch.setattr(
-            hm, "_gated_norm", lambda y, gate, weight, eps: hm._rms(
-                y, weight, eps) * jax.nn.silu(gate))
+            hm, "_gated_norm", lambda y, gate, weight, eps, groups=1:
+            hm._rms(y, weight, eps) * jax.nn.silu(gate))
     elif omission == "embedding_multiplier":
         settings = dataclasses.replace(cfg, embed_scale=1.0)
     elif omission == "residual_multiplier":

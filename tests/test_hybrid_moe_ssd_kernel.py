@@ -347,6 +347,25 @@ def test_mosaic_compiles_the_kernel_at_a_dispatch_of_the_real_model(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_mosaic_compiles_the_kernel_at_eight_groups_of_b_and_c(
+        one_chip, as_on_the_chip):
+    """The second model's dispatch (PR 49): 8 windows of 1,920 tokens, 64
+    heads of 64 in 8 groups of B and C (a group's 8 heads are two steps of
+    two lane tiles), chunks of 640: the scores and the split pieces are a
+    group's, computed at its first step."""
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, F32, sharding=one_chip)
+
+    b, t, h, p, g, n = 8, 1920, 64, 64, 8, 128
+    assert ss.kernel_fits(shape(b, t, h, p), shape(b, t, g, n), 640)
+    compiled = jax.jit(
+        lambda x, bm, cm, dt, a, d: ss.ssd_scan.__wrapped__(
+            x, bm, cm, dt, a, d, chunk=640)).lower(
+        shape(b, t, h, p), shape(b, t, g, n), shape(b, t, g, n),
+        shape(b, t, h), shape(b, t, h), shape(h)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 # -- the whole models -------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
