@@ -127,7 +127,7 @@ import jax
 import jax.numpy as jnp
 
 from ccfd_tpu.ops import (causal_attention, grouped_experts, kda_scan,
-                          ssd_scan)
+                          short_conv, ssd_scan)
 
 Params = Mapping[str, Any]
 
@@ -1155,6 +1155,30 @@ def _gated_norm(y, gate, weight, eps, groups: int = 1):
     return _rms(apart, weight.reshape(groups, -1), eps).reshape(v.shape)
 
 
+def _conv_silu(proj, taps, bias, keep, at: int, widths: tuple):
+    """SiLU(causal depthwise convolution + bias) of ``proj``'s (B, T, W)
+    columns from ``at``, masked by ``keep`` (B, T, 1) first, cut into
+    ``widths``: float32 arrays (B, T, width). Two paths, one convolution
+    at one precision, chosen while the program is traced
+    (``ops/short_conv.py::kernel_fits`` says where): the Pallas kernel,
+    which reads the columns where they lie and writes each width
+    token-major, as ``ssd_scan`` takes them, or :func:`_short_conv`
+    through XLA on (B, T, tiles, 128) (a shift by a token then moves whole
+    tiles), the definition the tests hold the kernel against."""
+    if short_conv.kernel_fits(proj, taps, at, widths):
+        return short_conv.short_conv(proj, taps, bias, keep, at=at,
+                                     widths=widths)
+    b, t, _ = proj.shape
+    k, wide = taps.shape
+    lane = 128 if wide % 128 == 0 else wide
+    out = jax.nn.silu(_short_conv(
+        (proj[..., at:at + wide] * keep).reshape(b, t, -1, lane),
+        taps.reshape(k, -1, lane)) + bias.reshape(-1, lane)).reshape(
+        b, t, wide)
+    ends = [sum(widths[:i]) for i in range(1, len(widths))]
+    return tuple(jnp.split(out, ends, axis=-1))
+
+
 def mamba2(p, z, real, cfg: HybridConfig, dtype):
     """(B, T, hidden) normed input -> ``((B, T, hidden) mixer output, the
     most negative running log-decay inside a chunk)``: one projection to
@@ -1162,8 +1186,7 @@ def mamba2(p, z, real, cfg: HybridConfig, dtype):
     and SiLU over x, B and C together, the chunked scan (:func:`_ssd`) in
     float32, the skip D x, the gate and after it the RMS norm over the
     inner values (all together, or inside each of the settings'
-    ``norm_groups``), the output projection. The convolution runs on (B,
-    T, tiles, 128): a shift by a token then moves whole tiles."""
+    ``norm_groups``), the output projection."""
     b, t, _ = z.shape
     s = cfg.mixer("mamba2")
     h, hd, n, g = s.heads, s.head_dim, s.state, s.groups
@@ -1172,21 +1195,16 @@ def mamba2(p, z, real, cfg: HybridConfig, dtype):
     keep = real[:, :, None].astype(F32)
     with jax.named_scope("mamba.project"):
         proj = _mm(z, p["w_in"], dtype)
-        gate, xbc = proj[..., :inner], proj[..., inner:inner + wide]
-        dt = proj[..., inner + wide:]
+        gate, dt = proj[..., :inner], proj[..., inner + wide:]
     with jax.named_scope("mamba.conv"):
-        lane = 128 if wide % 128 == 0 else wide
-        xbc = jax.nn.silu(_short_conv(
-            (xbc * keep).reshape(b, t, -1, lane),
-            p["conv"].reshape(s.conv, -1, lane))
-            + p["conv_b"].reshape(-1, lane)).reshape(b, t, wide)
+        x, bm, cm = _conv_silu(proj, p["conv"], p["conv_b"], keep, inner,
+                               (inner, g * n, g * n))
     with jax.named_scope("mamba.scan"):
-        x = xbc[..., :inner].reshape(b, t, h, hd)
         dt = jax.nn.softplus(dt + p["dt_bias"]) * keep
         y, low = _state_scan(
-            x, xbc[..., inner:inner + g * n].reshape(b, t, g, n),
-            xbc[..., inner + g * n:].reshape(b, t, g, n), dt,
-            -jnp.exp(p["a_log"]) * dt, p["d"], s.chunk_for(t))
+            x.reshape(b, t, h, hd), bm.reshape(b, t, g, n),
+            cm.reshape(b, t, g, n), dt, -jnp.exp(p["a_log"]) * dt, p["d"],
+            s.chunk_for(t))
     with jax.named_scope("mamba.gate"):
         y = _gated_norm(y.reshape(b, t, inner), gate, p["norm"], cfg.eps,
                         s.norm_groups)

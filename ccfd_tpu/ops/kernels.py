@@ -58,6 +58,10 @@ FAMILIES = (
            "and the heads' states on the chip",
            "looped over chunks through XLA, or have no such mixer",
            ("kda_scan",)),
+    Family("conv_kernel", "seq_conv_kernel_dispatch_total",
+           "state-space mixers convolve through the token-major kernel that "
+           "hands the scan kernel x, B and C as it takes them",
+           "convolved through XLA, or have no such mixer", ("short_conv",)),
 )
 
 

@@ -486,10 +486,12 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     # the Mamba-2 mixer at widths that fill lane tiles, which that preset's
     # 16-wide heads do not (hidden 256, 8 heads of 64, a state of 128, 768
     # tokens, one row padded on the left past the first chunk): Mosaic
-    # compiles the scan's kernel (ops/ssd_scan.py) at chunks of 128 and 384,
-    # and the device's answer is the recurrence's a token at a time of the
-    # plain reference, both in float32 on the device
-    from ccfd_tpu.ops import kernels, ssd_scan
+    # compiles the scan's kernel (ops/ssd_scan.py) at chunks of 128 and 384
+    # and before it the convolution's (ops/short_conv.py: 768 columns from
+    # column 512, 24 strips of 32 tokens), and the device's answer is the
+    # convolution and the recurrence a token at a time of the plain
+    # reference, both in float32 on the device
+    from ccfd_tpu.ops import kernels, short_conv, ssd_scan
 
     wide = dict(small, hidden_size=256, mamba_n_heads=8, mamba_d_head=64,
                 mamba_d_state=128, mamba_n_groups=1, layers_kept=[0],
@@ -508,8 +510,9 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             mixer = jax.jit(lambda p, z: hybrid_moe.mamba2(
                 p, z, real, w_cfg, jnp.float32))
             check(f"hybrid_moe mamba2 at lane-wide heads, chunk {chunk}, "
-                  "scans through the kernel", kernels.held_by(
-                      mixer, wp, z, names=(ssd_scan.KERNEL,)))
+                  "convolves and scans through the kernels",
+                  kernels.kernels_of(mixer, wp, z) == {
+                      short_conv.KERNEL, ssd_scan.KERNEL})
             got, low = mixer(wp, z)
             zoo[f"hybrid_moe.mamba2.chunk{chunk}"] = {
                 "max_abs_diff": check.close(
@@ -557,8 +560,9 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     # heads of 64 in 2 groups of B and C, a state of 128, the gated norm
     # inside each of 2 groups of 512; 768 tokens, one row padded on the
     # left past the first chunk): Mosaic compiles the scan's kernel for
-    # more than one group, and the device's answer is the recurrence's a
-    # token at a time of the plain reference, both in float32
+    # more than one group and the convolution's at B and C 256 wide, and the
+    # device's answer is the recurrence's a token at a time of the plain
+    # reference, both in float32
     wide = dict(small, hidden_size=256, mamba_num_heads=16, mamba_head_dim=64,
                 ssm_state_size=128, n_groups=2, layers_kept=[0],
                 scan_chunk=384)
@@ -571,8 +575,9 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     keep = np.asarray(real)[..., None]
     mixer = jax.jit(lambda p, z: hybrid_moe.mamba2(p, z, real, w_cfg,
                                                    jnp.float32))
-    check("hybrid_moe mamba2 in 2 groups of B and C scans through the kernel",
-          kernels.held_by(mixer, wp, z, names=(ssd_scan.KERNEL,)))
+    check("hybrid_moe mamba2 in 2 groups of B and C convolves and scans "
+          "through the kernels", kernels.kernels_of(mixer, wp, z) == {
+              short_conv.KERNEL, ssd_scan.KERNEL})
     with jax.default_matmul_precision("highest"):
         want = np.asarray(ssm_relu2_moe_f32.mamba(wp, z, real, wide))
         got, low = mixer(wp, z)

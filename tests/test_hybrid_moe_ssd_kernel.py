@@ -23,7 +23,7 @@ import pytest
 from benchmark.reference import (cca_moe_f32, hybrid_moe_f32, mhc_moe_f32,
                                  mla_moe_f32, ssm_moe_f32, table)
 from ccfd_tpu.models import hybrid_moe as hm
-from ccfd_tpu.ops import kernels
+from ccfd_tpu.ops import kernels, short_conv
 from ccfd_tpu.ops import ssd_scan as ss
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -389,7 +389,7 @@ def test_the_lane_wide_program_holds_the_kernel_and_the_small_one_does_not(
         wide):
     config, params, cfg = wide
     assert kernels.kernels_of(_program(cfg), params, *_window()) == {
-        ss.KERNEL}
+        ss.KERNEL, short_conv.KERNEL}  # the convolution before it too
     small = _small("granite4h")
     shapes = jax.eval_shape(lambda: ssm_moe_f32.make_params(small))
     assert not kernels.kernels_of(

@@ -1,5 +1,5 @@
 """The kernels' seam (ops/kernels.py): the table of families against the
-kernel modules, the names a dashboard reads, what the five ``kernel_fits``
+kernel modules, the names a dashboard reads, what the six ``kernel_fits``
 answer at the shapes the kernel tests carry, and that a row of the table is
 all a family's key and counter take to appear."""
 
@@ -19,7 +19,7 @@ from ccfd_tpu.analysis.rules import metric_name_ok
 from ccfd_tpu.metrics.prom import Registry
 from ccfd_tpu.models import seq
 from ccfd_tpu.ops import (causal_attention, grouped_experts, kda_scan, kernels,
-                          seq_attention, ssd_scan)
+                          seq_attention, short_conv, ssd_scan)
 from ccfd_tpu.serving import history
 
 BF16, F32, F16 = jnp.bfloat16, jnp.float32, jnp.float16
@@ -41,7 +41,7 @@ def test_every_module_that_names_a_kernel_has_a_row_and_every_row_a_module():
 
 def test_every_kernel_name_belongs_to_exactly_one_family():
     claimed = [name for family in kernels.FAMILIES for name in family.names]
-    assert len(claimed) == len(set(claimed)) == 7
+    assert len(claimed) == len(set(claimed)) == 8
     assert all(isinstance(name, str) and name for name in claimed)
     for family in kernels.FAMILIES:
         assert set(family.names) == {
@@ -50,7 +50,8 @@ def test_every_kernel_name_belongs_to_exactly_one_family():
 
 
 @pytest.mark.parametrize("module", [seq_attention, causal_attention,
-                                    grouped_experts, ssd_scan, kda_scan],
+                                    grouped_experts, ssd_scan, kda_scan,
+                                    short_conv],
                          ids=lambda m: m.__name__.rsplit(".", 1)[1])
 def test_a_module_hands_pallas_call_no_name_but_those_it_declares(module):
     """The ``name=`` keywords of the module's calls, read from its source:
@@ -76,10 +77,11 @@ def test_the_names_on_the_wire_are_the_ones_dashboards_read():
         ("expert_kernel", "seq_expert_kernel_dispatch_total",
          ("expert_up", "expert_down", "expert_rows")),
         ("ssd_kernel", "seq_ssd_kernel_dispatch_total", ("ssd_scan",)),
-        ("kda_kernel", "seq_kda_kernel_dispatch_total", ("kda_scan",))]
+        ("kda_kernel", "seq_kda_kernel_dispatch_total", ("kda_scan",)),
+        ("conv_kernel", "seq_conv_kernel_dispatch_total", ("short_conv",))]
     assert kernels.held(lambda x: x, 1.0) == {
         "attn_kernel": 0, "expert_kernel": 0, "ssd_kernel": 0,
-        "kda_kernel": 0}
+        "kda_kernel": 0, "conv_kernel": 0}
     assert kernels.FAMILIES[0].help == (
         "seq dispatches of executables whose attention holds a kernel that "
         "keeps the scores on the chip (beside seq_bucket_dispatch_total: "
@@ -95,7 +97,7 @@ def test_a_familys_counter_keeps_the_naming_rule(family):
     assert family.help.count("seq_bucket_dispatch_total") == 1
 
 
-# -- what the five kernel_fits answer ----------------------------------------------------
+# -- what the six kernel_fits answer ----------------------------------------------------
 
 def _shape(dims, dtype, mesh=None):
     from jax.sharding import NamedSharding, PartitionSpec
@@ -128,6 +130,13 @@ def _kda(dims, dtype, mesh):
                                 64, 16)
 
 
+def _conv(dims, dtype, mesh):
+    proj, at, widths = dims
+    return short_conv.kernel_fits(
+        _shape(proj, dtype, mesh), _shape((4, sum(widths)), jnp.float32), at,
+        widths)
+
+
 # (family, which shape, its dimensions, whether the parent's ``kernel_fits``
 # took it in bfloat16 and float32 on one device): written out from the
 # parent's code before ``ops/kernels.py`` took over the common part
@@ -151,8 +160,14 @@ FITS = [
     (_kda, "served", (8, 1920, 32, 128), True),
     (_kda, "lane_wide", (2, 240, 2, 128), True),
     (_kda, "small", (3, 240, 4, 16), False),
+    # new with its row (PR 50): by the module's own rules, no parent to copy
+    (_conv, "served", ((4, 1920, 16768), 8192, (8192, 128, 128)), True),
+    (_conv, "served_8_groups", ((8, 1920, 10304), 4096, (4096, 1024, 1024)),
+     True),
+    (_conv, "lane_wide", ((2, 240, 1288), 512, (512, 128, 128)), True),
+    (_conv, "small", ((3, 240, 328), 128, (128, 32, 32)), False),
 ]
-ASKS_ABOUT_A_MESH = (_experts, _ssd, _kda)
+ASKS_ABOUT_A_MESH = (_experts, _ssd, _kda, _conv)
 
 
 @pytest.fixture(scope="module")
@@ -167,7 +182,7 @@ def mesh():
 def test_kernel_fits_answers_as_the_parents_did(fits, which, dims, taken,
                                                 mesh):
     """By dtype, with an operand on a mesh and under an abstract mesh: the
-    grouped experts and the two scans refuse either mesh; the two
+    grouped experts, the two scans and the convolution refuse either mesh; the two
     attentions never ask (``SeqScorer`` hands each device its rows under
     ``shard_map`` itself) and answer under an abstract mesh as without."""
     meshed = fits in ASKS_ABOUT_A_MESH
@@ -197,11 +212,11 @@ def test_a_backend_without_pallas_takes_no_kernel(monkeypatch):
 
 # -- a row is all it takes -----------------------------------------------------------------
 
-def test_a_fifth_row_brings_its_key_and_its_counter_with_no_other_edit(
+def test_a_further_row_brings_its_key_and_its_counter_with_no_other_edit(
         monkeypatch):
-    """A table of the test's own, with a fifth family that counts ``seq``'s
+    """A table of the test's own, with one more family that counts ``seq``'s
     kernel alone: the inventory, the ``seq.enqueue`` phase and the registry
-    carry it beside the four, and ``serving/history.py`` was not told."""
+    carry it beside the five, and ``serving/history.py`` was not told."""
     fifth = kernels.Family(
         "own_kernel", "seq_own_kernel_dispatch_total", "attention is seq's",
         "have another", ("seq_attention",))
@@ -220,10 +235,10 @@ def test_a_fifth_row_brings_its_key_and_its_counter_with_no_other_edit(
                                batch_sizes=(4,), registry=reg)
     scorer.score(np.zeros((3, 30), np.float32), ids=["a", "b", "c"])
     want = {"attn_kernel": 1, "expert_kernel": 0, "ssd_kernel": 0,
-            "kda_kernel": 0, "own_kernel": 1}
+            "kda_kernel": 0, "conv_kernel": 0, "own_kernel": 1}
     (stats,) = enqueued
     assert {key: stats[key] for key in want} == want
-    assert list(stats)[list(stats).index("tokens") + 1:][:6] == [
+    assert list(stats)[list(stats).index("tokens") + 1:][:len(want) + 1] == [
         *want, "flat_wire"]  # where they stood, in the table's order
     (entry,) = scorer.executable_grid()["grid"]
     assert {key: entry[key] for key in want} == {
@@ -232,7 +247,7 @@ def test_a_fifth_row_brings_its_key_and_its_counter_with_no_other_edit(
     assert reg.counter("seq_attention_kernel_dispatch_total").total() == 1
     assert reg.counter("seq_expert_kernel_dispatch_total").total() == 0
     assert fifth.help in reg.render()
-    # a stand-in for the program holds none of the five
+    # a stand-in for the program holds none of the six
     real = scorer._apply
     scorer._apply = lambda p, xs: real(p, xs)
     (entry,) = scorer.executable_grid()["grid"]

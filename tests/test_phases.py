@@ -297,10 +297,12 @@ def test_the_enqueue_phase_says_which_kernels_the_program_holds(
     where hidden and expert widths fill lane tiles the program holds the
     grouped kernels (``ops/grouped_experts.py``), where the Mamba-2 heads
     fill lane tiles and the state is a lane tile wide the scan's kernel
-    (``ops/ssd_scan.py``), where a KDA head's keys and values are a lane
-    tile wide the delta rule's (``ops/kda_scan.py``), and the inventory,
-    the ``seq.enqueue`` phase and ``seq_expert_kernel_dispatch_total`` /
-    ``seq_ssd_kernel_dispatch_total`` / ``seq_kda_kernel_dispatch_total``
+    (``ops/ssd_scan.py``) and, its x, B and C then whole lane tiles too, the
+    convolution's before it (``ops/short_conv.py``), where a KDA head's keys
+    and values are a lane tile wide the delta rule's (``ops/kda_scan.py``),
+    and the inventory, the ``seq.enqueue`` phase and
+    ``seq_expert_kernel_dispatch_total`` / ``seq_ssd_kernel_dispatch_total``
+    / ``seq_conv_kernel_dispatch_total`` / ``seq_kda_kernel_dispatch_total``
     say so of every dispatch, once a dispatch, as ``attn_kernel``'s signs
     do of the attention; the chunk stays the configuration's."""
     import json
@@ -328,19 +330,22 @@ def test_the_enqueue_phase_says_which_kernels_the_program_holds(
             scorer.score(rows(4, seed=lo), ids=["a", "b", "a", "c"])
     enqueues = cap.named("seq.enqueue")
     chunk = config.get("scan_chunk")  # none where no layer scans
-    assert [(e[3]["expert_kernel"], e[3]["ssd_kernel"], e[3]["kda_kernel"],
-             e[3].get("scan_chunk")) for e in enqueues] == [
-        (int(experts), int(scan), int(delta), chunk)] * 2
+    assert [(e[3]["expert_kernel"], e[3]["ssd_kernel"], e[3]["conv_kernel"],
+             e[3]["kda_kernel"], e[3].get("scan_chunk"))
+            for e in enqueues] == [
+        (int(experts), int(scan), int(scan), int(delta), chunk)] * 2
     assert [e[3]["attn_kernel"] for e in enqueues] == [0, 0]  # heads of 16
     assert [(g["b_bucket"], g["expert_kernel"], g["ssd_kernel"],
-             g["kda_kernel"], g["attn_kernel"], g.get("scan_chunk"),
-             g["dispatches"])
+             g["conv_kernel"], g["kda_kernel"], g["attn_kernel"],
+             g.get("scan_chunk"), g["dispatches"])
             for g in scorer.executable_grid()["grid"]] == [
-        (4, experts, scan, delta, False, chunk, 2)]
+        (4, experts, scan, scan, delta, False, chunk, 2)]
     assert reg.counter("seq_bucket_dispatch_total").total() == 2
     assert reg.counter("seq_expert_kernel_dispatch_total").total() == (
         2 if experts else 0)
     assert reg.counter("seq_ssd_kernel_dispatch_total").total() == (
+        2 if scan else 0)
+    assert reg.counter("seq_conv_kernel_dispatch_total").total() == (
         2 if scan else 0)
     assert reg.counter("seq_kda_kernel_dispatch_total").total() == (
         2 if delta else 0)
