@@ -126,8 +126,8 @@ from typing import Any, Mapping
 import jax
 import jax.numpy as jnp
 
-from ccfd_tpu.ops import (causal_attention, grouped_experts, kda_scan,
-                          short_conv, ssd_scan)
+from ccfd_tpu.ops import (causal_attention, cca_conv, grouped_experts,
+                          kda_scan, short_conv, ssd_scan)
 
 Params = Mapping[str, Any]
 
@@ -1015,12 +1015,12 @@ def _causal_taps(u, keep, taps, tap):
     return out
 
 
-def cca(p, z, real, position, cfg: HybridConfig, dtype):
-    """(B, T, hidden) normed input -> (B, T, hidden) mixer output. As in
-    ``kda`` the projections are kept as (B, T, heads, D), so that a shift
-    by one token moves whole tiles."""
+def _cca_latent(p, z, real, position, s: Cca, dtype):
+    """``cca``'s q (B, T, G, per, D), k and v (B, T, G, D) in ``dtype``
+    through XLA: the plain chain, the only path for every shape
+    ``ops/cca_conv.py::kernel_fits`` refuses and the definition the tests
+    hold the kernel against."""
     b, t, _ = z.shape
-    s = cfg.mixer("cca")
     h, g, hd = s.heads, s.kv_heads, s.head_dim
     per, rot = h // g, s.rotary_dim
     freq = _frequencies(s.theta, rot)
@@ -1032,46 +1032,83 @@ def cca(p, z, real, position, cfg: HybridConfig, dtype):
                           w.astype(dtype).reshape(w.shape[0], -1, hd),
                           preferred_element_type=F32)
 
+    q_lat, k_lat, v = heads(p["wq"]), heads(p["wk"]), heads(p["wv"])
+    # the later half of the value heads read the previous token: no
+    # bias, so the shifted product is the product of the shifted input
+    now = g - g // 2
+    v = jnp.concatenate([v[:, :, :now], _back(v[:, :, now:], keep)], 2)
+    u = jnp.concatenate([q_lat, k_lat], 2)  # (B, T, H + G, D)
+    c0 = _causal_taps(
+        u, keep, p["conv0"].reshape(-1, h + g, hd),
+        lambda x, w: x * w) + p["conv0_b"].reshape(h + g, hd)
+
+    def per_head(x, w):  # heads as the leading batch axis of the product
+        x = jnp.moveaxis(x, 2, 0).reshape(h + g, b * t, hd)
+        return jnp.moveaxis(jnp.einsum(
+            "hnd,hde->hne", x, w, preferred_element_type=F32).reshape(
+                h + g, b, t, hd), 0, 2)
+
+    c1 = _causal_taps(
+        c0.astype(dtype), keep.astype(dtype), p["conv1"].astype(dtype),
+        per_head) + p["conv1_b"].reshape(h + g, hd)
+    q_heads = q_lat.reshape(b, t, g, per, hd)
+    q = c1[:, :, :h].reshape(b, t, g, per, hd) + (
+        q_heads + k_lat[:, :, :, None]) * 0.5
+    k = c1[:, :, h:] + (q_heads.mean(3) + k_lat) * 0.5
+
+    def unit(x):
+        return x * (math.sqrt(hd) * jax.lax.rsqrt(
+            jnp.sum(x * x, -1, keepdims=True) + L2_EPS))
+
+    def turned(x):  # (B, T, n, D): the leading ``rot`` dims rotated
+        return jnp.concatenate([_rotary(
+            x[..., :rot], position, freq), x[..., rot:]], -1)
+
+    q = turned(unit(q).reshape(b, t, h, hd)).reshape(
+        b, t, g, per, hd).astype(dtype)
+    k = turned(unit(k) * p["tau"][:, None]).astype(dtype)
+    return q, k, v.astype(dtype)
+
+
+def _cca_latent_kernel(p, z, real, position, s: Cca, dtype):
+    """:func:`_cca_latent` with everything after the three projections in
+    ``ops/cca_conv.py``'s kernel: the projections token-major as their
+    products leave them, the turn's cosines and sines by ``_rotary``'s own
+    lines, laid along a head's lanes as the kernel reads them."""
+    b, t, _ = z.shape
+    h, g, hd, rot = s.heads, s.kv_heads, s.head_dim, s.rotary_dim
+    angle = position.astype(F32)[..., None] * _frequencies(s.theta, rot)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    rest = (b, t, hd - rot)
+    q, k, v = cca_conv.cca_conv(
+        _mm(z, p["wq"], dtype), _mm(z, p["wk"], dtype), _mm(z, p["wv"], dtype),
+        real[:, :, None].astype(F32),
+        jnp.concatenate([cos, cos, jnp.ones(rest, F32)], -1),
+        jnp.concatenate([-sin, sin, jnp.zeros(rest, F32)], -1),
+        p["conv0"], p["conv0_b"], p["conv1"], p["conv1_b"], p["tau"],
+        rot=rot, dtype=jnp.dtype(dtype), eps=L2_EPS)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    return q.reshape(b, t, g, h // g, hd), k, v
+
+
+def cca(p, z, real, position, cfg: HybridConfig, dtype):
+    """(B, T, hidden) normed input -> (B, T, hidden) mixer output. Between
+    the three projections and the attention two paths, one chain at one
+    precision, chosen while the program is traced
+    (``ops/cca_conv.py::kernel_fits`` says where): the Pallas kernel on the
+    projections token-major, or :func:`_cca_latent` through XLA, which
+    keeps them as (B, T, heads, D) as ``kda`` does. The kernel has no
+    derivative; nothing differentiates this family (it is served only)."""
+    b, t, _ = z.shape
+    s = cfg.mixer("cca")
+    latent = (_cca_latent_kernel if cca_conv.kernel_fits(
+        z, p["wq"], p["wk"], p["conv0"], p["conv1"], dtype) else _cca_latent)
     with jax.named_scope("cca.conv"):
-        q_lat, k_lat, v = heads(p["wq"]), heads(p["wk"]), heads(p["wv"])
-        # the later half of the value heads read the previous token: no
-        # bias, so the shifted product is the product of the shifted input
-        now = g - g // 2
-        v = jnp.concatenate([v[:, :, :now], _back(v[:, :, now:], keep)], 2)
-        u = jnp.concatenate([q_lat, k_lat], 2)  # (B, T, H + G, D)
-        c0 = _causal_taps(
-            u, keep, p["conv0"].reshape(-1, h + g, hd),
-            lambda x, w: x * w) + p["conv0_b"].reshape(h + g, hd)
-
-        def per_head(x, w):  # heads as the leading batch axis of the product
-            x = jnp.moveaxis(x, 2, 0).reshape(h + g, b * t, hd)
-            return jnp.moveaxis(jnp.einsum(
-                "hnd,hde->hne", x, w, preferred_element_type=F32).reshape(
-                    h + g, b, t, hd), 0, 2)
-
-        c1 = _causal_taps(
-            c0.astype(dtype), keep.astype(dtype), p["conv1"].astype(dtype),
-            per_head) + p["conv1_b"].reshape(h + g, hd)
-        q_heads = q_lat.reshape(b, t, g, per, hd)
-        q = c1[:, :, :h].reshape(b, t, g, per, hd) + (
-            q_heads + k_lat[:, :, :, None]) * 0.5
-        k = c1[:, :, h:] + (q_heads.mean(3) + k_lat) * 0.5
-
-        def unit(x):
-            return x * (math.sqrt(hd) * jax.lax.rsqrt(
-                jnp.sum(x * x, -1, keepdims=True) + L2_EPS))
-
-        def turned(x):  # (B, T, n, D): the leading ``rot`` dims rotated
-            return jnp.concatenate([_rotary(
-                x[..., :rot], position, freq), x[..., rot:]], -1)
-
-        q = turned(unit(q).reshape(b, t, h, hd)).reshape(
-            b, t, g, per, hd).astype(dtype)
-        k = turned(unit(k) * p["tau"][:, None]).astype(dtype)
-        v = v.astype(dtype)
+        q, k, v = latent(p, z, real, position, s, dtype)
     with jax.named_scope("cca.attend"):
-        o = _causal_attention(q, k, v, real, 1.0 / math.sqrt(hd), dtype)
-    return _mm(o.reshape(b, t, h * hd), p["wo"], dtype)
+        o = _causal_attention(q, k, v, real, 1.0 / math.sqrt(s.head_dim),
+                              dtype)
+    return _mm(o.reshape(b, t, s.heads * s.head_dim), p["wo"], dtype)
 
 
 # -- Mamba-2 and plain grouped-query attention -------------------------------------

@@ -62,6 +62,11 @@ FAMILIES = (
            "state-space mixers convolve through the token-major kernel that "
            "hands the scan kernel x, B and C as it takes them",
            "convolved through XLA, or have no such mixer", ("short_conv",)),
+    Family("cca_kernel", "seq_cca_kernel_dispatch_total",
+           "CCA mixers run everything between their projections and the "
+           "attention in the token-major kernel that convolves the latent",
+           "walked that chain through XLA, or have no such mixer",
+           ("cca_conv",)),
 )
 
 

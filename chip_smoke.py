@@ -631,6 +631,42 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             want, 5e-2),
         "xla_vs_recurrence": float(np.abs(through_xla - want).max())}
 
+    # the CCA mixer at heads a lane tile wide, which the second preset's
+    # 16-wide heads are not (4 : 2 heads of 128, 768 tokens = two tiles of
+    # 384, one row padded on the left past the first strips): Mosaic
+    # compiles the convolved latent's kernel (ops/cca_conv.py), the mixer
+    # holds it and the attention's and nothing between them, and the
+    # device's q, k and v are the plain chain's through XLA at every token
+    from benchmark.reference import cca_moe_f32
+    from ccfd_tpu.ops import causal_attention, cca_conv
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "zaya1_small_config.json")) as f:
+        wide = dict(json.load(f), head_dim=128, num_attention_heads=4,
+                    num_key_value_heads=2, hidden_size=256, layers_kept=[0])
+    c_cfg = hybrid_moe.HybridConfig.from_dict(wide)
+    cp = jax.jit(lambda: cca_moe_f32.layer_of(
+        cca_moe_f32.make_params(wide), 0)["mixer"])()
+    rng = np.random.default_rng(51)
+    z = jnp.asarray(rng.normal(size=(2, 768, 256)), jnp.float32)
+    real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [200]]))
+    position = jnp.maximum(jnp.arange(768)[None, :]
+                           - jnp.array([[0], [200]]), 0)
+    check("hybrid_moe cca at lane-wide heads holds the latent's kernel and "
+          "the attention's", kernels.kernels_of(
+              lambda p, z: hybrid_moe.cca(p, z, real, position, c_cfg,
+                                          jnp.bfloat16), cp, z)
+          == {cca_conv.KERNEL, causal_attention.KERNEL})
+    latent = [jax.jit(lambda p, z, path=path: path(
+        p, z, real, position, c_cfg.mixer("cca"), jnp.bfloat16))(cp, z)
+        for path in (hybrid_moe._cca_latent_kernel, hybrid_moe._cca_latent)]
+    zoo["hybrid_moe.cca.lane_wide"] = {
+        "max_abs_diff_" + name: check.close(
+            f"hybrid_moe cca: convolved latent through the kernel vs the "
+            f"plain chain, {name}", np.asarray(got, np.float32),
+            np.asarray(want, np.float32), 0.05)
+        for name, got, want in zip("qkv", *latent)}
+
     # the family's causal attention at head widths the small presets lack
     # (128 wide, and MLA's 128 + 64 = 192 in q and k against values of
     # 128; 768 tokens = two blocks of 384): Mosaic compiles the kernel
@@ -638,8 +674,6 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     # for a block that ends in half a lane tile, and the device's answer
     # is the plain path's at every real position, one row padded on the
     # left past the first block
-    from ccfd_tpu.ops import causal_attention
-
     rng = np.random.default_rng(37)
     real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [400]]))
     for label, q_shape in (("plain heads", (2, 768, 2, 128)),

@@ -1,5 +1,5 @@
 """The kernels' seam (ops/kernels.py): the table of families against the
-kernel modules, the names a dashboard reads, what the six ``kernel_fits``
+kernel modules, the names a dashboard reads, what the seven ``kernel_fits``
 answer at the shapes the kernel tests carry, and that a row of the table is
 all a family's key and counter take to appear."""
 
@@ -18,8 +18,9 @@ import ccfd_tpu.ops
 from ccfd_tpu.analysis.rules import metric_name_ok
 from ccfd_tpu.metrics.prom import Registry
 from ccfd_tpu.models import seq
-from ccfd_tpu.ops import (causal_attention, grouped_experts, kda_scan, kernels,
-                          seq_attention, short_conv, ssd_scan)
+from ccfd_tpu.ops import (causal_attention, cca_conv, grouped_experts,
+                          kda_scan, kernels, seq_attention, short_conv,
+                          ssd_scan)
 from ccfd_tpu.serving import history
 
 BF16, F32, F16 = jnp.bfloat16, jnp.float32, jnp.float16
@@ -41,7 +42,7 @@ def test_every_module_that_names_a_kernel_has_a_row_and_every_row_a_module():
 
 def test_every_kernel_name_belongs_to_exactly_one_family():
     claimed = [name for family in kernels.FAMILIES for name in family.names]
-    assert len(claimed) == len(set(claimed)) == 8
+    assert len(claimed) == len(set(claimed)) == 9
     assert all(isinstance(name, str) and name for name in claimed)
     for family in kernels.FAMILIES:
         assert set(family.names) == {
@@ -51,7 +52,7 @@ def test_every_kernel_name_belongs_to_exactly_one_family():
 
 @pytest.mark.parametrize("module", [seq_attention, causal_attention,
                                     grouped_experts, ssd_scan, kda_scan,
-                                    short_conv],
+                                    short_conv, cca_conv],
                          ids=lambda m: m.__name__.rsplit(".", 1)[1])
 def test_a_module_hands_pallas_call_no_name_but_those_it_declares(module):
     """The ``name=`` keywords of the module's calls, read from its source:
@@ -78,10 +79,11 @@ def test_the_names_on_the_wire_are_the_ones_dashboards_read():
          ("expert_up", "expert_down", "expert_rows")),
         ("ssd_kernel", "seq_ssd_kernel_dispatch_total", ("ssd_scan",)),
         ("kda_kernel", "seq_kda_kernel_dispatch_total", ("kda_scan",)),
-        ("conv_kernel", "seq_conv_kernel_dispatch_total", ("short_conv",))]
+        ("conv_kernel", "seq_conv_kernel_dispatch_total", ("short_conv",)),
+        ("cca_kernel", "seq_cca_kernel_dispatch_total", ("cca_conv",))]
     assert kernels.held(lambda x: x, 1.0) == {
         "attn_kernel": 0, "expert_kernel": 0, "ssd_kernel": 0,
-        "kda_kernel": 0, "conv_kernel": 0}
+        "kda_kernel": 0, "conv_kernel": 0, "cca_kernel": 0}
     assert kernels.FAMILIES[0].help == (
         "seq dispatches of executables whose attention holds a kernel that "
         "keeps the scores on the chip (beside seq_bucket_dispatch_total: "
@@ -97,7 +99,7 @@ def test_a_familys_counter_keeps_the_naming_rule(family):
     assert family.help.count("seq_bucket_dispatch_total") == 1
 
 
-# -- what the six kernel_fits answer ----------------------------------------------------
+# -- what the seven kernel_fits answer ----------------------------------------------------
 
 def _shape(dims, dtype, mesh=None):
     from jax.sharding import NamedSharding, PartitionSpec
@@ -137,6 +139,16 @@ def _conv(dims, dtype, mesh):
         widths)
 
 
+def _cca(dims, dtype, mesh):
+    tokens, hidden, heads, groups, head = dims
+    return cca_conv.kernel_fits(
+        _shape(tokens + (hidden,), jnp.float32, mesh),
+        _shape((hidden, heads * head), dtype), _shape((hidden, groups * head),
+                                                      dtype),
+        _shape((2, (heads + groups) * head), jnp.float32),
+        _shape((2, heads + groups, head, head), dtype), dtype)
+
+
 # (family, which shape, its dimensions, whether the parent's ``kernel_fits``
 # took it in bfloat16 and float32 on one device): written out from the
 # parent's code before ``ops/kernels.py`` took over the common part
@@ -166,8 +178,12 @@ FITS = [
      True),
     (_conv, "lane_wide", ((2, 240, 1288), 512, (512, 128, 128)), True),
     (_conv, "small", ((3, 240, 328), 128, (128, 32, 32)), False),
+    # and PR 51's
+    (_cca, "served", ((8, 1920), 2048, 8, 2, 128), True),
+    (_cca, "lane_wide", ((3, 240), 128, 4, 2, 128), True),
+    (_cca, "small", ((3, 240), 64, 8, 2, 16), False),
 ]
-ASKS_ABOUT_A_MESH = (_experts, _ssd, _kda, _conv)
+ASKS_ABOUT_A_MESH = (_experts, _ssd, _kda, _conv, _cca)
 
 
 @pytest.fixture(scope="module")
@@ -182,7 +198,7 @@ def mesh():
 def test_kernel_fits_answers_as_the_parents_did(fits, which, dims, taken,
                                                 mesh):
     """By dtype, with an operand on a mesh and under an abstract mesh: the
-    grouped experts, the two scans and the convolution refuse either mesh; the two
+    grouped experts, the two scans and the two convolutions refuse either mesh; the two
     attentions never ask (``SeqScorer`` hands each device its rows under
     ``shard_map`` itself) and answer under an abstract mesh as without."""
     meshed = fits in ASKS_ABOUT_A_MESH
@@ -216,7 +232,7 @@ def test_a_further_row_brings_its_key_and_its_counter_with_no_other_edit(
         monkeypatch):
     """A table of the test's own, with one more family that counts ``seq``'s
     kernel alone: the inventory, the ``seq.enqueue`` phase and the registry
-    carry it beside the five, and ``serving/history.py`` was not told."""
+    carry it beside the six, and ``serving/history.py`` was not told."""
     fifth = kernels.Family(
         "own_kernel", "seq_own_kernel_dispatch_total", "attention is seq's",
         "have another", ("seq_attention",))
@@ -235,7 +251,8 @@ def test_a_further_row_brings_its_key_and_its_counter_with_no_other_edit(
                                batch_sizes=(4,), registry=reg)
     scorer.score(np.zeros((3, 30), np.float32), ids=["a", "b", "c"])
     want = {"attn_kernel": 1, "expert_kernel": 0, "ssd_kernel": 0,
-            "kda_kernel": 0, "conv_kernel": 0, "own_kernel": 1}
+            "kda_kernel": 0, "conv_kernel": 0, "cca_kernel": 0,
+            "own_kernel": 1}
     (stats,) = enqueued
     assert {key: stats[key] for key in want} == want
     assert list(stats)[list(stats).index("tokens") + 1:][:len(want) + 1] == [
@@ -247,8 +264,87 @@ def test_a_further_row_brings_its_key_and_its_counter_with_no_other_edit(
     assert reg.counter("seq_attention_kernel_dispatch_total").total() == 1
     assert reg.counter("seq_expert_kernel_dispatch_total").total() == 0
     assert fifth.help in reg.render()
-    # a stand-in for the program holds none of the six
+    # a stand-in for the program holds none of the seven
     real = scorer._apply
     scorer._apply = lambda p, xs: real(p, xs)
     (entry,) = scorer.executable_grid()["grid"]
     assert not any(entry[key] for key in want)
+
+
+# -- the CCA family's row, end to end ------------------------------------------------------
+
+def _small_config(name):
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "tests", "benchmark",
+                           name + "_small_config.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("widths,held", [
+    ({"head_dim": 128, "num_attention_heads": 4, "hidden_size": 128}, 1),
+    ({}, 0)], ids=["lane_wide", "small"])
+def test_the_cca_row_is_on_the_enqueue_phase_in_the_grid_and_counted(
+        monkeypatch, widths, held):
+    """A ``zaya`` program served through ``SeqScorer``: with heads of 128
+    its CCA mixers hold ``ops/cca_conv.py``'s kernel, and ``cca_kernel`` on
+    every ``seq.enqueue``, the executable's entry in ``executable_grid()``
+    and ``seq_cca_kernel_dispatch_total`` beside
+    ``seq_bucket_dispatch_total`` say so; with the small preset's heads of
+    16 they say 0 of the same dispatches."""
+    from benchmark.reference import cca_moe_f32
+    from ccfd_tpu.models import hybrid_moe
+
+    config = {**_small_config("zaya1"), **widths}
+    enqueued = []
+    phase = history.phase
+
+    def recorded(name, **stats):
+        if name == "seq.enqueue":
+            enqueued.append(stats)
+        return phase(name, **stats)
+
+    monkeypatch.setattr(history, "phase", recorded)
+    reg = Registry()
+    scorer = history.SeqScorer(
+        cca_moe_f32.make_params(config), length=8, batch_sizes=(4,),
+        compute_dtype="float32", registry=reg, family="hybrid_moe",
+        family_config=hybrid_moe.HybridConfig.from_dict(config))
+    for _ in range(2):
+        scorer.score(np.zeros((3, 30), np.float32), ids=["a", "b", "a"])
+    assert [e["cca_kernel"] for e in enqueued] == [held] * 2
+    assert [e["conv_kernel"] for e in enqueued] == [0] * 2
+    (entry,) = scorer.executable_grid()["grid"]
+    assert entry["cca_kernel"] is bool(held) and entry["dispatches"] == 2
+    assert reg.counter("seq_bucket_dispatch_total").total() == 2
+    assert reg.counter("seq_cca_kernel_dispatch_total").total() == 2 * held
+    assert reg.counter("seq_conv_kernel_dispatch_total").total() == 0
+
+
+@pytest.mark.parametrize("name,module", [
+    ("ling3", "hybrid_moe_f32"), ("mistral4", "mla_moe_f32"),
+    ("xing4", "mhc_moe_f32"), ("granite4h", "ssm_moe_f32"),
+    ("nemotron3n", "ssm_relu2_moe_f32"), ("seq", None)])
+def test_a_program_without_the_mixer_holds_no_cca_kernel(name, module):
+    """The five other ``hybrid_moe`` models at their small presets, and
+    ``seq`` at a rung that holds its attention kernel."""
+    if module is None:
+        params = seq.init(jax.random.PRNGKey(0))
+        held = kernels.held(
+            lambda p, h: seq.apply_serving(p, h), params,
+            jax.ShapeDtypeStruct((4, 128, 30), np.float32))
+        assert held["attn_kernel"] == 1
+    else:
+        from ccfd_tpu.models import hybrid_moe
+
+        config = _small_config(name)
+        ref = importlib.import_module("benchmark.reference." + module)
+        cfg = hybrid_moe.HybridConfig.from_dict(config)
+        held = kernels.held(
+            lambda p, h, f: hybrid_moe.apply_serving(p, h, f, cfg, F32),
+            jax.eval_shape(lambda: ref.make_params(config)),
+            jax.ShapeDtypeStruct((2, 8, 30), np.float32),
+            jax.ShapeDtypeStruct((2,), np.int32))
+    assert held["cca_kernel"] == 0
