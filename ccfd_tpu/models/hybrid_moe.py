@@ -26,15 +26,26 @@ with a bias; per head S_t = e^(dt_t A) S_(t-1) + dt_t x_t B_t^T, y_t = S_t
 C_t + D x_t with B and C shared by the heads of a group; the gate, then an
 RMS norm over all inner values or inside each of the model's groups of
 them; served as a chunked scan whose chunk is the deployment's
-``scan_chunk``) and **GQA** (``gqa``: plain grouped-query causal softmax
-attention without positions, head width and scale the model's own).
+``scan_chunk``), **GQA** (``gqa``: grouped-query causal softmax
+attention, head width and scale the model's own; plain and without
+positions unless the model sets an RMS norm on every query and key head, a
+rotary turn of a head's leading dims, or a sigmoid gate a head that the
+query projection carries) and **Gated DeltaNet** (``gdn``: a delta rule
+whose decay is one scalar a value head, unbounded; fewer key heads than
+value heads, each key head read by several value heads; one causal
+convolution without a bias over q, k and v together; L2-normed q and k;
+S_t = e^(g_t) S_(t-1) + beta_t k_t (v_t - (e^(g_t) S_(t-1))^T k_t)^T, o_t =
+S_t^T q_t; an RMS norm over each head's values times SiLU of a gate;
+served as a chunked scan whose chunk is the deployment's ``gdn_chunk``).
 Routers of the **sparse expert layer**: ``top_k`` (sigmoid or softmax
 scores over all
 routed experts, an optional expert bias for the choice, group-limited or
 not, weights renormalised over the chosen) with or without a shared
-expert; or ``carried_mlp``, a small MLP on a down-projection whose hidden
-state is handed from one layer's router to the next, softmax, top 1, and a
-last output that means *no expert* (the token skips the layer). **An
+expert, which a model may put behind a sigmoid gate of its own (a leaf
+``shared_gate``: one scalar a token); or ``carried_mlp``, a small MLP on a
+down-projection whose hidden state is handed from one layer's router to
+the next, softmax, top 1, and a last output that means *no expert* (the
+token skips the layer). **An
 expert's body** (routed and shared alike) is a setting of the model
 (``EXPERT_BODIES``): SwiGLU of three matrices, or relu squared between two
 with no gate.
@@ -53,7 +64,9 @@ the list may itself be such a tree of alike layers (leading dense layers
 listed, the expert layers behind them scanned; or two stacks of alike
 layers around a single one of another kind). A model may multiply its
 embedding and divide its logits by constants (``embed_scale``,
-``logit_divisor``). Causal throughout. The window (B, L, F) of the
+``logit_divisor``), and may store its norm weights zero-centred
+(``norm_offset`` 1: the layers', the final and ``gqa``'s head norms
+multiply by 1 + w). Causal throughout. The window (B, L, F) of the
 ``HistoryStore`` is
 tokenised on the device (TabFormer-style: column j of a record is token
 j * bins + its quantile bin), so a verdict is one L * F token pass read
@@ -80,13 +93,15 @@ row's first real token; padding keys are masked in MLA; a padding token
 has beta = 0, alpha = 1 and sends zeros into the convolution, so the KDA
 state passes it unchanged (under ``mamba2``: dt = 0, so its decay is 1 and
 it puts nothing in; zeros into the convolution; a masked key under
-``gqa``); it routes to no expert. A row's verdict is
+``gqa``; under ``gdn`` beta = 0, g = 0 and zeros into the convolution); it
+routes to no expert. A row's verdict is
 therefore the same at every window length that holds its history.
 
 The equations, with the key each symbol is read from, are in the plain
 references ``benchmark/reference/hybrid_moe_f32.py``, ``cca_moe_f32.py``,
-``mla_moe_f32.py``, ``mhc_moe_f32.py``, ``ssm_moe_f32.py`` and
-``ssm_relu2_moe_f32.py`` (which import nothing from here); the parameter
+``mla_moe_f32.py``, ``mhc_moe_f32.py``, ``ssm_moe_f32.py``,
+``ssm_relu2_moe_f32.py`` and ``gdn_moe_f32.py`` (which import nothing from
+here); the parameter
 tree is the one their ``make_params`` draw (a layer's tree holds ``norm1``
 and ``mixer`` where it has a mixer, ``norm2`` and ``ffn`` where it has a
 feed-forward part).
@@ -99,7 +114,9 @@ stream-to-stream map is every later sublayer's), the KDA state and
 everything inside a chunk, CCA's convolution sums and L2 norms in float32;
 Mamba-2's convolution, steps, decays, state and everything inside a chunk
 too (a decay is always the exponential of a difference of running sums of
-log-decays that is <= 0, never a quotient of two exponentials).
+log-decays that is <= 0, never a quotient of two exponentials), and so
+Gated DeltaNet's (its pairwise decays one (C, C) matrix a value head, masked
+before the exponential; the triangular inverse at ``highest``).
 
 Device scopes (``jax.named_scope``, so a capture's operations carry them):
 ``lm.embed``, ``kda``, ``mla`` (inside it ``mla.project``: every
@@ -109,7 +126,10 @@ it ``cca.conv`` and ``cca.attend``; both ``attend`` scopes hold
 in- and out-projections, ``mamba.conv``, ``mamba.scan``: steps, decays and
 the chunked scan, ``mamba.gate``: the gate and its norm), ``gqa`` (inside it
 ``gqa.project`` and ``gqa.attend``, which holds :func:`_causal_attention`
-too), ``dense_ffn``, ``moe.route``,
+too), ``gdn`` (inside it ``gdn.project``: the two in-projections and the
+out-projection, ``gdn.conv``: the convolution, SiLU and the L2 norms,
+``gdn.scan``: beta, the decays and the chunked scan, ``gdn.gate``: the
+per-head norm and SiLU(z)), ``dense_ffn``, ``moe.route``,
 ``moe.experts``, ``moe.shared``, ``lm.head``, and ``hc`` around everything
 the ``mhc`` rule adds (inside it ``hc.maps``: the flattened norm, the
 product, the sigmoids and Sinkhorn; ``hc.mix``: the sublayer's input from
@@ -323,24 +343,64 @@ class Mamba2:
 
 @dataclasses.dataclass(frozen=True)
 class Gqa:
-    """Plain grouped-query softmax attention without positions."""
+    """Grouped-query softmax attention; plain and without positions
+    unless a setting says otherwise: ``rotary_dim`` leading dims of every
+    query and key head turned by position (0: none) at ``theta``,
+    ``qk_norm``: an RMS norm over every query and key head before the
+    turn, ``gated``: the query projection carries a gate beside each
+    head's query, and the head's output is multiplied by its sigmoid."""
 
     heads: int
     kv_heads: int
     head_dim: int
     scale: float  # what the scores are multiplied by before the softmax
+    rotary_dim: int = 0
+    theta: float = 0.0
+    qk_norm: bool = False
+    gated: bool = False
 
     @classmethod
-    def read(cls, m: Mapping[str, Any], head_dim: int,
-             scale: float) -> "Gqa":
-        """The head's width and the softmax scale are the reader's: each
-        family says them its own way."""
+    def read(cls, m: Mapping[str, Any], head_dim: int, scale: float,
+             **more) -> "Gqa":
+        """The head's width, the softmax scale and what is not plain
+        (``more``) are the reader's: each family says them its own way."""
         heads, kv = int(m["num_attention_heads"]), int(
             m["num_key_value_heads"])
-        if heads % kv or m["attention_bias"]:
+        if heads % kv or m.get("attention_bias", False):
             raise ValueError("gqa: num_attention_heads a multiple of "
                              "num_key_value_heads, attention_bias false")
-        return cls(heads, kv, int(head_dim), float(scale))
+        return cls(heads, kv, int(head_dim), float(scale), **more)
+
+
+@dataclasses.dataclass(frozen=True)
+class Gdn:
+    """Gated DeltaNet: a delta rule whose decay is one scalar a value
+    head: ``key_heads`` heads of ``key_dim`` feed ``value_heads`` heads of
+    ``value_dim`` (value head j reads key head j // (value_heads /
+    key_heads)), a causal depthwise convolution of ``conv`` taps over q, k
+    and v together; the deployment's ``chunk``, the tokens a step of the
+    served scan takes at once (no part of the result)."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv: int
+    chunk: int
+
+    @classmethod
+    def read(cls, m: Mapping[str, Any]) -> "Gdn":
+        keys, values = int(m["linear_num_key_heads"]), int(
+            m["linear_num_value_heads"])
+        chunk = int(m.get("gdn_chunk", 64))
+        if values % keys or chunk < 8 or chunk & (chunk - 1):
+            raise ValueError(
+                "gdn: linear_num_value_heads a multiple of "
+                "linear_num_key_heads, gdn_chunk a power of two from 8 (the "
+                "triangular inverse joins blocks of 8 pair by pair)")
+        return cls(keys, values, int(m["linear_key_head_dim"]),
+                   int(m["linear_value_head_dim"]),
+                   int(m["linear_conv_kernel_dim"]), chunk)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -413,6 +473,9 @@ class HybridConfig:
     embed_scale: float = 1.0  # on every token's embedding
     logit_divisor: float = 1.0  # under the logits
     expert_body: str = "swiglu"  # name in EXPERT_BODIES: routed and shared
+    # added to the weight of the layers', the final and gqa's head norms (1:
+    # a family that stores them zero-centred and multiplies by 1 + w)
+    norm_offset: float = 0.0
 
     @classmethod
     def from_dict(cls, m: Mapping[str, Any]) -> "HybridConfig":
@@ -602,9 +665,41 @@ def _read_nemotron(m: Mapping[str, Any]) -> dict:
         tied_head=bool(m["tie_word_embeddings"]), expert_body="relu2")
 
 
+def _read_qwen3_next(m: Mapping[str, Any]) -> dict:
+    """Qwen3-Next: Gated DeltaNet with every ``full_attention_interval``-th
+    layer gated grouped-query attention ``head_dim`` wide (an RMS norm on
+    every query and key head, a rotary turn of the leading
+    ``partial_rotary_factor`` of a head, a sigmoid gate the query
+    projection carries), the expert layer in every layer: softmax scores
+    over all routed experts renormalised over the chosen, one shared
+    expert behind a gate of its own; norms that multiply by 1 + w; an
+    untied head."""
+    _held_all_of(m, "num_experts")
+    if int(m["decoder_sparse_step"]) != 1 or m["mlp_only_layers"] \
+            or m.get("rope_scaling") or m.get("use_sliding_window") \
+            or not m["norm_topk_prob"] or m["hidden_act"] != "silu":
+        raise ValueError(
+            "qwen3_next: decoder_sparse_step 1, mlp_only_layers empty, no "
+            "rope_scaling, use_sliding_window false, norm_topk_prob true, "
+            "hidden_act silu")
+    period, hd = int(m["full_attention_interval"]), int(m["head_dim"])
+    layers = tuple(("gqa" if (i + 1) % period == 0 else "gdn", "moe")
+                   for i in m["layers_kept"])
+    return dict(
+        layers=layers, mixers=_mixers_of(layers, {
+            "gdn": lambda: Gdn.read(m),
+            "gqa": lambda: Gqa.read(
+                m, hd, hd ** -0.5, qk_norm=True, gated=True,
+                rotary_dim=int(hd * float(m["partial_rotary_factor"])),
+                theta=float(m["rope_theta"]))}),
+        router="top_k", routing=TopK("softmax", False, 1, 1, 1.0),
+        tied_head=bool(m["tie_word_embeddings"]), norm_offset=1.0)
+
+
 READERS = {"ling": _read_ling, "zaya": _read_zaya,
            "mistral4": _read_mistral4, "xing4_0": _read_xing4,
-           "granitemoehybrid": _read_granite, "nemotron_h": _read_nemotron}
+           "granitemoehybrid": _read_granite, "nemotron_h": _read_nemotron,
+           "qwen3_next": _read_qwen3_next}
 
 
 def owns(params: Any) -> bool:
@@ -616,8 +711,12 @@ def owns(params: Any) -> bool:
 
 # -- small pieces ---------------------------------------------------------------
 
-def _rms(x, weight, eps):
+def _rms(x, weight, eps, offset: float = 0.0):
+    """x / rms(x) times ``weight`` (plus ``offset`` where the family
+    stores its weights zero-centred: ``HybridConfig.norm_offset``)."""
     x = x.astype(F32)
+    if offset:
+        weight = weight + offset
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * weight
 
 
@@ -758,6 +857,35 @@ def _kda_chunk(state, chunk, sub: int):
     return state, out
 
 
+def _chunk_loop(xs: tuple, state, one_chunk, c: int, rest: tuple):
+    """``one_chunk(state, chunk) -> (state, o)`` over a window ``c`` tokens
+    at a time: ``xs`` arrays (B, T, ...), ``chunk`` their (B, C, ...)
+    slices, ``o`` (B, C, *rest) -> every chunk's ``o`` as (B, T, *rest)
+    float32. A window is padded on the left to whole chunks with zeros,
+    which every caller's recurrence passes its state through unchanged."""
+    b, t = xs[0].shape[:2]
+    lead = -t % c
+    n = (t + lead) // c
+
+    def chunks(x):  # (B, T, ...) -> (B, N, C, ...): no data moves
+        x = jnp.pad(x, ((0, 0), (lead, 0)) + ((0, 0),) * (x.ndim - 2))
+        return x.reshape(b, n, c, *x.shape[2:])
+
+    xs = tuple(chunks(x) for x in xs)
+
+    def step(i, carry):
+        state, out = carry
+        state, o = one_chunk(state, tuple(
+            jax.lax.dynamic_index_in_dim(x, i, 1, keepdims=False)
+            for x in xs))
+        return state, jax.lax.dynamic_update_index_in_dim(
+            out, o.astype(out.dtype), i, 1)
+
+    _, o = jax.lax.fori_loop(0, n, step, (
+        state, jnp.zeros((b, n, c, *rest), F32)))
+    return o.reshape(b, t + lead, *rest)[:, lead:]
+
+
 def _delta_scan(q, k, v, g, beta, c: int):
     """The gated delta rule over a window from a zero state: ``q``, ``k``,
     ``v`` (B, T, H, d) in the compute dtype, the log-decays ``g`` (B, T, H,
@@ -772,27 +900,10 @@ def _delta_scan(q, k, v, g, beta, c: int):
     family (it is served only)."""
     if kda_scan.kernel_fits(q, v, c, KDA_SUB):
         return kda_scan.kda_scan(q, k, v, g, beta, chunk=c, sub=KDA_SUB)
-    b, t, h, dk = q.shape
-    lead = -t % c  # padding tokens on the left pass the state unchanged
-    n = (t + lead) // c
-
-    def chunks(x):  # (B, T, ...) -> (B, N, C, ...): no data moves
-        x = jnp.pad(x, ((0, 0), (lead, 0)) + ((0, 0),) * (x.ndim - 2))
-        return x.reshape(b, n, c, *x.shape[2:])
-
-    xs = tuple(chunks(x) for x in (q, k, v, g, beta))
-
-    def one_chunk(i, carry):
-        state, out = carry
-        state, o = _kda_chunk(state, tuple(
-            jax.lax.dynamic_index_in_dim(x, i, 1, keepdims=False)
-            for x in xs), KDA_SUB)
-        return state, jax.lax.dynamic_update_index_in_dim(
-            out, o.astype(out.dtype), i, 1)
-
-    _, o = jax.lax.fori_loop(0, n, one_chunk, (
-        jnp.zeros((b, h, dk, dk), F32), jnp.zeros((b, n, c, h, dk), F32)))
-    return o.reshape(b, t + lead, h, dk)[:, lead:]
+    b, _, h, dk = q.shape
+    return _chunk_loop(
+        (q, k, v, g, beta), jnp.zeros((b, h, dk, dk), F32),
+        lambda state, chunk: _kda_chunk(state, chunk, KDA_SUB), c, (h, dk))
 
 
 def kda(p, z, real, cfg: HybridConfig, dtype):
@@ -1249,21 +1360,156 @@ def mamba2(p, z, real, cfg: HybridConfig, dtype):
         return _mm(y, p["w_out"], dtype), low
 
 
-def gqa(p, z, real, cfg: HybridConfig, dtype):
+def gqa(p, z, real, cfg: HybridConfig, dtype, position=None):
     """(B, T, hidden) normed input -> (B, T, hidden) mixer output: causal
     softmax attention of ``heads`` query heads over ``kv_heads`` key-value
-    heads, no positions, no norm, the scale the model's own."""
+    heads at the model's own scale; by the settings (``Gqa``) an RMS norm
+    on every query and key head, a turn of their leading ``rotary_dim``
+    dims by ``position`` (B, T) (the two halves of those dims are the
+    pairs; read by nothing where the model has no positions) and a
+    sigmoid gate a head that ``wq`` carries beside the head's query."""
     b, t, _ = z.shape
     s = cfg.mixer("gqa")
     h, g, hd = s.heads, s.kv_heads, s.head_dim
+
+    def heads(x, n: int, norm: str):
+        """(B, T, n * hd) as the product leaves it, each head normed and
+        turned where the model does either."""
+        if not (s.qk_norm or s.rotary_dim):
+            return x
+        x = x.reshape(b, t, n, hd)
+        if s.qk_norm:
+            x = _rms(x, p[norm], cfg.eps, cfg.norm_offset)
+        if s.rotary_dim:
+            x = jnp.concatenate([
+                _rotary(x[..., :s.rotary_dim], position,
+                        _frequencies(s.theta, s.rotary_dim)),
+                x[..., s.rotary_dim:]], -1)
+        return x
+
     with jax.named_scope("gqa.project"):
-        q = _mm(z, p["wq"], dtype).reshape(b, t, g, h // g, hd).astype(dtype)
-        k = _mm(z, p["wk"], dtype).reshape(b, t, g, hd).astype(dtype)
+        q, gate = _mm(z, p["wq"], dtype), None
+        if s.gated:  # a head's query and its gate side by side
+            q, gate = jnp.split(q.reshape(b, t, h, 2 * hd), 2, -1)
+        q = heads(q, h, "q_norm").reshape(b, t, g, h // g, hd).astype(dtype)
+        k = heads(_mm(z, p["wk"], dtype), g, "k_norm").reshape(
+            b, t, g, hd).astype(dtype)
         v = _mm(z, p["wv"], dtype).reshape(b, t, g, hd).astype(dtype)
     with jax.named_scope("gqa.attend"):
         o = _causal_attention(q, k, v, real, s.scale, dtype)
     with jax.named_scope("gqa.project"):
+        if s.gated:
+            o = o.reshape(b, t, h, hd).astype(F32) * jax.nn.sigmoid(gate)
         return _mm(o.reshape(b, t, h * hd), p["wo"], dtype)
+
+
+# -- Gated DeltaNet -----------------------------------------------------------------
+
+def _gdn_chunk(state, chunk):
+    """One chunk of the scalar-decay delta rule for every (row, head) at
+    once: :func:`_kda_chunk` with one log-decay a value head and token
+    and ``per`` value heads on each key head.
+
+    ``state`` (B, Hk, per, dk, dv) is S at the chunk's start; ``chunk``
+    holds q, k (B, C, Hk, dk), v (B, C, Hk, per, dv), the log-decays g <= 0
+    and beta (B, C, Hk, per). With G_t the running sum of g inside the
+    chunk, u_t = beta_t (v_t - e^(G_t) S_0^T k_t - sum_(i<t) e^(G_t - G_i)
+    (k_i . k_t) u_i) solves (I + A) U = beta (V - e^G K S_0), A_ti = beta_t
+    (k_t . k_i) e^(G_t - G_i) for i < t, and o_t = e^(G_t) S_0^T q_t +
+    sum_(i<=t) (q_t . k_i) e^(G_t - G_i) u_i. The pairwise factor is one
+    (C, C) matrix a value head, the exponential of a difference masked to
+    i <= t first, so that it is <= 1 however fast a head forgets (no bound
+    on g), never a quotient of two exponentials; K K^T and Q K^T are
+    formed once a key head and used by its value heads. Returns the new
+    state and o (B, C, Hk, per, dv)."""
+    q, k, v, g, beta = chunk
+    q, k, v = q.astype(F32), k.astype(F32), v.astype(F32)
+    c = q.shape[1]
+    run = jnp.cumsum(g, axis=1)  # G_t, inclusive
+    at = jnp.arange(c)
+    by_head = jnp.moveaxis(run, 1, -1)  # (B, Hk, per, C)
+    decay = jnp.exp(jnp.where(
+        at[:, None] >= at[None, :],
+        by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+    kk = jnp.einsum("bthc,bihc->bhti", k, k, precision=KDA_INSIDE)
+    qk = jnp.einsum("bthc,bihc->bhti", q, k, precision=KDA_INSIDE)
+    beta_h = jnp.moveaxis(beta, 1, -1)[..., None]  # (B, Hk, per, C, 1)
+    a = jnp.where(at[:, None] > at[None, :], kk[:, :, None] * decay, 0.0)
+    solve = _unit_lower_inverse(a * beta_h) * jnp.swapaxes(beta_h, -1, -2)
+    whole = jnp.exp(run)[..., None]  # e^(G_t)
+    seen = jnp.einsum("bthc,bhrcv->bthrv", k, state,
+                      precision=KDA_PRECISION) * whole
+    u = jnp.einsum("bhrti,bihrv->bthrv", solve, v - seen,
+                   precision=KDA_INSIDE)
+    out = (jnp.einsum("bthc,bhrcv->bthrv", q, state,
+                      precision=KDA_PRECISION) * whole
+           + jnp.einsum("bhrti,bihrv->bthrv", qk[:, :, None] * decay, u,
+                        precision=KDA_INSIDE))
+    last = run[:, -1]  # G_C (B, Hk, per)
+    left = jnp.exp(last[:, None] - run)[..., None]  # e^(G_C - G_i)
+    state = (state * jnp.exp(last)[..., None, None]
+             + jnp.einsum("bihc,bihrv->bhrcv", k, u * left,
+                          precision=KDA_PRECISION))
+    return state, out
+
+
+def _scalar_delta_scan(q, k, v, g, beta, c: int):
+    """The delta rule with one decay a value head over a window from a
+    zero state: ``q``, ``k`` (B, T, Hk, dk) and ``v`` (B, T, Hv, dv) in the
+    compute dtype, the log-decays ``g`` <= 0 and ``beta`` (B, T, Hv)
+    float32 -> o (B, T, Hv, dv) float32, the state moving ``c`` tokens at a
+    time: the loop over :func:`_gdn_chunk` through XLA, :func:`_delta_scan`'s
+    sibling (no kernel computes this recurrence yet). A padding token has g
+    = beta = 0 and passes the state unchanged."""
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2:]
+    per = hv // hk
+    o = _chunk_loop(
+        (q, k, v.reshape(b, t, hk, per, dv), g.reshape(b, t, hk, per),
+         beta.reshape(b, t, hk, per)),
+        jnp.zeros((b, hk, per, dk, dv), F32), _gdn_chunk, c, (hk, per, dv))
+    return o.reshape(b, t, hv, dv)
+
+
+def gdn(p, z, real, cfg: HybridConfig, dtype):
+    """(B, T, hidden) normed input -> ``((B, T, hidden) mixer output, the
+    most negative log-decay of a token)``: one projection to [q | k | v |
+    z] and one to [b | a], the causal depthwise convolution and SiLU over
+    q, k and v together (no bias), q and k L2-normed by head, beta =
+    sigmoid(b) and g = -exp(A_log) softplus(a + dt_bias) a value head, the
+    chunked scan (:func:`_gdn_chunk`) in float32, an RMS norm over each
+    head's values (times w, whatever ``norm_offset``) times SiLU(z), the
+    output projection."""
+    b, t, _ = z.shape
+    s = cfg.mixer("gdn")
+    hk, hv, dk, dv = s.key_heads, s.value_heads, s.key_dim, s.value_dim
+    keys, values = hk * dk, hv * dv
+    keep = real[:, :, None].astype(F32)
+    with jax.named_scope("gdn.project"):
+        proj = _mm(z, p["w_qkvz"], dtype)
+        ba = _mm(z, p["w_ba"], dtype)
+    with jax.named_scope("gdn.conv"):
+        q, k, v = _conv_silu(proj, p["conv"], jnp.zeros((
+            2 * keys + values,), F32), keep, 0, (keys, keys, values))
+
+        def unit(x):
+            x = x.reshape(b, t, hk, dk)
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
+
+        q = (unit(q) * dk ** -0.5).astype(dtype)
+        k = unit(k).astype(dtype)
+        v = v.reshape(b, t, hv, dv).astype(dtype)
+    with jax.named_scope("gdn.scan"):
+        beta = jax.nn.sigmoid(ba[..., :hv]) * keep
+        g = -jnp.exp(p["a_log"]) * jax.nn.softplus(
+            ba[..., hv:] + p["dt_bias"]) * keep
+        o = _scalar_delta_scan(q, k, v, g, beta, s.chunk)
+    with jax.named_scope("gdn.gate"):
+        o = _rms(o, p["norm"], cfg.eps) * jax.nn.silu(
+            proj[..., 2 * keys + values:].reshape(b, t, hv, dv))
+    with jax.named_scope("gdn.project"):
+        return _mm(o.reshape(b, t, values), p["w_out"], dtype), g.min()
 
 
 # -- the expert layer -------------------------------------------------------------
@@ -1458,7 +1704,11 @@ def moe(p, z, r, real, cfg: HybridConfig, dtype):
     if "shared" in p:
         _, body = EXPERT_BODIES[cfg.expert_body]
         with jax.named_scope("moe.shared"):
-            y = y + body(p["shared"], flat, dtype)
+            shared = body(p["shared"], flat, dtype)
+            if "shared_gate" in p:  # one scalar a token
+                shared = shared * jax.nn.sigmoid(
+                    _mm(flat, p["shared_gate"], dtype))
+            y = y + shared
     local = chosen - cfg.held_first
     mine = (local >= 0) & (local < cfg.held_count)
     counts = {
@@ -1485,10 +1735,23 @@ MIXERS = {  # name -> f(p, z, real, position, cfg, dtype): (y, its report)
     "mamba2": lambda p, z, real, position, cfg, dtype: mamba2(
         p, z, real, cfg, dtype),
     "gqa": lambda p, z, real, position, cfg, dtype: (
-        gqa(p, z, real, cfg, dtype), None),
+        gqa(p, z, real, cfg, dtype, position), None),
+    "gdn": lambda p, z, real, position, cfg, dtype: gdn(
+        p, z, real, cfg, dtype),
 }
 # a mixer's device scope, where it is not the mixer's name
 MIXER_SCOPES = {"mamba2": "mamba"}
+# a mixer's report: the leaf of ``aux`` it is the lowest of, over that
+# mixer's layers, and what the gauge ``lm_`` + leaf says of it
+MIXER_REPORTS = {
+    "mamba2": ("ssm_log_decay_min",
+               "most negative running sum of log-decays inside a chunk of "
+               "the state-space scan over real tokens, heads, layers and "
+               "dispatches"),
+    "gdn": ("gdn_log_decay_min",
+            "most negative log-decay of one token in a Gated DeltaNet layer "
+            "over real tokens, value heads, layers and dispatches"),
+}
 
 
 # -- the model ----------------------------------------------------------------------
@@ -1592,12 +1855,12 @@ def _layer(p, x, r, kind, real, position, cfg: HybridConfig, dtype):
     rule = RESIDUALS[cfg.residual]
 
     def mix(x):
-        z = _rms(x, p["norm1"], cfg.eps)
+        z = _rms(x, p["norm1"], cfg.eps, cfg.norm_offset)
         with jax.named_scope(MIXER_SCOPES.get(mixer, mixer)):
             return MIXERS[mixer](p["mixer"], z, real, position, cfg, dtype)
 
     def feed(x):
-        z = _rms(x, p["norm2"], cfg.eps)
+        z = _rms(x, p["norm2"], cfg.eps, cfg.norm_offset)
         if ffn == "dense":
             with jax.named_scope("dense_ffn"):
                 return _swiglu(p["ffn"], z, dtype), (r, None)
@@ -1664,13 +1927,14 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
                 return (x, r), out
 
             (x, r), out = jax.lax.scan(step, (x, r), p)
-        each.append((out, n is not None))
+        each.append((out, n is not None, kind[0]))
         first += n or 1
 
-    def over_layers(which: int):
-        """Every layer's ``out[which]`` with the layers leading."""
-        parts = [(out[which], stacked) for out, stacked in each
-                 if out[which] is not None]
+    def over_layers(which: int, mixer: str | None = None):
+        """Every layer's ``out[which]`` with the layers leading (of the
+        layers that mix by ``mixer``, where one is named)."""
+        parts = [(out[which], stacked) for out, stacked, mixed in each
+                 if out[which] is not None and mixer in (None, mixed)]
         if not parts:
             return None
         trees, stacked = zip(*parts)
@@ -1678,7 +1942,9 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
             leaf if whole else jnp.expand_dims(leaf, 0)
             for leaf, whole in zip(leaves, stacked)]), *trees)
 
-    counts, defect, report = (over_layers(i) for i in range(3))
+    counts, defect = over_layers(0), over_layers(1)
+    reports = {leaf: over_layers(2, mixer)  # over that mixer's layers
+               for mixer, (leaf, _) in MIXER_REPORTS.items()}
     if counts is None:
         none = jnp.zeros((0,), jnp.int32)
         counts = {"pairs": jnp.zeros((0, cfg.held_count), jnp.int32),
@@ -1694,8 +1960,9 @@ def hidden_states(params: Params, hist, filled, cfg: HybridConfig,
            "row_choice": jnp.swapaxes(counts["row_choice"], 0, 1)}
     if defect is not None:  # the rule's, over all sublayers
         aux["hc_defect"] = defect.max()
-    if report is not None:  # the state-space mixers', over their layers
-        aux["ssm_log_decay_min"] = report.min()
+    for leaf, report in reports.items():
+        if report is not None:
+            aux[leaf] = report.min()
     return x, aux
 
 
@@ -1711,7 +1978,7 @@ def slice_logits(params: Params, x, cfg: HybridConfig, dtype=jnp.bfloat16):
     with jax.named_scope("lm.head"):
         if cfg.residual == "mhc":
             x = x.sum(-2)
-        z = _rms(x, params["final_norm"], cfg.eps)
+        z = _rms(x, params["final_norm"], cfg.eps, cfg.norm_offset)
         if cfg.tied_head:
             logits = jnp.einsum("...i,vi->...v", z.astype(dtype),
                                 params["embed"].astype(dtype),
@@ -1750,7 +2017,10 @@ def apply_serving(params: Params, hist, filled, cfg: HybridConfig,
     negative running sum of log-decays inside a chunk of the scan over
     tokens, heads and layers (how far e^(R_t) is from float32's smallest:
     below -87 a chunk's late tokens no longer see the state it began
-    with, as in the recurrence itself)."""
+    with, as in the recurrence itself); where a layer mixes by ``gdn``
+    also ``gdn_log_decay_min``, the most negative log-decay of one token
+    over tokens, value heads and layers (what a kernel that wanted a bound
+    on it would have to hold)."""
     x, aux = hidden_states(params, hist, filled, cfg, compute_dtype)
     z = slice_logits(params, x[:, -1], cfg, compute_dtype)
     aux["logits"] = z
@@ -1769,9 +2039,9 @@ def make_observer(registry: Any):
     ``skipped_tokens``, ``routed_tokens``, ``max_expert_pairs``; where the
     program hands back ``hc_defect`` (the ``mhc`` residual rule), that too,
     and the gauge ``lm_hc_defect_max``, the largest since the process
-    began; where it hands back ``ssm_log_decay_min`` (a ``mamba2`` layer),
-    that and the gauge ``lm_ssm_log_decay_min``, the lowest since the
-    process began."""
+    began; where it hands back ``ssm_log_decay_min`` (a ``mamba2`` layer)
+    or ``gdn_log_decay_min`` (a ``gdn`` layer), that and the gauge ``lm_`` +
+    its name, the lowest since the process began."""
     served = registry.counter(
         "moe_pairs_served_total",
         "(token, held expert) pairs the expert layers multiplied")
@@ -1807,11 +2077,8 @@ def make_observer(registry: Any):
         "lm_hc_defect_max",
         "largest abs(row or column sum - 1) of a hyper-connection's "
         "stream-to-stream map over real tokens, sublayers and dispatches")
-
-    decay = registry.gauge(
-        "lm_ssm_log_decay_min",
-        "most negative running sum of log-decays inside a chunk of the "
-        "state-space scan over real tokens, heads, layers and dispatches")
+    lowest = {leaf: registry.gauge("lm_" + leaf, text)  # since start
+              for leaf, text in MIXER_REPORTS.values()}
 
     def observe(aux: dict) -> dict:
         pairs = aux["pairs"]  # (expert layers, held)
@@ -1840,9 +2107,10 @@ def make_observer(registry: Any):
         if "hc_defect" in aux:
             stats["hc_defect"] = float(aux["hc_defect"])
             defect.set(max(defect.value(), stats["hc_defect"]))
-        if "ssm_log_decay_min" in aux:
-            stats["ssm_log_decay_min"] = float(aux["ssm_log_decay_min"])
-            decay.set(min(decay.value(), stats["ssm_log_decay_min"]))
+        for leaf, gauge in lowest.items():
+            if leaf in aux:
+                stats[leaf] = float(aux[leaf])
+                gauge.set(min(gauge.value(), stats[leaf]))
         return stats
 
     return observe

@@ -587,6 +587,98 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
             "vs recurrence", np.asarray(got) * keep, want * keep, 2e-2),
         "log_decay_min": float(low)}
 
+    # the family's seventh model (three Gated DeltaNet layers, 2 key heads
+    # on 4 value heads of 16 with one scalar decay a value head, and a gated
+    # attention layer 4 : 2 at head width 16 with normed heads and a quarter
+    # rotary; softmax top 4 of 16 experts of which 4 are held, a shared
+    # expert behind a sigmoid gate, norms that multiply by 1 + w, an untied
+    # head): the same seam, every chosen pair served here or another
+    # chip's, the mixers' settings in the grid
+    from benchmark.reference import gdn_moe_f32
+    from ccfd_tpu.ops import causal_attention
+
+    with open(os.path.join(ROOT, "tests", "benchmark",
+                           "qwen3next_small_config.json")) as f:
+        small = json.load(f)
+    q_cfg = hybrid_moe.HybridConfig.from_dict(small)
+    q_params = gdn_moe_f32.make_params(small)
+    s = SeqScorer(q_params, length=8, batch_sizes=(16,), family="hybrid_moe",
+                  family_config=q_cfg, max_customers=64)
+    s.warmup()
+    direct, aux = hybrid_moe.apply_serving(
+        q_params, hist, np.ones(16, np.int32), q_cfg, jnp.bfloat16)
+    grid = s.executable_grid()
+    check("hybrid_moe (qwen3_next) served + absent pairs = 4 a token and "
+          "layer over 4 layers, the gdn chunk and the gated gqa in the grid, "
+          "decays below 0",
+          int(aux["pairs_served"]) + int(aux["pairs_absent"])
+          == 4 * int(aux["routed_tokens"]) * q_cfg.moe_layers
+          and q_cfg.moe_layers == 4 and grid["kinds"]["gdn"]["chunk"] == 32
+          and grid["kinds"]["gqa"]["gated"] and q_cfg.norm_offset == 1.0
+          and float(aux["gdn_log_decay_min"]) < 0)
+    zoo["hybrid_moe.qwen3_next"] = {
+        "max_abs_diff": check.close(
+            "hybrid_moe (qwen3_next) B=16 L=8", s.score(
+                rows, list(range(11_000, 11_016))), np.asarray(direct), 1e-6),
+        "grid": grid}
+
+    # its Gated DeltaNet mixer at the served heads (hidden 256, 2 key heads
+    # on 4 value heads of 128; 768 tokens, one row padded on the left past
+    # the first chunks): Mosaic compiles the convolution's kernel over q, k
+    # and v at offset 0 of the wide projection, the scalar-decay scan runs
+    # through XLA (no kernel computes it yet), and the device's answer is
+    # the delta rule's a token at a time of the plain reference, both in
+    # float32
+    wide = dict(small, hidden_size=256, linear_num_key_heads=2,
+                linear_num_value_heads=4, linear_key_head_dim=128,
+                linear_value_head_dim=128, layers_kept=[0])
+    wp = jax.jit(lambda: gdn_moe_f32.make_params(wide)["layers"][0][
+        "mixer"])()
+    rng = np.random.default_rng(52)
+    z = jnp.asarray(rng.normal(size=(2, 768, 256)), jnp.float32)
+    real = jnp.asarray(np.arange(768)[None, :] >= np.array([[0], [200]]))
+    position = jnp.maximum(jnp.arange(768)[None, :]
+                           - jnp.array([[0], [200]]), 0)
+    keep = np.asarray(real)[..., None]
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(gdn_moe_f32.gdn(wp, z, real, wide))
+        for chunk in (64, 128):
+            w_cfg = hybrid_moe.HybridConfig.from_dict(
+                dict(wide, gdn_chunk=chunk))
+            mixer = jax.jit(lambda p, z: hybrid_moe.gdn(
+                p, z, real, w_cfg, jnp.float32))
+            check(f"hybrid_moe gdn at heads of 128, chunk {chunk}, "
+                  "convolves through the kernel and scans through XLA",
+                  kernels.kernels_of(mixer, wp, z) == {short_conv.KERNEL})
+            got, low = mixer(wp, z)
+            zoo[f"hybrid_moe.gdn.chunk{chunk}"] = {
+                "max_abs_diff": check.close(
+                    f"hybrid_moe gdn at heads of 128, chunk {chunk}: "
+                    "chunked scan vs recurrence", np.asarray(got) * keep,
+                    want * keep, 2e-2),
+                "log_decay_min": float(low)}
+
+    # its gated attention at the served heads (hidden 256, 16 query heads
+    # on 2 key-value heads of 256, the first 64 dims turned): Mosaic
+    # compiles the causal-attention kernel at a query-key and value width
+    # of 256 and 8 query heads a key-value head, and the device's answer is
+    # the plain reference's full masked softmax with its norms, turn and
+    # gate
+    wide = dict(small, hidden_size=256, num_attention_heads=16,
+                num_key_value_heads=2, head_dim=256, layers_kept=[3])
+    a_cfg = hybrid_moe.HybridConfig.from_dict(wide)
+    ap = jax.jit(lambda: gdn_moe_f32.make_params(wide)["layers"][0][
+        "mixer"])()
+    attend = jax.jit(lambda p, z: hybrid_moe.gqa(p, z, real, a_cfg,
+                                                 jnp.bfloat16, position))
+    check("hybrid_moe gated gqa at heads of 256 attends through the kernel",
+          kernels.kernels_of(attend, ap, z) == {causal_attention.KERNEL})
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(gdn_moe_f32.attention(ap, z, real, position, wide))
+    zoo["hybrid_moe.gqa.gated"] = {"max_abs_diff": check.close(
+        "hybrid_moe gated gqa at heads of 256: kernel vs full softmax",
+        np.asarray(attend(ap, z), np.float32) * keep, want * keep, 5e-2)}
+
     # the KDA mixer at heads a lane tile wide, which the first preset's
     # 16-wide heads are not (2 heads of 128, 768 tokens = six spans of the
     # kernel, one row padded on the left past the first chunks): Mosaic
@@ -638,7 +730,7 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     # holds it and the attention's and nothing between them, and the
     # device's q, k and v are the plain chain's through XLA at every token
     from benchmark.reference import cca_moe_f32
-    from ccfd_tpu.ops import causal_attention, cca_conv
+    from ccfd_tpu.ops import cca_conv
 
     with open(os.path.join(ROOT, "tests", "benchmark",
                            "zaya1_small_config.json")) as f:

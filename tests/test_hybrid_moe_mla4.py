@@ -495,7 +495,7 @@ def test_a_model_is_its_kinds_settings_and_what_all_share(preset, wants):
         "eps", "layers", "mixers", "router", "routing", "routed",
         "held_first", "held_count", "per_token", "bins", "fraud_id",
         "legit_id", "shift", "residual", "residual_settings", "tied_head",
-        "embed_scale", "logit_divisor", "expert_body"}
+        "embed_scale", "logit_divisor", "expert_body", "norm_offset"}
     described = registry.get_history("hybrid_moe").describe(cfg)
     assert described["residual"] == cfg.residual
     assert set(described["kinds"]) == {name for name, _ in cfg.mixers} | (
