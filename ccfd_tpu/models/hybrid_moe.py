@@ -36,7 +36,9 @@ value heads, each key head read by several value heads; one causal
 convolution without a bias over q, k and v together; L2-normed q and k;
 S_t = e^(g_t) S_(t-1) + beta_t k_t (v_t - (e^(g_t) S_(t-1))^T k_t)^T, o_t =
 S_t^T q_t; an RMS norm over each head's values times SiLU of a gate;
-served as a chunked scan whose chunk is the deployment's ``gdn_chunk``).
+served as a chunked scan whose chunk is the deployment's ``gdn_chunk``:
+``ops/gdn_scan.py``'s kernel where it fits, else the loop over
+``_gdn_chunk`` through XLA).
 Routers of the **sparse expert layer**: ``top_k`` (sigmoid or softmax
 scores over all
 routed experts, an optional expert bias for the choice, group-limited or
@@ -127,9 +129,10 @@ in- and out-projections, ``mamba.conv``, ``mamba.scan``: steps, decays and
 the chunked scan, ``mamba.gate``: the gate and its norm), ``gqa`` (inside it
 ``gqa.project`` and ``gqa.attend``, which holds :func:`_causal_attention`
 too), ``gdn`` (inside it ``gdn.project``: the two in-projections and the
-out-projection, ``gdn.conv``: the convolution, SiLU and the L2 norms,
-``gdn.scan``: beta, the decays and the chunked scan, ``gdn.gate``: the
-per-head norm and SiLU(z)), ``dense_ffn``, ``moe.route``,
+out-projection, ``gdn.conv``: the convolution and SiLU, ``gdn.scan``:
+beta, the decays, the L2 norms of q and k (inside the kernel where the scan
+is ``ops/gdn_scan.py``'s) and the chunked scan, ``gdn.gate``: the per-head
+norm and SiLU(z)), ``dense_ffn``, ``moe.route``,
 ``moe.experts``, ``moe.shared``, ``lm.head``, and ``hc`` around everything
 the ``mhc`` rule adds (inside it ``hc.maps``: the flattened norm, the
 product, the sigmoids and Sinkhorn; ``hc.mix``: the sublayer's input from
@@ -146,8 +149,8 @@ from typing import Any, Mapping
 import jax
 import jax.numpy as jnp
 
-from ccfd_tpu.ops import (causal_attention, cca_conv, grouped_experts,
-                          kda_scan, short_conv, ssd_scan)
+from ccfd_tpu.ops import (causal_attention, cca_conv, gdn_scan,
+                          grouped_experts, kda_scan, short_conv, ssd_scan)
 
 Params = Mapping[str, Any]
 
@@ -1453,22 +1456,52 @@ def _gdn_chunk(state, chunk):
     return state, out
 
 
-def _scalar_delta_scan(q, k, v, g, beta, c: int):
+def _scalar_delta_scan(q, k, v, g, beta, c: int, l2=None, gate=None):
     """The delta rule with one decay a value head over a window from a
     zero state: ``q``, ``k`` (B, T, Hk, dk) and ``v`` (B, T, Hv, dv) in the
     compute dtype, the log-decays ``g`` <= 0 and ``beta`` (B, T, Hv)
     float32 -> o (B, T, Hv, dv) float32, the state moving ``c`` tokens at a
-    time: the loop over :func:`_gdn_chunk` through XLA, :func:`_delta_scan`'s
-    sibling (no kernel computes this recurrence yet). A padding token has g
-    = beta = 0 and passes the state unchanged."""
+    time (scope ``gdn.scan``). What ``gdn`` does around the scan rides
+    along, so that the kernel can take it in: with ``l2`` = (epsilon,
+    compute dtype), q, k and v arrive as the convolution leaves them: q
+    and k are L2-normed by head first (q times dk^-0.5) and all three
+    rounded to that dtype; with ``gate`` = (proj, column, weight, epsilon),
+    o leaves RMS-normed by head times weight, times SiLU of ``proj``'s Hv x
+    dv columns from ``column``, in the compute dtype (scope ``gdn.gate``
+    where XLA does it). Two paths, one recurrence at one precision, chosen
+    while the program is traced (``ops/gdn_scan.py::kernel_fits`` says
+    where): the Pallas kernel, or the loop over :func:`_gdn_chunk` through
+    XLA, :func:`_delta_scan`'s sibling and the definition the tests hold
+    the kernel against. A padding token has g = beta = 0 and passes the
+    state unchanged. The kernel has no derivative; nothing differentiates
+    this family (it is served only)."""
+    eps, dtype = l2 or (None, q.dtype)
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2:]
-    per = hv // hk
-    o = _chunk_loop(
-        (q, k, v.reshape(b, t, hk, per, dv), g.reshape(b, t, hk, per),
-         beta.reshape(b, t, hk, per)),
-        jnp.zeros((b, hk, per, dk, dv), F32), _gdn_chunk, c, (hk, per, dv))
-    return o.reshape(b, t, hv, dv)
+    proj, at, weight, gate_eps = gate or (None, 0, None, None)
+    with jax.named_scope("gdn.scan"):
+        if gdn_scan.kernel_fits(q, v, c, dtype):
+            return gdn_scan.gdn_scan(
+                q, k, v, g, beta, chunk=c, unit=eps, dtype=dtype, z=proj,
+                norm=weight, at=at, eps=gate_eps)
+        per = hv // hk
+        if l2 is not None:
+            def unit(x):
+                return x * jax.lax.rsqrt(
+                    jnp.sum(x * x, -1, keepdims=True) + eps)
+
+            q = (unit(q) * dk ** -0.5).astype(dtype)
+            k, v = unit(k).astype(dtype), v.astype(dtype)
+        o = _chunk_loop(
+            (q, k, v.reshape(b, t, hk, per, dv), g.reshape(b, t, hk, per),
+             beta.reshape(b, t, hk, per)),
+            jnp.zeros((b, hk, per, dk, dv), F32), _gdn_chunk, c,
+            (hk, per, dv)).reshape(b, t, hv, dv)
+    if gate is None:
+        return o
+    with jax.named_scope("gdn.gate"):
+        return (_rms(o, weight, gate_eps) * jax.nn.silu(
+            proj[..., at:at + hv * dv].reshape(b, t, hv, dv))).astype(dtype)
 
 
 def gdn(p, z, real, cfg: HybridConfig, dtype):
@@ -1477,7 +1510,7 @@ def gdn(p, z, real, cfg: HybridConfig, dtype):
     z] and one to [b | a], the causal depthwise convolution and SiLU over
     q, k and v together (no bias), q and k L2-normed by head, beta =
     sigmoid(b) and g = -exp(A_log) softplus(a + dt_bias) a value head, the
-    chunked scan (:func:`_gdn_chunk`) in float32, an RMS norm over each
+    chunked scan (:func:`_scalar_delta_scan`) in float32, an RMS norm over each
     head's values (times w, whatever ``norm_offset``) times SiLU(z), the
     output projection."""
     b, t, _ = z.shape
@@ -1491,23 +1524,14 @@ def gdn(p, z, real, cfg: HybridConfig, dtype):
     with jax.named_scope("gdn.conv"):
         q, k, v = _conv_silu(proj, p["conv"], jnp.zeros((
             2 * keys + values,), F32), keep, 0, (keys, keys, values))
-
-        def unit(x):
-            x = x.reshape(b, t, hk, dk)
-            return x * jax.lax.rsqrt(
-                jnp.sum(x * x, -1, keepdims=True) + L2_EPS)
-
-        q = (unit(q) * dk ** -0.5).astype(dtype)
-        k = unit(k).astype(dtype)
-        v = v.reshape(b, t, hv, dv).astype(dtype)
     with jax.named_scope("gdn.scan"):
         beta = jax.nn.sigmoid(ba[..., :hv]) * keep
         g = -jnp.exp(p["a_log"]) * jax.nn.softplus(
             ba[..., hv:] + p["dt_bias"]) * keep
-        o = _scalar_delta_scan(q, k, v, g, beta, s.chunk)
-    with jax.named_scope("gdn.gate"):
-        o = _rms(o, p["norm"], cfg.eps) * jax.nn.silu(
-            proj[..., 2 * keys + values:].reshape(b, t, hv, dv))
+    o = _scalar_delta_scan(
+        q.reshape(b, t, hk, dk), k.reshape(b, t, hk, dk),
+        v.reshape(b, t, hv, dv), g, beta, s.chunk, (L2_EPS, dtype),
+        (proj, 2 * keys + values, p["norm"], cfg.eps))
     with jax.named_scope("gdn.project"):
         return _mm(o.reshape(b, t, values), p["w_out"], dtype), g.min()
 

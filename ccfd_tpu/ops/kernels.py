@@ -67,6 +67,11 @@ FAMILIES = (
            "attention in the token-major kernel that convolves the latent",
            "walked that chain through XLA, or have no such mixer",
            ("cca_conv",)),
+    Family("gdn_kernel", "seq_gdn_kernel_dispatch_total",
+           "Gated DeltaNet mixers scan through the kernel that keeps a "
+           "span's factors and the value heads' states on the chip",
+           "looped over chunks through XLA, or have no such mixer",
+           ("gdn_scan",)),
 )
 
 

@@ -623,12 +623,17 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
         "grid": grid}
 
     # its Gated DeltaNet mixer at the served heads (hidden 256, 2 key heads
-    # on 4 value heads of 128; 768 tokens, one row padded on the left past
-    # the first chunks): Mosaic compiles the convolution's kernel over q, k
-    # and v at offset 0 of the wide projection, the scalar-decay scan runs
-    # through XLA (no kernel computes it yet), and the device's answer is
-    # the delta rule's a token at a time of the plain reference, both in
-    # float32
+    # on 4 value heads of 128; 768 tokens = six spans, one row padded on the
+    # left past the first chunks): Mosaic compiles the convolution's kernel
+    # over q, k and v at offset 0 of the wide projection and the
+    # scalar-decay delta rule's (ops/gdn_scan.py: the L2 norms inside) at
+    # the served chunk and a longer one, and the device's answer is the
+    # loop's over ``_gdn_chunk`` through XLA and the delta rule's a token at
+    # a time of the plain reference
+    from unittest import mock
+
+    from ccfd_tpu.ops import gdn_scan
+
     wide = dict(small, hidden_size=256, linear_num_key_heads=2,
                 linear_num_value_heads=4, linear_key_head_dim=128,
                 linear_value_head_dim=128, layers_kept=[0])
@@ -641,22 +646,31 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
                            - jnp.array([[0], [200]]), 0)
     keep = np.asarray(real)[..., None]
     with jax.default_matmul_precision("highest"):
-        want = np.asarray(gdn_moe_f32.gdn(wp, z, real, wide))
-        for chunk in (64, 128):
-            w_cfg = hybrid_moe.HybridConfig.from_dict(
-                dict(wide, gdn_chunk=chunk))
-            mixer = jax.jit(lambda p, z: hybrid_moe.gdn(
-                p, z, real, w_cfg, jnp.float32))
-            check(f"hybrid_moe gdn at heads of 128, chunk {chunk}, "
-                  "convolves through the kernel and scans through XLA",
-                  kernels.kernels_of(mixer, wp, z) == {short_conv.KERNEL})
-            got, low = mixer(wp, z)
-            zoo[f"hybrid_moe.gdn.chunk{chunk}"] = {
-                "max_abs_diff": check.close(
-                    f"hybrid_moe gdn at heads of 128, chunk {chunk}: "
-                    "chunked scan vs recurrence", np.asarray(got) * keep,
-                    want * keep, 2e-2),
-                "log_decay_min": float(low)}
+        want = np.asarray(gdn_moe_f32.gdn(wp, z, real, wide)) * keep
+    for chunk in (16, 64):
+        w_cfg = hybrid_moe.HybridConfig.from_dict(dict(wide, gdn_chunk=chunk))
+
+        def gdn_mixer():
+            return jax.jit(lambda p, z: hybrid_moe.gdn(p, z, real, w_cfg,
+                                                       jnp.bfloat16))
+
+        check(f"hybrid_moe gdn at heads of 128, chunk {chunk}, convolves "
+              "and scans through the kernels",
+              kernels.kernels_of(gdn_mixer(), wp, z)
+              == {short_conv.KERNEL, gdn_scan.KERNEL})
+        got, low = gdn_mixer()(wp, z)
+        got = np.asarray(got) * keep
+        with mock.patch.object(gdn_scan, "kernel_fits", return_value=False):
+            through_xla = np.asarray(gdn_mixer()(wp, z)[0]) * keep
+        zoo[f"hybrid_moe.gdn.chunk{chunk}"] = {
+            "max_abs_diff_xla": check.close(
+                f"hybrid_moe gdn at heads of 128, chunk {chunk}: kernel vs "
+                "the loop through XLA", got, through_xla, 2e-2),
+            "max_abs_diff": check.close(
+                f"hybrid_moe gdn at heads of 128, chunk {chunk}: kernel vs "
+                "recurrence", got, want, 5e-2),
+            "xla_vs_recurrence": float(np.abs(through_xla - want).max()),
+            "log_decay_min": float(low)}
 
     # its gated attention at the served heads (hidden 256, 16 query heads
     # on 2 key-value heads of 256, the first 64 dims turned): Mosaic
@@ -685,8 +699,6 @@ def phase_zoo(check: Checks, report: dict, platform: str) -> None:
     # compiles the delta rule's kernel (ops/kda_scan.py), and the device's
     # answer is the loop's over ``_kda_chunk`` through XLA and the
     # recurrence's a token at a time of the plain reference
-    from unittest import mock
-
     from ccfd_tpu.ops import kda_scan
 
     with open(os.path.join(ROOT, "tests", "benchmark",

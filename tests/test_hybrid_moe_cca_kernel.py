@@ -463,6 +463,7 @@ def test_the_lane_wide_program_holds_the_kernel(wide):
     held = kernels.held(_program(cfg), params, *_records())
     assert held["cca_kernel"] == 1
     assert held["conv_kernel"] == held["ssd_kernel"] == held["kda_kernel"] == 0
+    assert held["gdn_kernel"] == 0
 
 
 @pytest.mark.parametrize("name,ref", [

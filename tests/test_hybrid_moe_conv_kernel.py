@@ -399,7 +399,7 @@ def test_the_lane_wide_program_holds_both_kernels(wide):
     ref, config, params, cfg = wide
     held = kernels.held(_program(cfg), params, *_window())
     assert held["conv_kernel"] == held["ssd_kernel"] == 1
-    assert held["kda_kernel"] == 0
+    assert held["kda_kernel"] == held["gdn_kernel"] == 0
 
 
 def test_the_small_program_holds_neither_kernel(small):

@@ -576,7 +576,8 @@ def test_a_keyed_stream_through_the_scorer_equals_the_reference(
         assert entry["scan_chunk"] == min(32, entry["l_bucket"] * COLS)
         # heads of 16, experts 32 wide: through XLA
         assert (entry["ssd_kernel"], entry["expert_kernel"],
-                entry["attn_kernel"], entry["kda_kernel"]) == (False,) * 4
+                entry["attn_kernel"], entry["kda_kernel"],
+                entry["gdn_kernel"]) == (False,) * 5
     total = {k: reg.counter(k).total() for k in (
         "moe_pairs_served_total", "moe_pairs_routed_total",
         "moe_pairs_absent_total", "moe_routed_tokens_total",

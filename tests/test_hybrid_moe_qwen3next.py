@@ -458,13 +458,13 @@ def test_at_the_cells_shapes_a_layer_holds_the_kernels(layer, mixer):
     """One layer of the real configuration on 8 windows of 1,920 tokens,
     traced and not run: the Gated DeltaNet layer's jaxpr holds the
     ``short_conv`` kernel (q | k | v are 64 lane tiles at offset 0 of the
-    12,288-wide projection) and no scan kernel (``kda_scan``'s decays are a
-    vector a head; none computes the scalar-decay rule yet), the attention
+    12,288-wide projection) and, since PR 53, the ``gdn_scan`` kernel and
+    no other scan's (``kda_scan``'s decays are a vector a head), the attention
     layer's the ``causal_attention`` kernel (16 : 2 heads of 256), both the
     grouped expert kernels (2,048 x 512, 300 pairs an expert: tiles of
     128)."""
-    from ccfd_tpu.ops import (causal_attention, cca_conv, grouped_experts,
-                              kda_scan, short_conv, ssd_scan)
+    from ccfd_tpu.ops import (causal_attention, cca_conv, gdn_scan,
+                              grouped_experts, kda_scan, short_conv, ssd_scan)
 
     real = dict(_real_config(), layers_kept=[layer])
     cfg = hm.HybridConfig.from_dict(real)
@@ -476,6 +476,7 @@ def test_at_the_cells_shapes_a_layer_holds_the_kernels(layer, mixer):
         jax.ShapeDtypeStruct((8,), np.int32))
     assert set(grouped_experts.KERNELS) <= held
     assert (short_conv.KERNEL in held) == (mixer == "gdn")
+    assert (gdn_scan.KERNEL in held) == (mixer == "gdn")
     assert (causal_attention.KERNEL in held) == (mixer == "gqa")
     assert not held & {kda_scan.KERNEL, ssd_scan.KERNEL, cca_conv.KERNEL}
     assert grouped_experts.row_tile(8 * 1920 * 10 / 512) == 128
@@ -520,7 +521,8 @@ def test_a_keyed_stream_through_the_scorer_equals_the_reference(
         assert "scan_chunk" not in entry
         assert (entry["conv_kernel"], entry["expert_kernel"],
                 entry["attn_kernel"], entry["kda_kernel"],
-                entry["ssd_kernel"], entry["cca_kernel"]) == (False,) * 6
+                entry["ssd_kernel"], entry["cca_kernel"],
+                entry["gdn_kernel"]) == (False,) * 7
     total = {k: reg.counter(k).total() for k in (
         "moe_pairs_served_total", "moe_pairs_routed_total",
         "moe_pairs_absent_total", "moe_routed_tokens_total",

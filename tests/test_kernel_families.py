@@ -1,5 +1,5 @@
 """The kernels' seam (ops/kernels.py): the table of families against the
-kernel modules, the names a dashboard reads, what the seven ``kernel_fits``
+kernel modules, the names a dashboard reads, what the eight ``kernel_fits``
 answer at the shapes the kernel tests carry, and that a row of the table is
 all a family's key and counter take to appear."""
 
@@ -18,9 +18,9 @@ import ccfd_tpu.ops
 from ccfd_tpu.analysis.rules import metric_name_ok
 from ccfd_tpu.metrics.prom import Registry
 from ccfd_tpu.models import seq
-from ccfd_tpu.ops import (causal_attention, cca_conv, grouped_experts,
-                          kda_scan, kernels, seq_attention, short_conv,
-                          ssd_scan)
+from ccfd_tpu.ops import (causal_attention, cca_conv, gdn_scan,
+                          grouped_experts, kda_scan, kernels, seq_attention,
+                          short_conv, ssd_scan)
 from ccfd_tpu.serving import history
 
 BF16, F32, F16 = jnp.bfloat16, jnp.float32, jnp.float16
@@ -42,7 +42,7 @@ def test_every_module_that_names_a_kernel_has_a_row_and_every_row_a_module():
 
 def test_every_kernel_name_belongs_to_exactly_one_family():
     claimed = [name for family in kernels.FAMILIES for name in family.names]
-    assert len(claimed) == len(set(claimed)) == 9
+    assert len(claimed) == len(set(claimed)) == 10
     assert all(isinstance(name, str) and name for name in claimed)
     for family in kernels.FAMILIES:
         assert set(family.names) == {
@@ -52,7 +52,7 @@ def test_every_kernel_name_belongs_to_exactly_one_family():
 
 @pytest.mark.parametrize("module", [seq_attention, causal_attention,
                                     grouped_experts, ssd_scan, kda_scan,
-                                    short_conv, cca_conv],
+                                    short_conv, cca_conv, gdn_scan],
                          ids=lambda m: m.__name__.rsplit(".", 1)[1])
 def test_a_module_hands_pallas_call_no_name_but_those_it_declares(module):
     """The ``name=`` keywords of the module's calls, read from its source:
@@ -80,10 +80,11 @@ def test_the_names_on_the_wire_are_the_ones_dashboards_read():
         ("ssd_kernel", "seq_ssd_kernel_dispatch_total", ("ssd_scan",)),
         ("kda_kernel", "seq_kda_kernel_dispatch_total", ("kda_scan",)),
         ("conv_kernel", "seq_conv_kernel_dispatch_total", ("short_conv",)),
-        ("cca_kernel", "seq_cca_kernel_dispatch_total", ("cca_conv",))]
+        ("cca_kernel", "seq_cca_kernel_dispatch_total", ("cca_conv",)),
+        ("gdn_kernel", "seq_gdn_kernel_dispatch_total", ("gdn_scan",))]
     assert kernels.held(lambda x: x, 1.0) == {
         "attn_kernel": 0, "expert_kernel": 0, "ssd_kernel": 0,
-        "kda_kernel": 0, "conv_kernel": 0, "cca_kernel": 0}
+        "kda_kernel": 0, "conv_kernel": 0, "cca_kernel": 0, "gdn_kernel": 0}
     assert kernels.FAMILIES[0].help == (
         "seq dispatches of executables whose attention holds a kernel that "
         "keeps the scores on the chip (beside seq_bucket_dispatch_total: "
@@ -99,7 +100,7 @@ def test_a_familys_counter_keeps_the_naming_rule(family):
     assert family.help.count("seq_bucket_dispatch_total") == 1
 
 
-# -- what the seven kernel_fits answer ----------------------------------------------------
+# -- what the eight kernel_fits answer ----------------------------------------------------
 
 def _shape(dims, dtype, mesh=None):
     from jax.sharding import NamedSharding, PartitionSpec
@@ -149,6 +150,12 @@ def _cca(dims, dtype, mesh):
         _shape((2, heads + groups, head, head), dtype), dtype)
 
 
+def _gdn(dims, dtype, mesh):
+    q, v, chunk = dims
+    return gdn_scan.kernel_fits(_shape(q, dtype), _shape(v, dtype, mesh),
+                                chunk)
+
+
 # (family, which shape, its dimensions, whether the parent's ``kernel_fits``
 # took it in bfloat16 and float32 on one device): written out from the
 # parent's code before ``ops/kernels.py`` took over the common part
@@ -182,8 +189,12 @@ FITS = [
     (_cca, "served", ((8, 1920), 2048, 8, 2, 128), True),
     (_cca, "lane_wide", ((3, 240), 128, 4, 2, 128), True),
     (_cca, "small", ((3, 240), 64, 8, 2, 16), False),
+    # and PR 53's
+    (_gdn, "served", ((8, 1920, 16, 128), (8, 1920, 32, 128), 16), True),
+    (_gdn, "lane_wide", ((2, 240, 1, 128), (2, 240, 2, 128), 32), True),
+    (_gdn, "small", ((3, 240, 2, 16), (3, 240, 4, 16), 32), False),
 ]
-ASKS_ABOUT_A_MESH = (_experts, _ssd, _kda, _conv, _cca)
+ASKS_ABOUT_A_MESH = (_experts, _ssd, _kda, _conv, _cca, _gdn)
 
 
 @pytest.fixture(scope="module")
@@ -198,7 +209,7 @@ def mesh():
 def test_kernel_fits_answers_as_the_parents_did(fits, which, dims, taken,
                                                 mesh):
     """By dtype, with an operand on a mesh and under an abstract mesh: the
-    grouped experts, the two scans and the two convolutions refuse either mesh; the two
+    grouped experts, the three scans and the two convolutions refuse either mesh; the two
     attentions never ask (``SeqScorer`` hands each device its rows under
     ``shard_map`` itself) and answer under an abstract mesh as without."""
     meshed = fits in ASKS_ABOUT_A_MESH
@@ -232,7 +243,7 @@ def test_a_further_row_brings_its_key_and_its_counter_with_no_other_edit(
         monkeypatch):
     """A table of the test's own, with one more family that counts ``seq``'s
     kernel alone: the inventory, the ``seq.enqueue`` phase and the registry
-    carry it beside the six, and ``serving/history.py`` was not told."""
+    carry it beside the seven, and ``serving/history.py`` was not told."""
     fifth = kernels.Family(
         "own_kernel", "seq_own_kernel_dispatch_total", "attention is seq's",
         "have another", ("seq_attention",))
@@ -252,7 +263,7 @@ def test_a_further_row_brings_its_key_and_its_counter_with_no_other_edit(
     scorer.score(np.zeros((3, 30), np.float32), ids=["a", "b", "c"])
     want = {"attn_kernel": 1, "expert_kernel": 0, "ssd_kernel": 0,
             "kda_kernel": 0, "conv_kernel": 0, "cca_kernel": 0,
-            "own_kernel": 1}
+            "gdn_kernel": 0, "own_kernel": 1}
     (stats,) = enqueued
     assert {key: stats[key] for key in want} == want
     assert list(stats)[list(stats).index("tokens") + 1:][:len(want) + 1] == [
@@ -264,7 +275,7 @@ def test_a_further_row_brings_its_key_and_its_counter_with_no_other_edit(
     assert reg.counter("seq_attention_kernel_dispatch_total").total() == 1
     assert reg.counter("seq_expert_kernel_dispatch_total").total() == 0
     assert fifth.help in reg.render()
-    # a stand-in for the program holds none of the seven
+    # a stand-in for the program holds none of the eight
     real = scorer._apply
     scorer._apply = lambda p, xs: real(p, xs)
     (entry,) = scorer.executable_grid()["grid"]
@@ -347,4 +358,68 @@ def test_a_program_without_the_mixer_holds_no_cca_kernel(name, module):
             jax.eval_shape(lambda: ref.make_params(config)),
             jax.ShapeDtypeStruct((2, 8, 30), np.float32),
             jax.ShapeDtypeStruct((2,), np.int32))
-    assert held["cca_kernel"] == 0
+    assert held["cca_kernel"] == held["gdn_kernel"] == 0
+
+
+# -- the Gated DeltaNet family's row, end to end -------------------------------------------
+
+@pytest.mark.parametrize("widths,held", [
+    ({"linear_num_key_heads": 1, "linear_num_value_heads": 2,
+      "linear_key_head_dim": 128, "linear_value_head_dim": 128}, 1),
+    ({}, 0)], ids=["lane_wide", "small"])
+def test_the_gdn_row_is_on_the_enqueue_phase_in_the_grid_and_counted(
+        monkeypatch, widths, held):
+    """A ``qwen3_next`` program served through ``SeqScorer``: with heads of
+    128 its Gated DeltaNet mixers hold ``ops/gdn_scan.py``'s kernel (and
+    ``ops/short_conv.py``'s in front of it), and ``gdn_kernel`` on every
+    ``seq.enqueue``, the executable's entry in ``executable_grid()`` and
+    ``seq_gdn_kernel_dispatch_total`` beside ``seq_bucket_dispatch_total``
+    say so; with the small preset's heads of 16 they say 0 of the same
+    dispatches."""
+    from benchmark.reference import gdn_moe_f32
+    from ccfd_tpu.models import hybrid_moe
+
+    config = {**_small_config("qwen3next"), **widths, "layers_kept": [0, 3]}
+    enqueued = []
+    phase = history.phase
+
+    def recorded(name, **stats):
+        if name == "seq.enqueue":
+            enqueued.append(stats)
+        return phase(name, **stats)
+
+    monkeypatch.setattr(history, "phase", recorded)
+    reg = Registry()
+    scorer = history.SeqScorer(
+        gdn_moe_f32.make_params(config), length=8, batch_sizes=(4,),
+        compute_dtype="float32", registry=reg, family="hybrid_moe",
+        family_config=hybrid_moe.HybridConfig.from_dict(config))
+    for _ in range(2):
+        scorer.score(np.zeros((3, 30), np.float32), ids=["a", "b", "a"])
+    assert [e["gdn_kernel"] for e in enqueued] == [held] * 2
+    assert [e["conv_kernel"] for e in enqueued] == [held] * 2
+    assert [e["kda_kernel"] for e in enqueued] == [0] * 2
+    (entry,) = scorer.executable_grid()["grid"]
+    assert entry["gdn_kernel"] is bool(held) and entry["dispatches"] == 2
+    assert reg.counter("seq_bucket_dispatch_total").total() == 2
+    assert reg.counter("seq_gdn_kernel_dispatch_total").total() == 2 * held
+    assert reg.counter("seq_kda_kernel_dispatch_total").total() == 0
+
+
+@pytest.mark.parametrize("name,module", [
+    ("ling3", "hybrid_moe_f32"), ("mistral4", "mla_moe_f32"),
+    ("zaya1", "cca_moe_f32"), ("xing4", "mhc_moe_f32"),
+    ("granite4h", "ssm_moe_f32"), ("nemotron3n", "ssm_relu2_moe_f32")])
+def test_a_program_without_the_mixer_holds_no_gdn_kernel(name, module):
+    """The six other ``hybrid_moe`` models at their small presets."""
+    from ccfd_tpu.models import hybrid_moe
+
+    config = _small_config(name)
+    ref = importlib.import_module("benchmark.reference." + module)
+    cfg = hybrid_moe.HybridConfig.from_dict(config)
+    held = kernels.held(
+        lambda p, h, f: hybrid_moe.apply_serving(p, h, f, cfg, F32),
+        jax.eval_shape(lambda: ref.make_params(config)),
+        jax.ShapeDtypeStruct((2, 8, 30), np.float32),
+        jax.ShapeDtypeStruct((2,), np.int32))
+    assert held["gdn_kernel"] == 0

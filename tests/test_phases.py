@@ -243,6 +243,7 @@ def test_a_capture_around_the_scorer_holds_its_phases(tmp_path):
         assert e[3]["expert_kernel"] == 0  # ``seq`` has no experts
         assert e[3]["ssd_kernel"] == 0  # and no state-space mixer
         assert e[3]["kda_kernel"] == 0  # nor a KDA one
+        assert e[3]["gdn_kernel"] == 0  # nor a Gated DeltaNet one
         assert e[3]["flat_wire"] == 0  # 240 values a row: no whole tile
     assert [e[3]["padded_rows"] for e in cap.named("seq.pad")] == [0, 0, 8]
     assert sum(e[3]["rows"] for e in cap.named("seq.wait")) == 40
@@ -265,6 +266,7 @@ def test_the_enqueue_phase_says_which_wire_the_batch_crossed(tmp_path):
     assert enqueue[3]["expert_kernel"] == 0
     assert enqueue[3]["ssd_kernel"] == 0
     assert enqueue[3]["kda_kernel"] == 0
+    assert enqueue[3]["gdn_kernel"] == 0
     assert enqueue[3]["bytes"] == 4 * 512 * 30 * 4
     assert (enqueue[3]["b_bucket"], enqueue[3]["l_bucket"]) == (4, 512)
 
@@ -335,6 +337,7 @@ def test_the_enqueue_phase_says_which_kernels_the_program_holds(
             for e in enqueues] == [
         (int(experts), int(scan), int(scan), int(delta), chunk)] * 2
     assert [e[3]["attn_kernel"] for e in enqueues] == [0, 0]  # heads of 16
+    assert [e[3]["gdn_kernel"] for e in enqueues] == [0, 0]  # no such mixer
     assert [(g["b_bucket"], g["expert_kernel"], g["ssd_kernel"],
              g["conv_kernel"], g["kda_kernel"], g["attn_kernel"],
              g.get("scan_chunk"), g["dispatches"])
@@ -349,6 +352,7 @@ def test_the_enqueue_phase_says_which_kernels_the_program_holds(
         2 if scan else 0)
     assert reg.counter("seq_kda_kernel_dispatch_total").total() == (
         2 if delta else 0)
+    assert reg.counter("seq_gdn_kernel_dispatch_total").total() == 0
 
 
 def test_a_deferred_batch_waits_and_commits_inside_the_next_call(tmp_path):
