@@ -559,7 +559,7 @@ def slo_dashboard() -> dict:
                ['ccfd_stage_latency_ms{stage="bus",component="queue",quantile="p99"}',
                 'ccfd_stage_latency_ms{stage="router.score",component="dispatch",quantile="p99"}']),
         _alert_stat(9, "XLA compiles under traffic / s",
-                    ["rate(ccfd_xla_compile_events_total[5m])"],
+                    ['rate(ccfd_xla_compile_events_total{cache="miss"}[5m])'],
                     red_above=0.1),
         _panel(10, "Cumulative XLA compile seconds",
                ["ccfd_xla_compile_seconds_total"]),
@@ -593,7 +593,7 @@ def device_dashboard() -> dict:
         _panel(4, "Compile seconds by stage",
                ["ccfd_compile_stage_seconds_total"]),
         _alert_stat(5, "XLA compiles under traffic / s",
-                    ["rate(ccfd_xla_compile_events_total[5m])"],
+                    ['rate(ccfd_xla_compile_events_total{cache="miss"}[5m])'],
                     red_above=0.1),
         _panel(6, "Flight-recorder snapshots / s (by reason)",
                ["rate(ccfd_incident_snapshots_total[5m])"]),

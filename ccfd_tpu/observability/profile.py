@@ -26,10 +26,24 @@ that, live:
      by name, so stages with no direct feed (producer, engine REST,
      notify, serving) profile for free wherever tracing is on.
 
-  XLA compile events attribute through a ``jax.monitoring`` duration
-  listener (``backend_compile``): a stage whose p99 spikes because a new
-  executable compiled mid-traffic shows the compile in the same profile
-  (`compile` section + ``ccfd_xla_compile_events_total``), and
+  JAX's own start-up events attribute through ONE ``jax.monitoring`` hook
+  (:func:`hear_compile_events`), registered when the process's start-up
+  trace opens or a profiler is armed, whichever is first: a jit's trace
+  (``jaxpr_trace_duration``), its conversion to MLIR
+  (``jaxpr_to_mlir_module_duration``), ``backend_compile_duration`` and
+  the persistent cache's ``cache_retrieval_time_sec`` / ``cache_hits``.
+  Each is billed to the innermost open :func:`billed` start-up phase on
+  the compiling thread (``startup.executable`` / ``startup.inventory`` of
+  ``observability/trace.py``'s start-up record: ``trace_s``, ``lower_s``,
+  ``compile_s``, ``cache_load_s``, ``traces`` ...) and, where a profiler
+  is armed, a backend compile to the stage :func:`compile_stage` names. A
+  ``backend_compile_duration`` that followed a cache hit is a LOAD, not a
+  compile (JAX wraps ``compile_or_get_cached`` in the one event): it
+  counts under ``ccfd_xla_compile_events_total{cache="hit"}`` and in
+  ``compile_counts(cache="hit")``, so a warm start reads as no compile
+  storm; a stage whose p99 spikes because a new executable compiled
+  mid-traffic shows the compile in the same profile (`compile` section +
+  ``ccfd_xla_compile_events_total{cache="miss"}``), and
   :meth:`StageProfiler.profile_device` wraps ``jax.profiler.trace`` for
   the deep device-level view.
 
@@ -56,6 +70,8 @@ import threading
 import time
 import weakref
 from typing import Any, Iterator, Mapping
+
+from ccfd_tpu.observability import trace
 
 PROFILE_SCHEMA = "ccfd.stage_profile.v1"
 
@@ -205,17 +221,32 @@ def _batch_bucket(n: int) -> int:
 
 
 # jax.monitoring listeners are process-global with no unregister: one hook,
-# registered once, forwarding to the CURRENT profiler via weakref (see
-# StageProfiler.arm_compile_listener)
-_COMPILE_HOOK_REGISTERED = False
+# registered once (hear_compile_events), forwarding a backend compile to the
+# CURRENT profiler via weakref (see StageProfiler.arm_compile_listener)
+_HOOK_MU = threading.Lock()
+_HOOK_REGISTERED = False
 _COMPILE_TARGET: "weakref.ref[StageProfiler] | None" = None
 
-# per-stage compile attribution: backend_compile events fire synchronously
-# on the compiling thread, so a contextvar label set by the component that
+# per-stage compile attribution: JAX's events fire synchronously on the
+# compiling thread, so a contextvar label set by the component that
 # triggered the compile (scorer warmup, a seq variant swap, a live
-# re-trace) names the stage the compile bills to
+# re-trace) names the stage the compile bills to, and the innermost open
+# start-up phase (billed) the executable that paid
 _COMPILE_STAGE: contextvars.ContextVar[str] = contextvars.ContextVar(
     "ccfd_compile_stage", default="untagged")
+_BILL: "contextvars.ContextVar[_Bill | None]" = contextvars.ContextVar(
+    "ccfd_startup_bill", default=None)
+# ``cache_hits`` arrives before the ``backend_compile_duration`` it belongs
+# to, on the same thread
+_THREAD = threading.local()
+
+# the last part of a duration event's name -> what it is billed as
+_EVENTS = {
+    "jaxpr_trace_duration": "trace_s",
+    "jaxpr_to_mlir_module_duration": "lower_s",
+    "backend_compile_duration": "compile_s",
+    "cache_retrieval_time_sec": "cache_read_s",
+}
 
 
 @contextlib.contextmanager
@@ -230,22 +261,120 @@ def compile_stage(label: str) -> Iterator[None]:
         _COMPILE_STAGE.reset(token)
 
 
-def _on_compile_event(event: str, secs: float, **_kw) -> None:
-    if not event.endswith("backend_compile_duration"):
+class _Bill:
+    """What JAX did for one start-up phase, from its own events.
+
+    ``trace_s`` counts the outermost traces only: a jit traced inside
+    another's trace reports first and is inside the outer one's seconds.
+    ``cache_load_s`` is the whole ``backend_compile_duration`` of a cache
+    hit (the key's hash, the read, the deserialisation), ``cache_read_s``
+    the read inside it. ``retraced``: trace events whose function an
+    EARLIER phase of this (L, B) traced already: a body paid twice."""
+
+    __slots__ = ("secs", "traces", "retraced", "hits", "compiles",
+                 "_names", "_before", "_nest")
+
+    def __init__(self, before: set):
+        self.secs = dict.fromkeys(
+            ("trace_s", "lower_s", "compile_s", "cache_load_s",
+             "cache_read_s"), 0.0)
+        self.traces = self.retraced = self.hits = self.compiles = 0
+        self._names: set = set()
+        self._before = before
+        self._nest: list[tuple[float, float]] = []  # (start, seconds)
+
+    def traced(self, secs: float, fun_name: Any) -> None:
+        start = time.perf_counter() - secs
+        while self._nest and self._nest[-1][0] >= start - 1e-4:
+            self.secs["trace_s"] -= self._nest.pop()[1]
+        self._nest.append((start, secs))
+        self.secs["trace_s"] += secs
+        self.traces += 1
+        self.retraced += fun_name in self._before
+        self._names.add(fun_name)
+
+    def compiled(self, secs: float, hit: bool) -> None:
+        self.secs["cache_load_s" if hit else "compile_s"] += secs
+        self.hits += hit
+        self.compiles += not hit
+
+    def close(self) -> dict:
+        self._before |= self._names
+        return {**self.secs, "cache_hit": int(self.hits > 0),
+                "compiles": self.compiles, "traces": self.traces,
+                "retraced": self.retraced}
+
+
+@contextlib.contextmanager
+def billed(name: str, **stats: Any) -> Iterator[trace.phase]:
+    """The start-up phase ``name`` (``startup.executable``,
+    ``startup.inventory``) of the executable ``stats`` names by
+    ``l_bucket`` / ``b_bucket``, with JAX's events on this thread billed to
+    it: at close the phase carries :class:`_Bill`'s numbers."""
+    record = trace.startup
+    with record.phase(name, **stats) as ph:
+        bill = _Bill(record.traced.setdefault(
+            (stats.get("l_bucket"), stats.get("b_bucket")), set()))
+        token = _BILL.set(bill)
+        try:
+            yield ph
+        finally:
+            _BILL.reset(token)
+            ph.set(**bill.close())
+
+
+def hear_compile_events() -> None:
+    """Register the one ``jax.monitoring`` hook, once a process, whoever
+    asks first (the start-up trace opening, a profiler being armed)."""
+    global _HOOK_REGISTERED
+    with _HOOK_MU:
+        if _HOOK_REGISTERED:
+            return
+        import jax.monitoring as monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_compile_event)
+        monitoring.register_event_listener(_on_cache_event)
+        _HOOK_REGISTERED = True
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    if event.endswith("/compilation_cache/cache_hits"):
+        _THREAD.cache_hit = True
+
+
+def _on_compile_event(event: str, secs: float, **kw) -> None:
+    part = _EVENTS.get(event.rpartition("/")[2])
+    if part is None:
         return
-    target = _COMPILE_TARGET() if _COMPILE_TARGET is not None else None
-    if target is not None:
-        target._record_compile(secs)
+    bill = _BILL.get()
+    if part == "compile_s":  # the one event the armed profiler hears too
+        hit = getattr(_THREAD, "cache_hit", False)
+        _THREAD.cache_hit = False
+        if bill is not None:
+            bill.compiled(secs, hit)
+        target = _COMPILE_TARGET() if _COMPILE_TARGET is not None else None
+        if target is not None:
+            target._record_compile(secs, hit)
+    elif bill is not None:
+        if part == "trace_s":
+            bill.traced(secs, kw.get("fun_name"))
+        else:
+            bill.secs[part] += secs
 
 
-def record_synthetic_compile(secs: float) -> None:
-    """Feed one synthetic backend_compile event to the armed profiler —
+def record_synthetic_compile(secs: float, cache_hit: bool = False) -> None:
+    """Feed one synthetic backend_compile event through the hook —
     the injection point the ``compile_stall`` device fault
     (runtime/faults.py) uses so a CPU CI drill moves the same
     compile-storm signal a real re-trace storm would. Bills to the
-    active :func:`compile_stage` label like any real compile. No-op when
-    no profiler armed the listener."""
-    _on_compile_event("backend_compile_duration", float(secs))
+    active :func:`compile_stage` label and the open :func:`billed` phase
+    like any real compile; ``cache_hit`` sends the cache's event ahead of
+    it, as a warm start does. The profiler's side is a no-op when none
+    armed the listener."""
+    if cache_hit:
+        _on_cache_event("/jax/compilation_cache/cache_hits")
+    _on_compile_event("/jax/core/compile/backend_compile_duration",
+                      float(secs))
 
 
 class StageProfiler:
@@ -268,7 +397,8 @@ class StageProfiler:
         # stage label -> digest (see compile_stage): the per-stage compile
         # attribution the Device board and incident bundles read
         self._compile_stages: dict[str, LatencyDigest] = {}
-        self._compile_armed = False
+        # stage label -> backend_compile events that were cache loads
+        self._cache_loads: dict[str, int] = {}
         self.registry = registry
         self._g_stage = self._c_compile = self._c_compile_s = None
         self._c_compile_stage_s = None
@@ -280,9 +410,10 @@ class StageProfiler:
             )
             self._c_compile = registry.counter(
                 "ccfd_xla_compile_events_total",
-                "XLA backend_compile events attributed to this process "
-                "(jax.monitoring hook; a mid-traffic compile explains a "
-                "stage p99 spike)",
+                "XLA backend_compile events attributed to this process, by "
+                "cache: miss = a compile, hit = a load from the persistent "
+                "cache (jax.monitoring hook; a mid-traffic compile "
+                "explains a stage p99 spike)",
             )
             # true counters (ccfd-lint metric-naming): a *_total gauge
             # set() out of order moves the series backwards, which
@@ -357,45 +488,50 @@ class StageProfiler:
     def arm_compile_listener(self) -> bool:
         """Attribute XLA backend compiles via ``jax.monitoring``. The jax
         registration is process-global with no unregister, so exactly ONE
-        module-level hook ever registers; it forwards to the most recently
-        armed profiler through a weakref (a torn-down platform's profiler
-        is collectable and stops receiving events — newest wins, exactly
-        like supervisor respawns elsewhere)."""
-        global _COMPILE_TARGET, _COMPILE_HOOK_REGISTERED
-        if not self._compile_armed:
-            import jax.monitoring as monitoring
-
-            if not _COMPILE_HOOK_REGISTERED:
-                monitoring.register_event_duration_secs_listener(
-                    _on_compile_event)
-                _COMPILE_HOOK_REGISTERED = True
-            self._compile_armed = True
+        module-level hook ever registers (:func:`hear_compile_events`); it
+        forwards to the most recently armed profiler through a weakref (a
+        torn-down platform's profiler is collectable and stops receiving
+        events — newest wins, exactly like supervisor respawns
+        elsewhere)."""
+        global _COMPILE_TARGET
+        hear_compile_events()
         _COMPILE_TARGET = weakref.ref(self)
         return True
 
-    def _record_compile(self, secs: float) -> None:
+    def _record_compile(self, secs: float, cache_hit: bool = False) -> None:
         stage = _COMPILE_STAGE.get()
         with self._compile_mu:
+            if cache_hit:  # a load: no compile, no compile seconds
+                self._cache_loads[stage] = self._cache_loads.get(stage, 0) + 1
+                if self._c_compile is not None:
+                    self._c_compile.inc(labels={"cache": "hit"})
+                return
             self._compile.add(float(secs))
             d = self._compile_stages.get(stage)
             if d is None:
                 d = self._compile_stages[stage] = LatencyDigest()
             d.add(float(secs))
             if self._c_compile is not None:
-                self._c_compile.inc()
+                self._c_compile.inc(labels={"cache": "miss"})
                 self._c_compile_s.inc(float(secs))
                 self._c_compile_stage_s.inc(float(secs),
                                             labels={"stage": stage})
 
-    def compile_counts(self) -> dict[str, int]:
+    def compile_counts(self, cache: str = "miss") -> dict[str, int]:
         """Per-stage compile-event counts (``total`` included) — the cheap
         read the DeviceSupervisor's compile-storm signal and the heal
         drills' warm-re-promotion assertions diff per tick, without
-        paying a full :meth:`snapshot`."""
+        paying a full :meth:`snapshot`. ``cache="hit"``: the events that
+        were loads from the persistent cache instead, which no caller
+        counts as a compile."""
         with self._compile_mu:
-            out = {stage: d.count
-                   for stage, d in self._compile_stages.items()}
-            out["total"] = self._compile.count
+            if cache == "hit":
+                out = dict(self._cache_loads)
+                out["total"] = sum(self._cache_loads.values())
+            else:
+                out = {stage: d.count
+                       for stage, d in self._compile_stages.items()}
+                out["total"] = self._compile.count
         return out
 
     @contextlib.contextmanager

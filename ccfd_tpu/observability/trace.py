@@ -46,6 +46,21 @@ SLOs need per-stage critical-path visibility, not endpoint histograms):
   ``batch`` on its ``router.*`` phases, ``seq_batch`` on what the scorer
   does for it, whichever call or thread that is.
 
+- **The start-up trace** — :data:`startup`, one record a process: a root
+  span ``startup`` that begins where the OS says the process began, and
+  under it every ``startup.*`` phase the building of the service opens
+  (``startup.head``: interpreter, imports, the accelerator's runtime, up
+  to the program's first phase; ``startup.weights``, ``startup.store``,
+  ``startup.executable`` once an (L, B) with JAX's own trace, lower,
+  compile and cache-load seconds (``observability/profile.py``'s one
+  ``jax.monitoring`` hook bills them), ``startup.inventory``,
+  ``startup.restore``, ``startup.gc``, ``startup.router``,
+  ``startup.platform``). Nobody opens it: :meth:`Startup.phase` does at a
+  process's first such phase. Every span of it is kept (no sampling; a
+  bounded list, read after the fact), and each carries its
+  ``perf_counter`` start (:attr:`Span.t0`) beside the wall one, so the
+  record lays over any host-clock mark without conversion.
+
 Span context is tracked per-thread via ``contextvars``; pipelined code that
 hops threads (the router's score worker) passes ``parent=`` explicitly.
 """
@@ -55,6 +70,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
+import functools
 import os
 import threading
 import time
@@ -182,6 +198,12 @@ class Span:
     @property
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id)
+
+    @property
+    def t0(self) -> float:
+        """The span's start on ``time.perf_counter`` (the clock durations
+        are taken on): what lays it beside another host-clock mark."""
+        return self._t0
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -537,3 +559,156 @@ class phase:  # noqa: N801 - reads as a statement: ``with phase("seq.pad"):``
             self._tracer.finish(
                 self.span, "error" if exc_type is not None else None,
                 duration_s=self.seconds)
+
+
+# -- the start-up trace --------------------------------------------------------
+
+_IMPORTED = time.perf_counter()  # the process's start where the OS hides it
+
+
+def _process_start() -> float:
+    """The process's start on ``time.perf_counter``: ``/proc/self/stat``'s
+    field 22 (start, in ticks since boot) against ``CLOCK_BOOTTIME``, to
+    the tick (10 ms). Where that cannot be read, or reads later than this
+    module's import, the import."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            fields = f.read().rsplit(b")", 1)[1].split()  # from field 3 on
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - int(fields[19]) / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return _IMPORTED
+    began = time.perf_counter() - age
+    return began if began <= _IMPORTED else _IMPORTED
+
+
+class _Kept:
+    """The start-up record's sink: every span of the one trace in the
+    order it closed, none sampled away, and no more than ``CAP`` (a
+    process that keeps building scorers stops adding to it)."""
+
+    CAP = 1024
+
+    def __init__(self) -> None:
+        self.spans: list[Span] = []
+        self.dropped = 0
+
+    def add(self, span: Span) -> None:
+        if len(self.spans) < self.CAP:
+            self.spans.append(span)  # GIL-atomic: any thread may close one
+        else:
+            self.dropped += 1
+
+
+class Startup:
+    """One process's start-up trace (module docstring): the root span, the
+    tracer that records under it, and the two instants that end it.
+
+    ``phase(name, **stats)`` is the one way in. The first call opens the
+    record: the root ``startup`` from the process's start, a closed child
+    ``startup.head`` from there to now, and the ``jax.monitoring`` hook
+    that bills JAX's trace, lower, compile and cache-load events to the
+    innermost ``startup.executable`` / ``startup.inventory``
+    (``observability/profile.py``). A phase opened while another start-up
+    phase is open on the thread is that one's child; any other is the
+    root's, handed over as ``tracer=`` / ``parent=``, so nothing is left
+    set in a thread's context and no ``router.*`` / ``seq.*`` phase ever
+    parents on the root.
+
+    ``ready()`` closes the root (once: the first ``Router.start()``
+    returning, or ``Platform.up()``); ``first_verdict()`` stamps once. A
+    ``startup.*`` phase that opens later (an inventory first asked for
+    after the build, a swap's executables) still lands in the record,
+    after the root's end. ``t0``: the root's start for a record built by
+    hand (tests); left out, the process's.
+    """
+
+    def __init__(self, t0: float | None = None):
+        self._given_t0 = t0
+        self._lock = threading.Lock()
+        self._kept = _Kept()
+        self.tracer: Tracer | None = None
+        self.root: Span | None = None
+        # (l_bucket, b_bucket) -> the functions JAX traced for it in the
+        # phases closed so far (observability/profile.py::billed)
+        self.traced: dict[tuple, set] = {}
+        self.ready_at: float | None = None          # perf_counter
+        self.first_verdict_at: float | None = None  # perf_counter
+
+    def _open(self) -> None:
+        from ccfd_tpu.observability import profile
+
+        with self._lock:
+            if self.root is not None:
+                return
+            now = time.perf_counter()
+            t0 = self._given_t0 if self._given_t0 is not None \
+                else _process_start()
+            tracer = Tracer(component="startup", sink=self._kept)
+            # ccfd-lint: disable=monotonic-durations -- no duration: the wall-clock instant of a monotonic start (a span's ``start`` is wall by contract)
+            began_wall = time.time() - (now - t0)
+            root = Span(new_trace_id(), new_span_id(), None, "startup",
+                        "startup", began_wall)
+            root._t0 = t0
+            head = Span(root.trace_id, new_span_id(), root.span_id,
+                        "startup.head", "startup", root.start)
+            head._t0 = t0
+            tracer.finish(head, duration_s=now - t0)
+            self.tracer, self.root = tracer, root
+        profile.hear_compile_events()
+
+    def phase(self, name: str, **stats: Any) -> phase:
+        """``phase(name, **stats)`` under the record (class docstring)."""
+        if self.root is None:
+            self._open()
+        cur = _current.get()
+        if cur is not None and cur[1] is self.tracer:
+            return phase(name, **stats)
+        return phase(name, tracer=self.tracer, parent=self.root.context,
+                     **stats)
+
+    def ready(self) -> None:
+        """The service is built: the root closes here, once."""
+        with self._lock:
+            if self.root is None or self.ready_at is not None:
+                return
+            self.ready_at = time.perf_counter()
+            self.tracer.finish(self.root,
+                               duration_s=self.ready_at - self.root.t0)
+
+    def first_verdict(self) -> None:
+        """The first routed batch has left the router: stamped once."""
+        if self.first_verdict_at is None:
+            self.first_verdict_at = time.perf_counter()
+
+    def spans(self) -> list[Span]:
+        """Every closed span of the record, in the order they closed."""
+        return list(self._kept.spans)
+
+    def seconds(self) -> dict[str, float]:
+        """Seconds by phase over the closed spans, the ``startup.`` prefix
+        dropped and the root as ``total``: what the operator's
+        ``ccfd_startup_seconds{phase}`` is set from."""
+        out: dict[str, float] = {}
+        for sp in self._kept.spans:
+            key = "total" if sp.parent_id is None else sp.name.partition(
+                ".")[2]
+            out[key] = out.get(key, 0.0) + sp.duration_s
+        return out
+
+
+# the process's record: code reads ``trace.startup`` where it runs, so a
+# test can stand a fresh one in its place
+startup = Startup()
+
+
+def startup_phase(name: str):
+    """Decorator: every call is the start-up phase ``name`` of the
+    process's record (a constructor that is a phase from end to end)."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def inside(*args, **kwargs):
+            with startup.phase(name):
+                return fn(*args, **kwargs)
+        return inside
+    return decorate

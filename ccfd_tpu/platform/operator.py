@@ -193,10 +193,33 @@ class Platform:
 
     # -- bring-up, in the run-book's dependency order ---------------------
     def up(self, wait_ready_s: float = 30.0) -> "Platform":
-        from ccfd_tpu.runtime.supervisor import Supervisor
-
+        """Build the platform: one ``startup.platform`` phase of the
+        process's start-up trace (``observability/trace.py``), whose end
+        is the trace's ``ready()``; then ``ccfd_startup_seconds{phase}``
+        is set from its closed spans and the trace is handed to the
+        platform's span sink, which serves it at ``/traces/<id>``."""
         if self._up:
             return self
+        from ccfd_tpu.observability import trace
+
+        record = trace.startup
+        with record.phase("startup.platform"):
+            self._build(wait_ready_s)
+        record.ready()
+        seconds = self._registry("startup").gauge(
+            "ccfd_startup_seconds",
+            "seconds of this process's start-up by phase (process start "
+            "to ready = total; head = before the program's first phase)")
+        for name, secs in record.seconds().items():
+            seconds.set(secs, labels={"phase": name})
+        if self.trace_sink is not None:
+            for span in record.spans():
+                self.trace_sink.add(span)
+        return self
+
+    def _build(self, wait_ready_s: float) -> None:
+        from ccfd_tpu.runtime.supervisor import Supervisor
+
         spec, cfg = self.spec, self.cfg
         self.supervisor = Supervisor()
 
@@ -755,7 +778,6 @@ class Platform:
             ).start()
 
         self._up = True
-        return self
 
     # -- per-component builders -------------------------------------------
     def _registry(self, name: str):

@@ -49,6 +49,7 @@ from ccfd_tpu.config import Config
 from ccfd_tpu.data.ccfd import FEATURE_NAMES
 from ccfd_tpu.metrics.prom import Registry
 from ccfd_tpu.native import decode_csv as native_decode_csv
+from ccfd_tpu.observability import trace
 from ccfd_tpu.observability.trace import extract_context, phase
 from ccfd_tpu.process.fraud import CUSTOMER_RESPONSE_SIGNAL
 from ccfd_tpu.router.rules import RuleSet, default_rules
@@ -321,6 +322,7 @@ def _host_scores(scores: Any) -> Any:
 
 
 class Router:
+    @trace.startup_phase("startup.router")
     def __init__(
         self,
         cfg: Config,
@@ -586,6 +588,9 @@ class Router:
         # router.* phase of one batch carries, so a capture's two host
         # lines are joined by it and not by order
         self._batches = 0
+        # the process's start-up record until this router's first routed
+        # batch has left (its ``first_verdict`` stamp), None from then on
+        self._startup = trace.startup
         self._stop = threading.Event()
         # checkpoint barrier (runtime/recovery.py): pause() parks the run
         # loop at a batch boundary — consumed records fully routed into the
@@ -1060,8 +1065,12 @@ class Router:
             # would orphan the engine/notify leg
             with self._stage("router.route", batch_span, len(txs),
                              batch=batch) as ph:
-                return self._route_inner(x, txs, proba, ts, batch_span,
-                                         ph.span, meta, fired)
+                n = self._route_inner(x, txs, proba, ts, batch_span,
+                                      ph.span, meta, fired)
+            if self._startup is not None:
+                self._startup.first_verdict()
+                self._startup = None
+            return n
         finally:
             if self._profiler is not None:
                 self._profiler.observe(
@@ -1521,13 +1530,16 @@ class Router:
     def start(
         self, poll_timeout_s: float = 0.05, pipeline: bool = True
     ) -> threading.Thread:
-        # direct (unsupervised) start: re-arm here, before the thread exists
-        self.reset()
-        t = threading.Thread(
-            target=self.run, args=(poll_timeout_s, pipeline),
-            daemon=True, name="ccfd-router",
-        )
-        t.start()
+        with trace.startup.phase("startup.router", threads=1):
+            # direct (unsupervised) start: re-arm here, before the thread
+            # exists
+            self.reset()
+            t = threading.Thread(
+                target=self.run, args=(poll_timeout_s, pipeline),
+                daemon=True, name="ccfd-router",
+            )
+            t.start()
+        trace.startup.ready()  # the first router started ends the build
         return t
 
     def stop(self) -> None:

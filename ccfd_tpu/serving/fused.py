@@ -330,7 +330,7 @@ class FusedDecisionScorer:
 
     def _precompile(self, params: Any, fused_params: Any,
                     preq_norm: Any) -> None:
-        from ccfd_tpu.observability.profile import compile_stage
+        from ccfd_tpu.observability.profile import billed, compile_stage
 
         preq = self._preq_live(fused_params, preq_norm)
         fn = self._fn_preq() if preq else self._fn_for(fused_params)
@@ -338,9 +338,10 @@ class FusedDecisionScorer:
         base = self._base
         with compile_stage("fused.warm"):
             for b in base.batch_sizes:
-                zeros = np.zeros((b, base.num_features), np.float32)
-                jax.block_until_ready(
-                    self._dispatch_one(fn, which, zeros, preq, preq_norm))
+                with billed("startup.executable", b_bucket=int(b)):
+                    zeros = np.zeros((b, base.num_features), np.float32)
+                    jax.block_until_ready(self._dispatch_one(
+                        fn, which, zeros, preq, preq_norm))
         self._disabled = False  # the whole grid compiled and ran: (re-)armed
 
     # -- observability -------------------------------------------------------

@@ -93,6 +93,7 @@ from typing import Any
 import numpy as np
 
 from ccfd_tpu.data.ccfd import NUM_FEATURES
+from ccfd_tpu.observability import trace
 from ccfd_tpu.observability.trace import phase
 from ccfd_tpu.runtime.faults import device_seam
 
@@ -517,7 +518,15 @@ class HistoryStore:
         restore — replay from offset 0 rebuilds everything). The
         generation bumps LAST, so a prepare racing this call either sees
         the old generation (its commit is dropped) or the fully-restored
-        state."""
+        state. A ``startup.restore`` phase: a service restarts from its
+        checkpoint through here."""
+        customers = len(snap["customers"]) if snap is not None else 0
+        with trace.startup.phase(
+                "startup.restore", customers=customers,
+                bytes=customers * self.length * self.num_features * 4):
+            self._restore(snap)
+
+    def _restore(self, snap: dict | None) -> None:
         L = self.length
         with self._commit_lock:
             for st in self._stripes:
@@ -690,6 +699,7 @@ class _Program:
         if got is None:
             import jax
 
+            from ccfd_tpu.observability.profile import billed
             from ccfd_tpu.ops import kernels
 
             shape = jax.ShapeDtypeStruct
@@ -698,8 +708,11 @@ class _Program:
                                      // _WIRE_LANES, _WIRE_LANES))
                         if self.flat_wire(lb)
                         else (self.fn, (b, lb, self.num_features)))
-            got = self._held[(lb, b)] = kernels.held(
-                fn, params, shape(hist, np.float32), *extra)
+            with billed("startup.inventory", l_bucket=int(lb),
+                        b_bucket=int(b)) as ph:
+                got = self._held[(lb, b)] = kernels.held(
+                    fn, params, shape(hist, np.float32), *extra)
+                ph.set(**got)
         return got
 
 
@@ -802,8 +815,12 @@ class SeqScorer:
         import jax
         import jax.numpy as jnp
 
-        self.store = HistoryStore(length=length, max_customers=max_customers,
-                                  stripes=stripes)
+        with trace.startup.phase(
+                "startup.store",
+                bytes=max_customers * length * NUM_FEATURES * 4):
+            self.store = HistoryStore(length=length,
+                                      max_customers=max_customers,
+                                      stripes=stripes)
         # recycled (largest bucket, L, F) staging batches: a batch takes
         # at most inflight + 1 and puts them back once it is committed, so
         # the list is bounded by what is in flight (deque.pop / extend are
@@ -885,11 +902,19 @@ class SeqScorer:
                 max(1, -(-b // dsize)) * dsize for b in batch_sizes
             )
             self._part_axes = part_axes
-            # param layout: the partitioner's (rule table under `rules`,
-            # replicated under dp); legacy bare-mesh callers replicate
-            params = jax.device_put(params, self._param_layout(params))
             self._batch_sharding = NamedSharding(
                 mesh, PartitionSpec(part_axes, None, None))
+        with trace.startup.phase("startup.weights") as ph:
+            if mesh is not None:
+                # param layout: the partitioner's (rule table under
+                # `rules`, replicated under dp); legacy bare-mesh callers
+                # replicate
+                params = jax.device_put(params, self._param_layout(params))
+            # whoever drew or loaded the tree may still be writing it: the
+            # wait is the weights', not the first executable's first run
+            leaves = jax.block_until_ready(jax.tree.leaves(params))
+            ph.set(leaves=len(leaves),
+                   bytes=sum(getattr(x, "nbytes", 0) for x in leaves))
         self.params = params
         self.batch_sizes = tuple(sorted(set(batch_sizes)))
         self._jax = jax
@@ -1186,14 +1211,25 @@ class SeqScorer:
                 self._apply = new_apply
 
     def _run_grid(self, apply_fn: Any, params: Any, family: Any) -> None:
-        """Every (B bucket, L bucket) executable once, on zeros."""
+        """Every (B bucket, L bucket) executable once, on zeros: a
+        ``startup.executable`` phase each, which carries at close what
+        JAX traced, lowered, compiled or loaded for it
+        (``observability/profile.py::billed``); what is left of the phase
+        is the first run and its wait."""
+        from ccfd_tpu.observability.profile import billed
+
         for b in self.batch_sizes:
             for lb in self.len_buckets:
-                xs = np.zeros((b, lb, self.store.num_features), np.float32)
-                extra = ((np.zeros((b,), np.int32),)
-                         if family.reads_filled else ())
-                self._jax.block_until_ready(
-                    apply_fn(params, self._put_hist(xs), *extra))
+                with billed("startup.executable", l_bucket=int(lb),
+                            b_bucket=int(b),
+                            flat_wire=int(_takes_flat_wire(apply_fn, lb)),
+                            **self._scan_chunk(lb)):
+                    xs = np.zeros((b, lb, self.store.num_features),
+                                  np.float32)
+                    extra = ((np.zeros((b,), np.int32),)
+                             if family.reads_filled else ())
+                    self._jax.block_until_ready(
+                        apply_fn(params, self._put_hist(xs), *extra))
 
     def warmup(self) -> None:
         """Compile every (B bucket, L bucket) executable the ladder can

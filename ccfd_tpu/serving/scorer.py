@@ -492,14 +492,21 @@ class Scorer:
     def _warmup_body(self) -> None:
         zeros = np.zeros((max(self.batch_sizes), self.num_features),
                          np.float32)
+        from ccfd_tpu.observability.profile import billed
+
         for b in self.batch_sizes:
-            if self._fused_params is not None:
-                # through _fused_dispatch so the SERVING wire path
-                # (incl. the q8 int8 wire) is what compiles here
-                out = self._fused_dispatch(self._fused_params, zeros[:b])
-            else:
-                out = self._apply(self._params, self._put_batch(zeros[:b]))
-            jax.block_until_ready(out)
+            # the finer name under ``scorer.warmup``: what JAX traced,
+            # lowered, compiled or loaded for this bucket's executable
+            with billed("startup.executable", b_bucket=int(b)):
+                if self._fused_params is not None:
+                    # through _fused_dispatch so the SERVING wire path
+                    # (incl. the q8 int8 wire) is what compiles here
+                    out = self._fused_dispatch(self._fused_params,
+                                               zeros[:b])
+                else:
+                    out = self._apply(self._params,
+                                      self._put_batch(zeros[:b]))
+                jax.block_until_ready(out)
 
     def set_swap_gate(self, gate: Any) -> None:
         """Arm the partitioner's publish gate: every ``swap_params`` then

@@ -19,8 +19,11 @@ defaults untouched).
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import os
+
+from ccfd_tpu.observability import trace
 
 
 def tune_for_service(gen0: int | None = None) -> bool:
@@ -35,10 +38,17 @@ def tune_for_service(gen0: int | None = None) -> bool:
         gen0 = 100_000
     if gen0 <= 0:
         return False
-    # collect once so freeze() moves a clean startup set to the permanent
-    # generation (imports, compiled-executable wrappers, registries)
-    gc.collect()
-    gc.freeze()
-    _, g1, g2 = gc.get_threshold()
-    gc.set_threshold(gen0, g1, g2)
+    # a ``startup.gc`` phase where the process's start-up trace is open
+    # (one that built a scorer); the JAX-free services call this too, and
+    # a phase would import JAX there
+    record = trace.startup
+    with (record.phase("startup.gc") if record.root is not None
+          else contextlib.nullcontext()):
+        # collect once so freeze() moves a clean startup set to the
+        # permanent generation (imports, compiled-executable wrappers,
+        # registries)
+        gc.collect()
+        gc.freeze()
+        _, g1, g2 = gc.get_threshold()
+        gc.set_threshold(gen0, g1, g2)
     return True
